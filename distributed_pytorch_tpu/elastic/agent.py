@@ -80,6 +80,10 @@ from typing import Dict, List, Optional
 from distributed_pytorch_tpu.chaos import FaultProxy, get_plan as _get_fault_plan
 from distributed_pytorch_tpu.elastic.store import KVStoreClient, KVStoreServer
 from distributed_pytorch_tpu.obs import MetricsRegistry
+from distributed_pytorch_tpu.utils.platform import (
+    local_tpu_chips,
+    workers_pinned_to_cpu,
+)
 
 GEN_KEY = "tpurun/generation"  # bumped on every failure -> restart-the-world
 FATAL_KEY = "tpurun/fatal"  # set when restarts are exhausted or world aborts
@@ -361,10 +365,33 @@ class WorldCompleted(Exception):
         self.finished = finished
 
 
+def _refuse_shared_tpu(cfg: ElasticConfig) -> None:
+    """Several workers on one TPU host cannot work today: workers are not
+    assigned chips, so each asks libtpu for every local chip, the first to
+    start takes them all and the rest die with "The TPU is already in use"
+    — which the agent would then restart ``max_restarts`` times. Refuse
+    before anything is spawned. One worker per host drives all of its
+    chips; workers pinned to the CPU are unaffected."""
+    if cfg.nproc_per_node <= 1:
+        return
+    env = {**os.environ, **cfg.env}
+    chips = local_tpu_chips()
+    if chips and not workers_pinned_to_cpu(env):
+        raise SystemExit(
+            f"tpurun: --nproc-per-node {cfg.nproc_per_node} on a host with "
+            f"{chips} TPU chip(s): a chip belongs to one process and "
+            "workers are not assigned chips of their own, so every worker "
+            "after the first would fail to open the TPU. Use "
+            "--nproc-per-node 1 (one process drives all local chips), or "
+            "set JAX_PLATFORMS=cpu for a CPU run."
+        )
+
+
 class ElasticAgent:
     """One per node. Runs the rendezvous/spawn/monitor/restart loop."""
 
     def __init__(self, cfg: ElasticConfig, cmd: List[str]):
+        _refuse_shared_tpu(cfg)
         self.cfg = cfg
         self.cmd = cmd
         self.server: Optional[KVStoreServer] = None

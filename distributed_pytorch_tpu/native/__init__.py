@@ -3,8 +3,11 @@
 The reference inherits all of its native capability from the ``torch`` wheel
 (SURVEY.md §2a); this package is where our framework's own native runtime
 lives. Sources are compiled on first use with ``g++`` into ``_build/`` next to
-this file and cached by source mtime, so there is no separate install step
-(mirroring the zero-setup character of the reference scripts).
+this file, so there is no separate install step (mirroring the zero-setup
+character of the reference scripts). Each artifact's file name carries a
+hash of the source it was built from, so a binary is only ever reused for
+the exact source text that produced it — whatever the files' mtimes say
+after a copy or a checkout.
 
 Components:
 
@@ -18,6 +21,7 @@ Components:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -37,8 +41,6 @@ def _build_dir() -> str:
     preferred = os.path.join(_NATIVE_DIR, "_build")
     if os.access(_NATIVE_DIR, os.W_OK):
         return preferred
-    import hashlib
-
     key = hashlib.sha256(_NATIVE_DIR.encode()).hexdigest()[:16]
     cache_root = os.environ.get(
         "XDG_CACHE_HOME", os.path.join(os.path.expanduser("~"), ".cache")
@@ -46,17 +48,17 @@ def _build_dir() -> str:
     return os.path.join(cache_root, "distributed_pytorch_tpu", key)
 
 
-def _needs_rebuild(src: str, out: str) -> bool:
-    return not os.path.exists(out) or os.path.getmtime(out) < os.path.getmtime(src)
-
-
 def _compile(src_name: str, out_name: str, *, shared: bool) -> str:
-    """Compile ``src_name`` (in this dir) to ``_build/out_name`` if stale."""
+    """Compile ``src_name`` (in this dir) to ``_build/<out_name>`` keyed by
+    the source's content hash, unless that exact build already exists."""
     src = os.path.join(_NATIVE_DIR, src_name)
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    stem, ext = os.path.splitext(out_name)
     build_dir = _build_dir()
-    out = os.path.join(build_dir, out_name)
+    out = os.path.join(build_dir, f"{stem}-{digest}{ext}")
     with _BUILD_LOCK:
-        if not _needs_rebuild(src, out):
+        if os.path.exists(out):
             return out
         os.makedirs(build_dir, exist_ok=True)
         cmd = ["g++", "-O2", "-std=c++17", "-pthread"]

@@ -38,7 +38,9 @@ import numpy as np
 # ----------------------------------------------------------------- peak FLOPs
 
 # Peak bf16 FLOP/s per chip by generation (public spec sheets). Used as the
-# MFU denominator; unknown kinds fall back to v5e-class DEFAULT_PEAK.
+# MFU denominator. A TPU whose kind matches no entry is an error; the CPU
+# and kind-less test devices get DEFAULT_PEAK (the goodput accounting tests
+# run there and need a denominator, not a meaningful one).
 PEAK_BF16_FLOPS = {
     "v6": 918e12,
     "v5p": 459e12,
@@ -51,13 +53,27 @@ PEAK_BF16_FLOPS = {
 DEFAULT_PEAK = 197e12
 
 
-def peak_flops_per_chip(device) -> float:
-    """Best-effort peak bf16 FLOP/s for a jax device, by kind substring."""
+def peak_for_device(table: Dict[str, float], device, default: float) -> float:
+    """``table``'s entry whose key is a substring of ``device.device_kind``.
+    An unknown kind raises on a TPU (a chip measured against another
+    chip's peak is a wrong number, not a conservative one) and returns
+    ``default`` anywhere else."""
     kind = getattr(device, "device_kind", "").lower()
-    for key, peak in PEAK_BF16_FLOPS.items():
+    for key, peak in table.items():
         if key in kind:
             return peak
-    return DEFAULT_PEAK
+    if getattr(device, "platform", "") == "tpu" or kind.startswith("tpu"):
+        raise ValueError(
+            f"no peak recorded for TPU device kind "
+            f"{getattr(device, 'device_kind', '')!r}; add it to the table "
+            f"(known: {sorted(table)})"
+        )
+    return default
+
+
+def peak_flops_per_chip(device) -> float:
+    """Peak bf16 FLOP/s for a jax device, by kind substring."""
+    return peak_for_device(PEAK_BF16_FLOPS, device, DEFAULT_PEAK)
 
 
 # ---------------------------------------------------------------- FLOPs model

@@ -43,11 +43,12 @@ from __future__ import annotations
 import time
 from typing import Callable, Dict, List, Optional
 
-from .goodput import peak_flops_per_chip
+from .goodput import peak_flops_per_chip, peak_for_device
 
 # Peak HBM bandwidth per chip by generation, bytes/second (public spec
 # sheets; v5e 819 GB/s matches tools/mfu_probe.py's historical default).
-# Unknown kinds fall back to v5e-class DEFAULT_HBM_BW.
+# An unknown TPU kind is an error; other devices get DEFAULT_HBM_BW (see
+# goodput.peak_for_device).
 HBM_BYTES_PER_SEC = {
     "v6": 1640e9,
     "v5p": 2765e9,
@@ -69,13 +70,9 @@ FUSED_PROGRAM_PREFIXES = ("decode_step_paged",)
 
 
 def hbm_bandwidth_per_chip(device) -> float:
-    """Best-effort peak HBM bytes/sec for a jax device, by kind substring
-    (mirrors :func:`~.goodput.peak_flops_per_chip`)."""
-    kind = getattr(device, "device_kind", "").lower()
-    for key, bw in HBM_BYTES_PER_SEC.items():
-        if key in kind:
-            return bw
-    return DEFAULT_HBM_BW
+    """Peak HBM bytes/sec for a jax device, by kind substring (mirrors
+    :func:`~.goodput.peak_flops_per_chip`)."""
+    return peak_for_device(HBM_BYTES_PER_SEC, device, DEFAULT_HBM_BW)
 
 
 def roofline_point(

@@ -270,8 +270,6 @@ class ProgramLedger:
             cost = compiled.cost_analysis()
         except Exception:
             cost = None
-        if isinstance(cost, (list, tuple)) and cost:
-            cost = cost[0]
         if isinstance(cost, dict):
             record.flops = float(cost.get("flops", 0.0) or 0.0)
 

@@ -69,25 +69,6 @@ from distributed_pytorch_tpu.utils.platform import on_tpu
 KERNEL_MODES = ("auto", "pallas", "interpret", "xla")
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across the JAX versions this repo meets: the top-level
-    ``jax.shard_map`` (with ``check_vma``) when present, else the
-    ``jax.experimental`` original (with ``check_rep``). Unlike the training
-    kernels' mesh paths, this one runs on the CPU test rig (interpret-mode
-    parity matrix), so it cannot assume the newest API."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def resolve_kernel(kernel) -> str:
     """Settle a ``kernel=`` toggle to a concrete mode.
 
@@ -425,7 +406,11 @@ def paged_attention(
         ks, vs = (a[5], a[6]) if len(a) == 7 else (None, None)
         return run(a[0], a[1], a[2], a[3], a[4], ks, vs)
 
-    out3 = _shard_map(
-        local, mesh, tuple(specs), P(None, heads_axis, None)
+    out3 = jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=tuple(specs),
+        out_specs=P(None, heads_axis, None),
+        check_vma=False,
     )(*args)
     return out3.reshape(s, 1, h, d)

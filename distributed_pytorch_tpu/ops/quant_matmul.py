@@ -5,8 +5,8 @@ read from HBM as **int8**, converted and scaled in VMEM registers, and fed
 straight to the MXU — the bf16/f32 weight tensor never exists in HBM. This
 is the fallback for the case where XLA chooses to materialize the dequant
 instead of fusing it into the dot (observed on the CPU backend; the TPU
-fusion A/B is ``tools/decode_bench.py`` — see BASELINE.md "pending on-chip
-measurements"). Decode-shaped: small-batch x [B, K] against q [K, N].
+fusion A/B is ``tools/decode_bench.py``, not yet run on a chip).
+Decode-shaped: small-batch x [B, K] against q [K, N].
 
 Grid: ``(N/block_n, K/block_k)`` — K is TILED, not held whole in VMEM.
 TPU grid execution is sequential with the last dimension fastest, so each

@@ -72,10 +72,7 @@ def setup_distributed(
         # selected (gloo ships in jaxlib). Must be set before the backend
         # initializes; never touched on real TPU.
         if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-            try:
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            except Exception:
-                pass  # older jax: CPU multiprocess either works or is absent
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
@@ -85,19 +82,18 @@ def setup_distributed(
 
 
 def _on_tpu_pod() -> bool:
-    """Heuristic for 'running as one worker of a multi-HOST TPU slice': the
-    Cloud TPU runtime exports worker topology env vars on every pod VM.
+    """True when this process is one worker of a multi-HOST TPU slice: the
+    TPU runtime's ``TPU_WORKER_HOSTNAMES`` names more than one worker.
 
-    A single-host slice also exports ``TPU_WORKER_HOSTNAMES`` (one entry), and
-    there ``jax.distributed.initialize()``'s autodetection is pointless — and
-    breaks off-cloud single-host rigs with no metadata server — so when the
-    hostname list is present it must name more than one worker. Runtimes that
-    export only a task/worker id (no hostname list) are trusted to be pods.
+    Only then is ``jax.distributed.initialize()``'s autodetection wanted.
+    A single host exports the same family of variables
+    (``TPU_WORKER_ID=0``, ``TPU_WORKER_HOSTNAMES=localhost``, sometimes a
+    worker id alone); there the call has nothing to rendezvous with and,
+    with no metadata server in reach, would wait on one — so anything
+    short of a list of several hosts is a single-process run.
     """
-    hostnames = os.environ.get("TPU_WORKER_HOSTNAMES")
-    if hostnames is not None:
-        return len([h for h in hostnames.split(",") if h.strip()]) > 1
-    return any(k in os.environ for k in ("TPU_WORKER_ID", "CLOUD_TPU_TASK_ID"))
+    hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
+    return len([h for h in hostnames.split(",") if h.strip()]) > 1
 
 
 def shutdown_distributed() -> None:

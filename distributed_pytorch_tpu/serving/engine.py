@@ -546,8 +546,10 @@ class InferenceEngine:
         # refilled in place every step instead of reallocated. Rows for
         # inactive slots MUST be re-zeroed each step (a stale block-table
         # row would scatter the masked write into a page some other request
-        # now owns); jnp.asarray copies host->device, so mutating these
-        # after dispatch is safe.
+        # now owns). They go to the device through jnp.array, which always
+        # copies: jnp.asarray may ALIAS a 64-byte-aligned numpy buffer on
+        # the CPU backend, and the next step's refill would then race the
+        # still-running dispatch that reads it.
         self._stage_tokens = np.zeros((max_slots,), np.int32)
         self._stage_tables = np.zeros(
             (max_slots, self.pages_per_seq), np.int32
@@ -1464,15 +1466,15 @@ class InferenceEngine:
             self.xla.count_h2d(staged)
         # No modded rows: reuse the zeros device constant — the bias
         # operand costs the common path nothing.
-        bias_arr = self._zero_bias if bias is None else jnp.asarray(bias)
+        bias_arr = self._zero_bias if bias is None else jnp.array(bias)
         nxt, self.cache = self._decode_step(
             params, self.cache,
-            jnp.asarray(self._stage_tokens), prev,
-            jnp.asarray(self._stage_use_prev),
-            jnp.asarray(self._stage_tables),
-            jnp.asarray(self._stage_lens),
-            jnp.asarray(self._stage_temps),
-            jnp.asarray(self._stage_keys),
+            jnp.array(self._stage_tokens), prev,
+            jnp.array(self._stage_use_prev),
+            jnp.array(self._stage_tables),
+            jnp.array(self._stage_lens),
+            jnp.array(self._stage_temps),
+            jnp.array(self._stage_keys),
             bias_arr,
         )
         return nxt
@@ -1856,11 +1858,11 @@ class InferenceEngine:
                     self._spec_step(
                         self.params, self.draft_params,
                         self.cache, self.draft_cache,
-                        jnp.asarray(self._stage_tokens),
-                        jnp.asarray(self._stage_tables),
-                        jnp.asarray(self._stage_lens),
-                        jnp.asarray(self._stage_temps),
-                        jnp.asarray(self._stage_keys),
+                        jnp.array(self._stage_tokens),
+                        jnp.array(self._stage_tables),
+                        jnp.array(self._stage_lens),
+                        jnp.array(self._stage_temps),
+                        jnp.array(self._stage_keys),
                     )
                 )
                 dispatched = (

@@ -79,6 +79,10 @@ from distributed_pytorch_tpu.serving.journal import (
 )
 from distributed_pytorch_tpu.serving.engine import RequestStatus
 from distributed_pytorch_tpu.serving.scheduler import SamplingParams
+from distributed_pytorch_tpu.utils.platform import (
+    holds_accelerator,
+    workers_pinned_to_cpu,
+)
 
 _JSON = "application/json"
 
@@ -645,6 +649,19 @@ class ProcessReplicaClient(ReplicaClient):
             return
 
         child_env = dict(os.environ if env is None else env)
+        if holds_accelerator() and not workers_pinned_to_cpu(child_env):
+            # A chip belongs to one process: this one has opened it, so the
+            # worker would die at backend start-up ("already in use") or,
+            # on a host with several chips, claim all of them. Refuse now
+            # rather than after the spawn timeout.
+            raise ReplicaError(
+                f"cannot spawn replica worker {self.name}: this process "
+                "already holds the accelerator, and a worker subprocess "
+                "needs a device of its own. Start workers from a parent "
+                "that has not initialised JAX, pin them to the CPU "
+                "(JAX_PLATFORMS=cpu in env), or use in-process replicas "
+                "(LocalReplicaClient)."
+            )
         # Chaos plans are delivered by the ROUTER through this client —
         # a worker that also armed the plan would double-fire every fault.
         child_env.pop("TPURUN_FAULT_PLAN", None)

@@ -636,8 +636,7 @@ class Trainer:
             )
         if totals is None:
             return 0.0 if metric_fns is None else {}
-        # One host fetch for all sums (a fetch per scalar would cost a round
-        # trip per metric on remote-tunnel backends).
+        # One host fetch for all sums, not one device sync per metric.
         names = sorted(totals)
         host = np.asarray(jnp.stack([totals[k] for k in names]))
         sums = dict(zip(names, host))

@@ -280,8 +280,8 @@ class NativeShardedLoader(ShardedLoader):
     (:class:`MaterializedDataset`). Batch order and contents are IDENTICAL to
     the Python loader (same index table); only who does the copying changes.
 
-    When it wins, measured (tools/loader_overlap_bench.py, BASELINE.md round
-    3): at SMALL rows the Python loader's per-item overhead dominates and the
+    When it wins, measured (tools/loader_overlap_bench.py, round 3): at
+    SMALL rows the Python loader's per-item overhead dominates and the
     pool assembles ~1.4x faster; at large rows (e.g. 224x224x3 images, where
     one ``np.stack`` is a single fused memcpy) the pool's safe-ownership
     design costs a second copy (worker gather -> ring slot, slot -> caller

@@ -274,8 +274,6 @@ if __name__ == "__main__":
     parser.add_argument("--fake_devices", default=0, type=int,
                         help="debug: present N virtual CPU devices")
     args = parser.parse_args()
-    if args.fake_devices:
-        from distributed_pytorch_tpu.utils.platform import use_fake_cpu_devices
-
-        use_fake_cpu_devices(args.fake_devices)
+    from distributed_pytorch_tpu.utils.platform import init_platform
+    init_platform(args.fake_devices)
     main(args)

@@ -67,8 +67,8 @@ def main(args):
         if args.augment:
             # No silent caps: crop/flip assume translation/flip invariance,
             # which the stand-in's pixel-aligned templates do not have —
-            # measured on this rig, augmentation pins eval accuracy at
-            # chance (BASELINE.md round 4). Real CIFAR-10 wants it; the
+            # measured in round 4, augmentation pins eval accuracy at
+            # chance. Real CIFAR-10 wants it; the
             # synthetic stand-in does not.
             print(
                 "[datasets] WARNING: --augment on the synthetic stand-in "
@@ -143,8 +143,7 @@ if __name__ == "__main__":
     parser.add_argument("--width", default=64, type=int,
                         help="stem filter count (64 = standard ResNet-18; "
                         "smaller = width-reduced variant for CPU-scale runs "
-                        "where the full net overfits small subsets, "
-                        "BASELINE.md round 4)")
+                        "where the full net overfits small subsets)")
     parser.add_argument("--smooth_frac", default=0.5, type=float,
                         help="stand-in only: fraction of template variance "
                         "in a low-frequency component. Spatially-WHITE "
@@ -153,14 +152,13 @@ if __name__ == "__main__":
                         "position-specific matched filter weight sharing "
                         "cannot express (measured: ResNet-18 stays at "
                         "chance while a linear probe reaches the oracle "
-                        "band; BASELINE.md rounds 4-5). Real images are "
+                        "band; rounds 4-5). Real images are "
                         "low-frequency dominated, so 0.5 is the more "
                         "CIFAR-faithful default; ignored with real data.")
     parser.add_argument("--log_every", default=0, type=int)
     parser.add_argument("--fake_devices", default=0, type=int,
                         help="debug: present N virtual CPU devices")
     args = parser.parse_args()
-    if args.fake_devices:
-        from distributed_pytorch_tpu.utils.platform import use_fake_cpu_devices
-        use_fake_cpu_devices(args.fake_devices)
+    from distributed_pytorch_tpu.utils.platform import init_platform
+    init_platform(args.fake_devices)
     main(args)

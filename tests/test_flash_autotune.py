@@ -1,6 +1,6 @@
 """Flash block-size selection: candidate legality and lookup tiers (the
 measured sweep itself needs real hardware; its results ship in
-DEFAULT_TABLE — see BASELINE.md)."""
+DEFAULT_TABLE)."""
 
 import json
 
@@ -120,3 +120,29 @@ class TestShippedTableFile:
         assert fa.lookup(
             4096, 64, "bfloat16", True, device_kind="tpu v99"
         ) == fa.analytic_default(4096, 64)
+
+
+@pytest.mark.parametrize(
+    "kind, seeded, tier",
+    [
+        ("TPU v5 lite", False, "shipped_table"),
+        ("TPU v99", False, "analytic"),
+        # A winner in this machine's per-user file outranks the committed
+        # table — and says so, which is how chip_smoke.py notices.
+        ("TPU v5 lite", True, "disk_cache"),
+    ],
+)
+def test_lookup_names_its_tier(kind, seeded, tier):
+    if seeded:
+        key = fa._key(kind, 8192, 128, "bfloat16", True)
+        paged = fa._paged_key(kind, 2048, 16, 128, "bfloat16")
+        fa._save_disk_cache({key: (256, 512), paged: (4, 64)})
+    blocks, got = fa.lookup_with_tier(8192, 128, device_kind=kind)
+    assert got == tier
+    assert blocks == fa.lookup(8192, 128, device_kind=kind)
+    npb, got = fa.lookup_paged_with_tier(
+        2048, 16, 128, "bfloat16", device_kind=kind
+    )
+    assert got == {"analytic": "fallback"}.get(tier, tier)
+    assert npb == fa.lookup_paged(2048, 16, 128, "bfloat16", device_kind=kind)
+    assert (blocks, npb) == ((256, 512), 4) if seeded else npb in (4, 8)

@@ -182,7 +182,7 @@ class TestRematPolicy:
     """remat / remat_policy variants must be numerically identical — they
     trade memory for recompute, never math (the 'mlp' policy keeps attention
     kernels un-recomputed; measured +18% step time for 'full' at T=8192 on
-    v5e, BASELINE.md round 3)."""
+    v5e in round 3)."""
 
     @pytest.mark.slow
     def test_policies_match_no_remat(self):

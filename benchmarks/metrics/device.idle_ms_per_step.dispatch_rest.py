@@ -1,0 +1,10 @@
+"""Device-idle time a traced step during which the host's innermost slice was
+``dispatch`` itself, ``dispatch.stage`` or ``dispatch.launch``: the device
+trace's gaps split over the engine tracer's slices (``harness/phases.py``)."""
+
+from harness import phases
+
+
+def read(ctx):
+    return phases.idle_ms_per_step(
+        ctx, ("dispatch", "dispatch.stage", "dispatch.launch"))

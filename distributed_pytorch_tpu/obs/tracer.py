@@ -1,6 +1,7 @@
-"""Request lifecycle tracer + engine step timeline, Perfetto-exportable.
+"""Request lifecycle tracer + engine and trainer step timeline,
+Perfetto-exportable.
 
-Two timelines, one clock:
+Three timelines, one clock:
 
 * **Request spans** — one async span per accepted request, opened at
   ``submit`` and closed at retire, with instant events for every lifecycle
@@ -12,15 +13,32 @@ Two timelines, one clock:
   nested phase slices (``schedule`` / ``cow`` / ``prefill`` / ``dispatch``
   / ``readback``) and per-step counter tracks (batch composition,
   token-budget utilization, pages free/referenced/cached-idle, queue
-  depth).
+  depth). The phases that hold the host's time have children:
+  ``dispatch.key`` (one slice a decode row), ``dispatch.stage``,
+  ``dispatch.launch``, ``readback.wait`` (the host blocked on the device),
+  ``readback.resolve``, and one ``prefill.chunk`` a chunk.
+* **Training steps** — a ``Trainer`` writes one ``epoch`` slice an epoch,
+  inside it a ``step`` slice a batch holding ``put_batch`` and
+  ``step.dispatch``, and ``epoch.loss_fetch`` where the host waits for the
+  device at the epoch's end; its ``ShardedLoader`` writes ``loader.index``
+  and ``loader.stack`` for every batch between the steps. Both record to
+  :func:`process_tracer` unless handed another.
 
 Export is Chrome ``trace_event`` JSON (:meth:`Tracer.to_perfetto` /
 :meth:`Tracer.save`) — load it at https://ui.perfetto.dev or
 ``chrome://tracing``. Request spans are async events keyed by request id,
-so they line up under the engine-step track; timestamps are host
-``perf_counter`` microseconds from tracer construction, the same host
-clock ``jax.profiler`` stamps its XLA trace with, so a device trace
-captured over the same window lines up alongside.
+so they line up under the engine-step track.
+
+**The clock.** ``ts`` is microseconds of ``time.perf_counter`` counted from
+the tracer's construction, so it is NOT a ``jax.profiler`` trace's clock
+(that one counts nanoseconds from the profiler's own start). Every slice
+(``ph: X``) therefore carries the raw ``perf_counter`` nanoseconds of its
+start as ``args["perf_counter_ns"]``: a holder of ``events`` alone places
+any event on ``perf_counter`` with :func:`perf_counter_offset_us`, and
+whoever holds a profiler trace of the same window maps ``perf_counter``
+onto it from one instant known on both clocks (``benchmarks/harness/
+phases.py`` does). ``wall_epoch_s`` anchors ``ts`` zero on the wall clock
+for :func:`~.disttrace.merge_traces` across processes.
 
 The disabled path is the null-object pattern: :data:`NULL_TRACER` is a
 shared :class:`NullTracer` whose every method is a no-op ``pass`` and whose
@@ -33,11 +51,12 @@ bitwise-identical either way (pinned by tests).
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import json
 import os
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, Iterable, Optional
 
 # Perfetto process lanes: engine steps/phases under pid 1, request spans
 # under pid 2 — two top-level tracks that scroll together. The serving
@@ -68,10 +87,13 @@ class _NullContext:
     __slots__ = ()
 
     def __enter__(self):
-        return None
+        return self
 
     def __exit__(self, *exc):
         return False
+
+    def note(self, **args) -> None:
+        pass
 
 
 _NULL_CONTEXT = _NullContext()
@@ -91,7 +113,7 @@ class NullTracer:
     def end_step(self, **gauges) -> None:
         pass
 
-    def phase(self, name: str) -> _NullContext:
+    def phase(self, name: str, **args) -> _NullContext:
         return _NULL_CONTEXT
 
     def request_begin(self, req_id: int, **attrs) -> None:
@@ -127,31 +149,42 @@ NULL_TRACER = NullTracer()
 
 class _Phase:
     """Context manager emitting one ``X`` (complete) slice on the engine
-    track; nested phases nest visually by time containment."""
+    track; nested phases nest visually by time containment. ``args`` given
+    at construction or through :meth:`note` (counts known only at the end)
+    land in the slice's ``args`` beside the step index; ``seconds`` is the
+    slice's duration once it has closed."""
 
-    __slots__ = ("_tracer", "_name", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "seconds")
 
-    def __init__(self, tracer: "Tracer", name: str):
+    def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
+        self._args = args
 
     def __enter__(self):
-        self._t0 = self._tracer._now_us()
+        self._t0 = self._tracer._clock()
         return self
+
+    def note(self, **args) -> None:
+        self._args.update(args)
 
     def __exit__(self, *exc):
         tr = self._tracer
-        t1 = tr._now_us()
+        self.seconds = tr._clock() - self._t0
         tr.events.append(
             {
                 "name": self._name,
                 "cat": "engine",
                 "ph": "X",
-                "ts": self._t0,
-                "dur": t1 - self._t0,
+                "ts": (self._t0 - tr._epoch) * 1e6,
+                "dur": self.seconds * 1e6,
                 "pid": _PID_ENGINE,
                 "tid": 0,
-                "args": {"step": tr.step_index},
+                "args": {
+                    "step": tr.step_index,
+                    "perf_counter_ns": int(self._t0 * 1e9),
+                    **self._args,
+                },
             }
         )
         return False
@@ -163,7 +196,8 @@ class Tracer:
     :meth:`save` writes a Perfetto-loadable JSON trace.
 
     Events accumulate in memory as ``trace_event`` dicts (microsecond
-    timestamps relative to construction). ``spans_opened`` /
+    timestamps relative to construction): without bound by default, or,
+    with ``max_events``, in a ring that drops the oldest. ``spans_opened`` /
     ``spans_closed`` count request spans — a drained engine satisfies
     ``spans_closed == requests completed``.
     """
@@ -174,6 +208,7 @@ class Tracer:
         self,
         clock: Callable[[], float] = time.perf_counter,
         wall_clock: Callable[[], float] = time.time,
+        max_events: Optional[int] = None,
     ):
         self._clock = clock
         self._epoch = clock()
@@ -184,9 +219,12 @@ class Tracer:
         # :meth:`to_perfetto` metadata; `merge_traces` shifts by the epoch
         # deltas. Old saved traces without the field align at 0.0.
         self.wall_epoch_s: float = wall_clock()
-        self.events: List[dict] = []
+        self.events = (
+            [] if max_events is None
+            else collections.deque(maxlen=max_events)
+        )
         self.step_index = -1
-        self._step_t0 = 0.0
+        self._step_t0 = self._epoch
         self.spans_opened = 0
         self.spans_closed = 0
         self.engine_label: str = ""
@@ -205,22 +243,27 @@ class Tracer:
 
     def begin_step(self) -> None:
         self.step_index += 1
-        self._step_t0 = self._now_us()
+        self._step_t0 = self._clock()
 
     def end_step(self, **gauges) -> None:
         """Close the current step slice and sample every gauge onto its own
         counter track (``ph: C``) at the step boundary."""
+        ts = (self._step_t0 - self._epoch) * 1e6
         now = self._now_us()
         self.events.append(
             {
                 "name": "step",
                 "cat": "engine",
                 "ph": "X",
-                "ts": self._step_t0,
-                "dur": now - self._step_t0,
+                "ts": ts,
+                "dur": now - ts,
                 "pid": _PID_ENGINE,
                 "tid": 1,
-                "args": {"step": self.step_index, **gauges},
+                "args": {
+                    "step": self.step_index,
+                    "perf_counter_ns": int(self._step_t0 * 1e9),
+                    **gauges,
+                },
             }
         )
         for name, value in gauges.items():
@@ -235,8 +278,8 @@ class Tracer:
                 }
             )
 
-    def phase(self, name: str) -> _Phase:
-        return _Phase(self, name)
+    def phase(self, name: str, **args) -> _Phase:
+        return _Phase(self, name, args)
 
     # ------------------------------------------------------- request spans
 
@@ -426,7 +469,7 @@ class Tracer:
                     }
                 )
         return {
-            "traceEvents": meta + self.events,
+            "traceEvents": meta + list(self.events),
             "displayTimeUnit": "ms",
             # Clock anchor for multi-tracer assembly (see `merge_traces`):
             # seconds-since-Unix-epoch at which this tracer's ts=0 was.
@@ -440,3 +483,31 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_perfetto(), f)
         return path
+
+
+def perf_counter_offset_us(events: Iterable[dict]) -> Optional[float]:
+    """What to add to an event's ``ts`` to get ``time.perf_counter()`` in
+    microseconds, from the events alone: any slice carries both. ``None``
+    where ``events`` hold no slice."""
+    for event in events:
+        start_ns = event.get("args", {}).get("perf_counter_ns")
+        if start_ns is not None and "ts" in event:
+            return start_ns / 1e3 - event["ts"]
+    return None
+
+
+PROCESS_TRACER_EVENTS = 65_536
+_process_tracer: Optional[Tracer] = None
+
+
+def process_tracer() -> Tracer:
+    """The process's one bounded :class:`Tracer`, made on first use: what
+    a ``Trainer`` and a ``ShardedLoader`` record to unless handed another.
+    Its ring holds the last ``PROCESS_TRACER_EVENTS`` events (a training
+    step writes five), so it costs a fixed few tens of MB however long the
+    process runs; ``process_tracer().save(path)`` writes the last steps'
+    phases out."""
+    global _process_tracer
+    if _process_tracer is None:
+        _process_tracer = Tracer(max_events=PROCESS_TRACER_EVENTS)
+    return _process_tracer

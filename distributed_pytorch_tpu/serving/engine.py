@@ -139,15 +139,19 @@ class _PhaseSpan:
     detector attributes blame with. Built by ``InferenceEngine._phase``
     only when accounting or a perf fault is active."""
 
-    __slots__ = ("engine", "name", "stall", "_ctx", "_t0")
+    __slots__ = ("engine", "name", "stall", "args", "_ctx", "_t0")
 
-    def __init__(self, engine, name: str, stall: float):
+    def __init__(self, engine, name: str, stall: float, args: dict):
         self.engine = engine
         self.name = name
         self.stall = stall
+        self.args = args
+
+    def note(self, **args) -> None:
+        self._ctx.note(**args)
 
     def __enter__(self):
-        self._ctx = self.engine.tracer.phase(self.name)
+        self._ctx = self.engine.tracer.phase(self.name, **self.args)
         self._ctx.__enter__()
         self._t0 = time.perf_counter()
         if self.stall > 0.0:
@@ -1398,24 +1402,28 @@ class InferenceEngine:
     ) -> List[int]:
         """Resolve one decode dispatch's sampled tokens (async inflight
         or an in-step sync mod group): fill values, retire finishers."""
-        nxt_host = np.asarray(nxt)
+        with self._phase("readback.wait", bytes=nxt.nbytes):
+            nxt_host = np.asarray(nxt)
         if self.xla is not None:
             self.xla.count_d2h(nxt_host.nbytes)
         now = time.perf_counter()
         finished: List[int] = []
-        for slot, req in zip(slots, reqs):
-            done = self.scheduler.resolve_decoded(
-                req, int(nxt_host[slot]), now=now
-            )
-            if self.tracer.enabled:
-                self.tracer.request_event(
-                    req.req_id, "decode_token", n_generated=req.n_generated
+        with self._phase("readback.resolve", rows=len(slots)) as span:
+            for slot, req in zip(slots, reqs):
+                done = self.scheduler.resolve_decoded(
+                    req, int(nxt_host[slot]), now=now
                 )
-            if done is not None:
-                self.scheduler.retire(done, now=now)
-                self.metrics.observe_finished(done)
-                self._keys.pop(done.req_id, None)
-                finished.append(done.req_id)
+                if self.tracer.enabled:
+                    self.tracer.request_event(
+                        req.req_id, "decode_token",
+                        n_generated=req.n_generated,
+                    )
+                if done is not None:
+                    self.scheduler.retire(done, now=now)
+                    self.metrics.observe_finished(done)
+                    self._keys.pop(done.req_id, None)
+                    finished.append(done.req_id)
+            span.note(finished=len(finished))
         return finished
 
     def _dispatch_decode(self, slots: List[int], params, prev):
@@ -1428,6 +1436,8 @@ class InferenceEngine:
         self._stage_lens.fill(0)
         self._stage_use_prev.fill(0)
         bias = None
+        # One slice a row costs a dictionary each: only when someone reads.
+        key_span = self._phase if self.tracer.enabled else NULL_TRACER.phase
         for slot in slots:
             req = self.scheduler.slots[slot]
             pos = req.len_cached
@@ -1442,41 +1452,47 @@ class InferenceEngine:
             self._stage_tables[slot] = req.table.as_row(self.pages_per_seq)
             self._stage_lens[slot] = pos
             self._stage_temps[slot] = req.params.temperature
-            self._stage_keys[slot] = np.asarray(
-                jax.random.fold_in(self._keys[req.req_id], req.n_issued),
-                np.uint32,
-            )
+            with key_span("dispatch.key", slot=slot):
+                self._stage_keys[slot] = np.asarray(
+                    jax.random.fold_in(
+                        self._keys[req.req_id], req.n_issued
+                    ),
+                    np.uint32,
+                )
             row = req.mods.bias_row() if req.mods is not None else None
             if row is not None:
                 if bias is None:
                     bias = self._stage_bias
                     bias.fill(0.0)
                 bias[slot] = row
-        if self.xla is not None:
-            staged = (
-                self._stage_tokens.nbytes
-                + self._stage_use_prev.nbytes
-                + self._stage_tables.nbytes
-                + self._stage_lens.nbytes
-                + self._stage_temps.nbytes
-                + self._stage_keys.nbytes
-            )
-            if bias is not None:
-                staged += bias.nbytes
-            self.xla.count_h2d(staged)
-        # No modded rows: reuse the zeros device constant — the bias
-        # operand costs the common path nothing.
-        bias_arr = self._zero_bias if bias is None else jnp.array(bias)
-        nxt, self.cache = self._decode_step(
-            params, self.cache,
-            jnp.array(self._stage_tokens), prev,
-            jnp.array(self._stage_use_prev),
-            jnp.array(self._stage_tables),
-            jnp.array(self._stage_lens),
-            jnp.array(self._stage_temps),
-            jnp.array(self._stage_keys),
-            bias_arr,
+        staged = (
+            self._stage_tokens.nbytes
+            + self._stage_use_prev.nbytes
+            + self._stage_tables.nbytes
+            + self._stage_lens.nbytes
+            + self._stage_temps.nbytes
+            + self._stage_keys.nbytes
         )
+        if bias is not None:
+            staged += bias.nbytes
+        with self._phase("dispatch.stage", rows=len(slots), bytes=staged):
+            if self.xla is not None:
+                self.xla.count_h2d(staged)
+            # No modded rows: reuse the zeros device constant — the bias
+            # operand costs the common path nothing.
+            bias_arr = self._zero_bias if bias is None else jnp.array(bias)
+            decode_step = self._decode_step
+            tokens = jnp.array(self._stage_tokens)
+            use_prev = jnp.array(self._stage_use_prev)
+            tables = jnp.array(self._stage_tables)
+            lens = jnp.array(self._stage_lens)
+            temps = jnp.array(self._stage_temps)
+            keys = jnp.array(self._stage_keys)
+        with self._phase("dispatch.launch"):
+            nxt, self.cache = decode_step(
+                params, self.cache, tokens, prev, use_prev, tables, lens,
+                temps, keys, bias_arr,
+            )
         return nxt
 
     def _end_step_trace(self, plan) -> None:
@@ -1630,7 +1646,7 @@ class InferenceEngine:
             rework = self._acct["rework"] = {}
         rework[req.rework_kind] = rework.get(req.rework_kind, 0) + rw
 
-    def _phase(self, name: str):
+    def _phase(self, name: str, **args):
         """Step-phase span: the tracer's phase slice, plus (when the
         accounting wrapper is active) per-phase wall-time accumulation
         into ``_acct["phases"]`` — the series the regression detector
@@ -1639,7 +1655,8 @@ class InferenceEngine:
         and detector attribution all see the slowdown where it was
         injected. With no accounting and no armed perf fault this returns
         the tracer's own context, so the all-obs-off fast path stays one
-        attribute lookup away from the original code."""
+        attribute lookup away from the original code. ``args`` (and what
+        the body adds through the context's ``note``) go into the slice."""
         plan = chaos.get_plan()
         stall = (
             plan.serving_stall(name)
@@ -1647,8 +1664,8 @@ class InferenceEngine:
             else 0.0
         )
         if self._acct is None and stall <= 0.0:
-            return self.tracer.phase(name)
-        return _PhaseSpan(self, name, stall)
+            return self.tracer.phase(name, **args)
+        return _PhaseSpan(self, name, stall, args)
 
     def _step_impl(self) -> List[int]:
         chaos.on_serving_phase(
@@ -1716,26 +1733,31 @@ class InferenceEngine:
                     start = req.len_cached
                     if self._acct is not None and req.rework_until > start:
                         self._note_rework(req, start, chunk)
-                    tok = np.asarray(
-                        [req.tokens[start : start + chunk]], np.int32
-                    )
-                    table = req.table.as_row(self.pages_per_seq)[None]
-                    if self.xla is not None:
-                        self.xla.count_h2d(tok.nbytes + table.nbytes + 4)
-                    # Adapter rows prefill under their merged weights —
-                    # K/V written under base params would poison every
-                    # decode step that attends to it.
-                    ms = req.mods
-                    chunk_params = (
-                        self.adapters.params_for(ms.adapter)
-                        if ms is not None and ms.adapter is not None
-                        else self.params
-                    )
-                    self.cache = self._prefill_step(chunk)(
-                        chunk_params, self.cache, jnp.asarray(tok),
-                        jnp.asarray(table),
-                        jnp.asarray([start], jnp.int32),
-                    )
+                    with self._phase(
+                        "prefill.chunk", tokens=chunk, start=start
+                    ):
+                        tok = np.asarray(
+                            [req.tokens[start : start + chunk]], np.int32
+                        )
+                        table = req.table.as_row(self.pages_per_seq)[None]
+                        if self.xla is not None:
+                            self.xla.count_h2d(
+                                tok.nbytes + table.nbytes + 4
+                            )
+                        # Adapter rows prefill under their merged weights
+                        # — K/V written under base params would poison
+                        # every decode step that attends to it.
+                        ms = req.mods
+                        chunk_params = (
+                            self.adapters.params_for(ms.adapter)
+                            if ms is not None and ms.adapter is not None
+                            else self.params
+                        )
+                        self.cache = self._prefill_step(chunk)(
+                            chunk_params, self.cache, jnp.asarray(tok),
+                            jnp.asarray(table),
+                            jnp.asarray([start], jnp.int32),
+                        )
                     self.scheduler.note_prefilled(slot, chunk)
 
         finished: List[int] = []
@@ -1829,6 +1851,9 @@ class InferenceEngine:
             with self._phase("dispatch"):
                 self._stage_tables.fill(0)
                 self._stage_lens.fill(0)
+                key_span = (
+                    self._phase if tr.enabled else NULL_TRACER.phase
+                )
                 for slot in plan.decode_slots:
                     req = self.scheduler.slots[slot]
                     pos = req.len_cached
@@ -1840,31 +1865,40 @@ class InferenceEngine:
                     )
                     self._stage_lens[slot] = pos
                     self._stage_temps[slot] = req.params.temperature
-                    self._stage_keys[slot] = np.asarray(
-                        jax.random.fold_in(
-                            self._keys[req.req_id], req.n_issued
-                        ),
-                        np.uint32,
-                    )
-                if self.xla is not None:
-                    self.xla.count_h2d(
-                        self._stage_tokens.nbytes
-                        + self._stage_tables.nbytes
-                        + self._stage_lens.nbytes
-                        + self._stage_temps.nbytes
-                        + self._stage_keys.nbytes
-                    )
-                emitted, n_acc, self.cache, self.draft_cache = (
-                    self._spec_step(
-                        self.params, self.draft_params,
-                        self.cache, self.draft_cache,
-                        jnp.array(self._stage_tokens),
-                        jnp.array(self._stage_tables),
-                        jnp.array(self._stage_lens),
-                        jnp.array(self._stage_temps),
-                        jnp.array(self._stage_keys),
-                    )
+                    with key_span("dispatch.key", slot=slot):
+                        self._stage_keys[slot] = np.asarray(
+                            jax.random.fold_in(
+                                self._keys[req.req_id], req.n_issued
+                            ),
+                            np.uint32,
+                        )
+                staged = (
+                    self._stage_tokens.nbytes
+                    + self._stage_tables.nbytes
+                    + self._stage_lens.nbytes
+                    + self._stage_temps.nbytes
+                    + self._stage_keys.nbytes
                 )
+                with self._phase(
+                    "dispatch.stage",
+                    rows=len(plan.decode_slots), bytes=staged,
+                ):
+                    if self.xla is not None:
+                        self.xla.count_h2d(staged)
+                    spec_step = self._spec_step
+                    tokens = jnp.array(self._stage_tokens)
+                    tables = jnp.array(self._stage_tables)
+                    lens = jnp.array(self._stage_lens)
+                    temps = jnp.array(self._stage_temps)
+                    keys = jnp.array(self._stage_keys)
+                with self._phase("dispatch.launch"):
+                    emitted, n_acc, self.cache, self.draft_cache = (
+                        spec_step(
+                            self.params, self.draft_params,
+                            self.cache, self.draft_cache,
+                            tokens, tables, lens, temps, keys,
+                        )
+                    )
                 dispatched = (
                     emitted,
                     n_acc,
@@ -1886,25 +1920,28 @@ class InferenceEngine:
                     start = req.len_cached
                     if self._acct is not None and req.rework_until > start:
                         self._note_rework(req, start, chunk)
-                    tok = np.asarray(
-                        [req.tokens[start : start + chunk]], np.int32
-                    )
-                    table = req.table.as_row(self.pages_per_seq)[None]
-                    if self.xla is not None:
-                        # Chunk + table + start staged into BOTH pools.
-                        self.xla.count_h2d(
-                            2 * (tok.nbytes + table.nbytes + 4)
+                    with self._phase(
+                        "prefill.chunk", tokens=chunk, start=start
+                    ):
+                        tok = np.asarray(
+                            [req.tokens[start : start + chunk]], np.int32
                         )
-                    self.cache = self._prefill_step(chunk)(
-                        self.params, self.cache, jnp.asarray(tok),
-                        jnp.asarray(table),
-                        jnp.asarray([start], jnp.int32),
-                    )
-                    self.draft_cache = self._draft_prefill_step(chunk)(
-                        self.draft_params, self.draft_cache,
-                        jnp.asarray(tok), jnp.asarray(table),
-                        jnp.asarray([start], jnp.int32),
-                    )
+                        table = req.table.as_row(self.pages_per_seq)[None]
+                        if self.xla is not None:
+                            # Chunk + table + start staged into BOTH pools.
+                            self.xla.count_h2d(
+                                2 * (tok.nbytes + table.nbytes + 4)
+                            )
+                        self.cache = self._prefill_step(chunk)(
+                            self.params, self.cache, jnp.asarray(tok),
+                            jnp.asarray(table),
+                            jnp.asarray([start], jnp.int32),
+                        )
+                        self.draft_cache = self._draft_prefill_step(chunk)(
+                            self.draft_params, self.draft_cache,
+                            jnp.asarray(tok), jnp.asarray(table),
+                            jnp.asarray([start], jnp.int32),
+                        )
                     self.scheduler.note_prefilled(slot, chunk)
 
         finished: List[int] = []
@@ -1912,37 +1949,50 @@ class InferenceEngine:
         if dispatched is not None:
             with self._phase("readback"):
                 emitted, n_acc, slot_reqs = dispatched
-                emitted_host = np.asarray(emitted)  # the ONE blocking sync
-                n_acc_host = np.asarray(n_acc)
+                with self._phase(
+                    "readback.wait", bytes=emitted.nbytes + n_acc.nbytes
+                ):
+                    # the ONE blocking sync
+                    emitted_host = np.asarray(emitted)
+                    n_acc_host = np.asarray(n_acc)
                 if self.xla is not None:
                     self.xla.count_d2h(
                         emitted_host.nbytes + n_acc_host.nbytes
                     )
                 now = time.perf_counter()
-                for slot, req in slot_reqs:
-                    accepted = int(n_acc_host[slot])
-                    n_emit = min(accepted + 1, self.gamma)
-                    if self._acct is not None:
-                        self._acct["emitted"] += n_emit
-                        self._acct["proposed"] += self.gamma
-                    toks = [int(t) for t in emitted_host[slot, :n_emit]]
-                    before = req.n_generated
-                    done = self.scheduler.resolve_spec(req, toks, now=now)
-                    self.metrics.observe_verify(
-                        accepted=accepted, emitted=n_emit, gamma=self.gamma
-                    )
-                    if tr.enabled:
-                        tr.request_event(
-                            req.req_id, "verify_round",
-                            accepted=accepted, emitted=n_emit,
-                            n_generated=req.n_generated,
+                with self._phase(
+                    "readback.resolve", rows=len(slot_reqs)
+                ) as span:
+                    for slot, req in slot_reqs:
+                        accepted = int(n_acc_host[slot])
+                        n_emit = min(accepted + 1, self.gamma)
+                        if self._acct is not None:
+                            self._acct["emitted"] += n_emit
+                            self._acct["proposed"] += self.gamma
+                        toks = [
+                            int(t) for t in emitted_host[slot, :n_emit]
+                        ]
+                        before = req.n_generated
+                        done = self.scheduler.resolve_spec(
+                            req, toks, now=now
                         )
-                    new_tokens += req.n_generated - before
-                    if done is not None:
-                        self.scheduler.retire(done, now=now)
-                        self.metrics.observe_finished(done)
-                        self._keys.pop(done.req_id, None)
-                        finished.append(done.req_id)
+                        self.metrics.observe_verify(
+                            accepted=accepted, emitted=n_emit,
+                            gamma=self.gamma,
+                        )
+                        if tr.enabled:
+                            tr.request_event(
+                                req.req_id, "verify_round",
+                                accepted=accepted, emitted=n_emit,
+                                n_generated=req.n_generated,
+                            )
+                        new_tokens += req.n_generated - before
+                        if done is not None:
+                            self.scheduler.retire(done, now=now)
+                            self.metrics.observe_finished(done)
+                            self._keys.pop(done.req_id, None)
+                            finished.append(done.req_id)
+                    span.note(finished=len(finished))
         self.metrics.observe_step(new_tokens=new_tokens)
         if tr.enabled:
             self._end_step_trace(plan)

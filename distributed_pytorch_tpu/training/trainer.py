@@ -24,7 +24,6 @@ from __future__ import annotations
 import os
 import signal
 import threading
-import time
 from typing import Callable, Optional
 
 import jax
@@ -40,6 +39,7 @@ from distributed_pytorch_tpu.checkpoint import (
     save_snapshot,
 )
 from distributed_pytorch_tpu.metrics import MetricLogger, ReservoirHistogram
+from distributed_pytorch_tpu.obs.tracer import process_tracer
 from distributed_pytorch_tpu.parallel.bootstrap import is_main_process
 from distributed_pytorch_tpu.parallel.sharding import (
     put_global_batch,
@@ -112,6 +112,7 @@ class Trainer:
         keep_checkpoints: int = 0,
         dropout_seed: Optional[int] = None,
         registry=None,
+        tracer=None,
     ):
         self.model = model
         self.train_data = train_data
@@ -123,10 +124,15 @@ class Trainer:
         self.loss_fn = loss_fn
         self.profiler = profiler
         self.metrics = metrics or MetricLogger()
+        # Where the step loop writes its host phases (epoch > step >
+        # put_batch, step.dispatch; epoch.loss_fetch): the process's bounded
+        # tracer unless the caller hands over a Tracer of its own.
+        self.tracer = tracer if tracer is not None else process_tracer()
         # Per-batch wall time (dispatch + any sync the loop already does) in
-        # a bounded reservoir; p50/p95 logged at every epoch boundary. Tail
-        # percentiles are where stragglers, recompiles, and host stalls show
-        # up — the mean hides them.
+        # a bounded reservoir, fed from the ``step`` slice's own two clock
+        # reads; p50/p95 logged at every epoch boundary. Tail percentiles
+        # are where stragglers, recompiles, and host stalls show up — the
+        # mean hides them.
         self.step_times = ReservoirHistogram(1024)
         # Optional unified-observability hookup: expose the step-time
         # reservoir and global step through a shared MetricsRegistry
@@ -486,6 +492,12 @@ class Trainer:
     def _run_epoch(self, epoch: int) -> float:
         """One pass over this process's shard (twin of ``_run_epoch``,
         ``single_gpu.py:28-34``). Returns the mean loss over the epoch."""
+        with self.tracer.phase("epoch", epoch=epoch):
+            return self._run_epoch_steps(epoch)
+
+    def _run_epoch_steps(self, epoch: int) -> float:
+        """The body of :meth:`_run_epoch`, inside its ``epoch`` slice."""
+        tr = self.tracer
         self.train_data.set_epoch(epoch)
         n_batches = len(self.train_data)
         start = 0
@@ -511,11 +523,17 @@ class Trainer:
         for i, (xs, ys) in enumerate(
             self.train_data.iter_batches(start), start=start
         ):
-            t0 = time.perf_counter()
-            batch = self._put_batch(xs, ys)
-            loss = self._run_batch(batch)
-            losses.append(loss)
-            self.step_times.record(time.perf_counter() - t0)
+            where = dict(step=i, epoch=epoch)
+            with tr.phase("step", **where) as step_span:
+                with tr.phase(
+                    "put_batch", bytes=xs.nbytes + ys.nbytes, **where
+                ):
+                    batch = self._put_batch(xs, ys)
+                with tr.phase("step.dispatch", **where):
+                    loss = self._run_batch(batch)
+                losses.append(loss)
+            if tr.enabled:
+                self.step_times.record(step_span.seconds)
             if self.profiler is not None:
                 # Device sync so the profiled window reflects real step time.
                 jax.block_until_ready(loss)
@@ -531,9 +549,11 @@ class Trainer:
                     loss_sum=carry_sum + float(np.sum(host_losses)),
                     loss_count=carry_count + len(host_losses),
                 )
-        total = carry_sum + (
-            float(np.sum([float(l) for l in losses])) if losses else 0.0
-        )
+        # The host waits here for the device to finish the epoch's steps.
+        with tr.phase("epoch.loss_fetch", epoch=epoch, steps=len(losses)):
+            total = carry_sum + (
+                float(np.sum([float(l) for l in losses])) if losses else 0.0
+            )
         count = carry_count + len(losses)
         epoch_loss = total / count if count else 0.0
         self.metrics.log(

@@ -24,6 +24,8 @@ from typing import Iterator, Sequence, Tuple
 
 import numpy as np
 
+from distributed_pytorch_tpu.obs.tracer import process_tracer
+
 Batch = Tuple[np.ndarray, np.ndarray]
 
 
@@ -137,6 +139,10 @@ class ShardedLoader:
 
     With the reference's divisible defaults (2048 samples / batch 32) neither
     changes anything.
+
+    Every batch leaves two slices in ``tracer`` (the process's own unless
+    another is handed over): ``loader.index`` round the ``dataset[i]`` calls
+    and ``loader.stack`` round the two ``np.stack``.
     """
 
     def __init__(
@@ -150,6 +156,7 @@ class ShardedLoader:
         seed: int = 0,
         drop_last: bool = False,
         pad_final_batch: bool = False,
+        tracer=None,
     ):
         if not 0 <= shard_index < num_shards:
             raise ValueError(f"shard_index {shard_index} not in [0, {num_shards})")
@@ -161,6 +168,7 @@ class ShardedLoader:
         self.seed = seed
         self.drop_last = drop_last
         self.pad_final_batch = pad_final_batch
+        self.tracer = tracer if tracer is not None else process_tracer()
         self._epoch = 0
 
     def set_epoch(self, epoch: int) -> None:
@@ -259,10 +267,17 @@ class ShardedLoader:
         """Iterate this epoch's batches, optionally skipping the first
         ``start_batch`` of them (mid-epoch resume: batches already applied to
         the restored state before a drain snapshot must not be replayed)."""
-        for chunk in self.batch_index_table()[start_batch:]:
-            samples = [self.dataset[int(i)] for i in chunk]
-            xs = np.stack([s[0] for s in samples])
-            ys = np.stack([s[1] for s in samples])
+        tr = self.tracer
+        for step, chunk in enumerate(
+            self.batch_index_table()[start_batch:], start=start_batch
+        ):
+            where = dict(step=step, epoch=self._epoch, rows=len(chunk))
+            with tr.phase("loader.index", **where):
+                samples = [self.dataset[int(i)] for i in chunk]
+            with tr.phase("loader.stack", **where) as span:
+                xs = np.stack([s[0] for s in samples])
+                ys = np.stack([s[1] for s in samples])
+                span.note(bytes=xs.nbytes + ys.nbytes)
             yield xs, ys
 
     def __iter__(self) -> Iterator[Batch]:

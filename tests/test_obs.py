@@ -21,6 +21,11 @@ from distributed_pytorch_tpu.obs import (
     NullTracer,
     Tracer,
 )
+from distributed_pytorch_tpu.obs.flight import FlightRecorder
+from distributed_pytorch_tpu.obs.tracer import (
+    perf_counter_offset_us,
+    process_tracer,
+)
 from distributed_pytorch_tpu.serving import InferenceEngine, SamplingParams
 
 
@@ -252,6 +257,77 @@ class TestMetricsRegistry:
         assert again["counters"]["srv_reqs_total"] == 40
 
 
+class TestTracerClockAndRing:
+    @pytest.mark.parametrize("n", [1, 3, 8])
+    def test_a_bounded_tracer_drops_the_oldest(self, n):
+        tr = Tracer(clock=FakeClock(), max_events=n)
+        for i in range(20):
+            with tr.phase("work", i=i):
+                pass
+            assert len(tr.events) <= n
+        assert [e["args"]["i"] for e in tr.events] == list(range(20 - n, 20))
+        # the export takes the ring as it takes the list
+        names = [e["name"] for e in tr.to_perfetto()["traceEvents"]]
+        assert names.count("work") == n
+
+    def test_a_tracer_built_by_hand_stays_unbounded(self):
+        tr = Tracer(clock=FakeClock())
+        for _ in range(100):
+            tr.instant("mark")
+        assert isinstance(tr.events, list) and len(tr.events) == 100
+
+    def test_every_event_is_placed_on_the_clock_from_events_alone(self):
+        clock = FakeClock()
+        for _ in range(7):
+            clock()  # the tracer is not built at the clock's zero
+        tr = Tracer(clock=clock)
+        read_at = {}  # index in events -> the clock when it was written
+
+        tr.request_begin(3)
+        read_at[len(tr.events) - 1] = clock.t
+        tr.begin_step()
+        step_t0 = clock.t
+        with tr.phase("dispatch"):
+            phase_t0 = clock.t
+            tr.request_event(3, "decode_token")
+            read_at[len(tr.events) - 1] = clock.t
+        read_at[len(tr.events) - 1] = phase_t0
+        tr.instant("evict")
+        read_at[len(tr.events) - 1] = clock.t
+        tr.end_step(decode_rows=1)
+        read_at[len(tr.events) - 2] = step_t0  # the slice, then its gauge
+
+        offset = perf_counter_offset_us(tr.events)
+        assert offset is not None
+        for i, t in read_at.items():
+            assert tr.events[i]["ts"] + offset == pytest.approx(
+                t * 1e6, abs=1e-3
+            ), tr.events[i]["name"]
+        slices = [e for e in tr.events if e["ph"] == "X"]
+        assert {e["name"] for e in slices} == {"step", "dispatch"}
+        for e in slices:  # each slice says so itself, too
+            assert e["args"]["perf_counter_ns"] == pytest.approx(
+                (e["ts"] + offset) * 1e3, abs=1
+            )
+        assert perf_counter_offset_us([tr.events[0]]) is None  # no slice
+
+    def test_a_phase_takes_counts_at_its_start_and_at_its_end(self):
+        tr = Tracer(clock=FakeClock())
+        with tr.phase("readback.resolve", rows=4) as span:
+            span.note(finished=1)
+        assert span.seconds == pytest.approx(0.001)
+        (event,) = tr.events
+        assert event["dur"] == pytest.approx(1000.0)
+        assert event["args"]["rows"] == 4 and event["args"]["finished"] == 1
+        with NULL_TRACER.phase("readback.resolve", rows=4) as span:
+            span.note(finished=1)  # off stays off
+
+    def test_the_process_has_one_bounded_tracer(self):
+        tr = process_tracer()
+        assert tr is process_tracer() and tr.enabled
+        assert tr.events.maxlen == 65_536
+
+
 # ------------------------------------------------------- engine integration
 
 
@@ -276,6 +352,19 @@ def _tiny_engine(tracer=None, **kw):
 PROMPTS = [[5, 7, 11, 2, 9, 3], [1, 4, 8], [2, 2, 3, 17, 40], [6, 1, 9, 9]]
 
 
+def _draft_kw():
+    from distributed_pytorch_tpu.models.transformer import TransformerLM
+
+    draft = TransformerLM(
+        vocab_size=48, d_model=8, n_layers=1, n_heads=2, d_ff=16,
+        dtype=jnp.float32,
+    )
+    params = draft.init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    return dict(draft_model=draft, draft_params=params, gamma=2)
+
+
 def _run_all(eng):
     ids = [
         eng.submit(p, SamplingParams(max_new_tokens=6)) for p in PROMPTS
@@ -285,12 +374,82 @@ def _run_all(eng):
 
 
 class TestEngineObservability:
-    def test_tracing_does_not_change_tokens(self):
+    @pytest.mark.parametrize(
+        "mode", ["overlap", "sync", "speculative", "accounted"]
+    )
+    def test_tracing_does_not_change_tokens(self, mode):
         """Acceptance: with tracing enabled, greedy outputs are
-        bitwise-identical to the untraced engine."""
-        plain = _run_all(_tiny_engine())
-        traced = _run_all(_tiny_engine(tracer=Tracer()))
+        bitwise-identical to the untraced engine, and the trace holds
+        every child slice inside its parent: as many ``dispatch.key`` a
+        step as decode rows."""
+        kw = {
+            "overlap": dict,
+            "sync": lambda: dict(overlap=False),
+            "speculative": _draft_kw,
+            "accounted": lambda: dict(flight=FlightRecorder(64)),
+        }[mode]
+        plain = _run_all(_tiny_engine(**kw()))
+        tr = Tracer()
+        traced = _run_all(_tiny_engine(tracer=tr, **kw()))
         assert traced == plain
+
+        slices = [e for e in tr.events if e["ph"] == "X"]
+        by_step = {}
+        for e in slices:
+            by_step.setdefault(e["args"]["step"], []).append(e)
+
+        def inside(child, parent):
+            return (
+                parent["ts"] <= child["ts"]
+                and child["ts"] + child["dur"]
+                <= parent["ts"] + parent["dur"]
+            )
+
+        seen = set()
+        for step, rows in by_step.items():
+            (whole,) = [e for e in rows if e["name"] == "step"]
+            for e in rows:
+                if e is whole:
+                    continue
+                assert inside(e, whole), (step, e["name"])
+                seen.add(e["name"])
+                parent_name, dot, _ = e["name"].partition(".")
+                if dot:
+                    assert any(
+                        p["name"] == parent_name and inside(e, p)
+                        for p in rows
+                    ), (step, e["name"])
+            keys = [e for e in rows if e["name"] == "dispatch.key"]
+            assert len(keys) == whole["args"]["decode_rows"]
+            assert len({e["args"]["slot"] for e in keys}) == len(keys)
+            launches = [e for e in rows if e["name"] == "dispatch.launch"]
+            assert len(launches) == (1 if keys else 0)
+        assert seen >= {
+            "schedule", "prefill", "prefill.chunk", "dispatch",
+            "dispatch.key", "dispatch.stage", "dispatch.launch", "readback",
+            "readback.wait", "readback.resolve",
+        }
+        stage = next(e for e in slices if e["name"] == "dispatch.stage")
+        assert stage["args"]["rows"] > 0 and stage["args"]["bytes"] > 0
+        resolved = [e for e in slices if e["name"] == "readback.resolve"]
+        assert sum(e["args"]["finished"] for e in resolved) == len(PROMPTS)
+        chunks = [e for e in slices if e["name"] == "prefill.chunk"]
+        assert sum(e["args"]["tokens"] for e in chunks) == sum(
+            e["args"]["prefill_tokens"] for e in slices
+            if e["name"] == "step"
+        ) > 0
+
+    def test_an_untraced_engine_writes_no_key_slices(self):
+        """``dispatch.key`` is one slice a row: the accounting path, which
+        sees the other children, is spared it while no tracer reads."""
+        eng = _tiny_engine(flight=FlightRecorder(64), timeseries=True)
+        _run_all(eng)
+        names = {
+            n for n in eng.timeseries.series_names() if n.startswith("phase_")
+        }
+        assert "phase_dispatch.stage_seconds" in names
+        assert "phase_readback.wait_seconds" in names
+        assert "phase_dispatch.key_seconds" not in names
 
     def test_span_count_equals_completed_requests(self, tmp_path):
         tr = Tracer()

@@ -433,3 +433,88 @@ def test_drain_resume_geometry_mismatch_replays_epoch(tmp_path, capsys, _restore
     assert t2._resume_step == 0
     t2.train(1)  # replays epoch 0 from scratch, completes
     assert t2.epochs_run == 1
+
+
+# ------------------------------------------------ host phases in the tracer
+
+
+def _traced_run(tmp_path, tracer, name, mesh=None, epochs=2):
+    """Two epochs over a toy ``ArrayDataset`` with loader and Trainer
+    recording to ``tracer``; returns the Trainer."""
+    from distributed_pytorch_tpu.utils.data import ArrayDataset
+
+    rng = np.random.default_rng(0)
+    data = ArrayDataset(
+        rng.random((64, 20), dtype=np.float32),
+        rng.random((64, 1), dtype=np.float32),
+    )
+    trainer = Trainer(
+        ToyRegressor(), ShardedLoader(data, 16, tracer=tracer),
+        optax.sgd(1e-2), save_every=0, mesh=mesh,
+        checkpoint_path=str(tmp_path / f"{name}.npz"), tracer=tracer,
+    )
+    if trainer.tracer.enabled:
+        # the Trainer drew one batch to initialise the model from
+        trainer.tracer.events.clear()
+    trainer.train(epochs)
+    return trainer
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["serial", "mesh"])
+def test_trainer_writes_its_host_phases_and_trains_the_same(tmp_path, mesh):
+    """Per step one each of ``loader.index``, ``loader.stack``, ``step`` >
+    ``put_batch`` + ``step.dispatch``; per epoch one ``epoch`` holding all
+    of them and one ``epoch.loss_fetch``; and the state a Trainer reaches
+    does not depend on who records."""
+    from distributed_pytorch_tpu.obs.tracer import NULL_TRACER, Tracer
+
+    mesh = make_mesh() if mesh else None
+    tr = Tracer()
+    traced = _traced_run(tmp_path, tr, "traced", mesh)
+    silent = _traced_run(tmp_path, NULL_TRACER, "silent", mesh)
+    for a, b in zip(jax.tree_util.tree_leaves(traced.state),
+                    jax.tree_util.tree_leaves(silent.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    assert {e["ph"] for e in tr.events} == {"X"}
+    named = lambda name, **args: [  # noqa: E731
+        e for e in tr.events if e["name"] == name
+        and all(e["args"][k] == v for k, v in args.items())]
+    inside = lambda c, p: (  # noqa: E731
+        p["ts"] <= c["ts"] and c["ts"] + c["dur"] <= p["ts"] + p["dur"])
+    before = lambda a, b: a["ts"] + a["dur"] <= b["ts"]  # noqa: E731
+    for epoch in range(2):
+        (whole,) = named("epoch", epoch=epoch)
+        (fetch,) = named("epoch.loss_fetch", epoch=epoch)
+        assert inside(fetch, whole) and fetch["args"]["steps"] == 4
+        for step in range(4):
+            at = dict(epoch=epoch, step=step)
+            (index,) = named("loader.index", **at)
+            (stack,) = named("loader.stack", **at)
+            (one,) = named("step", **at)
+            (put,) = named("put_batch", **at)
+            (dispatch,) = named("step.dispatch", **at)
+            for e in (index, stack, one):
+                assert inside(e, whole)
+            assert inside(put, one) and inside(dispatch, one)
+            assert before(index, stack) and before(stack, one)
+            assert before(put, dispatch) and before(one, fetch)
+            assert index["args"]["rows"] == 16
+            assert stack["args"]["bytes"] == 16 * 21 * 4
+            assert put["args"]["bytes"] == stack["args"]["bytes"]
+    assert len(tr.events) == 2 * (2 + 4 * 5)
+    # the step's own slice is what the step-time reservoir was fed from
+    assert traced.step_times.count == 8 and silent.step_times.count == 0
+    assert traced.step_times.quantile(1.0) == pytest.approx(
+        max(e["dur"] for e in named("step")) / 1e6)
+
+
+def test_trainer_and_loader_record_to_the_process_tracer_by_default(tmp_path):
+    from distributed_pytorch_tpu.obs.tracer import process_tracer
+
+    tr = process_tracer()
+    trainer = _traced_run(tmp_path, None, "default", epochs=1)
+    assert trainer.tracer is tr and trainer.train_data.tracer is tr
+    names = [e["name"] for e in tr.events]
+    assert names.count("epoch") == 1 and names.count("step.dispatch") == 4
+    assert names.count("loader.stack") == 4

@@ -134,6 +134,29 @@ def make_row_sampler(top_k: int, top_p: float):
     return sample
 
 
+def host_prng_key(seed: int) -> np.ndarray:
+    """``jax.random.key_data(jax.random.PRNGKey(seed))`` as host data,
+    ``uint32[2]``, with no dispatch to any device: threefry's seeding is
+    ``[seed >> 32, seed & 0xFFFFFFFF]`` of the seed as JAX reads a Python
+    int — an int64 (beyond it ``OverflowError``, as ``PRNGKey`` raises),
+    cut to its low 32 bits unless ``jax_enable_x64`` is on. The serving
+    engine keeps one of these a request and folds the step count in inside
+    its compiled program (:func:`fold_row_keys`)."""
+    bits = int(np.int64(seed)) & 0xFFFFFFFFFFFFFFFF
+    high = bits >> 32 if jax.config.jax_enable_x64 else 0
+    return np.array([high, bits & 0xFFFFFFFF], np.uint32)
+
+
+def fold_row_keys(staged):
+    """``staged [B, 3] uint32`` — a row's base key (:func:`host_prng_key`)
+    and, beside it, the index of the token the row is about to draw — to
+    the ``[B, 2]`` per-step keys ``fold_in(base, index)``. Traced inside
+    the serving programs, so no key costs the host a device round trip;
+    the same integer arithmetic ``jax.random.fold_in`` does anywhere, bit
+    for bit."""
+    return jax.vmap(jax.random.fold_in)(staged[:, :2], staged[:, 2])
+
+
 def decode_token_step(decode_model, params, cache, current, **apply_kwargs):
     """ONE decode-mode forward: apply ``decode_model`` on ``current``
     ([B, T_step] token ids) against ``cache``, returning ``(last_logits,

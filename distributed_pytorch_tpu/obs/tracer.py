@@ -14,7 +14,7 @@ Three timelines, one clock:
   / ``readback``) and per-step counter tracks (batch composition,
   token-budget utilization, pages free/referenced/cached-idle, queue
   depth). The phases that hold the host's time have children:
-  ``dispatch.key`` (one slice a decode row), ``dispatch.stage``,
+  ``dispatch.key`` (one slice a launch), ``dispatch.stage``,
   ``dispatch.launch``, ``readback.wait`` (the host blocked on the device),
   ``readback.resolve``, and one ``prefill.chunk`` a chunk.
 * **Training steps** — a ``Trainer`` writes one ``epoch`` slice an epoch,

@@ -46,9 +46,8 @@ import signal
 import time
 from typing import Dict, List, Optional, Tuple
 
-import jax
-
 from distributed_pytorch_tpu import chaos
+from distributed_pytorch_tpu.generation import host_prng_key
 from distributed_pytorch_tpu.obs.tracer import _PID_REQUESTS
 from distributed_pytorch_tpu.serving.mods import Mods, ModState
 from distributed_pytorch_tpu.serving.scheduler import (
@@ -470,7 +469,7 @@ def restore_engine(
             req.rework_until = rec.kv_committed
             req.rework_kind = "restore_reprefill"
             engine.requests[req_id] = req
-            engine._keys[req_id] = jax.random.PRNGKey(params.seed)
+            engine._keys[req_id] = host_prng_key(params.seed)
             engine.scheduler.add(req)
             if tr.enabled:
                 extra = (

@@ -42,7 +42,14 @@ drawn with ``fold_in(key, i)`` — independent of batch composition, slot
 assignment, and preemption, so a preempted-then-resumed request reproduces
 its exact stream. Under overlap the fold index is the DISPATCH count
 (``n_issued``), which equals the generated count at the same point of the
-synchronous schedule.
+synchronous schedule. The fold happens INSIDE the compiled programs: the
+host keeps each request's base key as ``uint32[2]`` host data
+(:func:`~distributed_pytorch_tpu.generation.host_prng_key`, made at submit
+without touching a device), stages it with the count beside it as one
+``[max_slots, 3]`` operand, and ``_decode_step`` / ``_spec_step`` run
+:func:`~distributed_pytorch_tpu.generation.fold_row_keys` over it. A key
+fetched from the device instead would queue behind the decode program in
+flight and make the host wait out the step it is meant to overlap.
 
 Speculative serving (``draft_model``): each scheduled decode becomes one
 draft+verify ROUND — gamma single-token draft steps propose a chunk, one
@@ -83,6 +90,8 @@ from distributed_pytorch_tpu import chaos
 from distributed_pytorch_tpu.generation import (
     decode_chunk_step,
     decode_token_step,
+    fold_row_keys,
+    host_prng_key,
     make_row_sampler,
     truncate_logits,
 )
@@ -129,6 +138,16 @@ from distributed_pytorch_tpu.serving.scheduler import (
     SamplingParams,
     Scheduler,
 )
+
+
+def _staged(buf: np.ndarray) -> jax.Array:
+    """A reused host staging buffer as a device operand, copied on the host
+    first. A backend may read the array it is handed only once the device
+    gets to the transfer (the CPU backend aliases an aligned NumPy buffer
+    and queues the read behind the program in flight), and nothing in a
+    dispatch waits for the device, so the next refill of the buffer can
+    come sooner than that. The copy is the transfer's alone."""
+    return jnp.array(buf.copy())
 
 
 class _PhaseSpan:
@@ -544,23 +563,25 @@ class InferenceEngine:
             chaos.add_fault_observer(self._on_chaos_fault)
         self.requests: Dict[int, Request] = {}
         self._next_id = 0
-        self._keys: Dict[int, jax.Array] = {}
+        # req_id -> the request's base key, host data (host_prng_key).
+        self._keys: Dict[int, np.ndarray] = {}
 
         # Reusable host staging buffers for the batched decode inputs —
         # refilled in place every step instead of reallocated. Rows for
         # inactive slots MUST be re-zeroed each step (a stale block-table
         # row would scatter the masked write into a page some other request
-        # now owns). They go to the device through jnp.array, which always
-        # copies: jnp.asarray may ALIAS a 64-byte-aligned numpy buffer on
-        # the CPU backend, and the next step's refill would then race the
-        # still-running dispatch that reads it.
+        # now owns). They go to the device through _staged, which copies
+        # them on the host first: the next refill must not reach a dispatch
+        # that has yet to read them.
         self._stage_tokens = np.zeros((max_slots,), np.int32)
         self._stage_tables = np.zeros(
             (max_slots, self.pages_per_seq), np.int32
         )
         self._stage_lens = np.zeros((max_slots,), np.int32)
         self._stage_temps = np.zeros((max_slots,), np.float32)
-        self._stage_keys = np.zeros((max_slots, 2), np.uint32)
+        # A row's base key and, beside it, the index of the token it is
+        # about to draw: the programs fold the one into the other.
+        self._stage_keys = np.zeros((max_slots, 3), np.uint32)
         self._stage_use_prev = np.zeros((max_slots,), np.int32)
         self._zero_prev = jnp.zeros((max_slots,), jnp.int32)
         # Fixed-shape additive-logit operand for per-request mods. The
@@ -853,7 +874,10 @@ class InferenceEngine:
         lifetime. Greedy and sampled rows coexist via a per-slot temperature
         vector (0 = greedy); ``prev``/``use_prev`` splice the previous
         step's device-resident samples in as inputs so overlapped slots
-        never wait on a host readback. ``bias`` is the fixed-shape
+        never wait on a host readback. ``keys`` is the staged
+        ``[max_slots, 3]`` operand of base keys and token indices; the
+        per-step keys are folded here, in the program (see the module
+        docstring). ``bias`` is the fixed-shape
         ``[max_slots, vocab]`` additive logit operand carrying
         per-request logit-bias and grammar-mask rows — always present
         (all-zeros when no row has mods, a cached device constant so the
@@ -868,7 +892,7 @@ class InferenceEngine:
                 self.decode_model, params, cache, tok[:, None],
                 block_tables=tables, seq_lens=lens,
             )
-            nxt = row_sample(last_logits, temps, keys, bias)
+            nxt = row_sample(last_logits, temps, fold_row_keys(keys), bias)
             return nxt, cache
 
         # The fused-kernel decode compiles under its own ledger name so the
@@ -1137,8 +1161,10 @@ class InferenceEngine:
         tokens, ``emitted[s, :that]``. K/V past a row's emitted count is
         rejected garbage in BOTH pools and needs no cleanup: reads mask
         positions >= seq_len and the next round overwrites before
-        attending. Per-round sub-draws derive from the staged per-request
-        key: draft step i folds i, acceptance uniforms fold gamma, the
+        attending. The round's key is folded in the program from the
+        staged ``[max_slots, 3]`` operand (base key, token index), as in
+        :meth:`_decode_step`, and per-round sub-draws derive from it:
+        draft step i folds i, acceptance uniforms fold gamma, the
         residual draw folds gamma+1 — batch-composition independent, like
         everything else about sampling here."""
         top_k, top_p = self._top_k, self._top_p
@@ -1161,6 +1187,7 @@ class InferenceEngine:
         def run(params, draft_params, cache, draft_cache, tokens, tables,
                 lens, temps, keys):
             rows = jnp.arange(n_slots)
+            keys = fold_row_keys(keys)
 
             def fold_all(i):
                 return jax.vmap(jax.random.fold_in, in_axes=(0, None))(
@@ -1373,7 +1400,7 @@ class InferenceEngine:
         )
         self._next_id += 1
         self.requests[req.req_id] = req
-        self._keys[req.req_id] = jax.random.PRNGKey(params.seed)
+        self._keys[req.req_id] = host_prng_key(params.seed)
         if self.tracer.enabled:
             extra = {"trace_id": trace_id} if trace_id is not None else {}
             self.tracer.request_begin(
@@ -1426,18 +1453,31 @@ class InferenceEngine:
             span.note(finished=len(finished))
         return finished
 
+    def _stage_row_keys(self, slots: List[int]) -> None:
+        """Write each row's base key and ``n_issued`` (the index of the
+        token it is about to draw, pending ones counted) into the staged
+        key operand: plain NumPy writes under one ``dispatch.key`` slice a
+        launch, which times all that the keys cost the host."""
+        with self._phase("dispatch.key", rows=len(slots)):
+            for slot in slots:
+                req = self.scheduler.slots[slot]
+                staged = self._stage_keys[slot]
+                staged[:2] = self._keys[req.req_id]
+                staged[2] = req.n_issued
+
     def _dispatch_decode(self, slots: List[int], params, prev):
         """Stage and run THE decode program for ``slots``. Rows outside
         the group stage a zeroed block table and length, so their masked
         K/V writes land in the null page — per-group dispatch commits
         state for its own rows only, which is what lets one step issue
-        the base program plus per-adapter groups against one cache."""
+        the base program plus per-adapter groups against one cache. The
+        sampling keys are staged as host data (:meth:`_stage_row_keys`)
+        and folded inside the program: nothing here reads from the device,
+        so the dispatch never waits for the program in flight."""
         self._stage_tables.fill(0)
         self._stage_lens.fill(0)
         self._stage_use_prev.fill(0)
         bias = None
-        # One slice a row costs a dictionary each: only when someone reads.
-        key_span = self._phase if self.tracer.enabled else NULL_TRACER.phase
         for slot in slots:
             req = self.scheduler.slots[slot]
             pos = req.len_cached
@@ -1452,19 +1492,13 @@ class InferenceEngine:
             self._stage_tables[slot] = req.table.as_row(self.pages_per_seq)
             self._stage_lens[slot] = pos
             self._stage_temps[slot] = req.params.temperature
-            with key_span("dispatch.key", slot=slot):
-                self._stage_keys[slot] = np.asarray(
-                    jax.random.fold_in(
-                        self._keys[req.req_id], req.n_issued
-                    ),
-                    np.uint32,
-                )
             row = req.mods.bias_row() if req.mods is not None else None
             if row is not None:
                 if bias is None:
                     bias = self._stage_bias
                     bias.fill(0.0)
                 bias[slot] = row
+        self._stage_row_keys(slots)
         staged = (
             self._stage_tokens.nbytes
             + self._stage_use_prev.nbytes
@@ -1480,14 +1514,14 @@ class InferenceEngine:
                 self.xla.count_h2d(staged)
             # No modded rows: reuse the zeros device constant — the bias
             # operand costs the common path nothing.
-            bias_arr = self._zero_bias if bias is None else jnp.array(bias)
+            bias_arr = self._zero_bias if bias is None else _staged(bias)
             decode_step = self._decode_step
-            tokens = jnp.array(self._stage_tokens)
-            use_prev = jnp.array(self._stage_use_prev)
-            tables = jnp.array(self._stage_tables)
-            lens = jnp.array(self._stage_lens)
-            temps = jnp.array(self._stage_temps)
-            keys = jnp.array(self._stage_keys)
+            tokens = _staged(self._stage_tokens)
+            use_prev = _staged(self._stage_use_prev)
+            tables = _staged(self._stage_tables)
+            lens = _staged(self._stage_lens)
+            temps = _staged(self._stage_temps)
+            keys = _staged(self._stage_keys)
         with self._phase("dispatch.launch"):
             nxt, self.cache = decode_step(
                 params, self.cache, tokens, prev, use_prev, tables, lens,
@@ -1844,16 +1878,14 @@ class InferenceEngine:
         block on the round's readback — speculative rounds must resolve
         within their own step (the next schedule needs each row's accepted
         count), so overlap here means hiding the sync under prefill rather
-        than deferring it a step like the plain path."""
+        than deferring it a step like the plain path. Keys are staged as
+        in :meth:`_dispatch_decode` and folded inside ``_spec_step``."""
         tr = self.tracer
         dispatched = None
         if plan.decode_slots:
             with self._phase("dispatch"):
                 self._stage_tables.fill(0)
                 self._stage_lens.fill(0)
-                key_span = (
-                    self._phase if tr.enabled else NULL_TRACER.phase
-                )
                 for slot in plan.decode_slots:
                     req = self.scheduler.slots[slot]
                     pos = req.len_cached
@@ -1865,13 +1897,7 @@ class InferenceEngine:
                     )
                     self._stage_lens[slot] = pos
                     self._stage_temps[slot] = req.params.temperature
-                    with key_span("dispatch.key", slot=slot):
-                        self._stage_keys[slot] = np.asarray(
-                            jax.random.fold_in(
-                                self._keys[req.req_id], req.n_issued
-                            ),
-                            np.uint32,
-                        )
+                self._stage_row_keys(plan.decode_slots)
                 staged = (
                     self._stage_tokens.nbytes
                     + self._stage_tables.nbytes
@@ -1886,11 +1912,11 @@ class InferenceEngine:
                     if self.xla is not None:
                         self.xla.count_h2d(staged)
                     spec_step = self._spec_step
-                    tokens = jnp.array(self._stage_tokens)
-                    tables = jnp.array(self._stage_tables)
-                    lens = jnp.array(self._stage_lens)
-                    temps = jnp.array(self._stage_temps)
-                    keys = jnp.array(self._stage_keys)
+                    tokens = _staged(self._stage_tokens)
+                    tables = _staged(self._stage_tables)
+                    lens = _staged(self._stage_lens)
+                    temps = _staged(self._stage_temps)
+                    keys = _staged(self._stage_keys)
                 with self._phase("dispatch.launch"):
                     emitted, n_acc, self.cache, self.draft_cache = (
                         spec_step(

@@ -380,8 +380,8 @@ class TestEngineObservability:
     def test_tracing_does_not_change_tokens(self, mode):
         """Acceptance: with tracing enabled, greedy outputs are
         bitwise-identical to the untraced engine, and the trace holds
-        every child slice inside its parent: as many ``dispatch.key`` a
-        step as decode rows."""
+        every child slice inside its parent: one ``dispatch.key`` a
+        launch, covering all of the step's decode rows."""
         kw = {
             "overlap": dict,
             "sync": lambda: dict(overlap=False),
@@ -420,10 +420,11 @@ class TestEngineObservability:
                         for p in rows
                     ), (step, e["name"])
             keys = [e for e in rows if e["name"] == "dispatch.key"]
-            assert len(keys) == whole["args"]["decode_rows"]
-            assert len({e["args"]["slot"] for e in keys}) == len(keys)
+            decode_rows = whole["args"]["decode_rows"]
+            assert len(keys) == (1 if decode_rows else 0)
+            assert sum(e["args"]["rows"] for e in keys) == decode_rows
             launches = [e for e in rows if e["name"] == "dispatch.launch"]
-            assert len(launches) == (1 if keys else 0)
+            assert len(launches) == len(keys)
         assert seen >= {
             "schedule", "prefill", "prefill.chunk", "dispatch",
             "dispatch.key", "dispatch.stage", "dispatch.launch", "readback",
@@ -439,9 +440,9 @@ class TestEngineObservability:
             if e["name"] == "step"
         ) > 0
 
-    def test_an_untraced_engine_writes_no_key_slices(self):
-        """``dispatch.key`` is one slice a row: the accounting path, which
-        sees the other children, is spared it while no tracer reads."""
+    def test_the_accounting_path_sees_the_key_slice(self):
+        """``dispatch.key`` is one slice a launch, so the accounting path
+        times it like ``dispatch``'s other children, tracer or none."""
         eng = _tiny_engine(flight=FlightRecorder(64), timeseries=True)
         _run_all(eng)
         names = {
@@ -449,7 +450,7 @@ class TestEngineObservability:
         }
         assert "phase_dispatch.stage_seconds" in names
         assert "phase_readback.wait_seconds" in names
-        assert "phase_dispatch.key_seconds" not in names
+        assert "phase_dispatch.key_seconds" in names
 
     def test_span_count_equals_completed_requests(self, tmp_path):
         tr = Tracer()

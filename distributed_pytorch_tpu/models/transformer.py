@@ -147,6 +147,19 @@ class Attention(nn.Module):
     # float32 scale pools, quantized at every page write, dequantized at
     # read (inline gather or inside the kernel).
     kv_quant: str = ""
+    # False: no positional term at all (a hybrid model whose recurrent
+    # layers carry position). Every path below then attends on the raw
+    # projections.
+    rope: bool = True
+    use_bias: bool = True  # biases on the four projections
+
+    def _rope(self, x, positions=None):
+        if not self.rope:
+            return x
+        return apply_rope(
+            x, theta=self.rope_theta, scale=self.rope_scale,
+            positions=positions,
+        )
 
     @nn.compact
     def __call__(
@@ -211,7 +224,12 @@ class Attention(nn.Module):
                 f"{kv_heads}"
             )
         dense = lambda heads, name: nn.DenseGeneral(  # noqa: E731
-            (heads, head_dim), dtype=self.dtype, name=name
+            (heads, head_dim), dtype=self.dtype, use_bias=self.use_bias,
+            name=name,
+        )
+        out_proj = nn.DenseGeneral(
+            self.d_model, axis=(-2, -1), dtype=self.dtype,
+            use_bias=self.use_bias, name="out",
         )
         q_raw = dense(self.n_heads, "query")(x)
         k_raw = dense(kv_heads, "key")(x)
@@ -229,9 +247,7 @@ class Attention(nn.Module):
                 )
             else:
                 out = self._decode_step(q_raw, k_raw, v)
-            return nn.DenseGeneral(
-                self.d_model, axis=(-2, -1), dtype=self.dtype, name="out"
-            )(out)
+            return out_proj(out)
         if self.decode:
             # Cache init pass: size the KV cache — to this call's (max)
             # length in contiguous mode, to the global page pool in paged
@@ -266,11 +282,8 @@ class Attention(nn.Module):
                     "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
                 )
 
-        rope = lambda x, **kw: apply_rope(  # noqa: E731
-            x, theta=self.rope_theta, scale=self.rope_scale, **kw
-        )
-        q = rope(q_raw)
-        k = rope(k_raw)
+        q = self._rope(q_raw)
+        k = self._rope(k_raw)
         if kv_heads != self.n_heads:
             # Compute-side broadcast for the cores that need full heads
             # (flash, ulysses). Ring and decode take the UN-repeated k/v so
@@ -313,9 +326,7 @@ class Attention(nn.Module):
                 q, kx, vx, causal=self.causal, window=self.window,
                 mesh=self.mesh,
             )
-        return nn.DenseGeneral(
-            self.d_model, axis=(-2, -1), dtype=self.dtype, name="out"
-        )(out)
+        return out_proj(out)
 
     def _decode_step(self, q_raw, k_raw, v):
         """One autoregressive step: rotate q/k by their absolute positions,
@@ -348,14 +359,8 @@ class Attention(nn.Module):
             positions = index[:, None] + jnp.arange(t_step)  # [B, T_step]
         else:
             positions = index + jnp.arange(t_step)  # [T_step]
-        q = apply_rope(
-            q_raw, positions=positions, theta=self.rope_theta,
-            scale=self.rope_scale,
-        )
-        k = apply_rope(
-            k_raw, positions=positions, theta=self.rope_theta,
-            scale=self.rope_scale,
-        )
+        q = self._rope(q_raw, positions)
+        k = self._rope(k_raw, positions)
 
         if self.quantized_cache:
             keys, values = self._update_quantized_cache(
@@ -452,14 +457,8 @@ class Attention(nn.Module):
 
         seq_lens = seq_lens.astype(jnp.int32)
         positions = seq_lens[:, None] + jnp.arange(t_step, dtype=jnp.int32)
-        q = apply_rope(
-            q_raw, positions=positions, theta=self.rope_theta,
-            scale=self.rope_scale,
-        )
-        k = apply_rope(
-            k_raw, positions=positions, theta=self.rope_theta,
-            scale=self.rope_scale,
-        )
+        q = self._rope(q_raw, positions)
+        k = self._rope(k_raw, positions)
 
         # Scatter this step's K/V into (physical page, in-page offset). A
         # position at or past the row's table capacity — a speculative
@@ -583,12 +582,40 @@ class MLPBlock(nn.Module):
     d_ff: int
     d_model: int
     dtype: Any = jnp.float32
+    # "gelu": down(gelu(up x)); "gated_silu": down(silu(gate x) * up x).
+    kind: str = "gelu"
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        h = nn.Dense(self.d_ff, dtype=self.dtype, name="up")(x)
-        h = nn.gelu(h)
-        return nn.Dense(self.d_model, dtype=self.dtype, name="down")(h)
+        dense = lambda feats, name: nn.Dense(  # noqa: E731
+            feats, dtype=self.dtype, use_bias=self.use_bias, name=name
+        )
+        h = dense(self.d_ff, "up")(x)
+        if self.kind == "gated_silu":
+            h = nn.silu(dense(self.d_ff, "gate")(x)) * h
+        elif self.kind == "gelu":
+            h = nn.gelu(h)
+        else:
+            raise ValueError(
+                f"unknown mlp kind {self.kind!r} "
+                "(expected 'gelu' or 'gated_silu')"
+            )
+        return dense(self.d_model, "down")(h)
+
+
+LAYER_TYPES = ("attention", "mamba")
+
+
+def make_norm(kind: str, eps: float, name: str) -> nn.Module:
+    """A block's normalisation: statistics and output in float32."""
+    if kind == "layernorm":
+        return nn.LayerNorm(epsilon=eps, dtype=jnp.float32, name=name)
+    if kind == "rmsnorm":
+        return nn.RMSNorm(epsilon=eps, dtype=jnp.float32, name=name)
+    raise ValueError(
+        f"unknown norm {kind!r} (expected 'layernorm' or 'rmsnorm')"
+    )
 
 
 class TransformerBlock(nn.Module):
@@ -614,6 +641,15 @@ class TransformerBlock(nn.Module):
     num_pages: int = 0
     paged_kernel: str = ""  # fused paged-decode read path (see Attention)
     kv_quant: str = ""  # int8 KV pages + scale pools (see Attention)
+    # The block's options (see TransformerLM): the defaults are the block
+    # every model had before them.
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    mlp: str = "gelu"
+    use_bias: bool = True
+    rope: bool = True
+    mixer: str = "attention"  # one of LAYER_TYPES
+    mamba: tuple = ()  # MambaMixer's sizes as (field, value) pairs
 
     @nn.compact
     def __call__(
@@ -622,6 +658,7 @@ class TransformerBlock(nn.Module):
         *,
         block_tables: Optional[jnp.ndarray] = None,
         seq_lens: Optional[jnp.ndarray] = None,
+        state_slots: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
         def drop(y):
             # Active only when a "dropout" rng is supplied (the train step
@@ -639,17 +676,33 @@ class TransformerBlock(nn.Module):
             {} if block_tables is None
             else {"block_tables": block_tables, "seq_lens": seq_lens}
         )
-        x = x + drop(Attention(
-            self.n_heads, self.d_model, self.dtype, self.causal,
-            n_kv_heads=self.n_kv_heads, window=self.window,
-            rope_scale=self.rope_scale, rope_theta=self.rope_theta,
-            mesh=self.mesh, sequence_axis=self.sequence_axis,
-            sequence_mode=self.sequence_mode, decode=self.decode,
-            quantized_cache=self.quantized_cache,
-            page_size=self.page_size, num_pages=self.num_pages,
-            paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
-            name="attention",
-        )(nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(x), **paged_kw))
+        normed = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
+        if self.mixer == "mamba":
+            from distributed_pytorch_tpu.models.mamba import MambaMixer
+
+            mixed = MambaMixer(
+                self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
+                decode=self.decode, name="mamba", **dict(self.mamba),
+            )(normed, seq_lens=seq_lens, state_slots=state_slots)
+        elif self.mixer == "attention":
+            mixed = Attention(
+                self.n_heads, self.d_model, self.dtype, self.causal,
+                n_kv_heads=self.n_kv_heads, window=self.window,
+                rope_scale=self.rope_scale, rope_theta=self.rope_theta,
+                mesh=self.mesh, sequence_axis=self.sequence_axis,
+                sequence_mode=self.sequence_mode, decode=self.decode,
+                quantized_cache=self.quantized_cache,
+                page_size=self.page_size, num_pages=self.num_pages,
+                paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
+                rope=self.rope, use_bias=self.use_bias,
+                name="attention",
+            )(normed, **paged_kw)
+        else:
+            raise ValueError(
+                f"unknown layer type {self.mixer!r} "
+                f"(expected one of {LAYER_TYPES})"
+            )
+        x = x + drop(mixed)
         if self.n_experts > 0:
             cls = nn.remat(MoEMLP) if self.remat_mlp else MoEMLP
             mlp = cls(
@@ -658,8 +711,11 @@ class TransformerBlock(nn.Module):
             )
         else:
             cls = nn.remat(MLPBlock) if self.remat_mlp else MLPBlock
-            mlp = cls(self.d_ff, self.d_model, self.dtype, name="mlp")
-        x = x + drop(mlp(nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(x)))
+            mlp = cls(
+                self.d_ff, self.d_model, self.dtype, kind=self.mlp,
+                use_bias=self.use_bias, name="mlp",
+            )
+        x = x + drop(mlp(make_norm(self.norm, self.norm_eps, "ln_mlp")(x)))
         return x
 
 
@@ -788,6 +844,29 @@ class TransformerLM(nn.Module):
     num_pages: int = 0
     paged_kernel: str = ""  # fused paged-decode read path (see Attention)
     kv_quant: str = ""  # int8 KV pages + scale pools (see Attention)
+    # The block, by option. The defaults are the one block every model had
+    # before these fields existed (LayerNorm at flax's epsilon, biased
+    # tanh-GELU MLP, RoPE, attention in every layer): its parameters and
+    # compiled programs are unchanged.
+    norm: str = "layernorm"  # "layernorm" | "rmsnorm"
+    norm_eps: float = 1e-6
+    mlp: str = "gelu"  # "gelu" | "gated_silu" (gate, up, down)
+    use_bias: bool = True  # on the attention and MLP projections
+    rope: bool = True  # False: attention has no positional term
+    # One of LAYER_TYPES per layer; None = attention everywhere. A "mamba"
+    # layer is models/mamba.py's mixer in the attention's place; in decode
+    # mode it keeps a per-slot recurrent state in the ``cache`` collection
+    # beside the KV pools, and ``__call__`` must be told ``state_slots``.
+    layer_types: Optional[tuple] = None
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0  # required where a layer is "mamba"
+
+    @property
+    def recurrent_layers(self) -> int:
+        """How many layers keep a per-slot state in decode mode."""
+        return sum(t == "mamba" for t in self.layer_types or ())
 
     @nn.compact
     def __call__(
@@ -797,7 +876,16 @@ class TransformerLM(nn.Module):
         *,
         block_tables: Optional[jnp.ndarray] = None,
         seq_lens: Optional[jnp.ndarray] = None,
+        state_slots: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
+        types = self.layer_types
+        if types is not None and len(types) != self.n_layers:
+            raise ValueError(
+                f"layer_types names {len(types)} layers, the model has "
+                f"{self.n_layers}"
+            )
+        if self.recurrent_layers and self.mamba_dt_rank < 1:
+            raise ValueError("a model with mamba layers needs mamba_dt_rank")
         embed = nn.Embed(
             self.vocab_size, self.d_model, dtype=self.dtype, name="embed"
         )
@@ -821,9 +909,22 @@ class TransformerLM(nn.Module):
             {} if block_tables is None
             else {"block_tables": block_tables, "seq_lens": seq_lens}
         )
+        if state_slots is not None:
+            paged_kw["state_slots"] = state_slots
+        block_kw = dict(
+            norm=self.norm, norm_eps=self.norm_eps, mlp=self.mlp,
+            use_bias=self.use_bias, rope=self.rope,
+        )
+        mamba_kw = (
+            ("d_state", self.mamba_d_state), ("d_conv", self.mamba_d_conv),
+            ("expand", self.mamba_expand), ("dt_rank", self.mamba_dt_rank),
+        )
         for i in range(self.n_layers):
             # GShard-style interleaving: every `moe_every`-th block is MoE.
             moe = self.n_experts if (i + 1) % self.moe_every == 0 else 0
+            layer_kw = block_kw
+            if types is not None and types[i] != "attention":
+                layer_kw = dict(block_kw, mixer=types[i], mamba=mamba_kw)
             x = block(
                 self.n_heads, self.d_model, self.d_ff, self.dtype,
                 True, self.mesh, self.sequence_axis,
@@ -836,9 +937,9 @@ class TransformerLM(nn.Module):
                 quantized_cache=self.quantized_cache,
                 page_size=self.page_size, num_pages=self.num_pages,
                 paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
-                name=f"block_{i}",
+                name=f"block_{i}", **layer_kw,
             )(x, **paged_kw)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
+        x = make_norm(self.norm, self.norm_eps, "ln_final")(x)
         if self.fused_head_chunk and self.vocab_size % self.fused_head_chunk:
             # Fail loudly here: a silent dense fallback would surface later as
             # a baffling "gradient only defined for scalar-output functions"

@@ -379,6 +379,12 @@ def restore_engine(
         raise ValueError(
             f"snapshot version {snapshot.version} != {SNAPSHOT_VERSION}"
         )
+    if getattr(engine, "state_layers", 0):
+        raise ValueError(
+            "an engine whose model has recurrent layers cannot restore a "
+            "snapshot yet: a snapshot holds tokens and page geometry, and "
+            "nothing of the per-slot recurrent state or of how it is rebuilt"
+        )
     if (snapshot.top_k, snapshot.top_p) != (engine._top_k, engine._top_p):
         raise ValueError(
             f"snapshot was taken under top_k={snapshot.top_k} "

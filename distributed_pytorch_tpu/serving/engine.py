@@ -72,6 +72,42 @@ match the single-token path bitwise at f32), so a speculative engine is
 token-identical to the plain engine; sampled rows follow Leviathan et
 al.'s residual-resampling rule, keeping every emitted token exactly
 target-distributed.
+
+Recurrent layers (a model whose ``layer_types`` name ``"mamba"`` layers,
+``models/mamba.py``): a request then owns, beside its pages, ONE fixed-size
+state, the row of its slot in every such layer's two ``cache`` variables
+(``conv_state [max_slots, K-1, d_inner]``, ``scan_state [max_slots, N,
+d_inner]`` float32). The engine reads that the model has them from the
+model's ``recurrent_layers`` and from the cache tree it builds; there is no
+argument for it. The one design:
+
+* **decode** runs all ``max_slots`` rows, row ``r`` on slot ``r``'s state in
+  place. A row outside the dispatched group has a zeroed block table; the
+  program derives ``state_slots = where(table[:, 0] != NULL_PAGE, r, -1)``
+  from the operand it already stages, and the mixer writes a state only
+  under ``state_slots >= 0``: such a row keeps both states bit for bit (a
+  recurrence has no null page to absorb a masked write). A row in the group
+  advances by one token. Overlap stays on: the one wasted step of a row
+  that had already stopped (its ``PENDING_TOKEN`` input) runs with the
+  row's old table and so writes the state of a slot that has been retired;
+  nobody reads it, because
+* **a position 0 starts from zeros**: whichever program carries a row whose
+  ``seq_lens`` is 0 (the first prefill chunk of an admission or of a
+  re-prefill after preemption, or the decode step of a one-token prompt)
+  reads zeros in place of what the slot held. Nothing is zeroed ahead of
+  time and no program exists for it. Each such start is a ``state.reset``
+  instant in the trace (slot, request, cause ``admit`` | ``preempt``).
+* **prefill** is the same ``[1, chunk]`` program with one more operand, the
+  slot whose state the chunk carries on. The recurrence is sequential in
+  the token, so chunks of any sizes give the state one pass over the whole
+  prompt gives, to float32 rounding of ``h`` at the chunk borders (none: the
+  carry is the float32 state itself).
+* **what cannot be served yet is refused in the constructor**: the prefix
+  cache (a hit skips positions whose state nobody kept: it needs a state
+  snapshot at the page boundary), a draft model (a rejected proposal
+  cannot be taken back out of a recurrence), the host page tier and a mesh
+  (both know pages only), and ``serving/elastic.py``'s restore. ``kv_quant``
+  is served: it concerns the attention layers' pages alone.
 """
 
 from __future__ import annotations
@@ -95,6 +131,7 @@ from distributed_pytorch_tpu.generation import (
     make_row_sampler,
     truncate_logits,
 )
+from distributed_pytorch_tpu.models.mamba import STATE_KEYS
 from distributed_pytorch_tpu.obs import MetricsRegistry, Tracer
 from distributed_pytorch_tpu.obs.flight import (
     NULL_FLIGHT_RECORDER,
@@ -307,6 +344,29 @@ class InferenceEngine:
                 )
         self.gamma = int(gamma) if self.speculative else 0
         self.draft_params = draft_params
+        # Layers that keep a per-slot recurrent state (module docstring):
+        # read from the model, never asked of the caller.
+        self.state_layers = int(getattr(model, "recurrent_layers", 0))
+        if self.state_layers:
+            for given, what, why in (
+                (prefix_cache, "prefix_cache=True",
+                 "a prefix hit skips positions whose recurrent state nobody "
+                 "kept (it needs a state snapshot at the page boundary)"),
+                (draft_model is not None, "draft_model",
+                 "a rejected proposal cannot be rolled back out of a "
+                 "recurrent state"),
+                (host_pages, "host_pages",
+                 "the host tier spills and fetches KV pages only, not "
+                 "recurrent state"),
+                (mesh is not None, "mesh",
+                 "the serving mesh shards KV page pools only, not "
+                 "recurrent state"),
+            ):
+                if given:
+                    raise ValueError(
+                        f"a model with recurrent layers cannot be served "
+                        f"with {what} yet: {why}"
+                    )
 
         # Mesh geometry is engine-static, like top_k/top_p: it is compiled
         # into every program and fingerprinted into elastic snapshots.
@@ -371,6 +431,15 @@ class InferenceEngine:
         # lifecycle decision moves both pools in lockstep. Head/width can
         # differ freely; only the page GEOMETRY must match.
         pools = {"target": _zero_cache(self.decode_model)}
+        # Bytes of recurrent state one slot owns, over every layer.
+        self.state_bytes_per_slot = sum(
+            leaf.nbytes // max_slots
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                pools["target"]
+            )[0]
+            if getattr(path[-1], "key", None) in STATE_KEYS
+        )
+        self.state_resets = 0
         if self.speculative:
             self.draft_decode_model = draft_model.clone(
                 decode=True, page_size=page_size, num_pages=num_pages,
@@ -722,6 +791,12 @@ class InferenceEngine:
             "pages_referenced", lambda: self.allocator.num_allocated
         )
         reg.gauge_fn("pages_cached_idle", lambda: self.allocator.num_idle)
+        if self.state_layers:
+            reg.gauge_fn(
+                "state_slots_in_use", lambda: len(self.scheduler.running)
+            )
+            reg.gauge_fn("state_bytes", self._state_bytes)
+            reg.counter_fn("state_resets_total", lambda: self.state_resets)
         reg.gauge_fn("queue_depth", lambda: self.scheduler.num_waiting)
         reg.gauge_fn(
             "running_requests", lambda: len(self.scheduler.running)
@@ -891,6 +966,7 @@ class InferenceEngine:
             last_logits, cache = decode_token_step(
                 self.decode_model, params, cache, tok[:, None],
                 block_tables=tables, seq_lens=lens,
+                **self._decode_state_kw(tables),
             )
             nxt = row_sample(last_logits, temps, fold_row_keys(keys), bias)
             return nxt, cache
@@ -922,15 +998,42 @@ class InferenceEngine:
             ),
         )
 
+    def _state_bytes(self) -> int:
+        """Recurrent state held by the requests that own a slot."""
+        return self.state_bytes_per_slot * len(self.scheduler.running)
+
+    def _decode_state_kw(self, tables) -> dict:
+        """What the batched decode program tells a model with recurrent
+        layers (nothing to any other): row ``r`` carries slot ``r``'s state
+        iff the row is in the dispatched group, which is iff its staged
+        block table is not the zeroed one."""
+        if not self.state_layers:
+            return {}
+        rows = jnp.arange(self.max_slots, dtype=jnp.int32)
+        return {"state_slots": jnp.where(tables[:, 0] != NULL_PAGE, rows, -1)}
+
+    def _note_state_reset(self, slot: int, req: Request) -> None:
+        """A row at position 0 is about to run: its state starts from
+        zeros (the mixer does it; this is the count and the trace)."""
+        self.state_resets += 1
+        if self.tracer.enabled:
+            self.tracer.instant(
+                "state.reset", slot=slot, request=req.req_id,
+                cause="preempt" if req.preempt_count else "admit",
+            )
+
     @functools.lru_cache(maxsize=16)
     def _prefill_step(self, chunk: int):
         """One compile per power-of-two chunk length; returns only the
-        updated cache, so XLA prunes the LM head from the program."""
+        updated cache, so XLA prunes the LM head from the program. With
+        recurrent layers the program takes one more operand: the slot
+        whose state the chunk carries on."""
 
-        def run(params, cache, tokens, table, length):
+        def run(params, cache, tokens, table, length, *slot):
+            state_kw = {"state_slots": slot[0]} if slot else {}
             _, cache = decode_token_step(
                 self.decode_model, params, cache, tokens,
-                block_tables=table, seq_lens=length,
+                block_tables=table, seq_lens=length, **state_kw,
             )
             return cache
 
@@ -1491,6 +1594,8 @@ class InferenceEngine:
                 self._stage_tokens[slot] = tok
             self._stage_tables[slot] = req.table.as_row(self.pages_per_seq)
             self._stage_lens[slot] = pos
+            if pos == 0 and self.state_layers:  # a one-token prompt
+                self._note_state_reset(slot, req)
             self._stage_temps[slot] = req.params.temperature
             row = req.mods.bias_row() if req.mods is not None else None
             if row is not None:
@@ -1552,6 +1657,9 @@ class InferenceEngine:
             extra["bytes_h2d"] = dh2d
             extra["bytes_d2h"] = dd2h
             extra["live_buffer_bytes"] = self.xla.live_bytes
+        if self.state_layers:
+            extra["state_slots_in_use"] = len(self.scheduler.running)
+            extra["state_bytes"] = self._state_bytes()
         self.tracer.end_step(
             decode_rows=len(plan.decode_slots),
             prefill_chunks=len(plan.prefill),
@@ -1787,10 +1895,15 @@ class InferenceEngine:
                             if ms is not None and ms.adapter is not None
                             else self.params
                         )
+                        state_slot = ()
+                        if self.state_layers:
+                            state_slot = (jnp.asarray([slot], jnp.int32),)
+                            if start == 0:
+                                self._note_state_reset(slot, req)
                         self.cache = self._prefill_step(chunk)(
                             chunk_params, self.cache, jnp.asarray(tok),
                             jnp.asarray(table),
-                            jnp.asarray([start], jnp.int32),
+                            jnp.asarray([start], jnp.int32), *state_slot,
                         )
                     self.scheduler.note_prefilled(slot, chunk)
 
@@ -2129,6 +2242,14 @@ class InferenceEngine:
                     ],
                 },
             }
+            if self.state_layers:
+                out["state"] = {
+                    "layers": self.state_layers,
+                    "bytes_per_slot": self.state_bytes_per_slot,
+                    "state_slots_in_use": len(self.scheduler.running),
+                    "state_bytes": self._state_bytes(),
+                    "resets": self.state_resets,
+                }
             if self.prefix_cache is not None:
                 out["prefix_cache"] = self.prefix_cache.stats()
             if self.hostkv is not None:

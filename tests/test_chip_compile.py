@@ -89,13 +89,15 @@ def flash_case(chip, t, grad):
 
 
 CASES = {
-    # (query heads, KV heads): the smoke's model, its Hkv=4 sibling, and
-    # what one of four tensor-parallel shards of the smoke's model holds.
+    # (query heads, KV heads): the smoke's model, its Hkv=4 sibling, what
+    # one of four tensor-parallel shards of the smoke's model holds, and a
+    # grouping that is no multiple of the sublane count: 20 query heads on
+    # one KV head (the benchmark's hybrid configuration's attention layers).
     **{
         f"paged-H{h}-Hkv{kv}-{'int8' if quant else 'bf16'}": functools.partial(
             paged_case, heads=h, kv_heads=kv, quantized=quant
         )
-        for h, kv in ((16, 8), (16, 4), (4, 2))
+        for h, kv in ((16, 8), (16, 4), (4, 2), (20, 1))
         for quant in (False, True)
     },
     **{

@@ -21,7 +21,9 @@ Three timelines, one clock:
   inside it a ``step`` slice a batch holding ``put_batch`` and
   ``step.dispatch``, and ``epoch.loss_fetch`` where the host waits for the
   device at the epoch's end; its ``ShardedLoader`` writes ``loader.index``
-  and ``loader.stack`` for every batch between the steps. Both record to
+  and ``loader.stack`` for every batch between the steps, and the Trainer
+  ``recycle.fence`` after a step, where it waits for an earlier step to end
+  before it hands that step's host arrays back to the loader. Both record to
   :func:`process_tracer` unless handed another.
 
 Export is Chrome ``trace_event`` JSON (:meth:`Tracer.to_perfetto` /

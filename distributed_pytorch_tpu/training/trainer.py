@@ -21,6 +21,7 @@ only, with a cross-host barrier after the write.
 
 from __future__ import annotations
 
+import collections
 import os
 import signal
 import threading
@@ -79,6 +80,13 @@ def _put_host_state(state, sharding):
     return jax.tree_util.tree_map(put, state, sharding)
 
 
+# Batches whose host arrays a Trainer holds at once: the one the loader is
+# stacking, and two lent to steps not known to be done (one being computed on,
+# one on its way to the device: that copy goes on after ``device_put`` has
+# returned, ~45 ms for 154 MB on a v5e's host, PERF.md §5).
+HOST_BATCHES = 3
+
+
 class Trainer:
     """Drives training of a flax model over a ShardedLoader.
 
@@ -116,6 +124,8 @@ class Trainer:
     ):
         self.model = model
         self.train_data = train_data
+        # (inputs, targets, loss) of the steps last dispatched: _hand_back
+        self._lent: collections.deque = collections.deque()
         self.optimizer = optimizer
         self.save_every = save_every
         self.snapshot_path = snapshot_path
@@ -399,6 +409,21 @@ class Trainer:
             return jax.device_put((xs, ys))
         return put_global_batch(self.mesh, (xs, ys))
 
+    def _hand_back(self, xs, ys, loss, where: dict) -> None:
+        """Lend the step just dispatched its batch's host arrays, and give
+        the loader back those of the step ``HOST_BATCHES - 1`` before it,
+        once that step is done: the device copy may alias them (CPU
+        backend), so the step's output is the fence, not the copy's. The
+        wait is also what keeps the host from running further ahead of the
+        device than that."""
+        self._lent.append((xs, ys, loss))
+        if len(self._lent) < HOST_BATCHES:
+            return
+        xs, ys, loss = self._lent.popleft()
+        with self.tracer.phase("recycle.fence", **where):
+            jax.block_until_ready(loss)
+        self.train_data.recycle(xs, ys)
+
     def _run_batch(self, batch) -> float:
         """One optimizer step (twin of ``_run_batch``, ``single_gpu.py:21-26``)."""
         # Chaos hook: deterministic "kill/hang worker N at step S" fires here
@@ -532,6 +557,7 @@ class Trainer:
                 with tr.phase("step.dispatch", **where):
                     loss = self._run_batch(batch)
                 losses.append(loss)
+            self._hand_back(xs, ys, loss, where)
             if tr.enabled:
                 self.step_times.record(step_span.seconds)
             if self.profiler is not None:

@@ -11,7 +11,8 @@ TPU-native replacement for the reference's data sublayer:
   ``multigpu.py:72-79``). Shard semantics mirror ``DistributedSampler``: the
   index list is padded *by wrapping around* so every shard sees the same number
   of samples, and shards are strided (``indices[shard_index::num_shards]``) so
-  they are pairwise disjoint before padding.
+  they are pairwise disjoint before padding. A consumer that hands a batch's
+  arrays back gets a later batch stacked into them (:meth:`ShardedLoader.recycle`).
 
 Data stays in numpy on the host; device placement (with sharding) happens in the
 Trainer so that the loader is backend-agnostic and cheap to test.
@@ -140,9 +141,18 @@ class ShardedLoader:
     With the reference's divisible defaults (2048 samples / batch 32) neither
     changes anything.
 
+    **Buffers are recycled only when handed back.** Stacking into a NEW array
+    costs ten times the copy itself at image sizes (the pages of a fresh 154 MB
+    array are faulted in one by one: PERF.md §6, PR 27). A consumer that is
+    done with a batch may give its arrays back with :meth:`recycle`, and a
+    later batch of as many rows is stacked into them. A consumer that never
+    hands back (``list(loader)``, an eval loop) owns fresh arrays for as long
+    as it likes.
+
     Every batch leaves two slices in ``tracer`` (the process's own unless
     another is handed over): ``loader.index`` round the ``dataset[i]`` calls
-    and ``loader.stack`` round the two ``np.stack``.
+    and ``loader.stack`` round the two ``np.stack``, noting ``bytes`` and
+    ``recycled``. ``batches_recycled`` / ``batches_allocated`` count the same.
     """
 
     def __init__(
@@ -170,6 +180,28 @@ class ShardedLoader:
         self.pad_final_batch = pad_final_batch
         self.tracer = tracer if tracer is not None else process_tracer()
         self._epoch = 0
+        self._free: list = []  # (inputs, targets) handed back, oldest first
+        self.batches_recycled = self.batches_allocated = 0
+
+    def recycle(self, xs: np.ndarray, ys: np.ndarray) -> None:
+        """Hand back a batch this loader yielded, to be stacked into again:
+        from now on the arrays are the loader's to write. Only for the
+        consumer the batch was yielded to, once NOTHING it started reads them
+        any more. For a copy to a device that is the end of the step that
+        consumed the copy, not of the copy: on the CPU backend
+        ``jax.device_put`` may alias these arrays for the device array's
+        life."""
+        self._free.append((xs, ys))
+
+    def _handed_back(self, rows: int):
+        """The oldest handed-back pair of ``rows`` rows (a ragged final
+        batch's waits for the next ragged one); ``(None, None)``, for
+        ``np.stack`` to allocate, where there is none."""
+        for n, (xs, ys) in enumerate(self._free):
+            if len(xs) == rows:
+                del self._free[n]
+                return xs, ys
+        return None, None
 
     def set_epoch(self, epoch: int) -> None:
         """Reseed shuffling for ``epoch`` (twin of ``DistributedSampler.set_epoch``);
@@ -272,12 +304,17 @@ class ShardedLoader:
             self.batch_index_table()[start_batch:], start=start_batch
         ):
             where = dict(step=step, epoch=self._epoch, rows=len(chunk))
+            out_x, out_y = self._handed_back(len(chunk))
             with tr.phase("loader.index", **where):
                 samples = [self.dataset[int(i)] for i in chunk]
             with tr.phase("loader.stack", **where) as span:
-                xs = np.stack([s[0] for s in samples])
-                ys = np.stack([s[1] for s in samples])
-                span.note(bytes=xs.nbytes + ys.nbytes)
+                xs = np.stack([s[0] for s in samples], out=out_x)
+                ys = np.stack([s[1] for s in samples], out=out_y)
+                span.note(bytes=xs.nbytes + ys.nbytes, recycled=out_x is not None)
+            if out_x is None:
+                self.batches_allocated += 1
+            else:
+                self.batches_recycled += 1
             yield xs, ys
 
     def __iter__(self) -> Iterator[Batch]:
@@ -305,6 +342,14 @@ class NativeShardedLoader(ShardedLoader):
     CPU-backend rig cannot show (measured ~1.0x end to end). Zero-copy slot
     views were considered and rejected: jax's CPU ``device_put`` may alias
     numpy buffers, so recycling slot memory under a live view corrupts data.
+
+    Since PR 27 :class:`ShardedLoader` recycles the buffers its consumer hands
+    back (the Trainer does once the step that read them is done, which is what
+    makes the aliasing above safe), and a copy into such a buffer runs at 10
+    GB/s on the benchmark's host (PERF.md §6, PR 27): no pool is needed at
+    image sizes.
+    This class copies into fresh arrays and recycles nothing; it stays for its
+    tests and ``bench.py`` until ROADMAP D9 folds it away.
     """
 
     def __init__(self, *args, num_workers: int = 2, prefetch_depth: int = 4, **kw):
@@ -342,6 +387,9 @@ class NativeShardedLoader(ShardedLoader):
                     "(dataset.inputs[i], dataset.targets[i]); this dataset's "
                     "__getitem__ transforms the stored arrays"
                 )
+
+    def recycle(self, xs, ys) -> None:
+        """Nothing is kept: the pool copies into fresh arrays."""
 
     def iter_batches(self, start_batch: int = 0) -> Iterator[Batch]:
         import ctypes

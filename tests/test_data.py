@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from distributed_pytorch_tpu.utils.data import (
+    ArrayDataset,
     MaterializedDataset,
     RandomDataset,
     ShardedLoader,
@@ -185,3 +186,196 @@ def test_native_loader_rejects_transforming_getitem():
 
     with pytest.raises(TypeError, match="__getitem__"):
         NativeShardedLoader(Transforming(16), 4)
+
+
+# ------------------------------------------ recycled batch buffers (PR 27)
+
+
+def _image_like(n=100):
+    rng = np.random.default_rng(11)
+    return ArrayDataset(
+        rng.random((n, 5, 4, 3), dtype=np.float32),
+        rng.integers(0, 10, size=n, dtype=np.int32),
+    )
+
+
+class Doubling(MaterializedDataset):
+    """Transforms in ``__getitem__``: its arrays are not what it yields."""
+
+    def __getitem__(self, i):
+        x, y = super().__getitem__(i)
+        return x * 2.0, y
+
+
+@pytest.mark.parametrize(
+    "kw, start",
+    [
+        (dict(shuffle=True, seed=4), 0),
+        (dict(shuffle=True, num_shards=3, shard_index=1), 0),
+        (dict(shuffle=True, pad_final_batch=True), 0),
+        (dict(drop_last=True), 0),
+        (dict(), 0),  # a ragged final batch among the handed-back
+        (dict(shuffle=True, seed=2, pad_final_batch=True), 3),
+    ],
+    ids=["shuffle", "shards", "pad_final_batch", "drop_last", "ragged",
+         "start_batch"],
+)
+@pytest.mark.parametrize(
+    "dataset",
+    [
+        _image_like,
+        lambda: MaterializedDataset(100, seed=5),
+        lambda: RandomDataset(100, (3, 4, 4), seed=1, num_classes=10),
+        lambda: Doubling(100, seed=6),
+    ],
+    ids=["ArrayDataset", "MaterializedDataset", "RandomDataset", "transforming"],
+)
+def test_refilled_batches_equal_fresh_ones_bit_for_bit(dataset, kw, start):
+    """Over three epochs with every batch handed back two batches later (as
+    the Trainer does), what the loader yields is what a loader that gets
+    nothing back yields: every dataset, lazy and transforming ones too, goes
+    through the one path."""
+    ds = dataset()
+    recycling = ShardedLoader(ds, 8, **kw)
+    fresh = ShardedLoader(ds, 8, **kw)
+    lent = []
+    for epoch in range(3):
+        recycling.set_epoch(epoch)
+        fresh.set_epoch(epoch)
+        want = list(fresh.iter_batches(start))
+        assert len(want) == len(fresh) - start > 3
+        got = recycling.iter_batches(start)
+        for (xs, ys), (xs_w, ys_w) in zip(got, want, strict=True):
+            assert xs.dtype == xs_w.dtype and ys.dtype == ys_w.dtype
+            np.testing.assert_array_equal(xs, xs_w)
+            np.testing.assert_array_equal(ys, ys_w)
+            lent.append((xs, ys))
+            if len(lent) == 3:
+                recycling.recycle(*lent.pop(0))
+    assert fresh.batches_recycled == 0
+    total = 3 * len(want)
+    assert recycling.batches_recycled + recycling.batches_allocated == total
+    # three full-size arrays go round, and one more where a batch is ragged
+    assert recycling.batches_allocated <= 4
+
+
+def test_kept_batches_are_distinct_and_nothing_is_recycled_unasked():
+    ds = _image_like(96)
+    loader = ShardedLoader(ds, 8, shuffle=True, seed=1)
+    kept = list(loader) + list(loader)  # two passes, nothing handed back
+    table = loader.batch_index_table()
+    for (xs, ys), rows in zip(kept, table + table):
+        np.testing.assert_array_equal(xs, ds.inputs[rows])
+        np.testing.assert_array_equal(ys, ds.targets[rows])
+    for i, (xs_a, _) in enumerate(kept):
+        for xs_b, _ in kept[i + 1:]:
+            assert not np.shares_memory(xs_a, xs_b)
+    assert (loader.batches_recycled, loader.batches_allocated) == (0, 24)
+
+
+def test_only_a_handed_back_batch_is_written_and_the_oldest_first():
+    ds = _image_like(96)
+    loader = ShardedLoader(ds, 8)
+    table = loader.batch_index_table()
+    batches = loader.iter_batches()
+    first, second, third = next(batches), next(batches), next(batches)
+    kept = [(xs.copy(), ys.copy()) for xs, ys in (first, second, third)]
+    loader.recycle(*second)
+    loader.recycle(*first)
+    fourth, fifth, sixth = next(batches), next(batches), next(batches)
+    assert fourth[0] is second[0] and fourth[1] is second[1]
+    assert fifth[0] is first[0] and fifth[1] is first[1]
+    for (xs, ys), rows in zip((fourth, fifth, sixth), table[3:6]):
+        np.testing.assert_array_equal(xs, ds.inputs[rows])
+        np.testing.assert_array_equal(ys, ds.targets[rows])
+    # the one not handed back still holds its batch, and shares with no other
+    np.testing.assert_array_equal(third[0], kept[2][0])
+    np.testing.assert_array_equal(third[1], kept[2][1])
+    for xs, _ in (fourth, fifth, sixth):
+        assert not np.shares_memory(xs, third[0])
+    assert (loader.batches_recycled, loader.batches_allocated) == (2, 4)
+
+
+def test_handed_back_arrays_wait_for_a_batch_of_their_size():
+    """A ragged final batch's arrays fit only the next ragged final batch."""
+    ds = _image_like(20)
+    loader = ShardedLoader(ds, 8)  # batches of 8, 8, 4
+    first = list(loader)
+    for xs, ys in reversed(first):  # the ragged one is the oldest
+        loader.recycle(xs, ys)
+    again = list(loader)
+    assert [len(xs) for xs, _ in again] == [8, 8, 4]
+    assert again[2][0] is first[2][0] and again[0][0] is first[1][0]
+    for (xs, ys), rows in zip(again, loader.batch_index_table()):
+        np.testing.assert_array_equal(xs, ds.inputs[rows])
+        np.testing.assert_array_equal(ys, ds.targets[rows])
+    assert loader.batches_recycled == 3 and not loader._free
+
+
+def test_a_failing_row_surfaces_at_its_batch_and_loses_no_buffer():
+    class Breaks:
+        def __init__(self, base):
+            self.base, self.broken = base, True
+
+        def __len__(self):
+            return len(self.base)
+
+        def __getitem__(self, index):
+            if self.broken and index == 21:
+                raise KeyError("row 21 is gone")
+            return self.base[index]
+
+    ds = Breaks(_image_like(40))
+    loader = ShardedLoader(ds, 8)
+    batches = loader.iter_batches()
+    got = [next(batches), next(batches)]  # rows 0-15: before the fault
+    loader.recycle(*got[0])
+    with pytest.raises(KeyError, match="row 21"):
+        next(batches)
+    with pytest.raises(StopIteration):
+        next(batches)
+    # the buffer taken for the failed batch was never yielded: it is lost to
+    # the loader, not written under anyone; the next batch allocates
+    ds.broken = False
+    third = list(loader)[2][0]
+    np.testing.assert_array_equal(third, ds.base.inputs[16:24])
+    np.testing.assert_array_equal(got[1][0], ds.base.inputs[8:16])
+
+
+def test_iterating_starts_no_thread():
+    import threading
+
+    loader = ShardedLoader(_image_like(96), 8)
+    before = threading.active_count()
+    batches = loader.iter_batches(2)
+    next(batches)
+    assert threading.active_count() == before
+    del batches  # an abandoned generator has nothing to stop
+
+
+def test_loader_slices_note_recycled():
+    from distributed_pytorch_tpu.obs.tracer import Tracer
+
+    tr = Tracer()
+    loader = ShardedLoader(_image_like(64), 8, tracer=tr)
+    for n, (xs, ys) in enumerate(loader):
+        if n % 2:
+            loader.recycle(xs, ys)
+    stacks = [e for e in tr.events if e["name"] == "loader.stack"]
+    assert [e["args"]["recycled"] for e in stacks] == [False, False, True] + (
+        [False, True] * 2 + [False]
+    )
+    assert all(e["args"]["bytes"] == 8 * (5 * 4 * 3 + 1) * 4 for e in stacks)
+    assert (loader.batches_recycled, loader.batches_allocated) == (3, 5)
+    names = [e["name"] for e in tr.events]
+    assert names == ["loader.index", "loader.stack"] * 8
+    assert {e["tid"] for e in tr.events} == {0}
+
+
+def test_native_loader_keeps_nothing_handed_back():
+    from distributed_pytorch_tpu.utils.data import NativeShardedLoader
+
+    loader = NativeShardedLoader(MaterializedDataset(64), 8)
+    for xs, ys in loader:
+        loader.recycle(xs, ys)
+    assert not loader._free and loader.batches_recycled == 0

@@ -464,9 +464,12 @@ def _traced_run(tmp_path, tracer, name, mesh=None, epochs=2):
 def test_trainer_writes_its_host_phases_and_trains_the_same(tmp_path, mesh):
     """Per step one each of ``loader.index``, ``loader.stack``, ``step`` >
     ``put_batch`` + ``step.dispatch``; per epoch one ``epoch`` holding all
-    of them and one ``epoch.loss_fetch``; and the state a Trainer reaches
-    does not depend on who records."""
+    of them and one ``epoch.loss_fetch``; a ``recycle.fence`` after every
+    step from the third on, where the Trainer waits for the step two back
+    before it hands that step's arrays to the loader; and the state a Trainer
+    reaches does not depend on who records."""
     from distributed_pytorch_tpu.obs.tracer import NULL_TRACER, Tracer
+    from distributed_pytorch_tpu.training.trainer import HOST_BATCHES
 
     mesh = make_mesh() if mesh else None
     tr = Tracer()
@@ -502,7 +505,19 @@ def test_trainer_writes_its_host_phases_and_trains_the_same(tmp_path, mesh):
             assert index["args"]["rows"] == 16
             assert stack["args"]["bytes"] == 16 * 21 * 4
             assert put["args"]["bytes"] == stack["args"]["bytes"]
-    assert len(tr.events) == 2 * (2 + 4 * 5)
+            # the Trainer holds HOST_BATCHES arrays: from then on every batch
+            # is stacked into the one it handed back after waiting for its step
+            nth = 4 * epoch + step
+            assert stack["args"]["recycled"] is (nth >= HOST_BATCHES)
+            fences = named("recycle.fence", **at)
+            assert len(fences) == (nth >= HOST_BATCHES - 1)
+            for fence in fences:
+                assert inside(fence, whole) and before(one, fence)
+    loader = traced.train_data
+    # the Trainer's own first draw (to initialise the model) it keeps
+    assert loader.batches_allocated == 1 + HOST_BATCHES
+    assert loader.batches_recycled == 8 - HOST_BATCHES
+    assert len(tr.events) == 2 * (2 + 4 * 5) + 8 - (HOST_BATCHES - 1)
     # the step's own slice is what the step-time reservoir was fed from
     assert traced.step_times.count == 8 and silent.step_times.count == 0
     assert traced.step_times.quantile(1.0) == pytest.approx(
@@ -518,3 +533,132 @@ def test_trainer_and_loader_record_to_the_process_tracer_by_default(tmp_path):
     names = [e["name"] for e in tr.events]
     assert names.count("epoch") == 1 and names.count("step.dispatch") == 4
     assert names.count("loader.stack") == 4
+
+
+# ------------------------------------------- recycled batch buffers (PR 27)
+
+
+def _watch_losses(trainer):
+    """Every step's loss, as the device array the step returned (fetched
+    after the run, so watching adds no sync between steps)."""
+    losses = []
+    run = trainer._run_batch
+
+    def watched(batch):
+        losses.append(run(batch))
+        return losses[-1]
+
+    trainer._run_batch = watched
+    return losses
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["serial", "mesh"])
+def test_recycled_buffers_train_the_same_as_copies(tmp_path, mesh):
+    """On this backend ``device_put`` may alias the host arrays, so a buffer
+    refilled before its step was done would change that step's batch. The
+    loss sequence with the loader's arrays recycled equals the sequence with
+    recycling out of play (the step reads a private copy of every batch)."""
+    from distributed_pytorch_tpu.training.trainer import HOST_BATCHES
+
+    def run(name, private_copies):
+        trainer = Trainer(
+            ToyRegressor(), _loader(batch=16, n=128, shuffle=True),
+            optax.sgd(1e-2), save_every=0,
+            mesh=make_mesh() if mesh else None,
+            checkpoint_path=str(tmp_path / f"{name}.npz"),
+        )
+        if private_copies:
+            put = trainer._put_batch
+            trainer._put_batch = lambda xs, ys: put(xs.copy(), ys.copy())
+        losses = _watch_losses(trainer)
+        trainer.train(2)
+        return [float(l) for l in losses], trainer.train_data
+
+    recycled, loader = run("recycled", False)
+    copied, _ = run("copied", True)
+    assert len(recycled) == 16 >= 3 * HOST_BATCHES
+    assert recycled == copied
+    # recycling was in play: all but the first few went into handed-back arrays
+    assert loader.batches_recycled == 16 - HOST_BATCHES
+    assert loader.batches_allocated == 1 + HOST_BATCHES
+
+
+def test_arrays_go_back_only_after_their_own_step_was_waited_for(
+    tmp_path, monkeypatch
+):
+    """The race above shows by luck on a small model; the order that rules
+    it out does not: every pair of arrays reaches ``loader.recycle`` after
+    ``block_until_ready`` of the loss of the step that read them, oldest
+    first, and the newest ``HOST_BATCHES - 1`` stay lent."""
+    from distributed_pytorch_tpu.training.trainer import HOST_BATCHES
+
+    trainer = Trainer(
+        ToyRegressor(), _loader(batch=16, n=128, shuffle=True),
+        optax.sgd(1e-2), save_every=0,
+        checkpoint_path=str(tmp_path / "c.npz"),
+    )
+    losses = _watch_losses(trainer)
+    put_order, waited, handed = [], [], []
+    put, recycle = trainer._put_batch, trainer.train_data.recycle
+    ready = jax.block_until_ready
+
+    def watched_put(xs, ys):
+        put_order.append(xs)
+        return put(xs, ys)
+
+    def watched_ready(x):
+        waited.append(x)
+        return ready(x)
+
+    def watched_recycle(xs, ys):
+        step = max(n for n, seen in enumerate(put_order) if seen is xs)
+        assert any(w is losses[step] for w in waited)
+        handed.append(step)
+        recycle(xs, ys)
+
+    trainer._put_batch = watched_put
+    trainer.train_data.recycle = watched_recycle
+    monkeypatch.setattr(jax, "block_until_ready", watched_ready)
+    trainer.train(2)
+    assert handed == list(range(16 - (HOST_BATCHES - 1)))
+
+
+def test_mid_epoch_resume_with_buffers_in_circulation(
+    tmp_path, _restore_sigterm
+):
+    """A drain leaves with batches lent to steps and one handed back; the
+    resumed Trainer's loader starts at the drained step with none, and from
+    there on the losses are the uninterrupted run's."""
+    from distributed_pytorch_tpu.obs.tracer import Tracer
+    from distributed_pytorch_tpu.training.trainer import HOST_BATCHES
+
+    def build(snap, tracer=None):
+        return Trainer(
+            ToyRegressor(), _loader(shuffle=True, seed=3, tracer=tracer),
+            optax.sgd(1e-2), save_every=1, snapshot_path=snap, tracer=tracer,
+        )
+
+    whole = build(str(tmp_path / "whole.npz"))
+    want = _watch_losses(whole)
+    whole.train(2)
+
+    snap = str(tmp_path / "snapshot.npz")
+    t1 = build(snap)
+    head = _watch_losses(t1)
+    _drain_after(t1, 11)  # 8 batches an epoch: epoch 1, 3 steps done
+    with pytest.raises(SystemExit):
+        t1.train(2)
+    assert t1.train_data.batches_recycled >= 5
+
+    tr = Tracer()
+    t2 = build(snap, tracer=tr)
+    tr.events.clear()  # the Trainer's own first draw
+    tail = _watch_losses(t2)
+    t2.train(2)
+    stacked = [e["args"] for e in tr.events if e["name"] == "loader.stack"]
+    assert [a["step"] for a in stacked] == [3, 4, 5, 6, 7]
+    assert [a["recycled"] for a in stacked] == (
+        [False] * HOST_BATCHES + [True] * (5 - HOST_BATCHES)
+    )
+    got = [float(l) for l in head + tail]
+    assert len(head) == 11 and got == [float(l) for l in want]

@@ -57,18 +57,16 @@ from distributed_pytorch_tpu.training.trainer import Trainer
 from distributed_pytorch_tpu.utils.data import (
     ArrayDataset,
     MaterializedDataset,
-    NativeShardedLoader,
     RandomDataset,
     ShardedLoader,
 )
 
-__version__ = "0.1.0"
+__version__ = "0.4.0"
 
 __all__ = [
     "AsyncCheckpointer",
     "ArrayDataset",
     "MaterializedDataset",
-    "NativeShardedLoader",
     "beam_search",
     "generate",
     "speculative_generate",

@@ -9,18 +9,12 @@ hash of the source it was built from, so a binary is only ever reused for
 the exact source text that produced it — whatever the files' mtimes say
 after a copy or a checkout.
 
-Components:
-
-* ``kvstore.cpp``   -> ``tpu_kvstore`` binary — TCP rendezvous/KV store
-  (c10d TCPStore twin; reference ``slurm/sbatch_run.sh:21-22``).
-* ``prefetch.cpp``  -> ``libtpu_prefetch.so`` — GIL-free batch-prefetch worker
-  pool (torch ``DataLoader`` worker/pin-memory twin; reference
-  ``multigpu.py:72-79``), driven via ctypes.
+One component: ``kvstore.cpp`` -> ``tpu_kvstore`` binary — TCP rendezvous/KV
+store (c10d TCPStore twin; reference ``slurm/sbatch_run.sh:21-22``).
 """
 
 from __future__ import annotations
 
-import ctypes
 import hashlib
 import os
 import subprocess
@@ -48,7 +42,7 @@ def _build_dir() -> str:
     return os.path.join(cache_root, "distributed_pytorch_tpu", key)
 
 
-def _compile(src_name: str, out_name: str, *, shared: bool) -> str:
+def _compile(src_name: str, out_name: str) -> str:
     """Compile ``src_name`` (in this dir) to ``_build/<out_name>`` keyed by
     the source's content hash, unless that exact build already exists."""
     src = os.path.join(_NATIVE_DIR, src_name)
@@ -62,8 +56,6 @@ def _compile(src_name: str, out_name: str, *, shared: bool) -> str:
             return out
         os.makedirs(build_dir, exist_ok=True)
         cmd = ["g++", "-O2", "-std=c++17", "-pthread"]
-        if shared:
-            cmd += ["-fPIC", "-shared"]
         # Per-process temp name: the threading lock doesn't cover concurrent
         # *processes* (two agents cold-starting on one machine), so each must
         # link into its own file before the atomic rename.
@@ -76,37 +68,4 @@ def _compile(src_name: str, out_name: str, *, shared: bool) -> str:
 
 def kvstore_binary() -> str:
     """Path to the ``tpu_kvstore`` server binary (building it if needed)."""
-    return _compile("kvstore.cpp", "tpu_kvstore", shared=False)
-
-
-_PREFETCH_LIB = None
-
-
-def prefetch_library() -> ctypes.CDLL:
-    """The batch-prefetch shared library, built on first use, with argtypes
-    bound."""
-    global _PREFETCH_LIB
-    if _PREFETCH_LIB is not None:
-        return _PREFETCH_LIB
-    path = _compile("prefetch.cpp", "libtpu_prefetch.so", shared=True)
-    lib = ctypes.CDLL(path)
-    lib.prefetch_create.restype = ctypes.c_void_p
-    lib.prefetch_create.argtypes = [
-        ctypes.c_void_p,  # x rows
-        ctypes.c_void_p,  # y rows
-        ctypes.c_long,  # row_x bytes
-        ctypes.c_long,  # row_y bytes
-        ctypes.POINTER(ctypes.c_long),  # indices
-        ctypes.c_long,  # n_indices
-        ctypes.c_long,  # batch
-        ctypes.c_int,  # depth
-        ctypes.c_int,  # n_threads
-    ]
-    lib.prefetch_next.restype = ctypes.c_int
-    lib.prefetch_next.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
-    lib.prefetch_stop.restype = None
-    lib.prefetch_stop.argtypes = [ctypes.c_void_p]
-    lib.prefetch_destroy.restype = None
-    lib.prefetch_destroy.argtypes = [ctypes.c_void_p]
-    _PREFETCH_LIB = lib
-    return lib
+    return _compile("kvstore.cpp", "tpu_kvstore")

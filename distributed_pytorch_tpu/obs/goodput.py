@@ -2,10 +2,8 @@
 
 Two halves, one module:
 
-* **The FLOPs model** — the single analytic source of truth for model
-  FLOPs, shared by ``bench.py`` (which re-exports these names for
-  backward compatibility) and ``tools/mfu_probe.py`` so the formula can
-  never drift between them. Training cost is the PaLM-style
+* **The FLOPs model** — the package's one analytic formula for model
+  FLOPs. Training cost is the PaLM-style
   ``3 * (2 * non-embedding-params * tokens + attention)`` with exact
   causal (and sliding-window) attention terms; decode cost is the
   forward-only per-token marginal at a given KV context length.
@@ -21,8 +19,7 @@ Two halves, one module:
   charges 3 units of its span to ``spec_rejected``. From the same feed it
   derives tokens/sec/device and MFU (emitted tokens x decode
   FLOPs-per-token over elapsed x peak FLOPs), surfaced in
-  ``registry.snapshot()``, ``bench.py --serving`` rows, and per-step
-  tracer gauges.
+  ``registry.snapshot()`` and per-step tracer gauges.
 
 Everything here is host-side float arithmetic on numbers the engine
 already has — no device work, no extra syncs.

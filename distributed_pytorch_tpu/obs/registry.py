@@ -6,8 +6,8 @@ their metrics here instead of growing another ad-hoc ``stats()`` dialect;
 the registry then renders them three ways:
 
 * :meth:`MetricsRegistry.snapshot` — structured JSON (``counters`` /
-  ``gauges`` / ``reservoirs``), the payload embedded in BENCH_SERVING.json
-  and asserted against engine ground truth in tests;
+  ``gauges`` / ``reservoirs``), the payload ``/statusz`` serves and tests
+  assert against engine ground truth;
 * :meth:`MetricsRegistry.prometheus_text` — Prometheus text exposition
   (counters/gauges as-is, reservoirs as ``summary`` with quantile labels);
 * :meth:`MetricsRegistry.merge` — cross-host aggregation: counters and
@@ -20,7 +20,7 @@ Registration is PULL-based: most metrics are registered as zero-arg
 callables resolved at snapshot time (``counter_fn`` / ``gauge_fn`` /
 ``reservoir``), so the owning object keeps its counters as plain attributes
 — one source of truth, no double bookkeeping, and an object that is
-replaced wholesale (bench.py swaps ``engine.metrics`` after warm-up) stays
+replaced wholesale (a caller may swap ``engine.metrics`` after warm-up) stays
 correct as long as the callable re-resolves it. :class:`Counter` /
 :class:`Gauge` cover the push-style cases (the elastic agent's restart
 loop) where no long-lived owner exists.

@@ -48,7 +48,8 @@ Attribution: at fire time the detector compares every per-phase series'
 fast-window mean IN THE FIRING STRATUM against its own frozen baseline
 and blames the phase with the largest absolute level shift — for a
 chaos ``slow_program`` stall of phase P, that is P by construction,
-which is what the seeded drill in ``bench.py --perfwatch`` asserts.
+which is what the seeded drill in ``tools/serving_smoke.sh perfwatch``
+asserts.
 
 After firing, the detector re-baselines the firing stratum onto the new
 level (the shift is now "normal"; a second regression on top should
@@ -184,7 +185,8 @@ class RegressionDetector:
     catch a sustained ~2x step-time shift within ~2-4 post-shift steps
     at steady batch while staying quiet through CPU-backend jitter,
     isolated mid-run compile spikes, AND open-loop load ramps — the
-    seeded-drill budget asserted in tests and ``bench.py --perfwatch``.
+    seeded-drill budget asserted in tests and
+    ``tools/serving_smoke.sh perfwatch``.
     """
 
     WATCHED = ("step_wall_seconds", "tpot_step_seconds")

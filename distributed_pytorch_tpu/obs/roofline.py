@@ -46,7 +46,7 @@ from typing import Callable, Dict, List, Optional
 from .goodput import peak_flops_per_chip, peak_for_device
 
 # Peak HBM bandwidth per chip by generation, bytes/second (public spec
-# sheets; v5e 819 GB/s matches tools/mfu_probe.py's historical default).
+# sheets).
 # An unknown TPU kind is an error; other devices get DEFAULT_HBM_BW (see
 # goodput.peak_for_device).
 HBM_BYTES_PER_SEC = {
@@ -78,8 +78,8 @@ def hbm_bandwidth_per_chip(device) -> float:
 def roofline_point(
     flops: float, hbm_bytes: float, peak_flops: float, peak_bw: float
 ) -> dict:
-    """Pure roofline math for one program invocation — the shared source
-    of truth for :class:`RooflineModel` and ``tools/mfu_probe.py``.
+    """Pure roofline math for one program invocation, as
+    :class:`RooflineModel` applies it.
 
     Returns intensity (flops/byte), the machine balance (ridge point),
     the bound classification, the implied time floor in seconds, and the

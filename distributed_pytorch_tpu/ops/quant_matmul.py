@@ -4,8 +4,8 @@ The guaranteed-fused counterpart to ``dequantize() + @``: the weight tile is
 read from HBM as **int8**, converted and scaled in VMEM registers, and fed
 straight to the MXU — the bf16/f32 weight tensor never exists in HBM. This
 is the fallback for the case where XLA chooses to materialize the dequant
-instead of fusing it into the dot (observed on the CPU backend; the TPU
-fusion A/B is ``tools/decode_bench.py``, not yet run on a chip).
+instead of fusing it into the dot (observed on the CPU backend; on the TPU
+neither has been measured: ROADMAP D14).
 Decode-shaped: small-batch x [B, K] against q [K, N].
 
 Grid: ``(N/block_n, K/block_k)`` — K is TILED, not held whole in VMEM.

@@ -368,8 +368,7 @@ class ServingMetrics:
 
     def snapshot(self) -> Dict[str, float]:
         """One flat dict: counters + tokens/s + per-metric percentiles —
-        the payload ``bench.py --serving`` writes and the smoke test
-        asserts non-empty."""
+        the payload the smoke test asserts non-empty."""
         elapsed = time.perf_counter() - self._start
         out: Dict[str, float] = {
             "engine_steps": self.engine_steps,

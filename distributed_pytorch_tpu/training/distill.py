@@ -1,15 +1,13 @@
 """Knowledge distillation: the forward-KL step used to train speculative
 draft models.
 
-One canonical implementation (round-5 review: the draft-distillation rung
-and the decode bench each carried a copy) of the step that makes
+The one implementation of the step that makes
 ``speculative_generate`` actually fast: train a small student on the
 teacher's next-token DISTRIBUTIONS (forward KL, teacher logits computed on
 the fly — no logit dataset to stage), so the student's greedy/sampled
 proposals match the teacher often enough for long accepted chunks.
 ``examples/draft_distill.py`` is the runnable story (acceptance
-1.00 -> 4.00 of gamma=4); ``tools/decode_bench.py --speculative`` is the
-measurement instrument.
+1.00 -> 4.00 of gamma=4).
 """
 
 from __future__ import annotations

@@ -1,7 +1,6 @@
 from distributed_pytorch_tpu.utils.data import (
     ArrayDataset,
     MaterializedDataset,
-    NativeShardedLoader,
     RandomDataset,
     ShardedLoader,
 )
@@ -19,7 +18,6 @@ __all__ = [
     "ArrayDataset",
     "AugmentedDataset",
     "MaterializedDataset",
-    "NativeShardedLoader",
     "RandomDataset",
     "ShardedLoader",
     "cifar10_or_synthetic",

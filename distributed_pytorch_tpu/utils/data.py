@@ -54,9 +54,8 @@ class ArrayDataset:
     """Materialized dataset over caller-provided arrays.
 
     The general form of :class:`MaterializedDataset` (any shapes/dtypes):
-    exposes C-contiguous ``inputs``/``targets``, so it feeds both the Python
-    :class:`ShardedLoader` and the C++-backed :class:`NativeShardedLoader`.
-    Used for real data (e.g. CIFAR-10) and materialized benchmark workloads.
+    exposes C-contiguous ``inputs``/``targets``. Used for real data (e.g.
+    CIFAR-10) and materialized benchmark workloads.
     """
 
     def __init__(self, inputs: np.ndarray, targets: np.ndarray):
@@ -319,116 +318,3 @@ class ShardedLoader:
 
     def __iter__(self) -> Iterator[Batch]:
         return self.iter_batches()
-
-
-class NativeShardedLoader(ShardedLoader):
-    """ShardedLoader whose batch assembly runs in the C++ prefetch worker pool
-    (``native/prefetch.cpp``) — the torch ``DataLoader(num_workers=...,
-    pin_memory=True)`` twin (reference ``multigpu.py:72-79``): batches are
-    gathered by GIL-free background threads into a bounded ring while the
-    training loop consumes, so host batch assembly overlaps device compute.
-
-    Requires a dataset exposing C-contiguous ``inputs``/``targets`` arrays
-    (:class:`MaterializedDataset`). Batch order and contents are IDENTICAL to
-    the Python loader (same index table); only who does the copying changes.
-
-    When it wins, measured (tools/loader_overlap_bench.py, round 3): at
-    SMALL rows the Python loader's per-item overhead dominates and the
-    pool assembles ~1.4x faster; at large rows (e.g. 224x224x3 images, where
-    one ``np.stack`` is a single fused memcpy) the pool's safe-ownership
-    design costs a second copy (worker gather -> ring slot, slot -> caller
-    array) and pure assembly is SLOWER than the Python loader — its value
-    there is only overlap with *device* compute, which a core-shared
-    CPU-backend rig cannot show (measured ~1.0x end to end). Zero-copy slot
-    views were considered and rejected: jax's CPU ``device_put`` may alias
-    numpy buffers, so recycling slot memory under a live view corrupts data.
-
-    Since PR 27 :class:`ShardedLoader` recycles the buffers its consumer hands
-    back (the Trainer does once the step that read them is done, which is what
-    makes the aliasing above safe), and a copy into such a buffer runs at 10
-    GB/s on the benchmark's host (PERF.md §6, PR 27): no pool is needed at
-    image sizes.
-    This class copies into fresh arrays and recycles nothing; it stays for its
-    tests and ``bench.py`` until ROADMAP D9 folds it away.
-    """
-
-    def __init__(self, *args, num_workers: int = 2, prefetch_depth: int = 4, **kw):
-        super().__init__(*args, **kw)
-        if not (
-            hasattr(self.dataset, "inputs") and hasattr(self.dataset, "targets")
-        ):
-            raise TypeError(
-                "NativeShardedLoader needs a materialized dataset with "
-                ".inputs/.targets arrays"
-            )
-        self.num_workers = num_workers
-        self.prefetch_depth = prefetch_depth
-        self._x = np.ascontiguousarray(self.dataset.inputs)
-        self._y = np.ascontiguousarray(self.dataset.targets)
-        # The pool gathers rows straight from .inputs/.targets, bypassing
-        # __getitem__. A dataset whose __getitem__ applies a transform would
-        # pass the attribute check above yet yield different batches than
-        # ShardedLoader — probe one sample to keep the "identical contents"
-        # contract honest.
-        if len(self.dataset):
-            x0, y0 = self.dataset[0]
-
-            def same(a, b):
-                try:
-                    # equal_nan: a stored NaN (masked feature) must not read
-                    # as "__getitem__ transformed the data".
-                    return np.array_equal(np.asarray(a), b, equal_nan=True)
-                except TypeError:  # non-float dtype rejects equal_nan
-                    return np.array_equal(np.asarray(a), b)
-
-            if not (same(x0, self._x[0]) and same(y0, self._y[0])):
-                raise TypeError(
-                    "NativeShardedLoader requires dataset[i] == "
-                    "(dataset.inputs[i], dataset.targets[i]); this dataset's "
-                    "__getitem__ transforms the stored arrays"
-                )
-
-    def recycle(self, xs, ys) -> None:
-        """Nothing is kept: the pool copies into fresh arrays."""
-
-    def iter_batches(self, start_batch: int = 0) -> Iterator[Batch]:
-        import ctypes
-
-        from distributed_pytorch_tpu.native import prefetch_library
-
-        rows = self.batch_index_table()[start_batch:]
-        full = [r for r in rows if len(r) == self.batch_size]
-        ragged = rows[len(full):]  # at most one short final batch
-
-        if full:
-            lib = prefetch_library()
-            table = np.ascontiguousarray(np.stack(full).ravel(), dtype=np.int64)
-            row_x = self._x.dtype.itemsize * int(np.prod(self._x.shape[1:]))
-            row_y = self._y.dtype.itemsize * int(np.prod(self._y.shape[1:]))
-            handle = lib.prefetch_create(
-                self._x.ctypes.data,
-                self._y.ctypes.data,
-                row_x,
-                row_y,
-                table.ctypes.data_as(ctypes.POINTER(ctypes.c_long)),
-                table.size,
-                self.batch_size,
-                self.prefetch_depth,
-                self.num_workers,
-            )
-            if not handle:
-                raise RuntimeError("prefetch_create failed")
-            try:
-                shape_x = (self.batch_size,) + self._x.shape[1:]
-                shape_y = (self.batch_size,) + self._y.shape[1:]
-                while True:
-                    xs = np.empty(shape_x, self._x.dtype)
-                    ys = np.empty(shape_y, self._y.dtype)
-                    if not lib.prefetch_next(handle, xs.ctypes.data, ys.ctypes.data):
-                        break
-                    yield xs, ys
-            finally:
-                lib.prefetch_destroy(handle)
-
-        for chunk in ragged:  # rare: no drop_last/pad on an uneven tail
-            yield self._x[chunk], self._y[chunk]

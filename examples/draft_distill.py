@@ -68,7 +68,7 @@ def main(args):
             target, target_params, draft, draft_params, prompts,
             args.new_tokens, gamma=args.gamma, return_stats=True,
         )
-        return int(stats["positions_advanced"]) / max(int(stats["rounds"]), 1)
+        return float(stats["positions_advanced"]) / max(int(stats["rounds"]), 1)
 
     draft_params = draft.init(
         jax.random.PRNGKey(args.seed + 1),
@@ -77,8 +77,7 @@ def main(args):
     before = acceptance(draft_params)
 
     # 2) Distill: forward KL(target || draft) on the training sequences,
-    # teacher logits computed on the fly (training/distill.py — the same
-    # step tools/decode_bench.py --speculative uses).
+    # teacher logits computed on the fly (training/distill.py).
     from distributed_pytorch_tpu.training.distill import make_distill_step
 
     inputs = jnp.asarray(data[:, :-1])

@@ -80,7 +80,7 @@ def test_cpu_rehearsal_passes_without_the_tpu_line(chips):
 # ----------------------------------------------- a fresh process, looked at
 
 MODULES = ("distributed_pytorch_tpu", "distributed_pytorch_tpu.serving",
-           "distributed_pytorch_tpu.elastic", "bench")
+           "distributed_pytorch_tpu.elastic", "chip_smoke")
 PROBE = """
 import importlib, json, sys
 from jax._src import xla_bridge

@@ -160,32 +160,60 @@ def test_order_state_matches_same_geometry_only():
     assert not loader.matches_order_state("stale")
 
 
-def test_native_iter_batches_start_matches_python_loader():
-    from distributed_pytorch_tpu.utils.data import NativeShardedLoader
+def _rows_by_hand(n, batch, *, shuffle=False, seed=0, epoch=0, num_shards=1,
+                  shard_index=0, pad_final_batch=False):
+    """DistributedSampler's index rows written out in NumPy, independent of
+    the loader: permute, wrap up to a multiple of the shards, stride, cut
+    into batches, wrap the last one when asked."""
+    order = np.arange(n)
+    if shuffle:
+        order = np.random.default_rng([seed, epoch]).permutation(n)
+    order = np.resize(order, -(-n // num_shards) * num_shards)
+    mine = order[shard_index::num_shards]
+    rows = [mine[i:i + batch] for i in range(0, len(mine), batch)]
+    if pad_final_batch and len(rows[-1]) < batch:
+        rows[-1] = np.concatenate(
+            [rows[-1], np.resize(mine, batch - len(rows[-1]))]
+        )
+    return rows
 
-    ds = MaterializedDataset(96)
-    py = ShardedLoader(ds, 16, shuffle=True, seed=9)
-    native = NativeShardedLoader(ds, 16, shuffle=True, seed=9)
-    py.set_epoch(1)
-    native.set_epoch(1)
-    py_tail = list(py.iter_batches(2))
-    native_tail = list(native.iter_batches(2))
-    assert len(py_tail) == len(native_tail)
-    for (xs_a, ys_a), (xs_b, ys_b) in zip(py_tail, native_tail):
-        np.testing.assert_array_equal(xs_a, xs_b)
-        np.testing.assert_array_equal(ys_a, ys_b)
 
-
-def test_native_loader_rejects_transforming_getitem():
-    from distributed_pytorch_tpu.utils.data import NativeShardedLoader
-
-    class Transforming(MaterializedDataset):
-        def __getitem__(self, i):
-            x, y = super().__getitem__(i)
-            return x * 2.0, y  # stored arrays no longer match __getitem__
-
-    with pytest.raises(TypeError, match="__getitem__"):
-        NativeShardedLoader(Transforming(16), 4)
+@pytest.mark.parametrize(
+    "n, batch, kw, epoch, start, n_batches, last_rows",
+    [
+        (256, 32, dict(), 1, 0, 8, 32),
+        (256, 32, dict(shuffle=True, seed=7), 1, 0, 8, 32),
+        # 25 rows a shard: a final batch of 1, wrapped to 8
+        (100, 8, dict(num_shards=4, shard_index=3, pad_final_batch=True),
+         0, 0, 4, 8),
+        # 102 rows over 4 shards: the shard itself is wrapped first
+        (102, 8, dict(shuffle=True, seed=2, num_shards=4, shard_index=2,
+                      pad_final_batch=True), 2, 0, 4, 8),
+        (70, 32, dict(), 0, 0, 3, 6),  # ragged tail delivered, not dropped
+        (96, 16, dict(shuffle=True, seed=9), 1, 2, 6, 16),  # mid-epoch start
+    ],
+    ids=["in_order", "shuffled", "sharded_padded", "sharded_wrapped_padded",
+         "ragged_tail", "start_batch"],
+)
+def test_batches_are_the_rows_the_index_table_names(
+    n, batch, kw, epoch, start, n_batches, last_rows
+):
+    """What the loader yields, held against ``inputs[rows]`` with the rows
+    worked out by hand: float32 images and int32 class targets both come
+    through with their dtype and every value."""
+    rng = np.random.default_rng(3)
+    x = rng.standard_normal((n, 5, 3)).astype(np.float32)
+    y = rng.integers(0, 10, (n, 1)).astype(np.int32)
+    loader = ShardedLoader(ArrayDataset(x, y), batch, **kw)
+    loader.set_epoch(epoch)
+    got = list(loader.iter_batches(start))
+    want = _rows_by_hand(n, batch, epoch=epoch, **kw)[start:]
+    assert len(got) == len(want) == n_batches - start
+    assert len(got[-1][0]) == last_rows
+    for (xs, ys), rows in zip(got, want):
+        assert xs.dtype == np.float32 and ys.dtype == np.int32
+        np.testing.assert_array_equal(xs, x[rows])
+        np.testing.assert_array_equal(ys, y[rows])
 
 
 # ------------------------------------------ recycled batch buffers (PR 27)
@@ -370,12 +398,3 @@ def test_loader_slices_note_recycled():
     names = [e["name"] for e in tr.events]
     assert names == ["loader.index", "loader.stack"] * 8
     assert {e["tid"] for e in tr.events} == {0}
-
-
-def test_native_loader_keeps_nothing_handed_back():
-    from distributed_pytorch_tpu.utils.data import NativeShardedLoader
-
-    loader = NativeShardedLoader(MaterializedDataset(64), 8)
-    for xs, ys in loader:
-        loader.recycle(xs, ys)
-    assert not loader._free and loader.batches_recycled == 0

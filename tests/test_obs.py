@@ -188,7 +188,7 @@ class TestMetricsRegistry:
         json.dumps(reg.snapshot(include_state=True))  # must not raise
 
     def test_resolver_survives_object_replacement(self):
-        """bench.py swaps engine.metrics wholesale after warm-up — a
+        """A caller may swap engine.metrics wholesale after warm-up — a
         callable-registered reservoir must follow the swap."""
         holder = {"h": ReservoirHistogram(8)}
         holder["h"].record(1.0)

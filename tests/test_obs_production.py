@@ -534,16 +534,24 @@ class TestFlopsModel:
         long = transformer_decode_flops_per_token(context_len=1024, **kw)
         assert long > short > 2.0 * 900_000
 
-    def test_peak_flops_lookup(self):
-        class Dev:
-            def __init__(self, kind):
-                self.device_kind = kind
+    class Dev:
+        def __init__(self, kind):
+            self.device_kind = kind
 
+    def test_peak_flops_lookup(self):
+        Dev = self.Dev
         assert peak_flops_per_chip(Dev("TPU v5p")) == 459e12
         assert peak_flops_per_chip(Dev("TPU v5e")) == 197e12
         assert peak_flops_per_chip(Dev("TPU v4")) == 275e12
         assert peak_flops_per_chip(Dev("cpu")) == DEFAULT_PEAK
         assert peak_flops_per_chip(object()) == DEFAULT_PEAK
+
+    def test_peak_flops_table(self):
+        """The v5e by the name its runtime gives it, and a TPU the table does
+        not know: an error, never another chip's peak."""
+        assert peak_flops_per_chip(self.Dev("TPU v5 lite")) == 197e12
+        with pytest.raises(ValueError, match="TPU v99"):
+            peak_flops_per_chip(self.Dev("TPU v99"))
 
 
 # ------------------------------------------------------ engine integration

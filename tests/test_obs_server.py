@@ -1,7 +1,7 @@
 """Observability-wire tests: the per-engine introspection server, the
 strict Prometheus text-format grammar checker, device-truth XLA program
 accounting, the recompile sentinel, and the fleet tooling riding the wire
-(``obs_top`` rendering, ``bench_history`` gating).
+(``obs_top`` rendering).
 
 The invariant under test throughout: observability OFF keeps the fast
 path; observability ON (server scraped from another thread mid-run,
@@ -536,79 +536,6 @@ class TestObsTop:
             ln for ln in frame.splitlines() if "e1:80" in ln
         )
         assert " - " in row  # HOST r/c cell degrades to '-'
-
-
-# ------------------------------------------------------- bench history gate
-
-
-class TestBenchHistory:
-    def _bench(self, tps=100.0, tpot=0.002, device="cpu"):
-        return {
-            "platform": "cpu",
-            "device_kind": device,
-            "rows": [
-                {
-                    "prefix_caching": True,
-                    "speculative": False,
-                    "stats": {
-                        "tokens_per_sec": tps,
-                        "tpot_s_p50": tpot,
-                        "ttft_s_p50": 0.01,
-                        "requests_completed": 24,
-                    },
-                },
-            ],
-            "obs": {"recompiles_at_steady_state": 0},
-        }
-
-    def test_extract_row_shape(self):
-        from tools.bench_history import extract_row
-
-        row = extract_row(self._bench())
-        assert "prefix=on,spec=off" in row["configs"]
-        cfg = row["configs"]["prefix=on,spec=off"]
-        assert cfg["tokens_per_sec"] == 100.0
-        assert row["obs"]["recompiles_at_steady_state"] == 0
-        assert row["recorded_at"]
-
-    def test_within_tolerance_passes(self):
-        from tools.bench_history import compare_rows, extract_row
-
-        prev = extract_row(self._bench(tps=100.0, tpot=0.002))
-        cur = extract_row(self._bench(tps=95.0, tpot=0.0021))
-        assert compare_rows(prev, cur) == []
-
-    def test_throughput_drop_fails(self):
-        from tools.bench_history import compare_rows, extract_row
-
-        prev = extract_row(self._bench(tps=100.0))
-        cur = extract_row(self._bench(tps=85.0))
-        failures = compare_rows(prev, cur)
-        assert len(failures) == 1 and "tokens_per_sec" in failures[0]
-
-    def test_tpot_rise_fails(self):
-        from tools.bench_history import compare_rows, extract_row
-
-        prev = extract_row(self._bench(tpot=0.002))
-        cur = extract_row(self._bench(tpot=0.0023))
-        failures = compare_rows(prev, cur)
-        assert len(failures) == 1 and "tpot_s_p50" in failures[0]
-
-    def test_device_kind_change_voids_gate(self):
-        from tools.bench_history import compare_rows, extract_row
-
-        prev = extract_row(self._bench(tps=100.0, device="cpu"))
-        cur = extract_row(self._bench(tps=10.0, device="TPU v4"))
-        assert compare_rows(prev, cur) == []
-
-    def test_new_config_has_no_baseline(self):
-        from tools.bench_history import compare_rows, extract_row
-
-        prev = extract_row(self._bench())
-        cur_doc = self._bench(tps=1.0)
-        cur_doc["rows"][0]["speculative"] = True  # different config key
-        cur = extract_row(cur_doc)
-        assert compare_rows(prev, cur) == []
 
 
 class TestScrapeHardening:

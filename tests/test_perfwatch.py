@@ -7,8 +7,8 @@ contracts of the admission rejection ring and the trace sampler.
 The parity invariant is the headline (same bar as every other
 observability layer in this repo): the observatory may time, bucket and
 test every step, but it must never change a greedy token. The drill
-mirrors ``bench.py --perfwatch`` / ``tools/serving_smoke.sh perfwatch``
-at unit scale — and, like them, warms the decode stratum BEFORE arming
+mirrors ``tools/serving_smoke.sh perfwatch``
+at unit scale — and, like it, warms the decode stratum BEFORE arming
 the stall: a stratum first seen mid-stall anchors its median/MAD
 baseline on stalled samples and honestly reports "normal".
 All on CPU (conftest pins JAX_PLATFORMS=cpu).

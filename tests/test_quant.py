@@ -246,10 +246,9 @@ class TestDecodeByteAccounting:
     the compiled decode program shows the int8 KV cache reads fewer bytes —
     a storage-level saving, so it holds on every backend. (The WEIGHT-quant
     traffic saving is fusion-dependent — the CPU backend materializes the
-    dequantized weights instead of fusing the convert into the dot — so its
-    verification is the on-chip A/B in tools/decode_bench.py, not a CPU
-    byte count.) The fori_loop body is counted once, so this is per-step
-    traffic."""
+    dequantized weights instead of fusing the convert into the dot — so a CPU
+    byte count cannot verify it.) The fori_loop body is counted once, so this
+    is per-step traffic."""
 
     @staticmethod
     def _body_bytes(model, params, batch, total_len):

@@ -52,6 +52,22 @@ def offline_greedy(model, params, prompt, max_new):
     return np.asarray(out)[0, len(prompt):].tolist()
 
 
+STOP_PROMPTS = ([6, 1, 9, 9], [5, 7, 11], [3, 1, 4, 1, 5], [2, 7, 1, 8, 2, 8])
+
+
+def prompt_with_a_late_stop(model, params, max_new=8):
+    """A prompt, its greedy reference and a token at index >= 2 of that
+    reference which did not occur before it: a stop token that ends the
+    request early and not at once. A random model's greedy stream is often
+    one token over and over, so the prompt is looked for, not assumed."""
+    for prompt in STOP_PROMPTS:
+        ref = offline_greedy(model, params, prompt, max_new)
+        late = [t for i, t in enumerate(ref) if i >= 2 and t not in ref[:i]]
+        if late:
+            return prompt, ref, late[0]
+    raise AssertionError("no prompt of STOP_PROMPTS has a late new token")
+
+
 # ---------------------------------------------------------------- allocator
 
 
@@ -475,17 +491,15 @@ class TestEngineParity:
 
     def test_stop_token_ends_request_early(self, model_and_params):
         model, params = model_and_params
-        ref = offline_greedy(model, params, [6, 1, 9, 9], 8)
-        stop = ref[2]
-        assert stop not in ref[:2], "test needs a stop token unique so far"
+        prompt, ref, stop = prompt_with_a_late_stop(model, params)
         eng = InferenceEngine(model, params, max_slots=2, max_seq_len=32,
                               page_size=4)
         rid = eng.submit(
-            [6, 1, 9, 9],
-            SamplingParams(max_new_tokens=8, stop_token=stop),
+            prompt, SamplingParams(max_new_tokens=8, stop_token=stop)
         )
         eng.run()
-        assert eng.poll(rid).generated == ref[:3]  # stop token included
+        # stop token included
+        assert eng.poll(rid).generated == ref[:ref.index(stop) + 1]
 
 
 # ------------------------------------------------------ prefix-cache parity
@@ -607,16 +621,16 @@ class TestPrefixCachingParity:
         speculative dispatch past it must be rolled back without leaking
         pages or placeholder tokens."""
         model, params = model_and_params
-        ref = offline_greedy(model, params, [6, 1, 9, 9], 8)
-        stop = ref[2]
+        prompt, ref, stop = prompt_with_a_late_stop(model, params)
+        want = ref[:ref.index(stop) + 1]
         eng = self._engine(model, params, overlap=True)
         rid = eng.submit(
-            [6, 1, 9, 9], SamplingParams(max_new_tokens=8, stop_token=stop)
+            prompt, SamplingParams(max_new_tokens=8, stop_token=stop)
         )
         eng.run()
-        assert eng.poll(rid).generated == ref[:3]
+        assert eng.poll(rid).generated == want
         req = eng.requests[rid]
-        assert req.tokens == [6, 1, 9, 9] + ref[:3]
+        assert req.tokens == prompt + want
         assert not req.pending_idx
         assert eng.allocator.num_allocated == 0
         eng.allocator.check_invariants()

@@ -440,9 +440,11 @@ class Attention(nn.Module):
         per-row, the write is a scatter into (physical page, offset), and the
         read gathers each row's pages into a [S, pages*page_size, Hkv, D]
         view. Rows whose table is all zeros (inactive slots) write into the
-        null page and read garbage that the visibility mask turns into a
-        discarded-but-finite output: the null page only ever holds finite
-        values written by other inactive rows.
+        null page; what they read is discarded and finite either way: on the
+        gather path garbage that the visibility mask averages (the null page
+        only ever holds finite values written by other inactive rows), in
+        the Pallas kernel nothing at all (``ops/paged_attention.py`` gives a
+        row whose table starts at the null page zeros).
         """
         cached_key = self.variable("cache", "cached_key", lambda: None)
         cached_value = self.variable("cache", "cached_value", lambda: None)

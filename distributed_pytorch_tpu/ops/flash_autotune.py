@@ -81,14 +81,16 @@ PAGED_FAMILY = "paged_decode"
 
 # device_kind -> pages-per-block for the paged decode kernel. The "cpu"
 # entry is the SEEDED interpret/CI value: the test rig resolves its block
-# size from here, so CI never runs a sweep. The "tpu v5 lite" entry is NOT
-# a measured winner: it follows the grow-the-tile direction the flash sweeps
-# measured (fewer grid steps, longer contractions) and is known to compile
-# and run on the chip; measure with ``main(--paged)`` before trusting it for
-# speed.
+# size from here, so CI never runs a sweep. The "tpu v5 lite" entry is the
+# winner of a sweep on the chip (PR 29; the table is in PERF.md section 6)
+# at both shapes the benchmark serves, bf16 pages of 16 tokens and head size
+# 128: 32 slots of 256 pages with 24 query heads on 2 KV heads, at a full
+# batch of ~400 tokens a row and at 4 live rows of ~1,500; 128 slots of 128
+# pages with 20 heads on 1, at ~290 tokens a row. 8 is within 4-8% of it
+# there; rows of thousands of tokens would take 32 or 64.
 PAGED_DEFAULT_TABLE = {
     "cpu": 2,
-    "tpu v5 lite": 8,
+    "tpu v5 lite": 16,
 }
 
 _FALLBACK = (512, 1024)
@@ -317,16 +319,21 @@ def autotune_paged(
     kv_heads: int = 8,
     group: int = 1,
     dtype=None,
+    context: Optional[int] = None,
     steps: int = 20,
     verbose: bool = False,
     force: bool = False,
     interpret: Optional[bool] = None,
 ) -> int:
     """Measured sweep for the paged decode kernel: times every legal
-    pages-per-block over a synthetic full-pool decode batch and caches the
-    winner under the ``paged_decode`` family key (in-process + on disk,
-    same persistence rules as :func:`autotune`). Offline tool — the serving
-    path only ever reads :func:`lookup_paged`."""
+    pages-per-block over a synthetic decode batch whose rows hold about
+    ``context`` tokens each, half to one and a half times it (default: the
+    table's whole width; the kernel walks only what a row holds, so sweep
+    at the contexts the deployment sees) and caches the winner under the ``paged_decode`` family key
+    (in-process + on disk, same persistence rules as :func:`autotune`).
+    ``steps`` calls run inside ONE program, each fed the last one's output:
+    a call takes tens of microseconds, less than a dispatch. Offline tool —
+    the serving path only ever reads :func:`lookup_paged`."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -363,21 +370,28 @@ def autotune_paged(
         1 + np.arange(slots * pages_per_seq).reshape(slots, pages_per_seq),
         jnp.int32,
     )
-    lens = jnp.full((slots,), kv_len - 1, jnp.int32)
+    if context is None:
+        held = np.full((slots,), kv_len)
+    else:  # ragged, as a batch is: between half and one and a half times it
+        held = rng.integers(
+            max(1, context // 2), min(kv_len, context * 3 // 2) + 1, slots
+        )
+    lens = jnp.asarray(held - 1, jnp.int32)
 
     best, best_dt = None, float("inf")
     for npb in paged_candidates(pages_per_seq, page_size):
         try:
+            attend = functools.partial(
+                paged_attention, kernel=mode, pages_per_block=npb
+            )
             fn = jax.jit(
-                functools.partial(
-                    paged_attention, kernel=mode, pages_per_block=npb
+                lambda q, *rest: jax.lax.fori_loop(
+                    0, steps, lambda _, x: attend(x, *rest), q
                 )
             )
             fn(q, k_pool, v_pool, tables, lens).block_until_ready()
             t0 = time.perf_counter()
-            for _ in range(steps):
-                out = fn(q, k_pool, v_pool, tables, lens)
-            out.block_until_ready()
+            fn(q, k_pool, v_pool, tables, lens).block_until_ready()
             dt = (time.perf_counter() - t0) / steps
         except Exception as e:  # lowering failure for this blocking: skip
             if verbose:
@@ -557,6 +571,16 @@ def main(argv=None) -> None:
     )
     parser.add_argument("--slots", default=8, type=int,
                         help="paged sweep: decode batch size")
+    parser.add_argument("--kv_heads", default=8, type=int,
+                        help="paged sweep: KV heads")
+    parser.add_argument("--group", default=1, type=int,
+                        help="paged sweep: query heads a KV head")
+    parser.add_argument(
+        "--context", default=None, type=int,
+        help="paged sweep: tokens a row holds, about (default: the whole table)",
+    )
+    parser.add_argument("--dtype", default="float32",
+                        help="paged sweep: dtype of queries and pages")
     args = parser.parse_args(argv)
     kind = _device_kind()
     if kind == "unknown":
@@ -573,10 +597,12 @@ def main(argv=None) -> None:
                 for d in (int(x) for x in args.head_dims.split(",")):
                     print(f"kv={kv_len} page={page} d={d}:", flush=True)
                     npb = autotune_paged(
-                        kv_len, page, d, slots=args.slots, verbose=True,
-                        force=args.force,
+                        kv_len, page, d, slots=args.slots,
+                        kv_heads=args.kv_heads, group=args.group,
+                        dtype=args.dtype, context=args.context,
+                        verbose=True, force=args.force,
                     )
-                    key = _paged_key(kind, kv_len, page, d, "float32")
+                    key = _paged_key(kind, kv_len, page, d, args.dtype)
                     if key in _failed_sweeps:
                         print("  -> MEASUREMENT FAILED (excluded)", flush=True)
                         failed.append((kv_len, page, d))

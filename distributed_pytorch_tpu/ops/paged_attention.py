@@ -8,28 +8,46 @@ tables — and until this module existed it did so via a plain-XLA gather
 ``obs/roofline.py`` classifies that program bandwidth-bound; the gather
 writes and re-reads the whole working set once per generated token.
 
-This kernel fuses the block-table indirection into the attention loop:
+This kernel fuses the block-table indirection into the attention loop, and
+walks only the KV a row has:
 
-* grid ``(slots, kv_blocks)`` with the KV dim innermost. Each grid step
-  streams ``pages_per_block`` PHYSICAL pages HBM->VMEM — the block table
-  rides as a scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``), so
-  every page's ``BlockSpec`` index map picks its physical page id before the
-  body runs and Pallas double-buffers the page fetches like any other block.
-  The gathered logical view is never materialized.
+* grid ``(slots,)``, run in order. The block table and the rows' positions
+  ride as scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``); the
+  pools stay in HBM (``memory_space=pl.ANY``) and are never gathered, copied
+  or re-laid-out as a whole.
+* per row, a ``fori_loop`` over its ``pos // block_tokens + 1`` KV blocks,
+  a trip count read from the prefetched positions: a row of 300 tokens in a
+  table of 4,096 walks two blocks of 256, not sixteen. Each block is
+  ``pages_per_block`` PHYSICAL pages, each copied whole by
+  ``pltpu.make_async_copy`` (one contiguous ``[page * Hkv, D]`` slab, the
+  rows the pool stores it as) into one of two VMEM buffers a pool; the next
+  block's copies, or the next live row's first block's, are started before
+  the current block is computed.
+* what is never fetched: a page the row does not own. A logical page past
+  the row's last live one clamps to that one (its key positions lie past
+  ``pos`` and die in the mask), so the padded tail of a table, the null
+  page it points at, and every other row's pages stay untouched.
+* rows out of the dispatch group do nothing: a row whose table STARTS at
+  the null page (the engine stages a zeroed table for it) starts no copy,
+  does no arithmetic, and its output is zeros. A length of 0 is no such
+  sign: a live one-token prompt decodes at position 0 and sees its own key.
+  The model discards those rows' outputs either way, and their K/V writes
+  still land in the null page.
 * online softmax (FlashAttention-style running max / denominator / output
-  accumulator in fp32 VMEM scratch, persisting across the KV blocks) with
+  accumulator in fp32 VMEM scratch, persisting across a row's blocks) with
   grouped-query head mapping: query head ``h`` reads kv head ``h // group``,
   the same contraction layout as the XLA reference's grouped einsums.
 * masking is positional, exactly as the reference: key position ``kpos`` is
-  visible iff ``kpos <= pos`` (the row's current absolute position). NULL
-  pages (physical page 0 — inactive slots, padded table tails) are read but
-  every one of their positions fails the visibility test, so their contents
-  die in the softmax; KV blocks entirely past ``pos`` skip their MXU work
-  via ``pl.when``.
+  visible iff ``kpos <= pos`` (the row's current absolute position), which
+  also kills the dead tail of a row's last block.
 * int8 KV pages: with ``k_scale``/``v_scale`` (``[num_pages, page_size,
-  Hkv]`` float32, quantized on page write by the model) the kernel fetches
-  int8 pages plus their scales and dequantizes in VMEM — HBM sees a quarter
-  of the fp32 page bytes plus one scale per (slot, head).
+  Hkv]`` float32, quantized on page write by the model) the kernel copies
+  int8 pages, a quarter of the fp32 page bytes. A ``[page, Hkv]`` scale
+  page has no lane-aligned slice a manual copy could take, so the rows'
+  scales are gathered through the table outside the kernel (one float a
+  key and kv head, a 1/D-th of a gathered view) and, being one number a
+  key, are applied to the block's scores and weights instead of its tiles:
+  the same product in float32, in another order.
 
 ``paged_attention_reference`` is the pure-XLA fallback: op-for-op the read
 side of ``_paged_decode_step``, so an engine toggling the kernel off is
@@ -46,7 +64,10 @@ the pools, so no collective is added beyond what the weight split implies.
 
 Block sizing (``pages_per_block``) comes from the ``ops/flash_autotune``
 harness' ``paged_decode`` family: measured winners on real hardware, a
-seeded table entry for CPU/interpret so CI never autotunes.
+seeded table entry for CPU/interpret so CI never autotunes. A block is also
+the unit of waste: a row walks whole blocks, so it reads up to one block of
+keys it cannot see (:func:`kv_tokens_walked`; the engine's tracer counts
+both sides as ``decode_kv_tokens_fetched`` / ``_visible``).
 """
 
 from __future__ import annotations
@@ -67,6 +88,11 @@ from distributed_pytorch_tpu.utils.platform import on_tpu
 #: the compiled kernel, "interpret" runs the kernel through the Pallas
 #: interpreter (CPU tests), "xla" forces the reference fallback.
 KERNEL_MODES = ("auto", "pallas", "interpret", "xla")
+
+#: The pool's reserved physical page (``serving/kv_cache.py`` ``NULL_PAGE``):
+#: padded table tails point at it, and a row whose table STARTS with it is
+#: out of the dispatch group.
+NULL_PAGE = 0
 
 
 def resolve_kernel(kernel) -> str:
@@ -139,168 +165,242 @@ def paged_attention_reference(
 def _decode_kernel(
     bt_ref, lens_ref, q_ref, *refs, npb, group, sm_scale, quantized
 ):
-    """One (slot, kv-block) grid step of the flash-decode kernel.
+    """One slot (grid step) of the flash-decode kernel: walk the row's own
+    KV blocks, and only those.
 
-    ``refs`` unpacks to ``npb`` K page blocks, ``npb`` V page blocks,
-    (when quantized) ``npb`` + ``npb`` scale blocks, the output block, and
-    the three fp32 scratch accumulators (running max ``m``, denominator
-    ``l``, output ``acc``) that persist across the innermost grid dim."""
-    k_refs, v_refs = refs[:npb], refs[npb : 2 * npb]
-    if quantized:
-        ks_refs = refs[2 * npb : 3 * npb]
-        vs_refs = refs[3 * npb : 4 * npb]
-        o_ref, m_scr, l_scr, acc_scr = refs[4 * npb :]
-    else:
-        ks_refs = vs_refs = None
-        o_ref, m_scr, l_scr, acc_scr = refs[2 * npb :]
+    ``refs`` unpacks to the K and V pools left in HBM, (when quantized) the
+    row's K and V scales by block, the output block, then the scratch: two
+    buffers of ``npb`` pages for each pool, one DMA semaphore a buffer, the
+    buffer the row's first block was prefetched into (SMEM), and the three
+    fp32 accumulators (running max ``m``, denominator ``l``, output
+    ``acc``)."""
+    k_hbm, v_hbm = refs[:2]
+    ks_ref, vs_ref = refs[2:4] if quantized else (None, None)
+    (o_ref, k_buf, v_buf, sems, first_buf, m_scr, l_scr, acc_scr) = refs[
+        4 if quantized else 2 :
+    ]
 
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    page = k_refs[0].shape[1]
-    kv_heads, d = k_refs[0].shape[2], k_refs[0].shape[3]
-    h = q_ref.shape[1]
+    slots, pages_per_seq = bt_ref.shape
+    h, d = q_ref.shape[1:]
+    kv_heads = h // group
+    page = k_buf.shape[2] // kv_heads
     bkv = npb * page
-    block_start = j * bkv
 
-    @pl.when(j == 0)
-    def _init():
+    def is_live(row):
+        # A row out of the dispatch group stages a zeroed block table: its
+        # first entry is the null page.
+        return bt_ref[row, 0] != NULL_PAGE
+
+    def page_copies(phys, buf, n):
+        return [
+            pltpu.make_async_copy(pool.at[phys], dst.at[buf, n], sems.at[buf])
+            for pool, dst in ((k_hbm, k_buf), (v_hbm, v_buf))
+        ]
+
+    # Both loops are unrolled on purpose: on the v5e a rolled page loop made
+    # a call 15-20% slower (PERF.md section 6), and ``_paged_flash`` is traced
+    # once a program, not once a layer.
+    def start(row, blk, buf):
+        """Start the K and V copies of ``row``'s block ``blk`` into buffer
+        ``buf``. A logical page past the row's last live one clamps to that
+        one: a page the row does not own is never fetched, and the
+        duplicates' key positions lie past ``pos``."""
+        last = jnp.minimum(lens_ref[row] // page, pages_per_seq - 1)
+        for n in range(npb):
+            phys = bt_ref[row, jnp.minimum(blk * npb + n, last)]
+            for copy in page_copies(phys, buf, n):
+                copy.start()
+
+    def wait(buf):
+        # A wait takes a copy's size and semaphore, not its source.
+        for n in range(npb):
+            for copy in page_copies(0, buf, n):
+                copy.wait()
+
+    @pl.when(jnp.logical_not(is_live(b)))
+    def _absent():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(is_live(b))
+    def _row():
+        pos = lens_ref[b]  # the decode token's absolute position
+        n_blocks = jnp.minimum(
+            pos // bkv + 1, pl.cdiv(pages_per_seq, npb)
+        )
+        # The row before, if live, started this row's first block while it
+        # computed its own last one; else this row starts it itself.
+        prefetched = jnp.logical_and(b > 0, is_live(jnp.maximum(b - 1, 0)))
+        buf0 = jnp.where(prefetched, first_buf[0], 0)
+
+        @pl.when(jnp.logical_not(prefetched))
+        def _first():
+            start(b, 0, 0)
+
+        next_row = jnp.minimum(b + 1, slots - 1)
+        next_live = jnp.logical_and(b + 1 < slots, is_live(next_row))
+
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    pos = lens_ref[b]  # the decode token's absolute position (T_step == 1)
+        def block(j, carry):
+            buf = (buf0 + j) % 2
 
-    # Blocks wholly past the row's current position contribute nothing —
-    # skip their MXU work (the page DMAs still happen; the grid is static).
-    # Every computed block has key `block_start` visible, so the running
-    # max stays finite and no exp(NEG_INF - NEG_INF) row can arise.
-    @pl.when(block_start <= pos)
-    def _step():
-        def load(page_refs, scale_refs):
-            tiles = []
-            for n in range(npb):
-                tile = page_refs[n][0].astype(jnp.float32)
-                if quantized:
-                    tile = tile * scale_refs[n][0].astype(jnp.float32)[
-                        ..., None
-                    ]
-                tiles.append(tile)
-            return (
-                jnp.concatenate(tiles, axis=0) if npb > 1 else tiles[0]
-            )  # [bkv, Hkv, D] f32
+            @pl.when(j + 1 < n_blocks)
+            def _next_block():
+                start(b, j + 1, 1 - buf)
 
-        k = load(k_refs, ks_refs)
-        v = load(v_refs, vs_refs)
-        q = q_ref[0].astype(jnp.float32)  # [H, D]
-        # Grouped-query mapping: query head h reads kv head h // group —
-        # kv leads group, matching the reference's qg reshape.
-        qg = q.reshape(kv_heads, group, d)
-        kt = k.transpose(1, 0, 2)  # [Hkv, bkv, D]
-        s_blk = (
-            jax.lax.dot_general(
+            @pl.when(jnp.logical_and(j + 1 == n_blocks, next_live))
+            def _next_row():
+                start(next_row, 0, 1 - buf)
+                first_buf[0] = 1 - buf
+
+            wait(buf)
+
+            def load(pages):
+                # [npb, page * Hkv, D] as stored -> [bkv, Hkv, D] f32
+                return pages[buf].astype(jnp.float32).reshape(
+                    bkv, kv_heads, d
+                )
+
+            k = load(k_buf)
+            v = load(v_buf)
+            q = q_ref[0].astype(jnp.float32)  # [H, D]
+            # Grouped-query mapping: query head h reads kv head h // group —
+            # kv leads group, matching the reference's qg reshape.
+            qg = q.reshape(kv_heads, group, d)
+            kt = k.transpose(1, 0, 2)  # [Hkv, bkv, D]
+            s_blk = jax.lax.dot_general(
                 qg, kt, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
+            )  # [Hkv, group, bkv]
+            if quantized:
+                # A key's scale is one number a (position, kv head): it
+                # factors out of the contraction over D.
+                s_blk = s_blk * ks_ref[0, j][:, None, :]
+            s_blk = s_blk * sm_scale
+            kpos = j * bkv + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, bkv), 2
             )
-            * sm_scale
-        )  # [Hkv, group, bkv]
-        kpos = block_start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, bkv), 2
+            # Every walked block has key ``j * bkv`` visible, so the running
+            # max stays finite and no exp(NEG_INF - NEG_INF) row can arise.
+            s_blk = jnp.where(kpos <= pos, s_blk, NEG_INF)
+            s2 = s_blk.reshape(h, bkv)
+            m_prev = m_scr[:, :1]
+            l_prev = l_scr[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1, keepdims=True))
+            p = jnp.exp(s2 - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+            pg = p.reshape(kv_heads, group, bkv)
+            if quantized:
+                pg = pg * vs_ref[0, j][:, None, :]
+            vt = v.transpose(1, 0, 2)  # [Hkv, bkv, D]
+            pv = jax.lax.dot_general(
+                pg, vt, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )  # [Hkv, group, D]
+            acc_scr[:] = acc_scr[:] * correction + pv.reshape(h, d)
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, block, 0)
+        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+def block_pages(
+    pages_per_seq: int, page: int, head_dim: int, dtype, pages_per_block=None
+) -> int:
+    """Pages in one KV block of the kernel for a decode shape: the autotune
+    harness' ``paged_decode`` entry unless ``pages_per_block`` is given,
+    held to the block table's width."""
+    if pages_per_block is None:
+        from distributed_pytorch_tpu.ops.flash_autotune import lookup_paged
+
+        pages_per_block = lookup_paged(
+            pages_per_seq * page, page, head_dim,
+            dtype_name=jnp.dtype(dtype).name,
         )
-        # Positional visibility IS the NULL-page mask: padded table tails
-        # and inactive slots resolve to physical page 0, whose every key
-        # position here fails kpos <= pos — their contents never survive.
-        s_blk = jnp.where(kpos <= pos, s_blk, NEG_INF)
-        s2 = s_blk.reshape(h, bkv)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1, keepdims=True))
-        p = jnp.exp(s2 - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-        pg = p.reshape(kv_heads, group, bkv)
-        vt = v.transpose(1, 0, 2)  # [Hkv, bkv, D]
-        pv = jax.lax.dot_general(
-            pg, vt, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [Hkv, group, D]
-        acc_scr[:] = acc_scr[:] * correction + pv.reshape(h, d)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+    return max(1, min(int(pages_per_block), int(pages_per_seq)))
 
 
+def kv_tokens_walked(positions, block_tokens: int):
+    """Key positions the kernel fetches and computes on for decode rows at
+    ``positions`` (a row at ``pos`` sees ``pos + 1`` keys): whole blocks of
+    ``block_tokens``, up to the one that holds ``pos``."""
+    return (positions // block_tokens + 1) * block_tokens
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
 def _paged_flash(
     q3, k_pool, v_pool, block_tables, seq_lens, k_scale, v_scale,
     *, pages_per_block, interpret,
 ):
     """Build and invoke the pallas_call for ``q3`` [S, H, D] (T_step == 1).
 
-    Each of the ``pages_per_block`` pages in a KV block is its own input
-    operand (the same pool array, aliased) with its own index map reading
-    the scalar-prefetched block table — Pallas fetches ``pages_per_block``
-    non-contiguous physical pages per grid step and the kernel concatenates
-    them in VMEM. Logical pages past the table width clamp to the last
-    entry; their key positions sit past any legal ``pos``, so the
-    visibility mask kills the duplicates."""
+    Jitted so that a model's layers, which all call it at one shape, share
+    ONE trace and one lowering: the kernel's page copies are unrolled, and
+    traced a layer at a time they cost a 30-layer decode program ~40 s of
+    set-up in every process, compile cache or not. The kernel is named, so
+    a device trace shows it as ``attention._paged_decode_step`` (the name it
+    has had in every trace, then taken from the calling module's scope)
+    whoever calls it.
+
+    The grid is the slots, run in order (``"arbitrary"``: a row's last block
+    starts the next row's first). The block table and the lengths are scalar
+    prefetch operands. The pools stay in HBM (``pl.ANY``), each page seen as
+    the ``[page * Hkv, D]`` rows it is stored as (a ``[page, Hkv, D]`` slice
+    is refused by Mosaic where Hkv is no multiple of the dtype's sublane
+    packing, one KV head in bf16 for one), and the kernel copies them page by
+    page into two VMEM buffers of ``pages_per_block`` pages a pool.
+
+    int8 pages: a scale page ``[page, Hkv]`` has no lane-aligned slice to
+    copy, so the rows' scales are gathered through the table here, by block
+    and with positions on the lanes (``[S, blocks, Hkv, block tokens]``
+    float32: a 1/D-th of a gathered view), and ride in as one block a row."""
     s, h, d = q3.shape
-    page = k_pool.shape[1]
-    kv_heads = k_pool.shape[2]
+    num_pages, page, kv_heads = k_pool.shape[:3]
     pages_per_seq = block_tables.shape[1]
     group = h // kv_heads
-    npb = max(1, min(int(pages_per_block), pages_per_seq))
+    npb = int(pages_per_block)
     nblk = -(-pages_per_seq // npb)
     quantized = k_scale is not None
-
-    def page_index(n):
-        def index_map(b, j, bt, lens):
-            logical = jnp.minimum(j * npb + n, pages_per_seq - 1)
-            return (bt[b, logical], 0, 0, 0)
-
-        return index_map
-
-    def scale_index(n):
-        def index_map(b, j, bt, lens):
-            logical = jnp.minimum(j * npb + n, pages_per_seq - 1)
-            return (bt[b, logical], 0, 0)
-
-        return index_map
+    bt = block_tables.astype(jnp.int32)
 
     def row_spec(shape):
         return pl.BlockSpec(
-            shape, lambda b, j, bt, lens: (b, 0, 0),
+            shape, lambda b, bt, lens: (b,) + (0,) * (len(shape) - 1),
             memory_space=pltpu.VMEM,
         )
 
-    k_specs = [
-        pl.BlockSpec(
-            (1, page, kv_heads, d), page_index(n), memory_space=pltpu.VMEM
+    padded = jnp.pad(bt, ((0, 0), (0, nblk * npb - pages_per_seq)))
+
+    def by_block(scale):
+        return scale[padded].reshape(s, nblk, npb * page, kv_heads).swapaxes(
+            2, 3
         )
-        for n in range(npb)
+
+    operands = [
+        pool.reshape(num_pages, page * kv_heads, d)
+        for pool in (k_pool, v_pool)
     ]
-    in_specs = [row_spec((1, h, d))] + k_specs + k_specs
-    operands = [q3] + [k_pool] * npb + [v_pool] * npb
+    in_specs = [row_spec((1, h, d))] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
     if quantized:
-        s_specs = [
-            pl.BlockSpec(
-                (1, page, kv_heads), scale_index(n),
-                memory_space=pltpu.VMEM,
-            )
-            for n in range(npb)
-        ]
-        in_specs += s_specs + s_specs
-        operands += [k_scale] * npb + [v_scale] * npb
+        operands += [by_block(k_scale), by_block(v_scale)]
+        in_specs += [row_spec((1, nblk, kv_heads, npb * page))] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, nblk),
+        grid=(s,),
         in_specs=in_specs,
         out_specs=row_spec((1, h, d)),
         scratch_shapes=[
+            pltpu.VMEM((2, npb, page * kv_heads, d), k_pool.dtype),
+            pltpu.VMEM((2, npb, page * kv_heads, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),  # buffer of the row's first block
             pltpu.VMEM((h, 128), jnp.float32),  # running max m
             pltpu.VMEM((h, 128), jnp.float32),  # denominator l
             pltpu.VMEM((h, d), jnp.float32),  # output accumulator
@@ -313,12 +413,12 @@ def _paged_flash(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, d), q3.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
-    )(
-        block_tables.astype(jnp.int32),
-        seq_lens.astype(jnp.int32),
-        *operands,
-    )
+        name="attention._paged_decode_step",
+    )(bt, seq_lens.astype(jnp.int32), q3, *operands)
 
 
 def paged_attention(
@@ -362,18 +462,12 @@ def paged_attention(
             k_scale=k_scale, v_scale=v_scale,
         )
 
-    if pages_per_block is None:
-        from distributed_pytorch_tpu.ops.flash_autotune import lookup_paged
-
-        page = k_pool.shape[1]
-        pages_per_block = lookup_paged(
-            block_tables.shape[1] * page, page, d,
-            dtype_name=jnp.dtype(q.dtype).name,
-        )
-
     run = functools.partial(
         _paged_flash,
-        pages_per_block=pages_per_block,
+        pages_per_block=block_pages(
+            block_tables.shape[1], k_pool.shape[1], d, q.dtype,
+            pages_per_block,
+        ),
         interpret=(mode == "interpret"),
     )
     q3 = q.reshape(s, h, d)

@@ -413,6 +413,20 @@ class InferenceEngine:
         self.decode_model = model.clone(
             decode=True, page_size=page_size, num_pages=num_pages, **clone_kw
         )
+        # What a decode dispatch reads of the pools, for the tracer's
+        # ``decode_kv_tokens_*`` gauges: the kernel walks whole blocks of
+        # this many tokens; 0 is the gather path, which reads every slot's
+        # whole table.
+        self._kv_block_tokens = 0
+        self._decode_positions: List[np.ndarray] = []
+        if self.paged_kernel:
+            from distributed_pytorch_tpu.ops import paged_attention as pa
+
+            if pa.resolve_kernel(self.paged_kernel) != "xla":
+                self._kv_block_tokens = page_size * pa.block_pages(
+                    self.pages_per_seq, page_size,
+                    model.d_model // model.n_heads, model.dtype,
+                )
         # Size the paged pool from abstract shapes only (eval_shape traces
         # init without running it); token length 1 — pool shapes depend only
         # on (num_pages, page_size), never on the init input.
@@ -1604,6 +1618,8 @@ class InferenceEngine:
                     bias.fill(0.0)
                 bias[slot] = row
         self._stage_row_keys(slots)
+        if self.tracer.enabled:
+            self._decode_positions.append(self._stage_lens[slots])
         staged = (
             self._stage_tokens.nbytes
             + self._stage_use_prev.nbytes
@@ -1660,6 +1676,25 @@ class InferenceEngine:
         if self.state_layers:
             extra["state_slots_in_use"] = len(self.scheduler.running)
             extra["state_bytes"] = self._state_bytes()
+        if self._decode_positions:
+            # Over every decode dispatch of the step: the key positions its
+            # rows could see, and the ones read for them.
+            from distributed_pytorch_tpu.ops.paged_attention import (
+                kv_tokens_walked,
+            )
+
+            block = self._kv_block_tokens
+            whole = self.max_slots * self.pages_per_seq * self.page_size
+            fetched = visible = 0
+            for pos in self._decode_positions:
+                visible += int(pos.sum()) + len(pos)
+                fetched += (
+                    int(kv_tokens_walked(pos, block).sum()) if block
+                    else whole
+                )
+            self._decode_positions.clear()
+            extra["decode_kv_tokens_fetched"] = fetched
+            extra["decode_kv_tokens_visible"] = visible
         self.tracer.end_step(
             decode_rows=len(plan.decode_slots),
             prefill_chunks=len(plan.prefill),

@@ -21,7 +21,7 @@ from distributed_pytorch_tpu.ops.flash_autotune import (
     DEFAULT_TABLE,
     PAGED_DEFAULT_TABLE,
 )
-from distributed_pytorch_tpu.ops.paged_attention import _paged_flash
+from distributed_pytorch_tpu.ops.paged_attention import paged_attention
 
 KIND = "tpu v5 lite"
 # chip_smoke.py's engine: 8 slots, 16 heads of 128, max_seq_len 2048 in
@@ -50,22 +50,24 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def paged_case(chip, heads, kv_heads, quantized):
+def paged_case(chip, heads, kv_heads, quantized, slots=SLOTS,
+               pages_per_seq=PAGES_PER_SEQ, num_pages=None):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    pool = (SLOTS * PAGES_PER_SEQ + 1, PAGE, kv_heads, HEAD_DIM)
+    num_pages = num_pages or slots * pages_per_seq + 1
+    pool = (num_pages, PAGE, kv_heads, HEAD_DIM)
     pool_dtype = jnp.int8 if quantized else jnp.bfloat16
     scale = arg(pool[:-1], jnp.float32) if quantized else None
     fn = functools.partial(
-        _paged_flash, pages_per_block=PAGED_DEFAULT_TABLE[KIND],
-        interpret=False,
+        paged_attention, kernel="pallas",
+        pages_per_block=PAGED_DEFAULT_TABLE[KIND],
     )
     return jax.jit(fn).lower(
-        arg((SLOTS, heads, HEAD_DIM), jnp.bfloat16),
+        arg((slots, 1, heads, HEAD_DIM), jnp.bfloat16),
         arg(pool, pool_dtype), arg(pool, pool_dtype),
-        arg((SLOTS, PAGES_PER_SEQ), jnp.int32), arg((SLOTS,), jnp.int32),
-        scale, scale,
+        arg((slots, pages_per_seq), jnp.int32), arg((slots,), jnp.int32),
+        k_scale=scale, v_scale=scale,
     )
 
 
@@ -100,6 +102,18 @@ CASES = {
         for h, kv in ((16, 8), (16, 4), (4, 2), (20, 1))
         for quant in (False, True)
     },
+    # The benchmark's engines, whole: StarCoder2-3B's 32 slots of 256 pages
+    # out of 12,288, and Jamba2-3B's 128 slots of 128 out of 16,385. The
+    # kernel copies pages out of the pool itself, so the pool's size and the
+    # table's width are part of what the compiler is asked.
+    "paged-sc2-3b": functools.partial(
+        paged_case, heads=24, kv_heads=2, quantized=False, slots=32,
+        pages_per_seq=256, num_pages=12288,
+    ),
+    "paged-jamba2-3b": functools.partial(
+        paged_case, heads=20, kv_heads=1, quantized=False, slots=128,
+        pages_per_seq=128, num_pages=16385,
+    ),
     **{
         f"flash-T{t}-{'grad' if grad else 'fwd'}": functools.partial(
             flash_case, t=t, grad=grad

@@ -16,6 +16,7 @@ int8<->fp restores exactly like the mesh-geometry refusal.
 """
 
 import dataclasses
+import functools
 import json
 
 import jax
@@ -25,12 +26,16 @@ import pytest
 
 from distributed_pytorch_tpu.models.transformer import TransformerLM
 from distributed_pytorch_tpu.ops import flash_autotune as fa
+from distributed_pytorch_tpu.obs import Tracer
 from distributed_pytorch_tpu.ops.paged_attention import (
+    NULL_PAGE,
+    kv_tokens_walked,
     paged_attention,
     paged_attention_reference,
     resolve_kernel,
 )
 from distributed_pytorch_tpu.ops.quant import quantize_int8
+from distributed_pytorch_tpu.serving import kv_cache
 from distributed_pytorch_tpu.serving import (
     EngineSnapshot,
     InferenceEngine,
@@ -63,6 +68,16 @@ def quantize_pool(pool):
     return qt.q, jnp.squeeze(qt.scale, -1)
 
 
+def assert_rows_match(out, ref, bt, tol=2e-6):
+    """The kernel against the reference: live rows within ``tol``, rows out
+    of the group (a table that starts at the null page) exactly zero."""
+    out, ref = np.asarray(out), np.asarray(ref)
+    assert out.shape == ref.shape
+    live = np.asarray(bt)[:, 0] != NULL_PAGE
+    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+    assert (out[~live] == 0).all()
+
+
 class TestPagedAttentionOp:
     @pytest.mark.parametrize("npb", [1, 2, 4])
     def test_kernel_matches_reference_fp(self, npb):
@@ -71,8 +86,7 @@ class TestPagedAttentionOp:
         out = paged_attention(
             q, kp, vp, bt, lens, kernel="interpret", pages_per_block=npb
         )
-        assert out.shape == ref.shape
-        np.testing.assert_allclose(out, ref, atol=2e-6, rtol=2e-6)
+        assert_rows_match(out, ref, bt)
 
     def test_xla_mode_is_reference_bitwise(self):
         q, kp, vp, bt, lens = make_problem()
@@ -96,8 +110,9 @@ class TestPagedAttentionOp:
         live = slice(0, 2)  # row 2 is inactive; only live rows must hold
         assert (np.asarray(ref_p)[live] == np.asarray(ref)[live]).all()
         assert (np.asarray(out_p)[live] == np.asarray(out)[live]).all()
-        # Inactive rows still produce FINITE (discarded) output.
-        assert np.isfinite(np.asarray(out_p)).all()
+        # Inactive rows still produce FINITE (discarded) output: the
+        # kernel's are zeros.
+        assert (np.asarray(out_p)[2] == 0).all()
         assert np.isfinite(np.asarray(ref_p)).all()
 
     def test_padded_table_tail_is_masked(self):
@@ -112,7 +127,7 @@ class TestPagedAttentionOp:
         ref_w = paged_attention_reference(q, kp, vp, wide_bt, lens)
         out_w = paged_attention(q, kp, vp, wide_bt, lens, kernel="interpret")
         np.testing.assert_allclose(ref_w, ref, atol=0, rtol=0)
-        np.testing.assert_allclose(out_w, ref, atol=2e-6, rtol=2e-6)
+        assert_rows_match(out_w, ref, wide_bt)
 
     def test_int8_kernel_matches_int8_reference(self):
         q, kp, vp, bt, lens = make_problem()
@@ -124,7 +139,7 @@ class TestPagedAttentionOp:
         out = paged_attention(
             q, k8, v8, bt, lens, k_scale=ks, v_scale=vs, kernel="interpret"
         )
-        np.testing.assert_allclose(out, ref, atol=2e-6, rtol=2e-6)
+        assert_rows_match(out, ref, bt)
         # And the quantized result is close to (not equal to) the fp one.
         fp = paged_attention_reference(q, kp, vp, bt, lens)
         err = np.abs(np.asarray(ref) - np.asarray(fp)).max()
@@ -136,7 +151,7 @@ class TestPagedAttentionOp:
         q, kp, vp, bt, lens = make_problem(h=8, kv_heads=2)
         ref = paged_attention_reference(q, kp, vp, bt, lens)
         out = paged_attention(q, kp, vp, bt, lens, kernel="interpret")
-        np.testing.assert_allclose(out, ref, atol=2e-6, rtol=2e-6)
+        assert_rows_match(out, ref, bt)
         bumped = paged_attention_reference(
             q, kp, vp.at[:, :, 0, :].add(1.0), bt, lens
         )
@@ -176,7 +191,7 @@ class TestPagedAttentionOp:
         out = paged_attention(
             q, kp, vp, bt, lens, kernel="interpret", mesh=mesh
         )
-        np.testing.assert_allclose(out, ref, atol=2e-6, rtol=2e-6)
+        assert_rows_match(out, ref, bt)
         k8, ks = quantize_pool(kp)
         v8, vs = quantize_pool(vp)
         ref8 = paged_attention_reference(
@@ -186,14 +201,146 @@ class TestPagedAttentionOp:
             q, k8, v8, bt, lens, k_scale=ks, v_scale=vs,
             kernel="interpret", mesh=mesh,
         )
-        np.testing.assert_allclose(out8, ref8, atol=2e-6, rtol=2e-6)
+        assert_rows_match(out8, ref8, bt)
 
     def test_jit_composes(self):
         q, kp, vp, bt, lens = make_problem()
         fn = jax.jit(lambda *a: paged_attention(*a, kernel="interpret"))
         out = fn(q, kp, vp, bt, lens)
         ref = paged_attention_reference(q, kp, vp, bt, lens)
-        np.testing.assert_allclose(out, ref, atol=2e-6, rtol=2e-6)
+        assert_rows_match(out, ref, bt)
+
+    def test_null_page_is_the_pools(self):
+        assert NULL_PAGE == kv_cache.NULL_PAGE
+
+
+# ------------------------------------- the kernel walks only a row's own KV
+
+PAGE, WIDTH, NPB = 4, 8, 2  # a block of 8 tokens, 4 blocks a table
+BLOCK = NPB * PAGE
+# A row's decode position, or None for a row out of the group: the first
+# key alone, the last of a block, the first of the next, one past it, one
+# whole page, the table's last position; absent rows first, between live
+# rows, twice in a row and last, so every hand-over of the prefetch runs.
+EDGES = [
+    None, 0, BLOCK - 1, None, BLOCK, BLOCK + 1, None, None, PAGE - 1,
+    WIDTH * PAGE - 1, None,
+]
+
+
+def ragged_problem(positions, *, h=4, kv_heads=2, d=8, page=PAGE,
+                   width=WIDTH, spare=3, seed=0):
+    """A decode batch with rows at ``positions``: each live row owns just
+    the pages its ``pos + 1`` keys need, the rest of its table is the null
+    page, and ``spare`` pages belong to no row. Every page holds random
+    numbers, the null page and the spare ones too."""
+    rng = np.random.default_rng(seed)
+    owned = [0 if p is None else p // page + 1 for p in positions]
+    num_pages = 1 + sum(owned) + spare
+    ids = iter(rng.permutation(np.arange(1, num_pages)))
+    bt = np.zeros((len(positions), width), np.int32)
+    for row, n in enumerate(owned):
+        bt[row, :n] = [next(ids) for _ in range(n)]
+    lens = np.asarray([p or 0 for p in positions], np.int32)
+    pool = (num_pages, page, kv_heads, d)
+    q = jnp.asarray(rng.standard_normal((len(positions), 1, h, d)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(lens)
+
+
+def through(variant, npb=NPB):
+    """``(kernel, reference)`` over ``(q, kp, vp, bt, lens)``: float pages,
+    int8 pages, the (1, 2) mesh, or under ``jit``."""
+    def scales(kp, vp):
+        (k8, ks), (v8, vs) = quantize_pool(kp), quantize_pool(vp)
+        return (k8, v8), dict(k_scale=ks, v_scale=vs)
+
+    def run(fn, q, kp, vp, bt, lens, **kw):
+        if variant == "int8":
+            (kp, vp), sc = scales(kp, vp)
+            kw.update(sc)
+        return fn(q, kp, vp, bt, lens, **kw)
+
+    kw = dict(kernel="interpret", pages_per_block=npb)
+    if variant == "mesh":
+        kw["mesh"] = make_serving_mesh(1, 2)
+    kernel = functools.partial(run, paged_attention, **kw)
+    if variant == "jit":
+        kernel = jax.jit(kernel)
+    return kernel, functools.partial(run, paged_attention_reference)
+
+
+VARIANTS = ["fp", "int8", "mesh", "jit"]
+
+
+class TestKernelWalksOwnKV:
+    @pytest.mark.parametrize("npb", [1, NPB, WIDTH])
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_ragged_rows_match_reference(self, variant, npb):
+        """Blocks of one page, of two, and one block a table, over rows
+        that end on every edge of a block."""
+        problem = ragged_problem(EDGES)
+        kernel, reference = through(variant, npb)
+        assert_rows_match(kernel(*problem), reference(*problem), problem[3])
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_absent_rows_are_zero_and_move_no_live_row(self, variant):
+        positions = [5, BLOCK, 2 * BLOCK + 3, 0, WIDTH * PAGE - 1]
+        q, kp, vp, bt, lens = ragged_problem(positions)
+        kernel, _ = through(variant)
+        full = np.asarray(kernel(q, kp, vp, bt, lens))
+        for absent in ([0], [1, 2], [4], [0, 2, 4]):
+            keep = np.ones(len(positions), bool)
+            keep[absent] = False
+            out = np.asarray(kernel(
+                q, kp, vp, jnp.where(keep[:, None], bt, NULL_PAGE),
+                jnp.where(keep, lens, 0),
+            ))
+            assert (out[~keep] == 0).all(), absent
+            assert (out[keep] == full[keep]).all(), absent
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_garbage_past_a_rows_keys_changes_nothing(self, variant):
+        """Large finite numbers in every slot a row owns past ``pos``, in
+        every page no row owns and in the null page: live rows bit for
+        bit."""
+        positions = [1, BLOCK - 1, BLOCK, None, 2 * BLOCK + 2]
+        q, kp, vp, bt, lens = ragged_problem(positions)
+        dead = np.ones(kp.shape[:2], bool)  # [page id, slot in page]
+        for row, pos in enumerate(positions):
+            for key in range(0 if pos is None else pos + 1):
+                dead[int(bt[row, key // PAGE]), key % PAGE] = False
+        assert dead[NULL_PAGE].all() and dead.sum() > 4 * PAGE
+        mask = jnp.asarray(dead)[:, :, None, None]
+        kernel, reference = through(variant)
+        clean = np.asarray(kernel(q, kp, vp, bt, lens))
+        ref = np.asarray(reference(q, kp, vp, bt, lens))
+        kp, vp = jnp.where(mask, 1e4, kp), jnp.where(mask, -1e4, vp)
+        live = [p is not None for p in positions]
+        assert (np.asarray(kernel(q, kp, vp, bt, lens))[live] == clean[live]).all()
+        assert (np.asarray(reference(q, kp, vp, bt, lens))[live] == ref[live]).all()
+
+    @pytest.mark.parametrize("kv_heads, group, width", [
+        (2, 12, 256),  # StarCoder2-3B: 24 query heads, 4,096 tokens a row
+        (1, 20, 128),  # Jamba2-3B's attention layers: 2,048 tokens a row
+    ])
+    def test_the_cells_head_groupings_and_table_widths(
+            self, kv_heads, group, width):
+        problem = ragged_problem(
+            [130, None, 0, 127], h=kv_heads * group, kv_heads=kv_heads,
+            d=128, page=16, width=width, seed=3,
+        )
+        kernel, reference = through("fp", npb=8)
+        assert_rows_match(
+            kernel(*problem), reference(*problem), problem[3], tol=1e-5
+        )
+
+    def test_tokens_walked(self):
+        pos = np.asarray([0, BLOCK - 1, BLOCK, 3 * BLOCK + 1])
+        assert kv_tokens_walked(pos, BLOCK).tolist() == [
+            BLOCK, BLOCK, 2 * BLOCK, 4 * BLOCK,
+        ]
 
 
 # ------------------------------------------------------- autotune family
@@ -379,6 +526,48 @@ class TestEngineKernelParity:
         eng.close()
         assert any(n.startswith("decode_step_paged") for n in names)
         assert not any(n == "decode_step" for n in names)
+
+    @pytest.mark.parametrize("kernel, block", [("interpret", 16), ("xla", 0)])
+    def test_step_slices_count_kv_tokens_fetched_and_visible(
+            self, model_and_params, kernel, block):
+        """Prompts of 15 and 3 tokens decode side by side from positions
+        14 and 2: a step's slice carries the keys its decode rows could see
+        (``pos + 1``) and the ones read for them, whole blocks of 2 pages
+        of 8 under the kernel (the seeded "cpu" entry), every slot's whole
+        table on the gather path."""
+        model, params = model_and_params
+        tracer = Tracer()
+        eng = InferenceEngine(
+            model, params, tracer=tracer, overlap=False,
+            paged_kernel=kernel, **ENGINE_KW,
+        )
+        assert eng._kv_block_tokens == block
+        for prompt in (list(range(1, 16)), [1, 2, 3]):
+            eng.submit(prompt, SamplingParams(max_new_tokens=4))
+        eng.run()
+        eng.close()
+        steps = [
+            e["args"] for e in tracer.events
+            if e.get("ph") == "X" and e["name"] == "step"
+        ]
+        counted = [
+            (a["decode_rows"], a["decode_kv_tokens_visible"],
+             a["decode_kv_tokens_fetched"])
+            for a in steps if "decode_kv_tokens_visible" in a
+        ]
+        assert all(
+            "decode_kv_tokens_fetched" not in a
+            for a in steps if not a["decode_rows"]
+        )
+        whole = ENGINE_KW["max_slots"] * ENGINE_KW["max_seq_len"]
+        # positions (14, 2), (15, 3), (16, 4), (17, 5): the long row
+        # crosses into its second block of 16 at position 16
+        assert counted == [
+            (2, 15 + 3, 16 + 16 if block else whole),
+            (2, 16 + 4, 16 + 16 if block else whole),
+            (2, 17 + 5, 32 + 16 if block else whole),
+            (2, 18 + 6, 32 + 16 if block else whole),
+        ]
 
     def test_bad_kernel_mode_fails_at_init(self, model_and_params):
         model, params = model_and_params

@@ -157,10 +157,15 @@ def fold_row_keys(staged):
     return jax.vmap(jax.random.fold_in)(staged[:, :2], staged[:, 2])
 
 
-def decode_token_step(decode_model, params, cache, current, **apply_kwargs):
+def decode_token_step(
+    decode_model, params, cache, current, *, mutable=("cache",),
+    **apply_kwargs,
+):
     """ONE decode-mode forward: apply ``decode_model`` on ``current``
     ([B, T_step] token ids) against ``cache``, returning ``(last_logits,
     cache)`` where ``last_logits`` is ``[B, V]`` at the final position.
+    A ``mutable`` that names more collections than ``"cache"`` adds a third
+    result: what the model wrote to those, by collection.
 
     This is the single-token step extracted from ``generate``'s loop body so
     the serving engine (serving/engine.py) drives EXACTLY the same compiled
@@ -172,10 +177,13 @@ def decode_token_step(decode_model, params, cache, current, **apply_kwargs):
     logits, updated = decode_model.apply(
         {"params": dequantize_pytree(params, dtype), "cache": cache},
         current,
-        mutable=["cache"],
+        mutable=list(mutable),
         **apply_kwargs,
     )
-    return logits[:, -1, :], updated["cache"]
+    out = logits[:, -1, :], updated["cache"]
+    if len(mutable) > 1:
+        out += ({k: updated[k] for k in mutable if k != "cache"},)
+    return out
 
 
 def decode_chunk_step(decode_model, params, cache, current, **apply_kwargs):

@@ -152,8 +152,8 @@ class MambaMixer(nn.Module):
                 )
             conv_var = self.variable("cache", "conv_state", lambda: None)
             scan_var = self.variable("cache", "scan_state", lambda: None)
-            tail = _load_rows(conv_var.value, state_slots, seq_lens)
-            h0 = _load_rows(scan_var.value, state_slots, seq_lens)
+            tail = load_rows(conv_var.value, state_slots, seq_lens)
+            h0 = load_rows(scan_var.value, state_slots, seq_lens)
         else:
             tail = jnp.zeros((batch, k - 1, d_inner), self.dtype)
             h0 = jnp.zeros((batch, n, d_inner), STATE_DTYPE)
@@ -198,15 +198,21 @@ class MambaMixer(nn.Module):
             )
             y = y + d_skip.astype(F32) * u32
             if cached:
-                conv_var.value = _store_rows(
+                conv_var.value = store_rows(
                     conv_var.value, new_tail, state_slots
                 )
-                scan_var.value = _store_rows(scan_var.value, h, state_slots)
+                scan_var.value = store_rows(scan_var.value, h, state_slots)
         gated = (y * nn.silu(z.astype(F32))).astype(self.dtype)
         return dense(self.d_model, "out_proj")(gated)
 
 
-def _load_rows(state, state_slots, seq_lens):
+def _a_row(mask, like):
+    """``mask [rows]`` against a state of any rank (``models/mamba2.py``'s has
+    one axis more)."""
+    return mask[(slice(None),) + (None,) * (like.ndim - 1)]
+
+
+def load_rows(state, state_slots, seq_lens):
     """Each batch row's state: its slot's, or zeros at position 0 and for a
     row that carries none. A batch as long as the slot table IS the slot
     table (module docstring)."""
@@ -215,15 +221,15 @@ def _load_rows(state, state_slots, seq_lens):
         jnp.clip(state_slots, 0, slots - 1)
     ]
     keep = (seq_lens > 0) & (state_slots >= 0)
-    return jnp.where(keep[:, None, None], rows, jnp.zeros_like(rows))
+    return jnp.where(_a_row(keep, rows), rows, jnp.zeros_like(rows))
 
 
-def _store_rows(state, new, state_slots):
+def store_rows(state, new, state_slots):
     """Write the rows that carry a slot; every other row of ``state`` is
     returned as it was."""
     slots = state.shape[0]
     new = new.astype(state.dtype)
     live = state_slots >= 0
     if state_slots.shape[0] == slots:
-        return jnp.where(live[:, None, None], new, state)
+        return jnp.where(_a_row(live, state), new, state)
     return state.at[jnp.where(live, state_slots, slots)].set(new, mode="drop")

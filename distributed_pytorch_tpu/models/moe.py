@@ -23,6 +23,31 @@ beyond-parity capability, built the TPU way:
 * The **load-balance auxiliary loss** (mean expert load x mean router prob,
   scaled by ``aux_weight``) is sown into the ``"losses"`` collection; the
   train step adds every term in that collection to the task loss.
+
+**Two expert layers live here until training moves** (ROADMAP queue D).
+:class:`MoEMLP` above is the training layer: capacity, dropped tokens, a dense
+one-hot. :class:`RoutedExperts` below is the serving layer, and the one an
+expert-parallel deployment needs: **dropless top-k over a held range**. It is
+told ``n_experts`` (the router's width, as published) and ``held = (lo, hi)``,
+the experts whose weights THIS chip has; it routes every token over all
+``n_experts`` (scores, top-k and gates in float32, ``ROUTER_DTYPE``; gates a
+softmax over the chosen ``top_k`` scores alone), and computes its own
+experts' part of the result for the (token, expert) pairs routed to them.
+Pairs on absent experts are left out: the partial sum is the layer's result
+here, and the parts of all the shares add up to the whole layer's. On one
+chip the layer runs without its exchange, and nothing stands in for it.
+
+Work is proportional to the routed pairs, not to tokens x experts: the pairs
+are sorted by expert (held ones first, absent ones and those of rows that
+carry nothing behind them), the rows gathered in that order, and the two
+projections are grouped matrix products over the held experts
+(``jax.lax.ragged_dot``, which the TPU compiler lowers to a Mosaic grouped
+matmul that reads an expert's weights only if a row reached it; the device
+trace names it ``ragged-dot``). No capacity, so no token is dropped at any
+load: the products' row count is ``tokens x top_k`` whatever the routing.
+The layer also counts the tokens routed to each of the ``n_experts``
+(``[n_experts] int32``, sown into the ``"routing"`` collection when the
+caller makes it mutable): the serving engine's routing counters.
 """
 
 from __future__ import annotations
@@ -36,6 +61,12 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_pytorch_tpu.parallel.partitioning import Rules
+
+F32 = jnp.float32
+#: The type of the router's scores, top-k and gates in :class:`RoutedExperts`.
+#: Not an option: the tests and the benchmark's control that show what
+#: catches a lower one patch it.
+ROUTER_DTYPE = F32
 
 #: Expert-parallel specs for :class:`MoEMLP` params (stacked over dim 0 = E).
 #: Compose with ``TRANSFORMER_TP_RULES`` for the dense layers: EP rules first,
@@ -182,3 +213,105 @@ class MoEMLP(nn.Module):
         # Expert slots -> tokens: the reverse all-to-all.
         y = jnp.einsum("btec,ebcm->btm", combine_t.astype(compute), out)
         return y.astype(x.dtype)
+
+
+def route_top_k(scores: jnp.ndarray, top_k: int):
+    """The published router: the ``top_k`` largest of a token's scores and
+    gates that are a softmax over THOSE scores. ``scores [..., E]`` ->
+    ``(gates [..., top_k]`` in the scores' type, ``experts [..., top_k])``."""
+    best, experts = jax.lax.top_k(scores, top_k)
+    return jax.nn.softmax(best, axis=-1), experts
+
+
+class RoutedExperts(nn.Module):
+    """Dropless top-k routed gated-SiLU experts over a held range (module
+    docstring). ``[B, T, d_model] -> [B, T, d_model]``.
+
+    Parameters: ``router/kernel [d_model, n_experts]`` (no bias),
+    ``in_kernel [held, d_model, 2 d_ff]`` (``[gate, up]``) and ``out_kernel
+    [held, d_ff, d_model]``. ``live [B]`` (optional) marks the batch rows
+    that carry a request: the others' pairs are computed by nobody and
+    counted nowhere (the batched decode step's rows outside its group)."""
+
+    n_experts: int
+    top_k: int
+    d_ff: int
+    d_model: int
+    held: Optional[tuple] = None  # (lo, hi): experts lo..hi-1; None = all
+    dtype: Any = F32
+
+    @nn.compact
+    def __call__(
+        self, x: jnp.ndarray, *, live: Optional[jnp.ndarray] = None
+    ) -> jnp.ndarray:
+        batch, t, d = x.shape
+        lo, hi = self.held or (0, self.n_experts)
+        if not 0 <= lo < hi <= self.n_experts:
+            raise ValueError(
+                f"held experts {self.held} outside 0..{self.n_experts}"
+            )
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(
+                f"top_k {self.top_k} of {self.n_experts} experts"
+            )
+        n_held, k = hi - lo, self.top_k
+        tokens = batch * t
+        flat = x.reshape(tokens, d)
+
+        with jax.named_scope("moe.route"):
+            router = self.param(
+                "router_kernel", nn.initializers.normal(0.02),
+                (d, self.n_experts), F32,
+            )
+            scores = jnp.dot(
+                flat.astype(ROUTER_DTYPE), router.astype(ROUTER_DTYPE),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=ROUTER_DTYPE,
+            )
+            gates, experts = route_top_k(scores, k)  # [tokens, k]
+            alive = (
+                jnp.ones((tokens,), bool) if live is None
+                else jnp.repeat(live, t)
+            )
+            # Held pairs by expert, everything else behind them.
+            local = experts - lo
+            mine = (local >= 0) & (local < n_held) & alive[:, None]
+            group = jnp.where(mine, local, n_held).reshape(-1)
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.bincount(group, length=n_held + 1)[:n_held]
+            counts = jnp.bincount(
+                jnp.where(alive[:, None], experts, self.n_experts).reshape(-1),
+                length=self.n_experts + 1,
+            )[: self.n_experts]
+            self.sow("routing", "counts", counts.astype(jnp.int32))
+
+        w_in = self.param(
+            "in_kernel", nn.initializers.lecun_normal(),
+            (n_held, d, 2 * self.d_ff),
+        )
+        w_out = self.param(
+            "out_kernel", nn.initializers.lecun_normal(),
+            (n_held, self.d_ff, d),
+        )
+        with jax.named_scope("moe.experts"):
+            rows = flat.astype(self.dtype)[order // k]  # [tokens k, d]
+            sizes = sizes.astype(jnp.int32)
+            gate_up = jax.lax.ragged_dot(
+                rows, w_in.astype(self.dtype), sizes,
+                preferred_element_type=F32,
+            )
+            g, u = jnp.split(gate_up, 2, axis=-1)
+            act = (nn.silu(g) * u).astype(self.dtype)
+            out = jax.lax.ragged_dot(
+                act, w_out.astype(self.dtype), sizes,
+                preferred_element_type=F32,
+            )
+        with jax.named_scope("moe.combine"):
+            # Rows past the held pairs belong to no group: whatever the
+            # product left there is not a result.
+            in_group = jnp.arange(tokens * k) < jnp.sum(sizes)
+            weight = gates.reshape(-1)[order].astype(F32)
+            out = jnp.where(in_group[:, None], out * weight[:, None], 0.0)
+            back = jnp.argsort(order)  # pair (token, choice) -> its row
+            y = out[back].reshape(tokens, k, d).sum(axis=1)
+        return y.reshape(batch, t, d).astype(x.dtype)

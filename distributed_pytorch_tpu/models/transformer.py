@@ -152,6 +152,14 @@ class Attention(nn.Module):
     # projections.
     rope: bool = True
     use_bias: bool = True  # biases on the four projections
+    # What the scores are multiplied by before the softmax, on every path
+    # below and in the paged kernel; None is the usual ``head_dim ** -0.5``.
+    score_scale: Optional[float] = None
+
+    def _scale(self, head_dim: int) -> float:
+        if self.score_scale is None:
+            return head_dim**-0.5
+        return self.score_scale
 
     def _rope(self, x, positions=None):
         if not self.rope:
@@ -284,6 +292,11 @@ class Attention(nn.Module):
 
         q = self._rope(q_raw)
         k = self._rope(k_raw)
+        if self.score_scale is not None:
+            # The cores below scale by head_dim ** -0.5 themselves.
+            q = q * jnp.asarray(
+                self.score_scale * head_dim**0.5, q.dtype
+            )
         if kv_heads != self.n_heads:
             # Compute-side broadcast for the cores that need full heads
             # (flash, ulysses). Ring and decode take the UN-repeated k/v so
@@ -388,7 +401,7 @@ class Attention(nn.Module):
             )
             keys, values = cached_key.value, cached_value.value
         cache_index.value = index + t_step
-        scale = q.shape[-1] ** -0.5
+        scale = self._scale(q.shape[-1])
         # Position k is visible to step-q q when k <= index + q (and, with
         # a sliding window, within the last `window` positions). Per-row
         # indices make the mask [B, T_step, K] instead of [T_step, K].
@@ -518,6 +531,7 @@ class Attention(nn.Module):
                 k_scale=None if key_scale is None else key_scale.value,
                 v_scale=None if value_scale is None else value_scale.value,
                 kernel=self.paged_kernel, mesh=self.mesh,
+                sm_scale=self.score_scale,
             )
 
         # Gather each row's pages into its contiguous logical view. K below
@@ -538,7 +552,7 @@ class Attention(nn.Module):
             )
             keys = keys.astype(q.dtype) * ks[..., None].astype(q.dtype)
             values = values.astype(q.dtype) * vs[..., None].astype(q.dtype)
-        scale = d**-0.5
+        scale = self._scale(d)
         k_abs = jnp.arange(pages_per_seq * page)[None, None, :]
         visible = k_abs <= positions[:, :, None]  # [S, T_step, K]
         group = h // kv_heads
@@ -606,7 +620,10 @@ class MLPBlock(nn.Module):
         return dense(self.d_model, "down")(h)
 
 
-LAYER_TYPES = ("attention", "mamba")
+LAYER_TYPES = ("attention", "mamba", "mamba2")
+#: The layer types that keep a per-slot recurrent state in decode mode.
+RECURRENT_TYPES = ("mamba", "mamba2")
+FFN_TYPES = ("dense", "routed")
 
 
 def make_norm(kind: str, eps: float, name: str) -> nn.Module:
@@ -651,7 +668,12 @@ class TransformerBlock(nn.Module):
     use_bias: bool = True
     rope: bool = True
     mixer: str = "attention"  # one of LAYER_TYPES
-    mamba: tuple = ()  # MambaMixer's sizes as (field, value) pairs
+    mamba: tuple = ()  # the recurrent mixer's sizes as (field, value) pairs
+    score_scale: Optional[float] = None  # see Attention
+    residual_multiplier: float = 1.0  # on both branches before they are added
+    ffn: str = "dense"  # one of FFN_TYPES
+    routed: tuple = ()  # RoutedExperts' sizes as (field, value) pairs
+    shared_d_ff: int = 0  # a routed layer's shared gated-SiLU MLP; 0 = none
 
     @nn.compact
     def __call__(
@@ -686,6 +708,13 @@ class TransformerBlock(nn.Module):
                 self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
                 decode=self.decode, name="mamba", **dict(self.mamba),
             )(normed, seq_lens=seq_lens, state_slots=state_slots)
+        elif self.mixer == "mamba2":
+            from distributed_pytorch_tpu.models.mamba2 import Mamba2Mixer
+
+            mixed = Mamba2Mixer(
+                self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
+                decode=self.decode, name="mamba", **dict(self.mamba),
+            )(normed, seq_lens=seq_lens, state_slots=state_slots)
         elif self.mixer == "attention":
             mixed = Attention(
                 self.n_heads, self.d_model, self.dtype, self.causal,
@@ -697,28 +726,50 @@ class TransformerBlock(nn.Module):
                 page_size=self.page_size, num_pages=self.num_pages,
                 paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
                 rope=self.rope, use_bias=self.use_bias,
-                name="attention",
+                score_scale=self.score_scale, name="attention",
             )(normed, **paged_kw)
         else:
             raise ValueError(
                 f"unknown layer type {self.mixer!r} "
                 f"(expected one of {LAYER_TYPES})"
             )
-        x = x + drop(mixed)
-        if self.n_experts > 0:
+        def scaled(branch):
+            if self.residual_multiplier == 1.0:
+                return branch
+            return branch * jnp.asarray(self.residual_multiplier, branch.dtype)
+
+        x = x + drop(scaled(mixed))
+        normed = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
+        if self.ffn == "routed":
+            from distributed_pytorch_tpu.models.moe import RoutedExperts
+
+            fed = RoutedExperts(
+                d_ff=self.d_ff, d_model=self.d_model, dtype=self.dtype,
+                name="experts", **dict(self.routed),
+            )(normed, live=None if state_slots is None else state_slots >= 0)
+            if self.shared_d_ff:
+                fed = fed + MLPBlock(
+                    self.shared_d_ff, self.d_model, self.dtype,
+                    kind="gated_silu", use_bias=False, name="shared_mlp",
+                )(normed).astype(fed.dtype)
+        elif self.ffn != "dense":
+            raise ValueError(
+                f"unknown feed-forward {self.ffn!r} "
+                f"(expected one of {FFN_TYPES})"
+            )
+        elif self.n_experts > 0:
             cls = nn.remat(MoEMLP) if self.remat_mlp else MoEMLP
-            mlp = cls(
+            fed = cls(
                 self.n_experts, self.d_ff, self.d_model, self.dtype,
                 router_top_k=self.moe_top_k, mesh=self.mesh, name="moe",
-            )
+            )(normed)
         else:
             cls = nn.remat(MLPBlock) if self.remat_mlp else MLPBlock
-            mlp = cls(
+            fed = cls(
                 self.d_ff, self.d_model, self.dtype, kind=self.mlp,
                 use_bias=self.use_bias, name="mlp",
-            )
-        x = x + drop(mlp(make_norm(self.norm, self.norm_eps, "ln_mlp")(x)))
-        return x
+            )(normed)
+        return x + drop(scaled(fed))
 
 
 class LMHead(nn.Module):
@@ -864,11 +915,41 @@ class TransformerLM(nn.Module):
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: int = 0  # required where a layer is "mamba"
+    # A "mamba2" layer is models/mamba2.py's mixer (a state [H, P, N] a
+    # slot, one decay a head, evaluated in blocks); it shares mamba_d_state
+    # and mamba_d_conv with "mamba".
+    mamba_n_heads: int = 0  # required where a layer is "mamba2"
+    mamba_d_head: int = 0
+    mamba_n_groups: int = 1
+    # Scalars some families put on the residual stream (defaults: none).
+    # The scores' scale (None = head_dim ** -0.5) reaches every attention path
+    # and the paged kernel; the others multiply the embedding, each branch
+    # before it is added, and divide the logits.
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # One of FFN_TYPES per layer; None = the dense MLP (or ``n_experts``'
+    # MoEMLP) everywhere. A "routed" layer is models/moe.py's dropless
+    # RoutedExperts of width ``d_ff`` over ``routed_experts`` router outputs
+    # (``routed_top_k`` a token) of which this program holds
+    # ``experts_held = (lo, hi)`` (None: all), plus, where ``shared_d_ff``,
+    # one shared gated-SiLU MLP on every token.
+    ffn_types: Optional[tuple] = None
+    routed_experts: int = 0
+    routed_top_k: int = 0
+    experts_held: Optional[tuple] = None
+    shared_d_ff: int = 0
 
     @property
     def recurrent_layers(self) -> int:
         """How many layers keep a per-slot state in decode mode."""
-        return sum(t == "mamba" for t in self.layer_types or ())
+        return sum(t in RECURRENT_TYPES for t in self.layer_types or ())
+
+    @property
+    def routed_layers(self) -> int:
+        """How many layers sow a routing count (``"routing"`` collection)."""
+        return sum(t == "routed" for t in self.ffn_types or ())
 
     @nn.compact
     def __call__(
@@ -886,12 +967,34 @@ class TransformerLM(nn.Module):
                 f"layer_types names {len(types)} layers, the model has "
                 f"{self.n_layers}"
             )
-        if self.recurrent_layers and self.mamba_dt_rank < 1:
+        if "mamba" in (types or ()) and self.mamba_dt_rank < 1:
             raise ValueError("a model with mamba layers needs mamba_dt_rank")
+        if "mamba2" in (types or ()) and min(
+            self.mamba_n_heads, self.mamba_d_head
+        ) < 1:
+            raise ValueError(
+                "a model with mamba2 layers needs mamba_n_heads and "
+                "mamba_d_head"
+            )
+        ffns = self.ffn_types
+        if ffns is not None and len(ffns) != self.n_layers:
+            raise ValueError(
+                f"ffn_types names {len(ffns)} layers, the model has "
+                f"{self.n_layers}"
+            )
+        if self.routed_layers and min(
+            self.routed_experts, self.routed_top_k
+        ) < 1:
+            raise ValueError(
+                "a model with routed layers needs routed_experts and "
+                "routed_top_k"
+            )
         embed = nn.Embed(
             self.vocab_size, self.d_model, dtype=self.dtype, name="embed"
         )
         x = embed(tokens)
+        if self.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(self.embedding_multiplier, x.dtype)
         if self.dropout_rate > 0.0:
             x = nn.Dropout(self.dropout_rate)(
                 x, deterministic=not self.has_rng("dropout")
@@ -916,17 +1019,43 @@ class TransformerLM(nn.Module):
         block_kw = dict(
             norm=self.norm, norm_eps=self.norm_eps, mlp=self.mlp,
             use_bias=self.use_bias, rope=self.rope,
+            score_scale=self.attention_multiplier,
+            residual_multiplier=self.residual_multiplier,
         )
-        mamba_kw = (
-            ("d_state", self.mamba_d_state), ("d_conv", self.mamba_d_conv),
-            ("expand", self.mamba_expand), ("dt_rank", self.mamba_dt_rank),
+        mixer_kw = {
+            "mamba": (
+                ("d_state", self.mamba_d_state), ("d_conv", self.mamba_d_conv),
+                ("expand", self.mamba_expand), ("dt_rank", self.mamba_dt_rank),
+            ),
+            "mamba2": (
+                ("n_heads", self.mamba_n_heads), ("d_head", self.mamba_d_head),
+                ("d_state", self.mamba_d_state), ("d_conv", self.mamba_d_conv),
+                ("n_groups", self.mamba_n_groups),
+            ),
+        }
+        routed_kw = dict(
+            ffn="routed", shared_d_ff=self.shared_d_ff,
+            routed=(
+                ("n_experts", self.routed_experts),
+                ("top_k", self.routed_top_k), ("held", self.experts_held),
+            ),
         )
         for i in range(self.n_layers):
             # GShard-style interleaving: every `moe_every`-th block is MoE.
             moe = self.n_experts if (i + 1) % self.moe_every == 0 else 0
             layer_kw = block_kw
             if types is not None and types[i] != "attention":
-                layer_kw = dict(block_kw, mixer=types[i], mamba=mamba_kw)
+                layer_kw = dict(
+                    block_kw, mixer=types[i],
+                    mamba=mixer_kw.get(types[i], ()),
+                )
+            if ffns is not None and ffns[i] != "dense":
+                if ffns[i] != "routed":
+                    raise ValueError(
+                        f"unknown feed-forward {ffns[i]!r} "
+                        f"(expected one of {FFN_TYPES})"
+                    )
+                layer_kw = dict(layer_kw, **routed_kw)
             x = block(
                 self.n_heads, self.d_model, self.d_ff, self.dtype,
                 True, self.mesh, self.sequence_axis,
@@ -950,10 +1079,18 @@ class TransformerLM(nn.Module):
                 f"vocab_size {self.vocab_size} not divisible by "
                 f"fused_head_chunk {self.fused_head_chunk}"
             )
-        return LMHead(
+        out = LMHead(
             self.vocab_size, self.fused_head_chunk, name="lm_head"
         )(
             x,
             targets,
             tied_kernel=embed.embedding.T if self.tie_embeddings else None,
         )
+        if self.logits_scaling != 1.0:
+            if self.fused_head_chunk and targets is not None:
+                raise ValueError(
+                    "logits_scaling does not compose with the fused loss "
+                    "head (it never has the logits to divide)"
+                )
+            out = out / self.logits_scaling
+        return out

@@ -122,6 +122,7 @@ def paged_attention_reference(
     *,
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
+    sm_scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """The XLA gather path: op-for-op the read side of
     ``_paged_decode_step`` (gather each row's pages into its contiguous
@@ -131,7 +132,8 @@ def paged_attention_reference(
     ``q`` [S, T_step, H, D] is post-RoPE; ``seq_lens`` [S] is each row's
     token count BEFORE the step (= the absolute position of its first new
     token). With ``k_scale``/``v_scale`` the pools are int8 and dequantize
-    at the gather, mirroring the contiguous quantized-cache idiom."""
+    at the gather, mirroring the contiguous quantized-cache idiom.
+    ``sm_scale`` multiplies the scores; ``None`` is ``D ** -0.5``."""
     s, t_step, h, d = q.shape
     kv_heads = k_pool.shape[2]
     page = k_pool.shape[1]
@@ -148,7 +150,7 @@ def paged_attention_reference(
         vs = v_scale[block_tables].reshape(s, kv_len, kv_heads)
         keys = keys.astype(q.dtype) * ks[..., None].astype(q.dtype)
         values = values.astype(q.dtype) * vs[..., None].astype(q.dtype)
-    scale = d**-0.5
+    scale = d**-0.5 if sm_scale is None else sm_scale
     k_abs = jnp.arange(kv_len)[None, None, :]
     visible = k_abs <= positions[:, :, None]  # [S, T_step, K]
     group = h // kv_heads
@@ -333,10 +335,12 @@ def kv_tokens_walked(positions, block_tokens: int):
     return (positions // block_tokens + 1) * block_tokens
 
 
-@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("pages_per_block", "interpret", "sm_scale")
+)
 def _paged_flash(
     q3, k_pool, v_pool, block_tables, seq_lens, k_scale, v_scale,
-    *, pages_per_block, interpret,
+    *, pages_per_block, interpret, sm_scale=None,
 ):
     """Build and invoke the pallas_call for ``q3`` [S, H, D] (T_step == 1).
 
@@ -408,7 +412,8 @@ def _paged_flash(
     )
     return pl.pallas_call(
         functools.partial(
-            _decode_kernel, npb=npb, group=group, sm_scale=d**-0.5,
+            _decode_kernel, npb=npb, group=group,
+            sm_scale=d**-0.5 if sm_scale is None else sm_scale,
             quantized=quantized,
         ),
         grid_spec=grid_spec,
@@ -434,6 +439,7 @@ def paged_attention(
     pages_per_block: Optional[int] = None,
     mesh=None,
     heads_axis: str = "model",
+    sm_scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Paged attention over ``q`` [S, T_step, H, D] against the page pools.
 
@@ -441,7 +447,8 @@ def paged_attention(
     per ``kernel`` (see :func:`resolve_kernel`); chunked reads — prefill
     chunks, speculative verification — always take the XLA reference, which
     handles any T_step. ``pages_per_block`` defaults to the autotune
-    harness' ``paged_decode`` family entry for this shape.
+    harness' ``paged_decode`` family entry for this shape. ``sm_scale``
+    multiplies the scores on every path (``None``: ``D ** -0.5``).
 
     Under a sharded jit pass ``mesh``: the kernel runs per-shard via
     ``shard_map`` with Q heads and KV heads (and scale heads) split over
@@ -459,7 +466,7 @@ def paged_attention(
     if mode == "xla" or t_step != 1:
         return paged_attention_reference(
             q, k_pool, v_pool, block_tables, seq_lens,
-            k_scale=k_scale, v_scale=v_scale,
+            k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,
         )
 
     run = functools.partial(
@@ -469,6 +476,8 @@ def paged_attention(
             pages_per_block,
         ),
         interpret=(mode == "interpret"),
+        # A static argument: None keeps the default block's one trace.
+        **({} if sm_scale is None else {"sm_scale": float(sm_scale)}),
     )
     q3 = q.reshape(s, h, d)
     bt = block_tables.astype(jnp.int32)

@@ -73,13 +73,19 @@ token-identical to the plain engine; sampled rows follow Leviathan et
 al.'s residual-resampling rule, keeping every emitted token exactly
 target-distributed.
 
-Recurrent layers (a model whose ``layer_types`` name ``"mamba"`` layers,
-``models/mamba.py``): a request then owns, beside its pages, ONE fixed-size
-state, the row of its slot in every such layer's two ``cache`` variables
-(``conv_state [max_slots, K-1, d_inner]``, ``scan_state [max_slots, N,
-d_inner]`` float32). The engine reads that the model has them from the
-model's ``recurrent_layers`` and from the cache tree it builds; there is no
-argument for it. The one design:
+Recurrent layers (a model whose ``layer_types`` name ``"mamba"`` or
+``"mamba2"`` layers, ``models/mamba.py`` and ``models/mamba2.py``): a request
+then owns, beside its pages, ONE fixed-size state, the row of its slot in
+every such layer's two ``cache`` variables (``STATE_KEYS``: for ``"mamba"``
+``conv_state [max_slots, K-1, d_inner]`` and ``scan_state [max_slots, N,
+d_inner]`` float32; for ``"mamba2"`` ``conv_state [max_slots, K-1, d_inner +
+2 G N]`` and ``scan_state [max_slots, H, P, N]`` float32, 4 MB a slot and
+layer at 128 x 64 x 128). The engine reads that the model has them from the
+model's ``recurrent_layers`` and from the cache tree it builds, whatever the
+states' shapes; there is no argument for it, and nothing below changed when
+the second kind of state arrived: the mask, the reset rule, the prefill
+operand, the byte count and the refusals are keyed on ``STATE_KEYS`` and on
+the leaves' leading ``max_slots`` alone. The one design:
 
 * **decode** runs all ``max_slots`` rows, row ``r`` on slot ``r``'s state in
   place. A row outside the dispatched group has a zeroed block table; the
@@ -108,6 +114,26 @@ argument for it. The one design:
   cannot be taken back out of a recurrence), the host page tier and a mesh
   (both know pages only), and ``serving/elastic.py``'s restore. ``kv_quant``
   is served: it concerns the attention layers' pages alone.
+
+Routed expert layers (a model whose ``ffn_types`` name ``"routed"`` layers,
+``models/moe.py``'s ``RoutedExperts``): the engine reads ``routed_layers``
+from the model, as it reads ``recurrent_layers``. Such a model's decode and
+prefill programs return one more result, ``[routed layers, n_experts] int32``:
+how many of the program's tokens (of the rows that carry a request) the
+router sent to each expert. ``routing_counts`` holds the last step's, as the
+device arrays the programs returned, in dispatch order, for whoever reads
+them (the benchmark's state probe does); the engine itself does not look at
+them unless a tracer is on. Then the arrays of a step are kept until the
+NEXT step's trace closes (by then the step's tokens have been read back, so
+reading them waits for nothing and the overlap a traced run measures is the
+untraced one) and are written as one ``moe.routing`` instant that names its
+step: ``moe_programs``,
+``moe_pairs_held`` and ``moe_pairs_absent`` (routed (token, expert) pairs on
+experts this model holds and on the others), ``moe_experts_hit`` (held
+experts with at least one token, summed over programs and layers) and
+``moe_tokens_per_expert_max`` / ``_mean`` (over the held experts of a layer,
+summed likewise). ``finish_inflight`` writes the last step's. A mesh is
+refused for such a model (no expert axis on the serving mesh yet).
 """
 
 from __future__ import annotations
@@ -367,6 +393,16 @@ class InferenceEngine:
                         f"a model with recurrent layers cannot be served "
                         f"with {what} yet: {why}"
                     )
+        # Layers that route tokens to experts (module docstring): their
+        # programs return the routing counts, read only under a tracer.
+        self.routed_layers = int(getattr(model, "routed_layers", 0))
+        if self.routed_layers and mesh is not None:
+            raise ValueError(
+                "a model with routed expert layers cannot be served with a "
+                "mesh yet: the serving mesh has no expert axis"
+            )
+        self.routing_counts: List[jax.Array] = []  # the last step's programs'
+        self._routing_due: Optional[Tuple[int, List[jax.Array]]] = None
 
         # Mesh geometry is engine-static, like top_k/top_p: it is compiled
         # into every program and fingerprinted into elastic snapshots.
@@ -977,13 +1013,13 @@ class InferenceEngine:
         def run(params, cache, tokens, prev, use_prev, tables, lens, temps,
                 keys, bias):
             tok = jnp.where(use_prev > 0, prev, tokens)
-            last_logits, cache = decode_token_step(
-                self.decode_model, params, cache, tok[:, None],
+            last_logits, cache, *routing = self._forward(
+                params, cache, tok[:, None],
                 block_tables=tables, seq_lens=lens,
                 **self._decode_state_kw(tables),
             )
             nxt = row_sample(last_logits, temps, fold_row_keys(keys), bias)
-            return nxt, cache
+            return (nxt, cache, *routing)
 
         # The fused-kernel decode compiles under its own ledger name so the
         # roofline attributes the before/after to two distinct programs
@@ -1010,6 +1046,45 @@ class InferenceEngine:
                 ),
                 out_shardings=(rep, pool),
             ),
+        )
+
+    def _forward(self, params, cache, tokens, **kw):
+        """``decode_token_step`` on the decode model: ``(last_logits,
+        cache)``, and for a model with routed layers a third result, the
+        layers' routing counts ``[routed layers, n_experts]`` in layer
+        order."""
+        if not self.routed_layers:
+            return decode_token_step(
+                self.decode_model, params, cache, tokens, **kw
+            )
+        last_logits, cache, sown = decode_token_step(
+            self.decode_model, params, cache, tokens,
+            mutable=("cache", "routing"), **kw,
+        )
+        sown = sown["routing"]
+        counts = jnp.stack([
+            sown[f"block_{i}"]["experts"]["counts"][0]
+            for i in range(self.decode_model.n_layers) if f"block_{i}" in sown
+        ])
+        return last_logits, cache, counts
+
+    def _flush_routing(self) -> None:
+        """Write the ``moe.routing`` instant of the step whose counts are
+        due (module docstring): its programs have finished by now."""
+        due, self._routing_due = self._routing_due, None
+        if due is None or not due[1]:
+            return
+        step, arrays = due
+        counts = np.stack([np.asarray(a) for a in arrays]).astype(np.int64)
+        lo, hi = self.decode_model.experts_held or (0, counts.shape[-1])
+        held = counts[..., lo:hi]  # [programs, layers, held experts]
+        self.tracer.instant(
+            "moe.routing", step=step, moe_programs=len(arrays),
+            moe_pairs_held=int(held.sum()),
+            moe_pairs_absent=int(counts.sum() - held.sum()),
+            moe_experts_hit=int((held > 0).sum()),
+            moe_tokens_per_expert_max=int(held.max(axis=-1).sum()),
+            moe_tokens_per_expert_mean=float(held.mean(axis=-1).sum()),
         )
 
     def _state_bytes(self) -> int:
@@ -1045,11 +1120,11 @@ class InferenceEngine:
 
         def run(params, cache, tokens, table, length, *slot):
             state_kw = {"state_slots": slot[0]} if slot else {}
-            _, cache = decode_token_step(
-                self.decode_model, params, cache, tokens,
+            _, cache, *routing = self._forward(
+                params, cache, tokens,
                 block_tables=table, seq_lens=length, **state_kw,
             )
-            return cache
+            return (cache, *routing) if routing else cache
 
         name = f"prefill_step_c{chunk}"
         if self.mesh is None:
@@ -1644,10 +1719,11 @@ class InferenceEngine:
             temps = _staged(self._stage_temps)
             keys = _staged(self._stage_keys)
         with self._phase("dispatch.launch"):
-            nxt, self.cache = decode_step(
+            nxt, self.cache, *routing = decode_step(
                 params, self.cache, tokens, prev, use_prev, tables, lens,
                 temps, keys, bias_arr,
             )
+        self.routing_counts.extend(routing)
         return nxt
 
     def _end_step_trace(self, plan) -> None:
@@ -1676,6 +1752,11 @@ class InferenceEngine:
         if self.state_layers:
             extra["state_slots_in_use"] = len(self.scheduler.running)
             extra["state_bytes"] = self._state_bytes()
+        if self.routed_layers:
+            # The step before this one has been read back: its counts cost
+            # no wait. This step's wait for the next trace (or a flush).
+            self._flush_routing()
+            self._routing_due = (self.tracer.step_index, self.routing_counts)
         if self._decode_positions:
             # Over every decode dispatch of the step: the key positions its
             # rows could see, and the ones read for them.
@@ -1850,6 +1931,8 @@ class InferenceEngine:
         )
         tr = self.tracer
         tr.begin_step()
+        if self.routed_layers:
+            self.routing_counts = []  # the last step's stay with who took them
         with self._phase("schedule"):
             plan = self.scheduler.schedule()
         if self._acct is not None:
@@ -1935,11 +2018,16 @@ class InferenceEngine:
                             state_slot = (jnp.asarray([slot], jnp.int32),)
                             if start == 0:
                                 self._note_state_reset(slot, req)
-                        self.cache = self._prefill_step(chunk)(
+                        out = self._prefill_step(chunk)(
                             chunk_params, self.cache, jnp.asarray(tok),
                             jnp.asarray(table),
                             jnp.asarray([start], jnp.int32), *state_slot,
                         )
+                        if self.routed_layers:
+                            self.cache, counts = out
+                            self.routing_counts.append(counts)
+                        else:
+                            self.cache = out
                     self.scheduler.note_prefilled(slot, chunk)
 
         finished: List[int] = []
@@ -2343,9 +2431,10 @@ class InferenceEngine:
         one blocking readback), retiring whatever it finished. After this
         no request holds a PENDING placeholder — the quiescent point the
         snapshot codec and close() both need. Returns finished ids."""
-        if self._inflight is None:
-            return []
-        return self._resolve_inflight()
+        finished = [] if self._inflight is None else self._resolve_inflight()
+        if self.routed_layers and self.tracer.enabled:
+            self._flush_routing()
+        return finished
 
     def drain(self):
         """Stop admission, finish the in-flight step, and return an

@@ -321,20 +321,43 @@ class TestKernelWalksOwnKV:
         assert (np.asarray(kernel(q, kp, vp, bt, lens))[live] == clean[live]).all()
         assert (np.asarray(reference(q, kp, vp, bt, lens))[live] == ref[live]).all()
 
-    @pytest.mark.parametrize("kv_heads, group, width", [
-        (2, 12, 256),  # StarCoder2-3B: 24 query heads, 4,096 tokens a row
-        (1, 20, 128),  # Jamba2-3B's attention layers: 2,048 tokens a row
+    @pytest.mark.parametrize("kv_heads, group, width, sm_scale", [
+        (2, 12, 256, None),  # StarCoder2-3B: 24 query heads, 4,096 tokens a row
+        (1, 20, 128, None),  # Jamba2-3B's attention layers: 2,048 tokens a row
+        # granite-4.0-h-small's: 32 query heads on 8, scores scaled by 1/128
+        (8, 4, 128, 1 / 128),
+        (8, 4, 128, None),
     ])
     def test_the_cells_head_groupings_and_table_widths(
-            self, kv_heads, group, width):
+            self, kv_heads, group, width, sm_scale):
         problem = ragged_problem(
             [130, None, 0, 127], h=kv_heads * group, kv_heads=kv_heads,
             d=128, page=16, width=width, seed=3,
         )
         kernel, reference = through("fp", npb=8)
+        kw = {} if sm_scale is None else {"sm_scale": sm_scale}
         assert_rows_match(
-            kernel(*problem), reference(*problem), problem[3], tol=1e-5
+            kernel(*problem, **kw), reference(*problem, **kw), problem[3],
+            tol=1e-5,
         )
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_the_score_scale_reaches_every_path(self, variant):
+        """A caller's ``sm_scale`` moves the kernel and the gather path
+        alike, and away from the default's result; ``head_dim ** -0.5``
+        handed over explicitly IS the default."""
+        problem = ragged_problem([5, BLOCK, None, 2 * BLOCK + 3])
+        kernel, reference = through("fp" if variant == "jit" else variant)
+        kernel = functools.partial(kernel, sm_scale=0.05)  # static, as in a model
+        if variant == "jit":
+            kernel = jax.jit(kernel)
+        d = problem[0].shape[-1]
+        default = np.asarray(reference(*problem))
+        scaled = np.asarray(reference(*problem, sm_scale=0.05))
+        assert np.abs(scaled - default).max() > 1e-2
+        assert_rows_match(kernel(*problem), scaled, problem[3])
+        assert np.array_equal(
+            np.asarray(reference(*problem, sm_scale=d**-0.5)), default)
 
     def test_tokens_walked(self):
         pos = np.asarray([0, BLOCK - 1, BLOCK, 3 * BLOCK + 1])

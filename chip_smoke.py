@@ -480,7 +480,8 @@ def run_engine(label, sz, model, params, counter, warm, prompts, *,
     prefill_has = "tpu_custom_call" in engine._prefill_step(sz.chunk).lower(
         engine.params, engine.cache,
         *(jnp.zeros(shape, jnp.int32)
-          for shape in ((1, sz.chunk), (1, engine.pages_per_seq), (1,))),
+          for shape in (
+              (1, sz.chunk), (1, engine.pages_per_seq), (1,), (1,))),
     ).as_text()
     say(f"engine[{label}]: paged_kernel={engine.paged_kernel or 'off'} "
         f"resolved to {mode or 'inline gather'}; tpu_custom_call in decode "

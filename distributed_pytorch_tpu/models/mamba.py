@@ -29,7 +29,14 @@ a row at position 0 starts from zeros whatever its slot held. When the batch
 IS the slot table (``B == slots``: the engine's batched decode step, row ``r``
 is slot ``r``) the states are updated in place under ``state_slots >= 0``,
 with no gather; a row outside the mask keeps both states bit for bit.
-Otherwise (a ``[1, chunk]`` prefill) the rows are gathered and scattered.
+Otherwise (a ``[1, width]`` prefill piece) the rows are gathered and scattered.
+
+**A padded piece.** ``valid_lens [B]`` (optional; the engine's prefill
+programs pass it) says how many of a row's ``T`` tokens are its own; the rest
+is padding up to the program's width. ``delta`` is zeroed there, so ``exp(0 *
+A) = 1`` and ``0 * u * B = 0``: ``h`` comes out as the last real token left it.
+The conv's tail kept is the ``K - 1`` inputs that END at the valid length, not
+at ``T``. A padded token's output is garbage nobody reads.
 """
 
 from __future__ import annotations
@@ -119,6 +126,7 @@ class MambaMixer(nn.Module):
         *,
         seq_lens: Optional[jnp.ndarray] = None,
         state_slots: Optional[jnp.ndarray] = None,
+        valid_lens: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
         batch, t, _ = x.shape
         d_inner = self.expand * self.d_model
@@ -169,7 +177,7 @@ class MambaMixer(nn.Module):
             # The taps are what the projection left in ``dtype``; the four
             # products and their sum are float32 (the VPU's own width).
             padded = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
-            new_tail = padded[:, t:]
+            new_tail = conv_tail(padded, k - 1, valid_lens)
             padded = padded.astype(F32)
             u32 = nn.silu(conv_b + sum(
                 conv_w[i] * padded[:, i : i + t] for i in range(k)
@@ -183,6 +191,8 @@ class MambaMixer(nn.Module):
         delta = jax.nn.softplus(
             _DtProjection(d_inner, self.dtype, name="dt_proj")(dt)
         )
+        if valid_lens is not None:
+            delta = jnp.where(token_mask(valid_lens, t)[..., None], delta, 0.0)
         a_log = self.param(
             "A_log",
             lambda _k, shape: jnp.log(
@@ -204,6 +214,24 @@ class MambaMixer(nn.Module):
                 scan_var.value = store_rows(scan_var.value, h, state_slots)
         gated = (y * nn.silu(z.astype(F32))).astype(self.dtype)
         return dense(self.d_model, "out_proj")(gated)
+
+
+def token_mask(valid_lens, t: int):
+    """``[B, t]`` bool: which of a row's ``t`` tokens are its own, the first
+    ``valid_lens [B]`` of them (the rest pads a prefill piece to its
+    program's width)."""
+    return jnp.arange(t, dtype=jnp.int32)[None, :] < valid_lens[:, None]
+
+
+def conv_tail(padded, taps: int, valid_lens=None):
+    """The ``taps`` conv inputs a row's next call starts from, out of
+    ``padded [B, taps + T, C]`` (the old tail, then this call's inputs): the
+    last ``taps``, or under ``valid_lens [B]`` the ``taps`` that end at the
+    row's valid length."""
+    if valid_lens is None:
+        return padded[:, padded.shape[1] - taps :]
+    at = valid_lens[:, None] + jnp.arange(taps, dtype=jnp.int32)[None, :]
+    return jnp.take_along_axis(padded, at[:, :, None], axis=1)
 
 
 def _a_row(mask, like):

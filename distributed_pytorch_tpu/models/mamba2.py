@@ -40,7 +40,9 @@ that is kept. One token (the decode step) is the recurrence itself.
 same rules: ``state_slots [B]`` says whose state a row carries (-1: none),
 a row whose ``seq_lens`` is 0 starts from zeros, a batch as long as the slot
 table IS the slot table and is updated in place under the mask, any other
-batch is gathered and scattered.
+batch is gathered and scattered; under ``valid_lens [B]`` (a prefill piece
+padded to its program's width) the padding is ``dt = 0`` tokens and the conv's
+tail ends at the valid length (``models/mamba.py``, "A padded piece").
 """
 
 from __future__ import annotations
@@ -158,6 +160,7 @@ class Mamba2Mixer(nn.Module):
         *,
         seq_lens: Optional[jnp.ndarray] = None,
         state_slots: Optional[jnp.ndarray] = None,
+        valid_lens: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
         batch, t, _ = u.shape
         heads, p, n, g, k = (
@@ -208,7 +211,7 @@ class Mamba2Mixer(nn.Module):
             # Taps in ``dtype`` (what the projection left, and the tail);
             # the K products and their sum in float32.
             padded = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-            new_tail = padded[:, t:]
+            new_tail = mamba.conv_tail(padded, k - 1, valid_lens)
             padded = padded.astype(F32)
             xbc32 = nn.silu(conv_b + sum(
                 conv_w[i] * padded[:, i : i + t] for i in range(k)
@@ -226,6 +229,11 @@ class Mamba2Mixer(nn.Module):
         )
         d_skip = self.param("D", nn.initializers.ones_init(), (heads,), F32)
         delta = jax.nn.softplus(dt.astype(F32) + dt_bias.astype(F32))
+        if valid_lens is not None:
+            # The padding of a prefill piece: ``dt = 0`` tokens, like the
+            # ones ``ssd_blocked`` pads its last block with.
+            delta = jnp.where(
+                mamba.token_mask(valid_lens, t)[..., None], delta, 0.0)
         a = -jnp.exp(a_log.astype(F32))
         with jax.named_scope("ssd.block"):
             if t == 1:

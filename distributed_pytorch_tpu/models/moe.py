@@ -229,9 +229,11 @@ class RoutedExperts(nn.Module):
 
     Parameters: ``router/kernel [d_model, n_experts]`` (no bias),
     ``in_kernel [held, d_model, 2 d_ff]`` (``[gate, up]``) and ``out_kernel
-    [held, d_ff, d_model]``. ``live [B]`` (optional) marks the batch rows
-    that carry a request: the others' pairs are computed by nobody and
-    counted nowhere (the batched decode step's rows outside its group)."""
+    [held, d_ff, d_model]``. ``live`` (optional) marks what carries a
+    request: ``[B]`` whole batch rows (the batched decode step's rows inside
+    its group) or ``[B, T]`` single tokens (a prefill piece's own, not its
+    padding). Everything else's pairs are computed by nobody, stand in no
+    group of either product and are counted nowhere."""
 
     n_experts: int
     top_k: int
@@ -269,10 +271,12 @@ class RoutedExperts(nn.Module):
                 preferred_element_type=ROUTER_DTYPE,
             )
             gates, experts = route_top_k(scores, k)  # [tokens, k]
-            alive = (
-                jnp.ones((tokens,), bool) if live is None
-                else jnp.repeat(live, t)
-            )
+            if live is None:
+                alive = jnp.ones((tokens,), bool)
+            elif live.ndim == 1:
+                alive = jnp.repeat(live, t)
+            else:
+                alive = live.reshape(tokens)
             # Held pairs by expert, everything else behind them.
             local = experts - lo
             mine = (local >= 0) & (local < n_held) & alive[:, None]

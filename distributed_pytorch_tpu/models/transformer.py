@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from distributed_pytorch_tpu.models.mamba import token_mask
 from distributed_pytorch_tpu.models.moe import MoEMLP
 from distributed_pytorch_tpu.ops.attention import (
     NEG_INF,
@@ -176,6 +177,7 @@ class Attention(nn.Module):
         *,
         block_tables: Optional[jnp.ndarray] = None,
         seq_lens: Optional[jnp.ndarray] = None,
+        valid_lens: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
         # Validate unconditionally: a typo'd mode must fail on the first
         # single-chip forward, not later when the job first meets an sp>1
@@ -251,7 +253,7 @@ class Attention(nn.Module):
                         "every step (the serving engine passes them)"
                     )
                 out = self._paged_decode_step(
-                    q_raw, k_raw, v, block_tables, seq_lens
+                    q_raw, k_raw, v, block_tables, seq_lens, valid_lens
                 )
             else:
                 out = self._decode_step(q_raw, k_raw, v)
@@ -438,7 +440,9 @@ class Attention(nn.Module):
         out = jnp.einsum("bhgqk,bkhd->bqhgd", weights, values)
         return out.reshape(b, t_q, h, d)
 
-    def _paged_decode_step(self, q_raw, k_raw, v, block_tables, seq_lens):
+    def _paged_decode_step(
+        self, q_raw, k_raw, v, block_tables, seq_lens, valid_lens=None
+    ):
         """One decode/prefill step against the PAGED cache pool.
 
         ``q_raw`` [S, T_step, H, D]: T_step is 1 for the batched decode step,
@@ -447,6 +451,8 @@ class Attention(nn.Module):
         physical page in the [num_pages, page_size, Hkv, D] pool (0 = the
         reserved null page). ``seq_lens`` [S] is each row's token count
         BEFORE this step, i.e. the absolute position of its first new token.
+        ``valid_lens`` [S] (optional: a padded prefill piece) says how many of
+        a row's ``T_step`` tokens are its own; ``None`` means all of them.
 
         Same math as :meth:`_decode_step` — RoPE at absolute positions,
         write-then-attend, grouped GQA einsums — except positions are
@@ -483,11 +489,17 @@ class Attention(nn.Module):
         # page, where it would clobber valid K/V at the same in-page
         # offset. The null page absorbs the garbage exactly like inactive
         # rows' writes; the visibility mask keeps it dead on every read.
+        # The padding of a prefill piece (a position at or past ``seq_lens
+        # + valid_lens``) goes the same way: a real token never attends to
+        # it (causal), and its own K/V must land on no page a read can see.
         flat_pos = positions.reshape(-1)  # [S*T_step]
         logical = jnp.clip(flat_pos // page, 0, pages_per_seq - 1)
         rows = jnp.repeat(jnp.arange(s, dtype=jnp.int32), t_step)
         phys = block_tables[rows, logical]  # [S*T_step]
-        phys = jnp.where(flat_pos < pages_per_seq * page, phys, 0)
+        kept = flat_pos < pages_per_seq * page
+        if valid_lens is not None:
+            kept &= token_mask(valid_lens, t_step).reshape(-1)
+        phys = jnp.where(kept, phys, 0)
         offset = flat_pos % page
         if self.kv_quant:
             # Quantize at the write: symmetric absmax per-(token, head) over
@@ -626,6 +638,18 @@ RECURRENT_TYPES = ("mamba", "mamba2")
 FFN_TYPES = ("dense", "routed")
 
 
+def live_tokens(state_slots, valid_lens, t_step: int):
+    """``RoutedExperts``' ``live`` for a call told ``state_slots [B]`` and
+    ``valid_lens [B]`` (either may be ``None``): the rows that carry a
+    request, as a row mask ``[B]``, and under a padded prefill piece only
+    their own tokens, as a token mask ``[B, t_step]``."""
+    live = None if state_slots is None else state_slots >= 0
+    if valid_lens is None:
+        return live
+    own = token_mask(valid_lens, t_step)
+    return own if live is None else own & live[:, None]
+
+
 def make_norm(kind: str, eps: float, name: str) -> nn.Module:
     """A block's normalisation: statistics and output in float32."""
     if kind == "layernorm":
@@ -683,6 +707,7 @@ class TransformerBlock(nn.Module):
         block_tables: Optional[jnp.ndarray] = None,
         seq_lens: Optional[jnp.ndarray] = None,
         state_slots: Optional[jnp.ndarray] = None,
+        valid_lens: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
         def drop(y):
             # Active only when a "dropout" rng is supplied (the train step
@@ -700,6 +725,9 @@ class TransformerBlock(nn.Module):
             {} if block_tables is None
             else {"block_tables": block_tables, "seq_lens": seq_lens}
         )
+        # A padded prefill piece says how many of a row's tokens are its
+        # own; every other call says nothing and lowers as it always did.
+        piece_kw = {} if valid_lens is None else {"valid_lens": valid_lens}
         normed = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
         if self.mixer == "mamba":
             from distributed_pytorch_tpu.models.mamba import MambaMixer
@@ -707,14 +735,14 @@ class TransformerBlock(nn.Module):
             mixed = MambaMixer(
                 self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
                 decode=self.decode, name="mamba", **dict(self.mamba),
-            )(normed, seq_lens=seq_lens, state_slots=state_slots)
+            )(normed, seq_lens=seq_lens, state_slots=state_slots, **piece_kw)
         elif self.mixer == "mamba2":
             from distributed_pytorch_tpu.models.mamba2 import Mamba2Mixer
 
             mixed = Mamba2Mixer(
                 self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
                 decode=self.decode, name="mamba", **dict(self.mamba),
-            )(normed, seq_lens=seq_lens, state_slots=state_slots)
+            )(normed, seq_lens=seq_lens, state_slots=state_slots, **piece_kw)
         elif self.mixer == "attention":
             mixed = Attention(
                 self.n_heads, self.d_model, self.dtype, self.causal,
@@ -727,7 +755,7 @@ class TransformerBlock(nn.Module):
                 paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
                 rope=self.rope, use_bias=self.use_bias,
                 score_scale=self.score_scale, name="attention",
-            )(normed, **paged_kw)
+            )(normed, **paged_kw, **piece_kw)
         else:
             raise ValueError(
                 f"unknown layer type {self.mixer!r} "
@@ -746,7 +774,7 @@ class TransformerBlock(nn.Module):
             fed = RoutedExperts(
                 d_ff=self.d_ff, d_model=self.d_model, dtype=self.dtype,
                 name="experts", **dict(self.routed),
-            )(normed, live=None if state_slots is None else state_slots >= 0)
+            )(normed, live=live_tokens(state_slots, valid_lens, x.shape[1]))
             if self.shared_d_ff:
                 fed = fed + MLPBlock(
                     self.shared_d_ff, self.d_model, self.dtype,
@@ -960,6 +988,7 @@ class TransformerLM(nn.Module):
         block_tables: Optional[jnp.ndarray] = None,
         seq_lens: Optional[jnp.ndarray] = None,
         state_slots: Optional[jnp.ndarray] = None,
+        valid_lens: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
         types = self.layer_types
         if types is not None and len(types) != self.n_layers:
@@ -1016,6 +1045,8 @@ class TransformerLM(nn.Module):
         )
         if state_slots is not None:
             paged_kw["state_slots"] = state_slots
+        if valid_lens is not None:
+            paged_kw["valid_lens"] = valid_lens
         block_kw = dict(
             norm=self.norm, norm_eps=self.norm_eps, mlp=self.mlp,
             use_bias=self.use_bias, rope=self.rope,

@@ -16,7 +16,8 @@ Three timelines, one clock:
   depth). The phases that hold the host's time have children:
   ``dispatch.key`` (one slice a launch), ``dispatch.stage``,
   ``dispatch.launch``, ``readback.wait`` (the host blocked on the device),
-  ``readback.resolve``, and one ``prefill.chunk`` a chunk.
+  ``readback.resolve``, and one ``prefill.chunk`` a prefill piece (one
+  program: ``tokens``, ``start``, and the ``width`` it was padded to).
 * **Training steps** — a ``Trainer`` writes one ``epoch`` slice an epoch,
   inside it a ``step`` slice a batch holding ``put_batch`` and
   ``step.dispatch``, and ``epoch.loss_fetch`` where the host waits for the

@@ -9,10 +9,16 @@ Every ``step()``:
    the token budget, the batched decode set, preemption);
 2. executes the CoW copies — one compiled page-copy program per shared page
    a writer is about to extend;
-3. executes the prefill chunks — each a ``[1, C]`` jit call writing K/V into
-   the request's pages (logits dead-code-eliminated), compiled once per
-   power-of-two chunk size, starting at the first token the prefix cache
-   did not already cover;
+3. executes the prefill pieces — each ONE ``[1, width]`` jit call writing
+   K/V into the request's pages (logits dead-code-eliminated), starting at
+   the first token the prefix cache did not already cover. A piece is up to
+   ``max_prefill_chunk`` tokens of one prompt; it runs padded to the next
+   multiple of ``g = min(PREFILL_GRANULE, max_prefill_chunk)`` with its
+   valid length as an operand, which the model honours (a padded token
+   writes the null page, moves no recurrent state, reaches no expert and is
+   counted nowhere). So the programs are the widths ``g, 2g, ...,
+   max_prefill_chunk``, and the engine builds and runs them ALL when it
+   needs the first: no later prompt length compiles anything;
 4. dispatches ONE batched decode step over all ``max_slots`` slots —
    inactive slots are padded (null block table, length 0) and masked, so
    the decode program compiles exactly once regardless of which requests
@@ -103,11 +109,12 @@ the leaves' leading ``max_slots`` alone. The one design:
   reads zeros in place of what the slot held. Nothing is zeroed ahead of
   time and no program exists for it. Each such start is a ``state.reset``
   instant in the trace (slot, request, cause ``admit`` | ``preempt``).
-* **prefill** is the same ``[1, chunk]`` program with one more operand, the
-  slot whose state the chunk carries on. The recurrence is sequential in
-  the token, so chunks of any sizes give the state one pass over the whole
-  prompt gives, to float32 rounding of ``h`` at the chunk borders (none: the
-  carry is the float32 state itself).
+* **prefill** is the same ``[1, width]`` program with one more operand, the
+  slot whose state the piece carries on. The recurrence is sequential in
+  the token, so pieces of any sizes give the state one pass over the whole
+  prompt gives, to float32 rounding of ``h`` at the piece borders (none: the
+  carry is the float32 state itself); a piece's padding leaves the state
+  where its last real token put it (``models/mamba.py``, "A padded piece").
 * **what cannot be served yet is refused in the constructor**: the prefix
   cache (a hit skips positions whose state nobody kept: it needs a state
   snapshot at the page boundary), a draft model (a rejected proposal
@@ -490,6 +497,11 @@ class InferenceEngine:
             if getattr(path[-1], "key", None) in STATE_KEYS
         )
         self.state_resets = 0
+        # Prefill programs run, the tokens they carried and the widths they
+        # were padded to (``stats()``; the step slice has each step's).
+        self.prefill_programs = 0
+        self.prefill_tokens = 0
+        self.prefill_width = 0
         if self.speculative:
             self.draft_decode_model = draft_model.clone(
                 decode=True, page_size=page_size, num_pages=num_pages,
@@ -1111,22 +1123,24 @@ class InferenceEngine:
                 cause="preempt" if req.preempt_count else "admit",
             )
 
-    @functools.lru_cache(maxsize=16)
-    def _prefill_step(self, chunk: int):
-        """One compile per power-of-two chunk length; returns only the
-        updated cache, so XLA prunes the LM head from the program. With
-        recurrent layers the program takes one more operand: the slot
-        whose state the chunk carries on."""
+    def _prefill_step(self, width: int):
+        """The prefill program of one width: ``[1, width]`` tokens of which
+        the first ``valid`` are a piece of a prompt and the rest padding
+        the model leaves out of everything (module docstring, item 3).
+        Returns only the updated cache, so XLA prunes the LM head from the
+        program. With recurrent layers the program takes one more operand:
+        the slot whose state the piece carries on. A builder, not a store:
+        :attr:`_prefill_programs` keeps what it returns."""
 
-        def run(params, cache, tokens, table, length, *slot):
+        def run(params, cache, tokens, table, length, valid, *slot):
             state_kw = {"state_slots": slot[0]} if slot else {}
             _, cache, *routing = self._forward(
-                params, cache, tokens,
-                block_tables=table, seq_lens=length, **state_kw,
+                params, cache, tokens, block_tables=table, seq_lens=length,
+                valid_lens=valid, **state_kw,
             )
             return (cache, *routing) if routing else cache
 
-        name = f"prefill_step_c{chunk}"
+        name = f"prefill_step_c{width}"
         if self.mesh is None:
             return self._ledgered(name, jax.jit(run, donate_argnums=(1,)))
         rep = self._replicated
@@ -1136,10 +1150,113 @@ class InferenceEngine:
             self._sharded_jit(
                 run,
                 donate=(1,),
-                in_shardings=(self._param_shardings, pool, rep, rep, rep),
+                in_shardings=(
+                    self._param_shardings, pool, rep, rep, rep, rep
+                ),
                 out_shardings=pool,
             ),
         )
+
+    def _prefill_width(self, tokens: int) -> int:
+        """The width of the program a piece of ``tokens`` tokens runs in:
+        the next whole number of granules."""
+        granule = self.scheduler.prefill_granule
+        return -(-tokens // granule) * granule
+
+    @functools.cached_property
+    def _prefill_programs(self) -> Dict[int, tuple]:
+        """``width -> (target program, draft program or None)`` for every
+        width a piece can have: ``g, 2g, ..., max_prefill_chunk``. Built
+        when the first piece asks for one, and WHOLE: a deployment's first
+        long prompt reaches only the widest program, and every other width
+        would compile under the first request that needs it. So each
+        program is also run here once, on an all-null table with valid
+        length 0 and no slot, which writes the null page alone and touches
+        no state, and leaves it compiled for the operands a real piece
+        brings: after the first prefill no prompt length compiles anything.
+        Eight programs at a cap of 512; one at any cap up to 64. A cap above
+        1,024 only lengthens this pass by a compile a width (32 of them at
+        2,048); nothing here is evicted."""
+        granule = self.scheduler.prefill_granule
+        zero = jnp.asarray([0], jnp.int32)
+        rest = (  # the null table, start 0, valid length 0
+            jnp.asarray(np.zeros((1, self.pages_per_seq), np.int32)),
+            zero, zero,
+        )
+        no_slot = (jnp.asarray([-1], jnp.int32),) if self.state_layers else ()
+        programs = {}
+        for width in range(
+            granule, self.scheduler.max_prefill_chunk + 1, granule
+        ):
+            target = self._prefill_step(width)
+            draft = (
+                self._draft_prefill_step(width) if self.speculative else None
+            )
+            tokens = jnp.asarray(np.zeros((1, width), np.int32))
+            out = target(self.params, self.cache, tokens, *rest, *no_slot)
+            self.cache = out[0] if self.routed_layers else out
+            if draft is not None:
+                self.draft_cache = draft(
+                    self.draft_params, self.draft_cache, tokens, *rest
+                )
+            programs[width] = (target, draft)
+        return programs
+
+    def _prefill_piece(self, slot: int, tokens: int) -> None:
+        """Run one planned prefill piece, ``tokens`` tokens of ``slot``'s
+        request from its first uncached one: ONE program (and the draft
+        pool's twin), of the next width, the piece padded to it on the
+        host. The one prefill loop body of both step bodies."""
+        req = self.scheduler.slots[slot]
+        start = req.len_cached
+        width = self._prefill_width(tokens)
+        if self._acct is not None and req.rework_until > start:
+            self._note_rework(req, start, tokens)
+        target, draft = self._prefill_programs[width]
+        with self._phase(
+            "prefill.chunk", tokens=tokens, start=start, width=width
+        ):
+            tok = np.zeros((1, width), np.int32)
+            tok[0, :tokens] = req.tokens[start : start + tokens]
+            table = req.table.as_row(self.pages_per_seq)[None]
+            if self.xla is not None:
+                # Piece, table, start and valid length, staged once a pool.
+                self.xla.count_h2d(
+                    (tok.nbytes + table.nbytes + 8) * len(self.pools.names)
+                )
+            operands = (
+                jnp.asarray(tok), jnp.asarray(table),
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray([tokens], jnp.int32),
+            )
+            # Adapter rows prefill under their merged weights — K/V
+            # written under base params would poison every decode step
+            # that attends to it.
+            ms = req.mods
+            params = (
+                self.adapters.params_for(ms.adapter)
+                if ms is not None and ms.adapter is not None
+                else self.params
+            )
+            state_slot = ()
+            if self.state_layers:
+                state_slot = (jnp.asarray([slot], jnp.int32),)
+                if start == 0:
+                    self._note_state_reset(slot, req)
+            out = target(params, self.cache, *operands, *state_slot)
+            if self.routed_layers:
+                self.cache, counts = out
+                self.routing_counts.append(counts)
+            else:
+                self.cache = out
+            if draft is not None:
+                self.draft_cache = draft(
+                    self.draft_params, self.draft_cache, *operands
+                )
+        self.prefill_programs += 1
+        self.prefill_tokens += tokens
+        self.prefill_width += width
+        self.scheduler.note_prefilled(slot, tokens)
 
     @functools.cached_property
     def _copy_page(self):
@@ -1298,22 +1415,21 @@ class InferenceEngine:
         if staged and self.xla is not None:
             self.xla.count_h2d(staged, tag="hostkv_fetch")
 
-    @functools.lru_cache(maxsize=16)
-    def _draft_prefill_step(self, chunk: int):
-        """Draft-pool twin of :meth:`_prefill_step`: every prefill chunk
+    def _draft_prefill_step(self, width: int):
+        """Draft-pool twin of :meth:`_prefill_step`: every prefill piece
         runs through BOTH models so the draft pool holds valid K/V for
         exactly the positions the target pool does — including
         trie-adopted pages, which were prefilled by both models when first
         written and so stay adoptable in lockstep."""
 
-        def run(draft_params, draft_cache, tokens, table, length):
+        def run(draft_params, draft_cache, tokens, table, length, valid):
             _, draft_cache = decode_token_step(
                 self.draft_decode_model, draft_params, draft_cache, tokens,
-                block_tables=table, seq_lens=length,
+                block_tables=table, seq_lens=length, valid_lens=valid,
             )
             return draft_cache
 
-        name = f"draft_prefill_step_c{chunk}"
+        name = f"draft_prefill_step_c{width}"
         if self.mesh is None:
             return self._ledgered(name, jax.jit(run, donate_argnums=(1,)))
         rep = self._replicated
@@ -1324,7 +1440,7 @@ class InferenceEngine:
                 run,
                 donate=(1,),
                 in_shardings=(
-                    self._draft_param_shardings, pool, rep, rep, rep
+                    self._draft_param_shardings, pool, rep, rep, rep, rep
                 ),
                 out_shardings=pool,
             ),
@@ -1778,8 +1894,11 @@ class InferenceEngine:
             extra["decode_kv_tokens_visible"] = visible
         self.tracer.end_step(
             decode_rows=len(plan.decode_slots),
-            prefill_chunks=len(plan.prefill),
-            prefill_tokens=sum(chunk for _s, chunk in plan.prefill),
+            prefill_programs=len(plan.prefill),
+            prefill_tokens=sum(tokens for _s, tokens in plan.prefill),
+            prefill_width=sum(
+                self._prefill_width(tokens) for _s, tokens in plan.prefill
+            ),
             budget_utilization=used / self.scheduler.token_budget,
             queue_depth=self.scheduler.num_waiting,
             running_requests=len(self.scheduler.running),
@@ -1988,47 +2107,8 @@ class InferenceEngine:
         if plan.prefill:
             chaos.on_serving_phase("mid_prefill")
             with self._phase("prefill"):
-                for slot, chunk in plan.prefill:
-                    req = self.scheduler.slots[slot]
-                    start = req.len_cached
-                    if self._acct is not None and req.rework_until > start:
-                        self._note_rework(req, start, chunk)
-                    with self._phase(
-                        "prefill.chunk", tokens=chunk, start=start
-                    ):
-                        tok = np.asarray(
-                            [req.tokens[start : start + chunk]], np.int32
-                        )
-                        table = req.table.as_row(self.pages_per_seq)[None]
-                        if self.xla is not None:
-                            self.xla.count_h2d(
-                                tok.nbytes + table.nbytes + 4
-                            )
-                        # Adapter rows prefill under their merged weights
-                        # — K/V written under base params would poison
-                        # every decode step that attends to it.
-                        ms = req.mods
-                        chunk_params = (
-                            self.adapters.params_for(ms.adapter)
-                            if ms is not None and ms.adapter is not None
-                            else self.params
-                        )
-                        state_slot = ()
-                        if self.state_layers:
-                            state_slot = (jnp.asarray([slot], jnp.int32),)
-                            if start == 0:
-                                self._note_state_reset(slot, req)
-                        out = self._prefill_step(chunk)(
-                            chunk_params, self.cache, jnp.asarray(tok),
-                            jnp.asarray(table),
-                            jnp.asarray([start], jnp.int32), *state_slot,
-                        )
-                        if self.routed_layers:
-                            self.cache, counts = out
-                            self.routing_counts.append(counts)
-                        else:
-                            self.cache = out
-                    self.scheduler.note_prefilled(slot, chunk)
+                for slot, tokens in plan.prefill:
+                    self._prefill_piece(slot, tokens)
 
         finished: List[int] = []
         dispatched = None
@@ -2177,34 +2257,8 @@ class InferenceEngine:
         if plan.prefill:
             chaos.on_serving_phase("mid_prefill")
             with self._phase("prefill"):
-                for slot, chunk in plan.prefill:
-                    req = self.scheduler.slots[slot]
-                    start = req.len_cached
-                    if self._acct is not None and req.rework_until > start:
-                        self._note_rework(req, start, chunk)
-                    with self._phase(
-                        "prefill.chunk", tokens=chunk, start=start
-                    ):
-                        tok = np.asarray(
-                            [req.tokens[start : start + chunk]], np.int32
-                        )
-                        table = req.table.as_row(self.pages_per_seq)[None]
-                        if self.xla is not None:
-                            # Chunk + table + start staged into BOTH pools.
-                            self.xla.count_h2d(
-                                2 * (tok.nbytes + table.nbytes + 4)
-                            )
-                        self.cache = self._prefill_step(chunk)(
-                            self.params, self.cache, jnp.asarray(tok),
-                            jnp.asarray(table),
-                            jnp.asarray([start], jnp.int32),
-                        )
-                        self.draft_cache = self._draft_prefill_step(chunk)(
-                            self.draft_params, self.draft_cache,
-                            jnp.asarray(tok), jnp.asarray(table),
-                            jnp.asarray([start], jnp.int32),
-                        )
-                    self.scheduler.note_prefilled(slot, chunk)
+                for slot, tokens in plan.prefill:
+                    self._prefill_piece(slot, tokens)
 
         finished: List[int] = []
         new_tokens = 0
@@ -2567,6 +2621,9 @@ class InferenceEngine:
         out["restores"] = self.restores
         out["requests_recovered"] = self.requests_recovered
         out["cow_copies"] = self.scheduler.cow_copies
+        out["prefill_programs"] = self.prefill_programs
+        out["prefill_tokens"] = self.prefill_tokens
+        out["prefill_width"] = self.prefill_width
         out["pages_free"] = self.allocator.num_free
         out["pages_allocated"] = self.allocator.num_allocated
         out["pages_idle"] = self.allocator.num_idle

@@ -22,9 +22,19 @@ Design decisions, in the order they bite:
   output is the first new token. This mirrors ``generate``'s serial loop
   exactly (the body at position t decides token t+1), which is what makes
   served output token-identical to offline decode.
-* **Chunks are power-of-two sized** (greedy decomposition, capped at
-  ``max_prefill_chunk``), so the engine compiles at most log2(cap)+1 prefill
-  variants — the "one compilation per shape bucket" contract.
+* **A prompt is prefilled in pieces, each one program**: a piece is what is
+  left of the prompt, of ``max_prefill_chunk`` and of the step's budget,
+  whichever is least, and the engine pads it to the next multiple of
+  ``g = min(PREFILL_GRANULE, max_prefill_chunk)`` tokens, so it compiles
+  ``max_prefill_chunk / g`` prefill programs and a prompt costs one
+  program a ``max_prefill_chunk`` tokens, not one a power of two. A piece
+  that is not the request's last is cut DOWN to whole granules, so only a
+  request's last piece is ragged; a budget sliver under ``g`` waits for
+  the next step rather than cost a program of its own, unless the step
+  has planned no prefill at all: then the oldest prefilling request takes
+  the sliver ragged, so a budget that stays under ``g`` (many decode rows
+  under a small ``token_budget``) starves nobody. The budget is charged
+  the tokens a piece holds, never its padding.
 * **Prefill starts at the first uncached token**: with a
   :class:`~.kv_cache.PrefixCache` attached, admission looks the request's
   tokens up in the trie and adopts (refs) every matched page, so a shared
@@ -250,7 +260,8 @@ class StepPlan:
     (``(slot, src_page, dst_page)``, executed first), host-tier page
     fetches (``(key, dst_page, parent_node, tokens, node_id)``, h2d
     stages executed before any prefill/decode that could read them),
-    prefill chunks (executed in order, each ``(slot, chunk_len)``), then
+    prefill pieces (executed in order, each ``(slot, tokens)``: the tokens
+    the piece holds, whatever width the engine pads it to), then
     one batched decode over ``decode_slots``. ``empty`` deliberately
     ignores ``fetches``: the engine executes them BEFORE its empty-plan
     early return, so a fetch planned for a request that was preempted in
@@ -269,8 +280,11 @@ class StepPlan:
     def empty(self) -> bool:
         return not self.prefill and not self.decode_slots
 
-def _pow2_floor(n: int) -> int:
-    return 1 << (n.bit_length() - 1) if n > 0 else 0
+#: Prefill programs come in widths of whole granules: a piece of a prompt
+#: runs padded to the next multiple of ``min(PREFILL_GRANULE, cap)`` tokens.
+#: One constant for every model (PERF.md §6, PR 32: on the chip 32 gains
+#: under 1% for twice the programs, 128 loses 3.5%).
+PREFILL_GRANULE = 64
 
 
 class Scheduler:
@@ -303,10 +317,12 @@ class Scheduler:
             raise ValueError(f"token_budget must be >= 1, got {token_budget}")
         if gamma < 0:
             raise ValueError(f"gamma must be >= 0, got {gamma}")
-        if _pow2_floor(max_prefill_chunk) != max_prefill_chunk:
+        cap = max_prefill_chunk
+        if cap < 1 or cap & (cap - 1):
             raise ValueError(
                 f"max_prefill_chunk must be a power of two, got "
-                f"{max_prefill_chunk} (chunk sizes are compile-cache keys)"
+                f"{max_prefill_chunk} (the prefill programs' widths are "
+                f"its whole granules)"
             )
         self.allocator = allocator
         self.max_slots = max_slots
@@ -314,6 +330,7 @@ class Scheduler:
         self.pages_per_seq = pages_per_seq
         self.token_budget = token_budget
         self.max_prefill_chunk = max_prefill_chunk
+        self.prefill_granule = min(PREFILL_GRANULE, max_prefill_chunk)
         self.prefix_cache = prefix_cache
         self.gamma = gamma
         self.debug = debug
@@ -737,10 +754,12 @@ class Scheduler:
                 plan.decode_slots.append(req.slot)
                 budget -= cost
 
-        # 3. Remaining budget goes to prefill chunks, highest priority
-        # first, power-of-two sized so compile variants stay bounded.
-        # Prefill starts at the first uncached token (len_cached covers the
-        # prefix-cache match).
+        # 3. Remaining budget goes to prefill pieces, highest priority
+        # first (module docstring: whole granules but for a request's last
+        # piece, and a sliver only where the step would else prefill
+        # nothing). Prefill starts at the first uncached token (len_cached
+        # covers the prefix-cache match).
+        granule = self.prefill_granule
         for req in sorted(self.running, key=lambda r: r.req_id):
             if req.state is not RequestState.PREFILL or budget <= 0:
                 continue
@@ -752,20 +771,18 @@ class Scheduler:
                 remaining = len(req.tokens) - 1 - planned
                 if remaining <= 0:
                     break
-                chunk = min(
-                    _pow2_floor(remaining),
-                    self.max_prefill_chunk,
-                    _pow2_floor(budget),
-                )
-                if chunk <= 0:
+                piece = min(remaining, self.max_prefill_chunk, budget)
+                if piece < remaining and (piece >= granule or plan.prefill):
+                    piece -= piece % granule
+                if piece <= 0:
                     break
-                if not self._ensure_pages(req, planned + chunk):
+                if not self._ensure_pages(req, planned + piece):
                     break  # req was preempted; its plan entries are dropped
-                plan.prefill.append((slot, chunk))
-                planned += chunk
-                budget -= chunk
+                plan.prefill.append((slot, piece))
+                planned += piece
+                budget -= piece
             if req.state is not RequestState.PREFILL:
-                # Preempted while growing: drop any chunks already planned
+                # Preempted while growing: drop any pieces already planned
                 # for its (now free) slot.
                 plan.prefill = [
                     (s, c) for (s, c) in plan.prefill if s != slot

@@ -525,7 +525,8 @@ class TestEngineObservability:
         assert steps, "no step slices recorded"
         args = steps[0]["args"]
         for key in (
-            "decode_rows", "prefill_chunks", "prefill_tokens",
+            "decode_rows", "prefill_programs", "prefill_tokens",
+            "prefill_width",
             "budget_utilization", "queue_depth", "running_requests",
             "pages_free", "pages_referenced", "pages_cached_idle",
         ):

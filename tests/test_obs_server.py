@@ -422,17 +422,29 @@ class TestRecompileSentinel:
         assert sentinel.count == 0
         assert not sentinel.firing
 
-        # A prompt long enough to need a never-seen prefill chunk forces
-        # a fresh XLA compile: exactly what the sentinel exists to catch.
+        # A prompt of another length compiles nothing: every prefill width
+        # exists since the first piece ran (two pieces here, 8 and 4).
         big = eng.submit(
             list(range(1, 14)), SamplingParams(max_new_tokens=2)
         )
         eng.run()
         assert eng.poll(big).finished
+        assert sentinel.count == 0
+
+        # A program the engine builds only when it is first needed, the
+        # page copy of a copy-on-write, is a fresh XLA compile: exactly
+        # what the sentinel exists to catch. Two continuations extend the
+        # same cached partial page (one full page of 4, one token more).
+        hist = [4, 5, 6] + eng.poll(rid).generated[:2]
+        conts = [eng.submit(hist + [t], SamplingParams(max_new_tokens=2))
+                 for t in (9, 17)]
+        eng.run()
+        assert all(eng.poll(c).finished for c in conts)
+        assert eng.scheduler.cow_copies >= 1
         assert sentinel.count >= 1
         assert sentinel.firing
         assert any(
-            "prefill" in trip["program"] for trip in sentinel.trips
+            "copy_page" in trip["program"] for trip in sentinel.trips
         )
         # ...and the trip is on the record everywhere it should be:
         assert eng.registry.read_counter("engine_recompiles_total") == (

@@ -526,7 +526,7 @@ class TestEngineKernelParity:
             paged_kernel=True,
         )
         assert out == baseline_greedy
-        assert eng._sharded_programs >= 3
+        assert eng._sharded_programs >= 2  # decode + the one prefill width
 
     @pytest.mark.parametrize("shape", [(1, 1), (1, 8)])
     def test_kernel_interpret_mesh(self, model_and_params,

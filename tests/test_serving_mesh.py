@@ -137,7 +137,9 @@ class TestMeshToggleMatrix:
             prefix=prefix, overlap=overlap,
         )
         assert out == baseline_greedy
-        assert eng._sharded_programs >= 3  # decode + prefill + copy_page
+        # decode + the ONE prefill width a cap of 16 has (+ copy_page
+        # where a shared page is extended)
+        assert eng._sharded_programs >= 2
 
     def test_greedy_parity_speculative(
         self, model_and_params, draft_and_params, baseline_greedy, shape,

@@ -223,6 +223,25 @@ def route_top_k(scores: jnp.ndarray, top_k: int):
     return jax.nn.softmax(best, axis=-1), experts
 
 
+#: The published gating rules. ``"softmax_of_top_k"``: :func:`route_top_k`.
+#: ``"top_k_of_softmax"``: a softmax over ALL the scores, then the ``top_k``
+#: largest probabilities as they are, NOT renormalised (a configuration's
+#: ``scoring_func: softmax`` with ``norm_topk_prob: false``).
+GATINGS = ("softmax_of_top_k", "top_k_of_softmax")
+
+
+def route(scores: jnp.ndarray, top_k: int, gating: str = GATINGS[0]):
+    """``(gates, experts)`` as :func:`route_top_k` gives them, under either
+    of :data:`GATINGS`."""
+    if gating == "softmax_of_top_k":
+        return route_top_k(scores, top_k)
+    if gating == "top_k_of_softmax":
+        return jax.lax.top_k(jax.nn.softmax(scores, axis=-1), top_k)
+    raise ValueError(
+        f"unknown gating rule {gating!r} (expected one of {GATINGS})"
+    )
+
+
 class RoutedExperts(nn.Module):
     """Dropless top-k routed gated-SiLU experts over a held range (module
     docstring). ``[B, T, d_model] -> [B, T, d_model]``.
@@ -241,6 +260,7 @@ class RoutedExperts(nn.Module):
     d_model: int
     held: Optional[tuple] = None  # (lo, hi): experts lo..hi-1; None = all
     dtype: Any = F32
+    gating: str = GATINGS[0]  # one of GATINGS
 
     @nn.compact
     def __call__(
@@ -270,7 +290,7 @@ class RoutedExperts(nn.Module):
                 precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=ROUTER_DTYPE,
             )
-            gates, experts = route_top_k(scores, k)  # [tokens, k]
+            gates, experts = route(scores, k, self.gating)  # [tokens, k]
             if live is None:
                 alive = jnp.ones((tokens,), bool)
             elif live.ndim == 1:

@@ -632,7 +632,7 @@ class MLPBlock(nn.Module):
         return dense(self.d_model, "down")(h)
 
 
-LAYER_TYPES = ("attention", "mamba", "mamba2")
+LAYER_TYPES = ("attention", "mamba", "mamba2", "latent")
 #: The layer types that keep a per-slot recurrent state in decode mode.
 RECURRENT_TYPES = ("mamba", "mamba2")
 FFN_TYPES = ("dense", "routed")
@@ -693,6 +693,7 @@ class TransformerBlock(nn.Module):
     rope: bool = True
     mixer: str = "attention"  # one of LAYER_TYPES
     mamba: tuple = ()  # the recurrent mixer's sizes as (field, value) pairs
+    latent: tuple = ()  # a "latent" layer's sizes (models/mla.py), likewise
     score_scale: Optional[float] = None  # see Attention
     residual_multiplier: float = 1.0  # on both branches before they are added
     ffn: str = "dense"  # one of FFN_TYPES
@@ -743,6 +744,16 @@ class TransformerBlock(nn.Module):
                 self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
                 decode=self.decode, name="mamba", **dict(self.mamba),
             )(normed, seq_lens=seq_lens, state_slots=state_slots, **piece_kw)
+        elif self.mixer == "latent":
+            from distributed_pytorch_tpu.models.mla import LatentAttention
+
+            mixed = LatentAttention(
+                self.n_heads, self.d_model, dtype=self.dtype,
+                norm_eps=self.norm_eps, rope_theta=self.rope_theta,
+                decode=self.decode, page_size=self.page_size,
+                num_pages=self.num_pages, paged_kernel=self.paged_kernel,
+                name="mla", **dict(self.latent),
+            )(normed, **paged_kw, **piece_kw)
         elif self.mixer == "attention":
             mixed = Attention(
                 self.n_heads, self.d_model, self.dtype, self.causal,
@@ -968,11 +979,30 @@ class TransformerLM(nn.Module):
     routed_top_k: int = 0
     experts_held: Optional[tuple] = None
     shared_d_ff: int = 0
+    routed_gating: str = "softmax_of_top_k"  # one of models/moe.py's GATINGS
+    # A "dense" layer's width where it is not ``d_ff`` (which stays the
+    # routed experts'); 0 = ``d_ff``.
+    dense_d_ff: int = 0
+    # A "latent" layer is models/mla.py's LatentAttention in the attention's
+    # place: in decode mode it keeps ONE page pool [num_pages, page_size,
+    # pool_width] (a token's ``[c | k_pe]``, no head axis) where an
+    # "attention" layer keeps a K and a V pool. ``rope_yarn`` is the
+    # configuration's ``rope_scaling`` block as (key, value) pairs.
+    kv_lora_rank: int = 0  # required where a layer is "latent", as the three below
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[tuple] = None
 
     @property
     def recurrent_layers(self) -> int:
         """How many layers keep a per-slot state in decode mode."""
         return sum(t in RECURRENT_TYPES for t in self.layer_types or ())
+
+    @property
+    def latent_layers(self) -> int:
+        """How many layers keep latent pages (one pool, no head axis)."""
+        return sum(t == "latent" for t in self.layer_types or ())
 
     @property
     def routed_layers(self) -> int:
@@ -1004,6 +1034,14 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 "a model with mamba2 layers needs mamba_n_heads and "
                 "mamba_d_head"
+            )
+        if "latent" in (types or ()) and min(
+            self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim,
+            self.v_head_dim,
+        ) < 1:
+            raise ValueError(
+                "a model with latent layers needs kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim"
             )
         ffns = self.ffn_types
         if ffns is not None and len(ffns) != self.n_layers:
@@ -1063,22 +1101,31 @@ class TransformerLM(nn.Module):
                 ("d_state", self.mamba_d_state), ("d_conv", self.mamba_d_conv),
                 ("n_groups", self.mamba_n_groups),
             ),
-        }
-        routed_kw = dict(
-            ffn="routed", shared_d_ff=self.shared_d_ff,
-            routed=(
-                ("n_experts", self.routed_experts),
-                ("top_k", self.routed_top_k), ("held", self.experts_held),
+            "latent": (
+                ("kv_lora_rank", self.kv_lora_rank),
+                ("qk_nope_head_dim", self.qk_nope_head_dim),
+                ("qk_rope_head_dim", self.qk_rope_head_dim),
+                ("v_head_dim", self.v_head_dim), ("yarn", self.rope_yarn),
             ),
+        }
+        routed = (
+            ("n_experts", self.routed_experts),
+            ("top_k", self.routed_top_k), ("held", self.experts_held),
+        )
+        if self.routed_gating != "softmax_of_top_k":
+            routed += (("gating", self.routed_gating),)
+        routed_kw = dict(
+            ffn="routed", shared_d_ff=self.shared_d_ff, routed=routed
         )
         for i in range(self.n_layers):
             # GShard-style interleaving: every `moe_every`-th block is MoE.
             moe = self.n_experts if (i + 1) % self.moe_every == 0 else 0
             layer_kw = block_kw
             if types is not None and types[i] != "attention":
+                sizes = "latent" if types[i] == "latent" else "mamba"
                 layer_kw = dict(
                     block_kw, mixer=types[i],
-                    mamba=mixer_kw.get(types[i], ()),
+                    **{sizes: mixer_kw.get(types[i], ())},
                 )
             if ffns is not None and ffns[i] != "dense":
                 if ffns[i] != "routed":
@@ -1087,8 +1134,11 @@ class TransformerLM(nn.Module):
                         f"(expected one of {FFN_TYPES})"
                     )
                 layer_kw = dict(layer_kw, **routed_kw)
+                d_ff = self.d_ff
+            else:
+                d_ff = self.dense_d_ff or self.d_ff
             x = block(
-                self.n_heads, self.d_model, self.d_ff, self.dtype,
+                self.n_heads, self.d_model, d_ff, self.dtype,
                 True, self.mesh, self.sequence_axis,
                 sequence_mode=self.sequence_mode,
                 n_kv_heads=self.n_kv_heads, window=self.attention_window,

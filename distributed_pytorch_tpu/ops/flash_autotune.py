@@ -91,6 +91,12 @@ PAGED_FAMILY = "paged_decode"
 PAGED_DEFAULT_TABLE = {
     "cpu": 2,
     "tpu v5 lite": 16,
+    # A (device kind, head size) entry goes before its kind's: the latent
+    # kernel's pool rows of 640 (``models/mla.py``: 16 query heads on ONE
+    # cached vector a token, bf16 pages of 16 tokens, 32 slots of 1,024 pages
+    # at 4,400-13,200 tokens a row; my chip run, PR 35): 16 pages a block take
+    # 941 us a call, 32 703, 64 622, **128 596**, 256 707.
+    ("tpu v5 lite", 640): 128,
 }
 
 _FALLBACK = (512, 1024)
@@ -280,7 +286,9 @@ def lookup_paged_with_tier(
         return int(entry[0]), tier
     kind = device_kind.lower()
     tier = "shipped_table" if kind in PAGED_DEFAULT_TABLE else "fallback"
-    npb = PAGED_DEFAULT_TABLE.get(kind, _PAGED_FALLBACK)
+    npb = PAGED_DEFAULT_TABLE.get(
+        (kind, int(head_dim)), PAGED_DEFAULT_TABLE.get(kind, _PAGED_FALLBACK)
+    )
     pages_per_seq = max(1, int(kv_len) // max(1, int(page_size)))
     legal = paged_candidates(pages_per_seq, page_size)
     fitting = [c for c in legal if c <= npb]
@@ -324,6 +332,7 @@ def autotune_paged(
     verbose: bool = False,
     force: bool = False,
     interpret: Optional[bool] = None,
+    latent: int = 0,
 ) -> int:
     """Measured sweep for the paged decode kernel: times every legal
     pages-per-block over a synthetic decode batch whose rows hold about
@@ -333,12 +342,21 @@ def autotune_paged(
     (in-process + on disk, same persistence rules as :func:`autotune`).
     ``steps`` calls run inside ONE program, each fed the last one's output:
     a call takes tens of microseconds, less than a dispatch. Offline tool —
-    the serving path only ever reads :func:`lookup_paged`."""
+    the serving path only ever reads :func:`lookup_paged`.
+
+    ``latent > 0`` sweeps the latent kernel instead (``models/mla.py``'s
+    page: ONE pool ``[num_pages, page, head_dim]`` with no head axis,
+    ``kv_heads * group`` query heads, a value of the row's first ``latent``
+    numbers); the winner is cached under the pool's width as its
+    ``head_dim``, which is where ``block_pages`` looks it up."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from distributed_pytorch_tpu.ops.paged_attention import paged_attention
+    from distributed_pytorch_tpu.ops.paged_attention import (
+        paged_attention,
+        paged_latent_attention,
+    )
     from distributed_pytorch_tpu.utils.platform import on_tpu
 
     dtype = dtype or jnp.float32
@@ -363,9 +381,13 @@ def autotune_paged(
     q = jnp.asarray(
         rng.standard_normal((slots, 1, h, head_dim)), dtype
     )
-    pool = (num_pages, page_size, kv_heads, head_dim)
-    k_pool = jnp.asarray(rng.standard_normal(pool), dtype)
-    v_pool = jnp.asarray(rng.standard_normal(pool), dtype)
+    if latent:
+        pools = (jnp.asarray(
+            rng.standard_normal((num_pages, page_size, head_dim)), dtype),)
+    else:
+        pool = (num_pages, page_size, kv_heads, head_dim)
+        pools = (jnp.asarray(rng.standard_normal(pool), dtype),
+                 jnp.asarray(rng.standard_normal(pool), dtype))
     tables = jnp.asarray(
         1 + np.arange(slots * pages_per_seq).reshape(slots, pages_per_seq),
         jnp.int32,
@@ -381,17 +403,28 @@ def autotune_paged(
     best, best_dt = None, float("inf")
     for npb in paged_candidates(pages_per_seq, page_size):
         try:
-            attend = functools.partial(
-                paged_attention, kernel=mode, pages_per_block=npb
-            )
+            if latent:
+                # The value is narrower than the query: pad it back.
+                def attend(x, *rest, npb=npb):
+                    out = paged_latent_attention(
+                        x, *rest, v_width=latent, kernel=mode,
+                        pages_per_block=npb,
+                    )
+                    return jnp.pad(
+                        out, ((0, 0),) * 3 + ((0, head_dim - latent),)
+                    )
+            else:
+                attend = functools.partial(
+                    paged_attention, kernel=mode, pages_per_block=npb
+                )
             fn = jax.jit(
                 lambda q, *rest: jax.lax.fori_loop(
                     0, steps, lambda _, x: attend(x, *rest), q
                 )
             )
-            fn(q, k_pool, v_pool, tables, lens).block_until_ready()
+            fn(q, *pools, tables, lens).block_until_ready()
             t0 = time.perf_counter()
-            fn(q, k_pool, v_pool, tables, lens).block_until_ready()
+            fn(q, *pools, tables, lens).block_until_ready()
             dt = (time.perf_counter() - t0) / steps
         except Exception as e:  # lowering failure for this blocking: skip
             if verbose:
@@ -581,6 +614,11 @@ def main(argv=None) -> None:
     )
     parser.add_argument("--dtype", default="float32",
                         help="paged sweep: dtype of queries and pages")
+    parser.add_argument(
+        "--latent", default=0, type=int,
+        help="paged sweep: the latent kernel (one pool of --head_dims wide "
+        "rows, no head axis) with a value of the row's first LATENT numbers",
+    )
     args = parser.parse_args(argv)
     kind = _device_kind()
     if kind == "unknown":
@@ -600,7 +638,7 @@ def main(argv=None) -> None:
                         kv_len, page, d, slots=args.slots,
                         kv_heads=args.kv_heads, group=args.group,
                         dtype=args.dtype, context=args.context,
-                        verbose=True, force=args.force,
+                        verbose=True, force=args.force, latent=args.latent,
                     )
                     key = _paged_key(kind, kv_len, page, d, args.dtype)
                     if key in _failed_sweeps:

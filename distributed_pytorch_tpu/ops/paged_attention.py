@@ -123,6 +123,7 @@ def paged_attention_reference(
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     sm_scale: Optional[float] = None,
+    v_width: Optional[int] = None,
 ) -> jnp.ndarray:
     """The XLA gather path: op-for-op the read side of
     ``_paged_decode_step`` (gather each row's pages into its contiguous
@@ -133,9 +134,13 @@ def paged_attention_reference(
     token count BEFORE the step (= the absolute position of its first new
     token). With ``k_scale``/``v_scale`` the pools are int8 and dequantize
     at the gather, mirroring the contiguous quantized-cache idiom.
-    ``sm_scale`` multiplies the scores; ``None`` is ``D ** -0.5``."""
+    ``sm_scale`` multiplies the scores; ``None`` is ``D ** -0.5``.
+
+    ``v_pool=None`` is the latent case (``models/mla.py``): ``k_pool`` is
+    ONE pool ``[num_pages, page, W]`` with no head axis, ``q`` is ``[S,
+    T_step, H, W]``, and a token's value is the first ``v_width`` numbers of
+    its key; the result is ``[S, T_step, H, v_width]``."""
     s, t_step, h, d = q.shape
-    kv_heads = k_pool.shape[2]
     page = k_pool.shape[1]
     pages_per_seq = block_tables.shape[1]
     kv_len = pages_per_seq * page
@@ -143,6 +148,23 @@ def paged_attention_reference(
     positions = seq_lens.astype(jnp.int32)[:, None] + jnp.arange(
         t_step, dtype=jnp.int32
     )
+    if v_pool is None:
+        # A latent pool [num_pages, page, W]: ONE cached vector a token that
+        # every query head reads, whose first ``v_width`` numbers are also
+        # the value (QK width W, V width ``v_width``).
+        if k_scale is not None:
+            raise ValueError("a latent pool has no per-head scales")
+        keys = k_pool[block_tables].reshape(s, kv_len, d)
+        scale = d**-0.5 if sm_scale is None else sm_scale
+        k_abs = jnp.arange(kv_len)[None, None, :]
+        visible = k_abs <= positions[:, :, None]  # [S, T_step, K]
+        logits = jnp.einsum("bqhd,bkd->bhqk", q, keys) * scale
+        logits = jnp.where(visible[:, None], logits, NEG_INF)
+        weights = jax.nn.softmax(
+            logits.astype(jnp.float32), axis=-1
+        ).astype(q.dtype)
+        return jnp.einsum("bhqk,bkd->bqhd", weights, keys[..., :v_width])
+    kv_heads = k_pool.shape[2]
     keys = k_pool[block_tables].reshape(s, kv_len, kv_heads, d)
     values = v_pool[block_tables].reshape(s, kv_len, kv_heads, d)
     if k_scale is not None:
@@ -517,3 +539,213 @@ def paged_attention(
         check_vma=False,
     )(*args)
     return out3.reshape(s, 1, h, d)
+
+
+# --------------------------------------------------------------- latent pool
+#
+# The second kind of page (``models/mla.py``): ONE pool a layer of ``[num_pages,
+# page, W]``, a token's ``[c | k_pe]`` with no head axis. Every query head
+# reads the same cached vector, and its first ``v_width`` numbers are also the
+# value: the block is copied once and used twice.
+
+
+def _latent_decode_kernel(
+    bt_ref, lens_ref, q_ref, pool_hbm, o_ref, buf, sems, first_buf, m_scr,
+    l_scr, acc_scr, *, npb, v_width, sm_scale,
+):
+    """One slot (grid step) of the latent flash-decode kernel: the frame of
+    :func:`_decode_kernel` (walk the row's own blocks and only those, two
+    buffers, the next block's or the next live row's first block's copies
+    started before this block is computed, online softmax in fp32 scratch)
+    over ONE pool. ``q_ref`` is the row's ``[H, W]`` absorbed query
+    (``[q~ | q_pe]``); a block's ``[bkv, W]`` latent is the key of all ``H``
+    heads at once (a matmul whose M is ``H``), and its first ``v_width``
+    columns, already in VMEM, are the value. The products take the pool's
+    type as it is stored (bf16 on the chip: the MXU's own) and accumulate in
+    float32."""
+    b = pl.program_id(0)
+    slots, pages_per_seq = bt_ref.shape
+    h, w = q_ref.shape[1:]
+    page = buf.shape[2]
+    bkv = npb * page
+
+    def is_live(row):
+        return bt_ref[row, 0] != NULL_PAGE
+
+    def page_copy(phys, slot, n):
+        return pltpu.make_async_copy(
+            pool_hbm.at[phys], buf.at[slot, n], sems.at[slot]
+        )
+
+    def start(row, blk, slot):
+        # A logical page past the row's last live one clamps to that one, as
+        # in ``_decode_kernel``: a page the row does not own is never fetched.
+        last = jnp.minimum(lens_ref[row] // page, pages_per_seq - 1)
+        for n in range(npb):
+            phys = bt_ref[row, jnp.minimum(blk * npb + n, last)]
+            page_copy(phys, slot, n).start()
+
+    def wait(slot):
+        for n in range(npb):
+            page_copy(0, slot, n).wait()
+
+    @pl.when(jnp.logical_not(is_live(b)))
+    def _absent():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(is_live(b))
+    def _row():
+        pos = lens_ref[b]
+        n_blocks = jnp.minimum(pos // bkv + 1, pl.cdiv(pages_per_seq, npb))
+        prefetched = jnp.logical_and(b > 0, is_live(jnp.maximum(b - 1, 0)))
+        slot0 = jnp.where(prefetched, first_buf[0], 0)
+
+        @pl.when(jnp.logical_not(prefetched))
+        def _first():
+            start(b, 0, 0)
+
+        next_row = jnp.minimum(b + 1, slots - 1)
+        next_live = jnp.logical_and(b + 1 < slots, is_live(next_row))
+
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+        def block(j, carry):
+            slot = (slot0 + j) % 2
+
+            @pl.when(j + 1 < n_blocks)
+            def _next_block():
+                start(b, j + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(j + 1 == n_blocks, next_live))
+            def _next_row():
+                start(next_row, 0, 1 - slot)
+                first_buf[0] = 1 - slot
+
+            wait(slot)
+            k = buf[slot].reshape(bkv, w)  # as stored
+            q = q_ref[0].astype(k.dtype)  # [H, W]
+            s_blk = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale  # [H, bkv]
+            kpos = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
+            s_blk = jnp.where(kpos <= pos, s_blk, NEG_INF)
+            m_prev = m_scr[:, :1]
+            l_prev = l_scr[:, :1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(s_blk, axis=-1, keepdims=True)
+            )
+            p = jnp.exp(s_blk - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+            # The value is the key's own first columns: no second copy.
+            pv = jax.lax.dot_general(
+                p.astype(k.dtype), k[:, :v_width], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [H, v_width]
+            acc_scr[:] = acc_scr[:] * correction + pv
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, block, 0)
+        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("pages_per_block", "interpret", "sm_scale", "v_width"),
+)
+def _latent_flash(
+    q3, pool, block_tables, seq_lens, *, pages_per_block, interpret,
+    sm_scale, v_width,
+):
+    """The latent kernel's ``pallas_call`` for ``q3`` [S, H, W]: jitted and
+    named for :func:`_paged_flash`'s reasons (one trace for a model's layers;
+    a device trace shows ``attention._latent_decode_step`` whoever calls
+    it). The pool stays in HBM and a page is copied as the ``[page, W]`` rows
+    it is stored as."""
+    s, h, w = q3.shape
+    page = pool.shape[1]
+    npb = int(pages_per_block)
+
+    def row_spec(shape):
+        return pl.BlockSpec(
+            shape, lambda b, bt, lens: (b,) + (0,) * (len(shape) - 1),
+            memory_space=pltpu.VMEM,
+        )
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s,),
+        in_specs=[row_spec((1, h, w)), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row_spec((1, h, v_width)),
+        scratch_shapes=[
+            pltpu.VMEM((2, npb, page, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),  # buffer of the row's first block
+            pltpu.VMEM((h, 128), jnp.float32),  # running max m
+            pltpu.VMEM((h, 128), jnp.float32),  # denominator l
+            pltpu.VMEM((h, v_width), jnp.float32),  # output accumulator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _latent_decode_kernel, npb=npb, v_width=v_width,
+            sm_scale=sm_scale,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, h, v_width), q3.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+        name="attention._latent_decode_step",
+    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q3, pool)
+
+
+def paged_latent_attention(
+    q: jnp.ndarray,
+    pool: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    seq_lens: jnp.ndarray,
+    *,
+    v_width: int,
+    kernel="auto",
+    pages_per_block: Optional[int] = None,
+    sm_scale: Optional[float] = None,
+) -> jnp.ndarray:
+    """Paged attention of ``q`` [S, T_step, H, W] over ONE latent pool
+    ``[num_pages, page, W]``: every head's key at a position is the pool's
+    vector there and its value that vector's first ``v_width`` numbers.
+    Returns ``[S, T_step, H, v_width]``. As :func:`paged_attention`: a
+    single-token step dispatches per ``kernel``, everything else takes
+    :func:`paged_attention_reference`'s latent case; ``sm_scale`` ``None`` is
+    ``W ** -0.5``; :func:`block_pages` looks the block up under the pool's
+    width."""
+    s, t_step, h, w = q.shape
+    if pool.ndim != 3 or pool.shape[2] != w:
+        raise ValueError(
+            f"a latent pool is [num_pages, page, {w}], got {pool.shape}"
+        )
+    if not 0 < v_width <= w:
+        raise ValueError(f"v_width {v_width} of a latent of width {w}")
+    mode = resolve_kernel(kernel)
+    if mode == "xla" or t_step != 1:
+        return paged_attention_reference(
+            q, pool, None, block_tables, seq_lens, sm_scale=sm_scale,
+            v_width=v_width,
+        )
+    out3 = _latent_flash(
+        q.reshape(s, h, w), pool, block_tables, seq_lens,
+        pages_per_block=block_pages(
+            block_tables.shape[1], pool.shape[1], w, pool.dtype,
+            pages_per_block,
+        ),
+        interpret=(mode == "interpret"),
+        sm_scale=float(w**-0.5 if sm_scale is None else sm_scale),
+        v_width=int(v_width),
+    )
+    return out3.reshape(s, 1, h, v_width)

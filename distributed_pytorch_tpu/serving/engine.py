@@ -140,7 +140,31 @@ experts this model holds and on the others), ``moe_experts_hit`` (held
 experts with at least one token, summed over programs and layers) and
 ``moe_tokens_per_expert_max`` / ``_mean`` (over the held experts of a layer,
 summed likewise). ``finish_inflight`` writes the last step's. A mesh is
-refused for such a model (no expert axis on the serving mesh yet).
+refused for such a model (no expert axis on the serving mesh yet). The decode
+program tells such a model, recurrent layers or none, which rows are in the
+dispatched group (``state_slots``): a row outside it reaches no expert and is
+in no count.
+
+Latent layers (a model whose ``layer_types`` name ``"latent"`` layers,
+``models/mla.py``'s ``LatentAttention``): a second KIND of page. Such a layer
+keeps ONE pool, ``cached_latent [num_pages, page_size, W]`` (a token's ``[c |
+k_pe]`` in whole lanes, no head axis), where an attention layer keeps a K and
+a V pool of ``[num_pages, page_size, Hkv, D]``. The engine reads
+``latent_layers`` from the model and the pools' geometry from the cache tree
+it builds (a page pool is a leaf whose first two axes are ``(num_pages,
+page_size)``; ``stats()["page_bytes_per_token_layer"]`` is what one layer's
+pools hold a token, whatever their kind); the allocator, the block tables, the
+trie, copy-on-write and the page-copy program never ask what a page holds, so
+``prefix_cache=True`` serves such a model as it serves any. The decode
+kernel's block is looked up under the pool's width. What is built on a K and
+a V pool of one head size is refused in the constructor, each with its
+reason: ``mesh`` (``KV_POOL_SPEC`` splits a KV-head axis), ``host_pages``
+(``serving/hostkv.py`` reckons a page's bytes from ``Hkv x D``), ``kv_quant``
+(one scale a (token, head)), ``draft_model`` (no verify path over latent
+pages). On every ``step`` slice that dispatched a decode the tracer carries,
+beside ``decode_kv_tokens_fetched`` / ``_visible``,
+``decode_kv_tokens_distinct``: the key positions the rows could see with each
+PHYSICAL page counted once, so rows that share a document count it once.
 """
 
 from __future__ import annotations
@@ -400,6 +424,29 @@ class InferenceEngine:
                         f"a model with recurrent layers cannot be served "
                         f"with {what} yet: {why}"
                     )
+        # Layers that keep latent pages (module docstring): ONE pool a layer
+        # with no head axis. Read from the model; what knows a K and a V pool
+        # of one head size is refused with its reason.
+        self.latent_layers = int(getattr(model, "latent_layers", 0))
+        if self.latent_layers:
+            for given, what, why in (
+                (mesh is not None, "mesh",
+                 "the serving mesh splits a page pool over its KV-head "
+                 "axis, and a latent pool has none"),
+                (host_pages, "host_pages",
+                 "the host tier reckons a page's bytes from KV heads and a "
+                 "head size"),
+                (kv_quant, "kv_quant",
+                 "int8 pages keep one scale a (token, head), and a latent "
+                 "has no head"),
+                (draft_model is not None, "draft_model",
+                 "the speculative verify step has no path over latent pages"),
+            ):
+                if given:
+                    raise ValueError(
+                        f"a model with latent layers cannot be served with "
+                        f"{what} yet: {why}"
+                    )
         # Layers that route tokens to experts (module docstring): their
         # programs return the routing counts, read only under a tracer.
         self.routed_layers = int(getattr(model, "routed_layers", 0))
@@ -462,14 +509,7 @@ class InferenceEngine:
         # whole table.
         self._kv_block_tokens = 0
         self._decode_positions: List[np.ndarray] = []
-        if self.paged_kernel:
-            from distributed_pytorch_tpu.ops import paged_attention as pa
-
-            if pa.resolve_kernel(self.paged_kernel) != "xla":
-                self._kv_block_tokens = page_size * pa.block_pages(
-                    self.pages_per_seq, page_size,
-                    model.d_model // model.n_heads, model.dtype,
-                )
+        self._decode_tables: List[np.ndarray] = []
         # Size the paged pool from abstract shapes only (eval_shape traces
         # init without running it); token length 1 — pool shapes depend only
         # on (num_pages, page_size), never on the init input.
@@ -488,6 +528,36 @@ class InferenceEngine:
         # lifecycle decision moves both pools in lockstep. Head/width can
         # differ freely; only the page GEOMETRY must match.
         pools = {"target": _zero_cache(self.decode_model)}
+        # What the target's pages hold, read from the pools it declared
+        # (leaves ``[num_pages, page_size, ...]``), whatever kind they are:
+        # bytes a token in one layer, and the last size of a latent pool.
+        page_leaves = [
+            (path, leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                pools["target"]
+            )[0]
+            if leaf.shape[:2] == (num_pages, page_size)
+            and getattr(path[-1], "key", None) not in STATE_KEYS
+        ]
+        paged_layers = len({str(path[:-1]) for path, _ in page_leaves})
+        self.page_bytes_per_token_layer = (
+            sum(leaf.nbytes for _, leaf in page_leaves)
+            // max(1, paged_layers * num_pages * page_size)
+        )
+        if self.paged_kernel:
+            from distributed_pytorch_tpu.ops import paged_attention as pa
+
+            if pa.resolve_kernel(self.paged_kernel) != "xla":
+                latent = [
+                    leaf for path, leaf in page_leaves
+                    if getattr(path[-1], "key", None) == "cached_latent"
+                ]
+                self._kv_block_tokens = page_size * pa.block_pages(
+                    self.pages_per_seq, page_size,
+                    latent[0].shape[-1] if latent
+                    else model.d_model // model.n_heads,
+                    model.dtype,
+                )
         # Bytes of recurrent state one slot owns, over every layer.
         self.state_bytes_per_slot = sum(
             leaf.nbytes // max_slots
@@ -1104,11 +1174,12 @@ class InferenceEngine:
         return self.state_bytes_per_slot * len(self.scheduler.running)
 
     def _decode_state_kw(self, tables) -> dict:
-        """What the batched decode program tells a model with recurrent
-        layers (nothing to any other): row ``r`` carries slot ``r``'s state
-        iff the row is in the dispatched group, which is iff its staged
-        block table is not the zeroed one."""
-        if not self.state_layers:
+        """What the batched decode program tells a model with recurrent or
+        routed layers (nothing to any other): row ``r`` carries slot ``r``'s
+        state, and is routed to experts and counted, iff the row is in the
+        dispatched group, which is iff its staged block table is not the
+        zeroed one."""
+        if not (self.state_layers or self.routed_layers):
             return {}
         rows = jnp.arange(self.max_slots, dtype=jnp.int32)
         return {"state_slots": jnp.where(tables[:, 0] != NULL_PAGE, rows, -1)}
@@ -1811,6 +1882,7 @@ class InferenceEngine:
         self._stage_row_keys(slots)
         if self.tracer.enabled:
             self._decode_positions.append(self._stage_lens[slots])
+            self._decode_tables.append(self._stage_tables[slots])
         staged = (
             self._stage_tokens.nbytes
             + self._stage_use_prev.nbytes
@@ -1889,9 +1961,16 @@ class InferenceEngine:
                     int(kv_tokens_walked(pos, block).sum()) if block
                     else whole
                 )
-            self._decode_positions.clear()
             extra["decode_kv_tokens_fetched"] = fetched
             extra["decode_kv_tokens_visible"] = visible
+            extra["decode_kv_tokens_distinct"] = sum(
+                self._distinct_kv_tokens(pos, tables)
+                for pos, tables in zip(
+                    self._decode_positions, self._decode_tables
+                )
+            )
+            self._decode_positions.clear()
+            self._decode_tables.clear()
         self.tracer.end_step(
             decode_rows=len(plan.decode_slots),
             prefill_programs=len(plan.prefill),
@@ -1907,6 +1986,21 @@ class InferenceEngine:
             pages_cached_idle=pages["pages_cached_idle"],
             **extra,
         )
+
+    def _distinct_kv_tokens(self, positions, tables) -> int:
+        """Key positions a decode dispatch's rows could see, each PHYSICAL
+        page counted once: rows that share a prefix share its pages, and a
+        kernel could serve them all by one read of it. A page counts the most
+        tokens any of its rows sees in it (a row at ``pos`` sees ``pos %
+        page + 1`` of its last page, every earlier one whole)."""
+        page = self.page_size
+        last = positions // page  # a row's last live logical page
+        seen = np.zeros((self.allocator.num_pages,), np.int64)
+        whole = np.arange(self.pages_per_seq)[None, :] < last[:, None]
+        seen[tables[whole]] = page
+        rows = np.arange(len(positions))
+        np.maximum.at(seen, tables[rows, last], positions % page + 1)
+        return int(seen.sum())
 
     def step(self) -> List[int]:
         """Run one engine iteration; returns ids of requests that FINISHED
@@ -2624,6 +2718,7 @@ class InferenceEngine:
         out["prefill_programs"] = self.prefill_programs
         out["prefill_tokens"] = self.prefill_tokens
         out["prefill_width"] = self.prefill_width
+        out["page_bytes_per_token_layer"] = self.page_bytes_per_token_layer
         out["pages_free"] = self.allocator.num_free
         out["pages_allocated"] = self.allocator.num_allocated
         out["pages_idle"] = self.allocator.num_idle

@@ -437,6 +437,7 @@ class Scheduler:
                 req.req_id, "admit",
                 slot=slot,
                 cached_tokens=req.len_cached,
+                prompt_tokens=len(req.prompt),
                 hit=req.len_cached > 0,
                 readmission=req.preempt_count > 0,
             )

@@ -4,7 +4,7 @@
 trace reduction it tests decides every per-layer metric: a package change
 that renames a slice would break a metric's reader unseen. This file puts
 ``benchmarks/`` on ``sys.path`` as ``benchmarks/tests/conftest.py`` does and
-hands pytest the cases of the seven fast files there, each as a case of a
+hands pytest the cases of the eight fast files there, each as a case of a
 class named after its file, so two files may each have a fixture ``spec``.
 The three rehearsal files stay outside, for their 45-90 s each.
 
@@ -26,7 +26,8 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 FILES = ("test_phases", "test_ssm_readers", "test_trace_reduction", "test_control",
-         "test_kernel_readers", "test_moe_readers", "test_prefill_readers")
+         "test_kernel_readers", "test_moe_readers", "test_prefill_readers",
+         "test_latent_readers")
 
 
 def cases_of(stem):

@@ -7,7 +7,9 @@ executed, so this says nothing about results or times; about a second a case.
 
 import functools
 import os
+import sys
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
 import jax
@@ -122,6 +124,104 @@ CASES = {
         for grad in (False, True)
     },
 }
+
+
+# ------------------------------------------------------------ latent pages
+#
+# The second kind of page (``models/mla.py``): one pool ``[num_pages, page,
+# lanes]`` a layer. How its layout was found, before any run on the chip.
+
+LATENT = dict(slots=32, heads=16, pages_per_seq=1024, num_pages=10241)
+
+
+def latent_case(chip, width, pages_per_block=None):
+    """``benchmarks/configs/deepseek-v2-lite.json``'s engine: 32 slots of
+    1,024 pages out of 10,241, 16 heads on one latent a token."""
+    from distributed_pytorch_tpu.ops.paged_attention import (
+        paged_latent_attention,
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    fn = functools.partial(
+        paged_latent_attention, v_width=512, kernel="pallas",
+        pages_per_block=pages_per_block or PAGED_DEFAULT_TABLE[KIND],
+        sm_scale=0.114721,
+    )
+    return jax.jit(fn).lower(
+        arg((LATENT["slots"], 1, LATENT["heads"], width), jnp.bfloat16),
+        arg((LATENT["num_pages"], PAGE, width), jnp.bfloat16),
+        arg((LATENT["slots"], LATENT["pages_per_seq"]), jnp.int32),
+        arg((LATENT["slots"],), jnp.int32),
+    )
+
+
+def latent_program(chip, t_step, layers=2):
+    """A serving program of the ``deepseek-v2-lite`` configuration at its own
+    shapes (published widths, the cell's engine), lowered for the described
+    chip on abstract operands: the decode step over all 32 slots (``t_step``
+    1, through the Pallas kernel) or a prefill piece of ``t_step`` tokens.
+    ``layers`` of the 27: the dense layer and the first expert layers, which
+    is every shape the compiler is asked about (the whole depth is compiled
+    by hand, for its memory: PERF.md section 6)."""
+    import json
+
+    from deepseek_toy import ROOT, driver, reference
+
+    with open(os.path.join(
+            ROOT, "benchmarks", "configs", "deepseek-v2-lite.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=layers)
+    engine = cfg["assumed"]["engine"]
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+            tree)
+
+    weights = jax.eval_shape(lambda: reference.make_weights(cfg, 0))
+    model, params = driver.build_program(cfg, weights)
+    decode_model = model.clone(
+        decode=True, page_size=engine["page_size"],
+        num_pages=engine["num_pages"], paged_kernel="pallas")
+    rows = engine["max_slots"] if t_step == 1 else 1
+    cache = jax.eval_shape(
+        decode_model.init, jax.random.PRNGKey(0),
+        jnp.zeros((rows, 1), jnp.int32))["cache"]
+    pages_per_seq = engine["max_seq_len"] // engine["page_size"]
+
+    def run(params, cache, tokens, tables, lens, valid):
+        kw = {} if t_step == 1 else {"valid_lens": valid}
+        logits, updated = decode_model.apply(
+            {"params": params, "cache": cache}, tokens, block_tables=tables,
+            seq_lens=lens, state_slots=jnp.arange(rows, dtype=jnp.int32),
+            mutable=["cache", "routing"], **kw)
+        return logits[:, -1], updated["cache"]
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    return jax.jit(run, donate_argnums=(1,)).lower(
+        abstract(params), abstract(cache), arg((rows, t_step)),
+        arg((rows, pages_per_seq)), arg((rows,)), arg((rows,)))
+
+
+CASES.update({
+    **{f"latent-640-npb{npb}": functools.partial(
+        latent_case, width=640, pages_per_block=npb)
+       for npb in (16, 64, PAGED_DEFAULT_TABLE[(KIND, 640)])},
+    "latent-cell-decode": functools.partial(latent_program, t_step=1),
+    "latent-cell-prefill-64": functools.partial(latent_program, t_step=64),
+    "latent-cell-prefill-512": functools.partial(latent_program, t_step=512),
+})
+
+
+def test_a_latent_pool_of_576_is_refused_by_mosaic(chip):
+    """Why the pool's rows are 640 wide: the chip stores a ``[.., 576]`` bf16
+    array in tiles of 128 lanes (640 a token in HBM whatever the shape says),
+    and Mosaic will not copy a page out of it."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        latent_case(chip, width=576).compile()
 
 
 @pytest.mark.parametrize("name", sorted(CASES))

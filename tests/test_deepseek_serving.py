@@ -1,0 +1,219 @@
+"""A model with latent attention and routed experts through
+``InferenceEngine``: ONE latent pool a layer under the allocator, the block
+tables, the prefix trie and copy-on-write that K/V pools have always had; the
+chip's share of the experts; the new counters. Everything is compared with
+``benchmarks/reference/deepseek_v2.py`` on seeded weights at toy widths,
+through logits: a served (greedy) token's reference logit has to lie within
+``LOGIT_TOL`` of the reference's best at its position (``deepseek_toy``)."""
+
+import jax
+import numpy as np
+import pytest
+
+from deepseek_toy import (
+    LOGIT_TOL, SEED, TOY, reference, share, slice_experts, tokens, toy_program,
+)
+
+from distributed_pytorch_tpu.obs.tracer import Tracer
+from distributed_pytorch_tpu.serving import InferenceEngine, SamplingParams
+from distributed_pytorch_tpu.serving.mesh import make_serving_mesh
+
+ENGINE = dict(max_slots=3, max_seq_len=64, page_size=4, max_prefill_chunk=8,
+              token_budget=11, prefix_cache=True)
+HELD = (2, 5)  # this chip's share of the toy's 8 experts
+
+
+@pytest.fixture(scope="module")
+def program():
+    """The toy holding experts 2-4 of 8, as the benchmark's cell holds 8 of
+    64: ``(cfg, weights, model, params)``."""
+    cfg = share(HELD)
+    weights = slice_experts(reference.make_weights(TOY, SEED), HELD)
+    return (cfg, *toy_program(cfg, weights))
+
+
+def engine_for(program, **kw):
+    _, _, model, params = program
+    return InferenceEngine(model, params, **{**ENGINE, **kw})
+
+
+def serve(engine, prompts, new_tokens=8):
+    ids = [engine.submit(p, SamplingParams(max_new_tokens=new_tokens))
+           for p in prompts]
+    engine.run()
+    out = []
+    for rid in ids:
+        status = engine.poll(rid)
+        assert status.state == "finished"
+        out.append(list(status.generated))
+    return out
+
+
+def served_gap(program, prompt, generated):
+    """How far below the reference's best logit each served token's
+    reference logit lies, at the positions that predicted them."""
+    cfg, weights = program[:2]
+    rows = [len(prompt) - 1 + i for i in range(len(generated))]
+    logits = np.asarray(reference.logits_at(
+        cfg, weights, list(prompt) + list(generated), rows))
+    return logits.max(-1) - logits[np.arange(len(generated)), generated]
+
+
+@pytest.mark.parametrize("kernel", [False, "interpret"])
+@pytest.mark.parametrize("chunk", [1, 4, 32])
+def test_chunked_prefill_then_paged_decode_matches_the_reference(
+        program, chunk, kernel):
+    """Prefill in padded pieces of every cap, then decode through latent
+    pages (the gather loop, and the Pallas kernel interpreted) and the held
+    experts, as served; against the reference's one expanded pass."""
+    prompt = tokens(38, seed=chunk)
+    engine = engine_for(
+        program, max_prefill_chunk=chunk, token_budget=chunk + 3,
+        paged_kernel=kernel)
+    (generated,) = serve(engine, [prompt], new_tokens=10)
+    assert served_gap(program, prompt, generated).max() < LOGIT_TOL
+
+
+def test_the_engine_reads_the_kind_of_page_from_the_model(program):
+    """ONE pool a layer, ``[num_pages, page, lanes]``, and its bytes a
+    token in ``stats()``: the toy's 16 numbers rounded up to 128 lanes of
+    float32."""
+    engine = engine_for(program, num_pages=9)
+    leaves = jax.tree_util.tree_flatten_with_path(engine.cache)[0]
+    assert sorted(path[-1].key for path, _ in leaves) == ["cached_latent"] * 3
+    assert {leaf.shape for _, leaf in leaves} == {(9, 4, 128)}
+    assert engine.latent_layers == 3 and engine.routed_layers == 2
+    assert engine.stats()["page_bytes_per_token_layer"] == 128 * 4
+
+
+def test_multi_head_pages_report_their_bytes_too():
+    from distributed_pytorch_tpu.models.transformer import TransformerLM
+    import jax.numpy as jnp
+
+    model = TransformerLM(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+        n_kv_heads=2)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    engine = InferenceEngine(model, params, **ENGINE)
+    # K and V, 2 heads of 8, float32.
+    assert engine.stats()["page_bytes_per_token_layer"] == 2 * 2 * 8 * 4
+    assert engine.latent_layers == 0
+
+
+@pytest.mark.parametrize("kernel", [False, "interpret"])
+def test_a_second_question_on_a_document_prefills_only_itself(program, kernel):
+    """The trie serves the document: the second request's prefill carries
+    its question (and the document's last partial page, which the first
+    request's tokens run on in) and nothing else, and it serves what an
+    engine with the trie off serves."""
+    document = tokens(27, seed=20)  # 6 whole pages and 3 tokens
+    first, second = document + tokens(5, seed=21), document + tokens(6, seed=22)
+    engine = engine_for(program, paged_kernel=kernel)
+    serve(engine, [first])
+    before = engine.stats()
+    (generated,) = serve(engine, [second], new_tokens=9)
+    after = engine.stats()
+    # 24 tokens in whole pages are hits; the rest, less the last token,
+    # which the decode step feeds, is prefilled.
+    assert after["prefix_tokens_hit"] - before["prefix_tokens_hit"] == 24
+    assert after["prefill_tokens"] - before["prefill_tokens"] == 33 - 24 - 1
+    (cold,) = serve(
+        engine_for(program, prefix_cache=False, paged_kernel=kernel),
+        [second], new_tokens=9)
+    assert generated == cold
+    assert served_gap(program, second, generated).max() < LOGIT_TOL
+
+
+def test_the_partial_last_page_is_copied_on_write(program):
+    """A document prefilled alone (one new token, so that its last partial
+    page holds the document's tail and nothing else) leaves that page in the
+    trie; askers extend it, and while more than one holds it each copies it
+    first (the last holder writes on in place, past the tail the trie's key
+    covers). The document's own pages stay what they were: a third asker,
+    alone, still matches."""
+    document = tokens(22, seed=30)  # 5 whole pages and 2 tokens
+    engine = engine_for(program)
+    serve(engine, [document], new_tokens=1)
+    askers = [document + tokens(4 + i, seed=31 + i) for i in range(3)]
+    before = engine.stats()
+    served = serve(engine, askers[:2], new_tokens=6)  # side by side
+    assert engine.stats()["cow_copies"] - before["cow_copies"] >= 1
+    # All 22 of the document's tokens are hits: whole pages and the partial.
+    hit = engine.stats()["prefix_tokens_hit"] - before["prefix_tokens_hit"]
+    assert hit == 2 * 22
+    served += serve(engine, askers[2:], new_tokens=6)
+    for prompt, generated in zip(askers, served):
+        assert served_gap(program, prompt, generated).max() < LOGIT_TOL
+
+
+def test_re_prefill_after_a_forced_preemption_serves_the_same_tokens(program):
+    """Too few pages for two long requests: one is preempted and prefilled
+    again, prompt and generated tokens."""
+    prompts = [tokens(30, seed=6), tokens(28, seed=7)]
+    engine = engine_for(program, num_pages=1 + 14, prefix_cache=False)
+    served = serve(engine, prompts, new_tokens=16)
+    assert engine.scheduler.preemptions > 0
+    for prompt, generated in zip(prompts, served):
+        (alone,) = serve(
+            engine_for(program, prefix_cache=False), [prompt], new_tokens=16)
+        assert generated == alone
+        assert served_gap(program, prompt, generated).max() < LOGIT_TOL
+
+
+@pytest.mark.parametrize("kw, why", [
+    (dict(mesh="mesh"), "KV-head axis"),
+    (dict(host_pages=4), "KV heads and a head size"),
+    (dict(kv_quant="int8"), "one scale a"),
+    (dict(draft_model="self", draft_params="self"), "speculative verify"),
+])
+def test_what_a_model_with_latent_layers_cannot_be_served_with(
+        program, kw, why):
+    _, _, model, params = program
+    if "mesh" in kw:
+        kw = dict(mesh=make_serving_mesh(1, 2), prefix_cache=False)
+    if "draft_model" in kw:
+        kw = dict(draft_model=model, draft_params=params)
+    with pytest.raises(ValueError, match="latent layers") as err:
+        engine_for(program, **kw)
+    assert why in str(err.value)
+
+
+def step_args(tracer):
+    return [e["args"] for e in tracer.events
+            if e["name"] == "step" and e.get("ph") == "X"]
+
+
+def test_the_distinct_token_counter_on_steps_with_known_tables(program):
+    """Two askers of one 24-token document (6 whole pages) decode side by
+    side: the steps' ``decode_kv_tokens_visible`` counts the document twice,
+    ``decode_kv_tokens_distinct`` once."""
+    document = tokens(24, seed=40)
+    tracer = Tracer()
+    engine = engine_for(program, tracer=tracer, paged_kernel="interpret")
+    serve(engine, [document + tokens(3, seed=41)], new_tokens=2)
+    tracer.events.clear()
+    askers = [document + tokens(3, seed=42), document + tokens(3, seed=43)]
+    serve(engine, askers, new_tokens=6)
+    both = [a for a in step_args(tracer) if a["decode_rows"] == 2]
+    assert both
+    for a in both:
+        visible, distinct = (a["decode_kv_tokens_visible"],
+                             a["decode_kv_tokens_distinct"])
+        assert visible - distinct == 24  # the document, counted once
+        assert a["decode_kv_tokens_fetched"] >= visible
+    admits = [e["args"] for e in tracer.events if e["name"] == "admit"]
+    assert [(a["prompt_tokens"], a["cached_tokens"]) for a in admits] == [
+        (27, 24), (27, 24)]
+
+
+def test_distinct_tokens_of_a_handmade_dispatch(program):
+    engine = engine_for(program, num_pages=20)
+    tables = np.zeros((3, engine.pages_per_seq), np.int32)
+    tables[0, :3] = [5, 6, 7]  # 10 tokens cached: sees 11 = 4 + 4 + 3
+    tables[1, :3] = [5, 6, 9]  # shares two pages, sees 4 + 4 + 1
+    tables[2, :1] = [7]  # shares row 0's LAST page and sees 4 of it
+    positions = np.asarray([10, 8, 3])
+    # pages 5, 6 whole (8), page 7 at the most any row sees (4), page 9 (1)
+    assert engine._distinct_kv_tokens(positions, tables) == 8 + 4 + 1
+    assert int(positions.sum()) + 3 == 11 + 9 + 4

@@ -741,3 +741,165 @@ class TestKvFingerprint:
         old = EngineSnapshot.from_json(json.dumps(doc))
         assert old.kv == "fp"
         assert dataclasses.replace(old, kv=snap.kv) == snap
+
+
+# ------------------------------------------------------------- latent pool
+#
+# The second kind of page (``models/mla.py``): ONE pool ``[num_pages, page,
+# W]`` with no head axis; 16 query heads read the same cached vector, whose
+# first ``v_width`` numbers are also the value (QK width W, V width
+# ``v_width``).
+
+
+def make_latent_problem(seed=0, h=16, w=20, page=4, pages_per_seq=6,
+                        dtype=jnp.float32):
+    """Ragged rows: mid-page, one token, a whole table but one, out of the
+    group (an all-null table), and a row that shares its first pages with
+    row 0 (a shared document)."""
+    from distributed_pytorch_tpu.ops.paged_attention import (
+        paged_latent_attention,
+    )
+
+    rng = np.random.default_rng(seed)
+    bt = jnp.asarray([
+        [3, 5, 9, 0, 0, 0], [7, 0, 0, 0, 0, 0], [1, 2, 4, 6, 8, 10],
+        [0, 0, 0, 0, 0, 0], [3, 5, 11, 12, 0, 0]], jnp.int32)
+    lens = jnp.asarray([9, 0, 22, 0, 13], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((5, 1, h, w)), dtype)
+    pool = jnp.asarray(rng.standard_normal((13, page, w)), dtype)
+    return paged_latent_attention, q, pool, bt, lens
+
+
+def dense_latent(q, pool, bt, lens, v_width, sm_scale):
+    """The same numbers written out a row at a time in NumPy."""
+    q, pool = np.asarray(q, np.float64), np.asarray(pool, np.float64)
+    out = np.zeros(q.shape[:3] + (v_width,))
+    for b in range(q.shape[0]):
+        keys = pool[np.asarray(bt[b])].reshape(-1, pool.shape[-1])
+        keys = keys[: int(lens[b]) + 1]
+        s = np.einsum("hw,kw->hk", q[b, 0], keys) * sm_scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[b, 0] = (p / p.sum(-1, keepdims=True)) @ keys[:, :v_width]
+    return out
+
+
+class TestLatentPagedAttention:
+    V = 16  # of a latent of 20: QK and V widths differ
+
+    def test_reference_is_the_dense_computation(self):
+        _, q, pool, bt, lens = make_latent_problem()
+        ref = paged_attention_reference(
+            q, pool, None, bt, lens, v_width=self.V, sm_scale=0.3)
+        assert ref.shape == (5, 1, 16, self.V)
+        want = dense_latent(q, pool, bt, lens, self.V, 0.3)
+        np.testing.assert_allclose(np.asarray(ref), want, atol=2e-6, rtol=2e-6)
+
+    @pytest.mark.parametrize("npb", [1, 2, 4, 6])
+    def test_kernel_matches_reference(self, npb):
+        """Group 16 on one latent, ragged rows, every block size: live rows
+        to float32 rounding, the row out of the group exactly zero."""
+        attend, q, pool, bt, lens = make_latent_problem()
+        ref = paged_attention_reference(
+            q, pool, None, bt, lens, v_width=self.V, sm_scale=0.3)
+        out = attend(q, pool, bt, lens, v_width=self.V, kernel="interpret",
+                     pages_per_block=npb, sm_scale=0.3)
+        assert_rows_match(out, ref, bt)
+
+    def test_default_scale_is_the_widths_root(self):
+        attend, q, pool, bt, lens = make_latent_problem(seed=2)
+        out = attend(q, pool, bt, lens, v_width=self.V, kernel="interpret")
+        want = dense_latent(q, pool, bt, lens, self.V, 20**-0.5)
+        live = np.asarray(bt)[:, 0] != NULL_PAGE
+        np.testing.assert_allclose(
+            np.asarray(out)[live], want[live], atol=2e-6, rtol=2e-6)
+
+    def test_xla_mode_and_chunks_are_the_reference_bitwise(self):
+        attend, q, pool, bt, lens = make_latent_problem(seed=3)
+        ref = paged_attention_reference(
+            q, pool, None, bt, lens, v_width=self.V)
+        out = attend(q, pool, bt, lens, v_width=self.V, kernel="xla")
+        assert (np.asarray(out) == np.asarray(ref)).all()
+        q2 = jnp.concatenate([q, q], axis=1)  # t_step 2: never the kernel
+        ref2 = paged_attention_reference(
+            q2, pool, None, bt, lens, v_width=self.V)
+        out2 = attend(q2, pool, bt, lens, v_width=self.V, kernel="interpret")
+        assert (np.asarray(out2) == np.asarray(ref2)).all()
+
+    def test_null_page_and_unowned_pages_never_survive(self):
+        """Poisoning the null page and every page no row owns with NaN
+        changes nothing for live rows in the kernel (it never fetches them);
+        with huge finite garbage nothing in the reference either."""
+        attend, q, pool, bt, lens = make_latent_problem(seed=4)
+        kw = dict(v_width=self.V, sm_scale=0.3)
+        out = attend(q, pool, bt, lens, kernel="interpret", **kw)
+        ref = paged_attention_reference(q, pool, None, bt, lens, **kw)
+        # Row 2 holds 23 of its table's 24 positions: its last page's last
+        # row is past its length; pages 10 (row 2's last) stays.
+        unowned = jnp.asarray([0])
+        out_p = attend(q, pool.at[unowned].set(jnp.nan), bt, lens,
+                       kernel="interpret", **kw)
+        ref_p = paged_attention_reference(
+            q, pool.at[unowned].set(1e4), None, bt, lens, **kw)
+        live = np.asarray(bt)[:, 0] != NULL_PAGE
+        assert (np.asarray(out_p)[live] == np.asarray(out)[live]).all()
+        assert (np.asarray(ref_p)[live] == np.asarray(ref)[live]).all()
+        assert (np.asarray(out_p)[~live] == 0).all()
+
+    def test_rows_that_share_pages_read_the_same_latent(self):
+        """Rows 0 and 4 share their first two pages (a document): each
+        attends to them under its own length."""
+        attend, q, pool, bt, lens = make_latent_problem(seed=5)
+        q = q.at[4].set(q[0])
+        out = attend(q, pool, bt, lens.at[4].set(7).at[0].set(7),
+                     v_width=self.V, kernel="interpret", pages_per_block=2)
+        np.testing.assert_allclose(
+            np.asarray(out[4]), np.asarray(out[0]), atol=1e-6, rtol=1e-6)
+
+    def test_bf16_pool_runs_in_its_own_type(self):
+        attend, q, pool, bt, lens = make_latent_problem(
+            seed=6, dtype=jnp.bfloat16)
+        out = attend(q, pool, bt, lens, v_width=self.V, kernel="interpret",
+                     sm_scale=0.3)
+        assert out.dtype == jnp.bfloat16
+        want = dense_latent(q, pool, bt, lens, self.V, 0.3)
+        live = np.asarray(bt)[:, 0] != NULL_PAGE
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32)[live], want[live], atol=0.06, rtol=0.06)
+
+    @pytest.mark.parametrize("bad, message", [
+        (dict(v_width=21), "v_width"), (dict(v_width=0), "v_width")])
+    def test_a_value_wider_than_the_latent_is_refused(self, bad, message):
+        attend, q, pool, bt, lens = make_latent_problem()
+        with pytest.raises(ValueError, match=message):
+            attend(q, pool, bt, lens, kernel="xla", **bad)
+
+    def test_a_pool_with_a_head_axis_is_refused(self):
+        attend, q, pool, bt, lens = make_latent_problem()
+        with pytest.raises(ValueError, match="latent pool"):
+            attend(q, pool[:, :, None], bt, lens, v_width=self.V)
+
+    def test_block_size_is_looked_up_under_the_pools_width(self, monkeypatch):
+        from distributed_pytorch_tpu.ops import paged_attention as pa
+
+        seen = []
+        real = pa.block_pages
+
+        def spy(*args):
+            seen.append(args)
+            return real(*args)
+
+        monkeypatch.setattr(pa, "block_pages", spy)
+        attend, q, pool, bt, lens = make_latent_problem(seed=8)
+        attend(q, pool, bt, lens, v_width=self.V, kernel="interpret")
+        assert seen and seen[0][:3] == (6, 4, 20)
+
+    def test_the_shipped_block_is_by_kind_and_width(self):
+        """A (kind, head size) entry of the shipped table goes before its
+        kind's: the latent pool's 640 lanes have a block of their own."""
+        kind = "TPU v5 lite"
+        assert fa.lookup_paged_with_tier(
+            16384, 16, 640, "bfloat16", device_kind=kind
+        ) == (fa.PAGED_DEFAULT_TABLE[("tpu v5 lite", 640)], "shipped_table")
+        assert fa.lookup_paged_with_tier(
+            4096, 16, 128, "bfloat16", device_kind=kind
+        ) == (fa.PAGED_DEFAULT_TABLE["tpu v5 lite"], "shipped_table")

@@ -204,3 +204,99 @@ def test_a_share_or_a_top_k_the_router_does_not_have_is_refused(
     layer = RoutedExperts(E, top_k, F, D, held=held)
     with pytest.raises(ValueError, match=message):
         layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 2, D)))
+
+
+# ------------------------------------- the second gating rule (deepseek_v2)
+#
+# ``gating="top_k_of_softmax"``: a softmax over ALL the scores, then the top
+# k probabilities as they are, against ``benchmarks/reference/deepseek_v2.py``.
+
+import deepseek_toy  # noqa: E402
+
+DS_CFG = dict(
+    deepseek_toy.TOY, hidden_size=D, moe_intermediate_size=F,
+    n_routed_experts=E, num_experts_per_tok=K)
+
+
+def ds_share(held):
+    lo, hi = held
+    return dict(DS_CFG, n_routed_experts=hi - lo, experts_held=[lo, hi],
+                n_routed_experts_published=E)
+
+
+def ds_layer_out(w, x, held=None):
+    lo, hi = held or (0, E)
+    layer = RoutedExperts(E, K, F, D, held=held, gating="top_k_of_softmax")
+    params = {"router_kernel": w["router"], "in_kernel": w["we_in"][lo:hi],
+              "out_kernel": w["we_out"][lo:hi]}
+    return np.asarray(layer.apply({"params": params}, x[None])[0])
+
+
+def ds_reference_out(w, x, held=None):
+    lo, hi = held or (0, E)
+    cut = dict(w, we_in=w["we_in"][lo:hi], we_out=w["we_out"][lo:hi])
+    return np.asarray(deepseek_toy.reference.routed_experts(
+        x, cut, cfg=ds_share((lo, hi)), einsum=jnp.einsum)[0])
+
+
+def test_the_default_gating_is_the_rule_it_always_was():
+    scores = jnp.asarray(np.random.default_rng(0).standard_normal((5, E)))
+    for got, want in zip(moe.route(scores, K), route_top_k(scores, K)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert RoutedExperts(E, K, F, D).gating == "softmax_of_top_k"
+
+
+def test_unrenormalised_gates_are_the_softmax_over_all_scores():
+    scores = jnp.asarray(
+        np.random.default_rng(1).standard_normal((7, E)), jnp.float32)
+    gates, experts = moe.route(scores, K, "top_k_of_softmax")
+    probs = np.asarray(jax.nn.softmax(scores, axis=-1))
+    np.testing.assert_allclose(
+        np.asarray(gates), np.take_along_axis(probs, np.asarray(experts), -1),
+        rtol=1e-6)
+    assert (np.asarray(gates).sum(-1) < 0.999).all()  # NOT renormalised
+    # The same experts as the default rule chooses: softmax is monotone.
+    np.testing.assert_array_equal(
+        np.asarray(experts), np.asarray(route_top_k(scores, K)[1]))
+
+
+def test_an_unknown_gating_rule_is_refused():
+    with pytest.raises(ValueError, match="unknown gating rule"):
+        ds = RoutedExperts(E, K, F, D, gating="sigmoid")
+        ds.init(jax.random.PRNGKey(0), jnp.zeros((1, 2, D)))
+
+
+def test_unrenormalised_layer_matches_the_reference():
+    w, x = weights(21), tokens_in(40, seed=22)
+    assert np.abs(ds_layer_out(w, x) - ds_reference_out(w, x)).max() < TOL
+    # ... and is not the default rule's layer.
+    assert np.abs(ds_layer_out(w, x) - layer_out(w, x)).max() > 0.01
+
+
+def test_the_eight_shares_and_the_shared_expert_counted_once_are_the_whole_layer():
+    """What the eight chips of the deployment compute of one layer: each its
+    one expert's part (the toy's eighth), and all of them the shared experts
+    alike. The eight parts and ONE shared output add up to the uncut
+    reference's feed-forward; each part is also the reference's, given the
+    same share."""
+    from distributed_pytorch_tpu.models.transformer import MLPBlock
+
+    w, x = weights(23), tokens_in(24, seed=24)
+    rng = np.random.default_rng(25)
+    f = lambda *s: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)  # noqa: E731
+    ws = {"ws_gate": f(D, 2 * F), "ws_up": f(D, 2 * F), "ws_down": f(2 * F, D)}
+    shared = np.asarray(
+        MLPBlock(2 * F, D, kind="gated_silu", use_bias=False).apply(
+            {"params": {"gate": {"kernel": ws["ws_gate"]},
+                        "up": {"kernel": ws["ws_up"]},
+                        "down": {"kernel": ws["ws_down"]}}}, x))
+    parts = []
+    for e in range(E):
+        got = ds_layer_out(w, x, (e, e + 1))
+        assert np.abs(got - ds_reference_out(w, x, (e, e + 1))).max() < TOL
+        parts.append(got)
+    ref = deepseek_toy.reference
+    whole = ds_reference_out(w, x) + np.asarray(ref.gated_mlp(
+        x, ws["ws_gate"], ws["ws_up"], ws["ws_down"], jnp.einsum))
+    assert np.abs(sum(parts) + shared - whole).max() < TOL
+    assert sum(np.abs(p).max() > 1e-3 for p in parts) == E

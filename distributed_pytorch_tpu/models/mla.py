@@ -54,7 +54,9 @@ row of the call holds (a trip count read from ``seq_lens``): a 64-token piece
 over 8,800 cached tokens gathers 9,216 of them, not the 16,384 the table could
 hold. The single-token decode step of a model built with ``paged_kernel``
 goes to ``ops/paged_attention.py``'s latent kernel instead
-(``attention._latent_decode_step``).
+(``attention._latent_decode_step``), which copies the pages that several rows
+hold in common (a document the prefix trie handed to each asker) once for all
+of them.
 """
 
 from __future__ import annotations
@@ -194,8 +196,11 @@ class LatentAttention(nn.Module):
     ``page_size`` it is a step against the paged latent pool and must be told
     ``block_tables [S, pages_per_seq]`` and ``seq_lens [S]`` (and, for a padded
     prefill piece, ``valid_lens [S]``), as :class:`models.transformer.Attention`
-    is. A contiguous decode cache is not built: latent layers are served
-    through pages."""
+    is. ``row_groups`` is for the decode kernel alone: which rows share their
+    tables' first pages (``ops/paged_attention.py`` ``shared_prefix_groups``),
+    where the program has worked that out once for all its layers; a call
+    that says nothing lets the kernel's wrapper work it out. A contiguous
+    decode cache is not built: latent layers are served through pages."""
 
     n_heads: int
     d_model: int
@@ -230,6 +235,7 @@ class LatentAttention(nn.Module):
         block_tables: Optional[jnp.ndarray] = None,
         seq_lens: Optional[jnp.ndarray] = None,
         valid_lens: Optional[jnp.ndarray] = None,
+        row_groups=None,
     ) -> jnp.ndarray:
         h, r = self.n_heads, self.kv_lora_rank
         dn, dr, dv = (
@@ -372,6 +378,7 @@ class LatentAttention(nn.Module):
             mixed = paged_latent_attention(
                 q_row, pool.value, block_tables, seq_lens, v_width=r,
                 kernel=self.paged_kernel, sm_scale=scale,
+                row_groups=row_groups,
             )
         else:
             held = seq_lens + (t_step if valid_lens is None else valid_lens)

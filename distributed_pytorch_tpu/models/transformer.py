@@ -709,6 +709,7 @@ class TransformerBlock(nn.Module):
         seq_lens: Optional[jnp.ndarray] = None,
         state_slots: Optional[jnp.ndarray] = None,
         valid_lens: Optional[jnp.ndarray] = None,
+        row_groups=None,
     ) -> jnp.ndarray:
         def drop(y):
             # Active only when a "dropout" rng is supplied (the train step
@@ -753,7 +754,7 @@ class TransformerBlock(nn.Module):
                 decode=self.decode, page_size=self.page_size,
                 num_pages=self.num_pages, paged_kernel=self.paged_kernel,
                 name="mla", **dict(self.latent),
-            )(normed, **paged_kw, **piece_kw)
+            )(normed, row_groups=row_groups, **paged_kw, **piece_kw)
         elif self.mixer == "attention":
             mixed = Attention(
                 self.n_heads, self.d_model, self.dtype, self.causal,
@@ -1019,6 +1020,7 @@ class TransformerLM(nn.Module):
         seq_lens: Optional[jnp.ndarray] = None,
         state_slots: Optional[jnp.ndarray] = None,
         valid_lens: Optional[jnp.ndarray] = None,
+        row_groups=None,
     ) -> jnp.ndarray:
         types = self.layer_types
         if types is not None and len(types) != self.n_layers:
@@ -1085,6 +1087,8 @@ class TransformerLM(nn.Module):
             paged_kw["state_slots"] = state_slots
         if valid_lens is not None:
             paged_kw["valid_lens"] = valid_lens
+        if row_groups is not None:  # latent layers' (models/mla.py)
+            paged_kw["row_groups"] = row_groups
         block_kw = dict(
             norm=self.norm, norm_eps=self.norm_eps, mlp=self.mlp,
             use_bias=self.use_bias, rope=self.rope,

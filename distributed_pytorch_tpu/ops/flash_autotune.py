@@ -94,8 +94,14 @@ PAGED_DEFAULT_TABLE = {
     # A (device kind, head size) entry goes before its kind's: the latent
     # kernel's pool rows of 640 (``models/mla.py``: 16 query heads on ONE
     # cached vector a token, bf16 pages of 16 tokens, 32 slots of 1,024 pages
-    # at 4,400-13,200 tokens a row; my chip run, PR 35): 16 pages a block take
-    # 941 us a call, 32 703, 64 622, **128 596**, 256 707.
+    # at 4,400-13,200 tokens a row). With no row sharing a page (my chip run,
+    # PR 35, that PR's kernel): 16 pages a block take 941 us a call, 32 703,
+    # 64 622, **128 596**, 256 707. With tables that share as a prefix
+    # cache's do (``--sharers 2``: 16 documents, two rows each, tails of
+    # 100-500 tokens; my chip run, PR 36, the kernel that copies a shared
+    # document once): 16 pages 702 us, 32 498, 64 425, **128 372**, 256 390;
+    # a walk's last block is copied at an eighth of the block or more, so a
+    # large block no longer taxes a short tail.
     ("tpu v5 lite", 640): 128,
 }
 
@@ -333,6 +339,7 @@ def autotune_paged(
     force: bool = False,
     interpret: Optional[bool] = None,
     latent: int = 0,
+    sharers: int = 1,
 ) -> int:
     """Measured sweep for the paged decode kernel: times every legal
     pages-per-block over a synthetic decode batch whose rows hold about
@@ -348,7 +355,13 @@ def autotune_paged(
     page: ONE pool ``[num_pages, page, head_dim]`` with no head axis,
     ``kv_heads * group`` query heads, a value of the row's first ``latent``
     numbers); the winner is cached under the pool's width as its
-    ``head_dim``, which is where ``block_pages`` looks it up."""
+    ``head_dim``, which is where ``block_pages`` looks it up.
+
+    ``sharers > 1`` builds tables that share as a prefix cache's do
+    (:func:`shared_tables`): every ``sharers`` rows hold one document of
+    about ``context`` tokens under the same physical pages, and each a tail
+    of its own of 100-500 tokens. The latent kernel copies a shared document
+    once for its rows, so its block is swept on what it runs."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -388,16 +401,23 @@ def autotune_paged(
         pool = (num_pages, page_size, kv_heads, head_dim)
         pools = (jnp.asarray(rng.standard_normal(pool), dtype),
                  jnp.asarray(rng.standard_normal(pool), dtype))
-    tables = jnp.asarray(
-        1 + np.arange(slots * pages_per_seq).reshape(slots, pages_per_seq),
-        jnp.int32,
-    )
-    if context is None:
-        held = np.full((slots,), kv_len)
-    else:  # ragged, as a batch is: between half and one and a half times it
-        held = rng.integers(
-            max(1, context // 2), min(kv_len, context * 3 // 2) + 1, slots
+    if sharers > 1:
+        tables, held = shared_tables(
+            rng, slots, pages_per_seq, page_size, context or kv_len // 2,
+            sharers,
         )
+    else:
+        tables = 1 + np.arange(slots * pages_per_seq).reshape(
+            slots, pages_per_seq
+        )
+        if context is None:
+            held = np.full((slots,), kv_len)
+        else:  # ragged, as a batch is: half to one and a half times it
+            held = rng.integers(
+                max(1, context // 2), min(kv_len, context * 3 // 2) + 1,
+                slots,
+            )
+    tables = jnp.asarray(tables, jnp.int32)
     lens = jnp.asarray(held - 1, jnp.int32)
 
     best, best_dt = None, float("inf")
@@ -452,6 +472,45 @@ def autotune_paged(
     disk[key] = (best, best * page_size)
     _save_disk_cache(disk)
     return best
+
+
+def shared_tables(
+    rng, slots: int, pages_per_seq: int, page_size: int, context: int,
+    sharers: int,
+):
+    """``(tables [slots, pages_per_seq], held [slots])`` of a decode batch
+    whose rows share documents as a prefix cache hands them out: ``slots /
+    sharers`` documents of half to one and a half times ``context`` tokens,
+    row ``r`` asking of document ``r % documents`` (every document is
+    visited before any repeats), under the document's physical pages as far
+    as they are whole, then pages of its own for the document's partial page
+    (copied on write) and a tail of 100-500 tokens (an eighth of the table
+    at most)."""
+    import numpy as np
+
+    documents = max(1, slots // sharers)
+    capacity = pages_per_seq * page_size
+    tail = min(500, capacity // 8)  # a toy table has toy tails
+    doc_tokens = rng.integers(
+        max(page_size, context // 2),
+        min(capacity - tail, context * 3 // 2) + 1, documents,
+    )
+    tails = rng.integers(max(1, tail // 5), tail + 1, slots)
+    tables = np.zeros((slots, pages_per_seq), np.int64)
+    held = np.zeros((slots,), np.int64)
+    free = 1  # page 0 is the reserved null page
+    whole = []
+    for tokens in doc_tokens:
+        whole.append(np.arange(free, free + tokens // page_size))
+        free += len(whole[-1])
+    for r in range(slots):
+        pages = whole[r % documents]
+        held[r] = doc_tokens[r % documents] + tails[r]
+        own = -(-held[r] // page_size) - len(pages)
+        tables[r, : len(pages)] = pages
+        tables[r, len(pages) : len(pages) + own] = np.arange(free, free + own)
+        free += own
+    return tables, held
 
 
 def _device_kind() -> str:
@@ -619,6 +678,12 @@ def main(argv=None) -> None:
         help="paged sweep: the latent kernel (one pool of --head_dims wide "
         "rows, no head axis) with a value of the row's first LATENT numbers",
     )
+    parser.add_argument(
+        "--sharers", default=1, type=int,
+        help="paged sweep: rows that hold each document of --context tokens "
+        "under the same physical pages, with a tail of 100-500 tokens each "
+        "(1: no row shares a page)",
+    )
     args = parser.parse_args(argv)
     kind = _device_kind()
     if kind == "unknown":
@@ -639,6 +704,7 @@ def main(argv=None) -> None:
                         kv_heads=args.kv_heads, group=args.group,
                         dtype=args.dtype, context=args.context,
                         verbose=True, force=args.force, latent=args.latent,
+                        sharers=args.sharers,
                     )
                     key = _paged_key(kind, kv_len, page, d, args.dtype)
                     if key in _failed_sweeps:

@@ -73,10 +73,12 @@ both sides as ``decode_kv_tokens_fetched`` / ``_visible``).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -546,48 +548,329 @@ def paged_attention(
 # The second kind of page (``models/mla.py``): ONE pool a layer of ``[num_pages,
 # page, W]``, a token's ``[c | k_pe]`` with no head axis. Every query head
 # reads the same cached vector, and its first ``v_width`` numbers are also the
-# value: the block is copied once and used twice.
+# value: the block is copied once and used twice. And every ROW that holds the
+# same physical pages (a document the prefix trie handed to several askers)
+# reads the same vectors: rows whose tables begin alike are served as a group,
+# the pages they share copied once for all of them.
+
+#: Rows one shared walk serves: its products' M is this many rows' heads (4 x
+#: 16 = 64 of the MXU's 128 rows at the published sizes, beside 5.2 MB of
+#: page buffers in VMEM). A wider group is split.
+GROUP_ROWS = 4
+
+#: Page buffers of the latent kernel: the one a block is computed from and
+#: the ones the blocks after it are copied into meanwhile.
+LATENT_BUFFERS = 3
+
+
+def block_widths(npb: int) -> tuple:
+    """The sizes, in pages, at which the latent kernel copies and computes a
+    block: the whole block of ``npb`` and its half, quarter and eighth. Only
+    a walk's last block holds fewer than ``npb`` pages; it takes the smallest
+    of these that holds what is left, so a tail of a few pages does not pay
+    for a whole block."""
+    return tuple(sorted({max(1, npb >> k) for k in range(4)}))
+
+
+def _fitting(n, widths: tuple):
+    """The smallest of ``widths`` that holds ``n`` pages (``n`` at most the
+    largest), for an array or a traced scalar alike."""
+    where = np.where if isinstance(n, (np.ndarray, np.generic)) else jnp.where
+    out = widths[-1]
+    for width in widths[-2::-1]:
+        out = where(n <= width, width, out)
+    return out
+
+
+def pages_walked(n_pages, npb: int):
+    """Pages the latent kernel copies for a walk over ``n_pages`` of a table:
+    whole blocks of ``npb``, and what is left at the width that holds it
+    (:func:`block_widths`)."""
+    rest = n_pages % npb
+    return n_pages - rest + (rest > 0) * _fitting(rest, block_widths(npb))
+
+
+def shared_prefix_groups(
+    tables, positions, page: int, min_pages: int, max_rows: int = GROUP_ROWS
+):
+    """Which decode rows the latent kernel serves together: ``(leader,
+    shared)``, both ``[S]`` int32. Row ``r`` belongs to the group of row
+    ``leader[r]`` (itself: a row served alone), and the group's rows hold the
+    same physical page at each of their tables' first ``shared[r]`` logical
+    indices: the kernel copies those once, at the leader's turn, and each
+    member walks only what follows them. Decided from the staged ``tables [S,
+    pages_per_seq]`` and ``positions [S]`` alone, NumPy or traced:
+
+    * rows whose tables start at the same physical page are one class (a row
+      out of the dispatch, whose table starts at the null page, is in none),
+      cut in slot order into groups of at most ``max_rows``; a group's leader
+      is its first row, so it runs before its members;
+    * a member shares the pages on which its table agrees with the leader's,
+      up to the first index where they part (a last page copied on write has
+      equal contents under another number: not shared), and no page at or past
+      the one that holds its ``pos``: every shared key lies below every
+      member's position and the shared walk needs no causal mask. The group
+      shares what all its members do;
+    * a group of one, or one that shares fewer than ``min_pages`` (a block:
+      not worth a walk of its own), stays rows served alone: ``leader[r] ==
+      r``, ``shared[r] == 0``, the walk of a row in a dispatch with no
+      sharing.
+
+    The kernel's operand and the tracer's count of what was fetched
+    (:func:`latent_tokens_fetched`) both come from here."""
+    xp = np if isinstance(tables, np.ndarray) else jnp
+    positions = xp.asarray(positions)
+    slots, width = tables.shape
+    rows = xp.arange(slots)
+    live = tables[:, 0] != NULL_PAGE
+    same = (
+        (tables[:, :1] == tables[None, :, 0]) & live[:, None] & live[None, :]
+    )
+    rank = (same & (rows[None, :] < rows[:, None])).sum(axis=1)
+    first = rank - rank % max_rows
+    leader = xp.where(
+        live, xp.argmax(same & (rank[None, :] == first[:, None]), axis=1),
+        rows,
+    )
+    agree = tables == tables[leader]
+    common = xp.where(agree.all(axis=1), width, xp.argmin(agree, axis=1))
+    common = xp.minimum(common, xp.minimum(positions // page, width - 1))
+    together = leader[:, None] == leader[None, :]
+    shared = xp.where(together, common[None, :], width).min(axis=1)
+    grouped = (together.sum(axis=1) > 1) & (shared >= min_pages)
+    return (
+        xp.where(grouped, leader, rows).astype(xp.int32),
+        xp.where(grouped, shared, 0).astype(xp.int32),
+    )
+
+
+def latent_tokens_fetched(
+    positions, leader, shared, page: int, npb: int, pages_per_seq: int
+) -> int:
+    """Key positions the latent kernel copies out of the pool for a decode
+    dispatch grouped as :func:`shared_prefix_groups` says (NumPy): a group's
+    shared pages once, at its leader's turn, and every row's own pages from
+    there to the one that holds its ``pos``, each walk in whole blocks and a
+    last one of its own width (:func:`pages_walked`)."""
+    last = np.minimum(positions // page, pages_per_seq - 1)
+    leads = (leader == np.arange(len(leader))) & (shared > 0)
+    pages = pages_walked(last + 1 - shared, npb).sum() + pages_walked(
+        shared[leads], npb
+    ).sum()
+    return int(pages) * page
 
 
 def _latent_decode_kernel(
-    bt_ref, lens_ref, q_ref, pool_hbm, o_ref, buf, sems, first_buf, m_scr,
-    l_scr, acc_scr, *, npb, v_width, sm_scale,
+    bt_ref, lens_ref, lead_ref, shared_ref, q_ref, pool_hbm, o_ref, buf,
+    sems, stream, members, qg_scr, mg_scr, lg_scr, accg_scr, m_st, l_st,
+    acc_st, *, npb, v_width, sm_scale,
 ):
     """One slot (grid step) of the latent flash-decode kernel: the frame of
-    :func:`_decode_kernel` (walk the row's own blocks and only those, two
-    buffers, the next block's or the next live row's first block's copies
-    started before this block is computed, online softmax in fp32 scratch)
-    over ONE pool. ``q_ref`` is the row's ``[H, W]`` absorbed query
-    (``[q~ | q_pe]``); a block's ``[bkv, W]`` latent is the key of all ``H``
-    heads at once (a matmul whose M is ``H``), and its first ``v_width``
-    columns, already in VMEM, are the value. The products take the pool's
-    type as it is stored (bf16 on the chip: the MXU's own) and accumulate in
-    float32."""
+    :func:`_decode_kernel` (walk the row's own blocks and only those, the
+    pool's pages copied into VMEM buffers ahead of the block that is being
+    computed, online softmax in fp32 scratch) over ONE pool, for rows grouped
+    as :func:`shared_prefix_groups` says (``lead_ref``, ``shared_ref``).
+
+    A step is one or two WALKS over a stretch of the row's table, one block
+    body for both. The row's own walk: from its ``shared`` pages on to the
+    page that holds ``pos``, under the causal mask, ``q_ref[b]``'s ``[H, W]``
+    absorbed query (``[q~ | q_pe]``) against a block's ``[keys, W]`` latent (a
+    matmul whose M is ``H``), whose first ``v_width`` columns, already in
+    VMEM, are the value. A row served alone shares nothing and starts from an
+    empty softmax state: PR 35's row. Before it, at a group's LEADER (its
+    first row) only, the shared walk: the group's ``shared`` leading pages,
+    all visible to every member, copied once and scored against all the
+    members' heads stacked (M = two rows' heads, or ``GROUP_ROWS``' where the
+    group is wider); each member's running max, denominator and accumulator
+    are kept in ``m_st / l_st / acc_st`` (a place a row), and the member's
+    own walk, at its own turn, carries on from them: the same online softmax
+    over the same keys, shared pages first.
+
+    The copies run ahead of the arithmetic as a STREAM of blocks in the order
+    they are computed (live rows in slot order; at a leader the shared walk,
+    then the own one), ``stream`` (SMEM) holding where the copying stands:
+    before a block is computed, the blocks after it are started into the
+    buffers that are free (``buf``'s first size, less the one in use), so a
+    short block's few copies do not leave the copy engine idle under the
+    long block before it. Only a walk's last block is short: it is copied
+    and computed at the narrowest of :func:`block_widths` that holds it
+    (pages past its live ones repeat the last live one and die in the mask,
+    so nothing unowned or stale is ever read), and only it needs a mask. The
+    products take the pool's type as it is stored (bf16 on the chip: the
+    MXU's own) and accumulate in float32."""
     b = pl.program_id(0)
     slots, pages_per_seq = bt_ref.shape
     h, w = q_ref.shape[1:]
-    page = buf.shape[2]
-    bkv = npb * page
+    n_buf, _, page, _ = buf.shape
+    group_rows = members.shape[0]
+    widths = block_widths(npb)
+    chunk = math.gcd(*widths)  # copies a turn of the rolled copy loop
+    ROW, OWN, BLOCK, STARTED, DONE = range(5)  # ``stream``'s places
 
     def is_live(row):
         return bt_ref[row, 0] != NULL_PAGE
+
+    def leads(row):
+        return jnp.logical_and(lead_ref[row] == row, shared_ref[row] > 0)
+
+    def own_pages(row):
+        last = jnp.minimum(lens_ref[row] // page, pages_per_seq - 1)
+        return last + 1 - shared_ref[row]
+
+    def next_live(row):
+        """The first live row at or after ``row``; ``slots`` if none."""
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(
+                r < slots,
+                jnp.logical_not(is_live(jnp.minimum(r, slots - 1))),
+            ),
+            lambda r: r + 1, row,
+        )
+
+    def opens_alone(row):
+        """1 where ``row``'s step opens with its own walk, 0 where with a
+        shared one (``row`` may be ``slots``: nothing follows)."""
+        return 1 - leads(jnp.minimum(row, slots - 1)).astype(jnp.int32)
 
     def page_copy(phys, slot, n):
         return pltpu.make_async_copy(
             pool_hbm.at[phys], buf.at[slot, n], sems.at[slot]
         )
 
-    def start(row, blk, slot):
-        # A logical page past the row's last live one clamps to that one, as
-        # in ``_decode_kernel``: a page the row does not own is never fetched.
-        last = jnp.minimum(lens_ref[row] // page, pages_per_seq - 1)
-        for n in range(npb):
-            phys = bt_ref[row, jnp.minimum(blk * npb + n, last)]
-            page_copy(phys, slot, n).start()
+    def turns(left):
+        """Turns of the copy loop for a block of a walk with ``left`` pages
+        to go: start and wait have to agree on it."""
+        return _fitting(jnp.minimum(left, npb), widths) // chunk
 
-    def wait(slot):
-        for n in range(npb):
-            page_copy(0, slot, n).wait()
+    def start_next():
+        """Start the copies of the stream's next block into the buffer that
+        is its turn, and move the stream on. A page past the block's live
+        ones clamps to the last of them, as in ``_decode_kernel``: a page
+        the row does not own is never fetched."""
+        row, own, j = stream[ROW], stream[OWN], stream[BLOCK]
+        ahead = shared_ref[row]
+        p0 = jnp.where(own == 1, ahead, 0) + j * npb
+        left = jnp.where(own == 1, own_pages(row), ahead) - j * npb
+        live = jnp.minimum(left, npb)
+        slot = stream[STARTED] % n_buf
+
+        def turn(c, carry):
+            for i in range(chunk):
+                n = c * chunk + i
+                phys = bt_ref[row, p0 + jnp.minimum(n, live - 1)]
+                page_copy(phys, slot, n).start()
+            return carry
+
+        jax.lax.fori_loop(0, turns(left), turn, 0)
+        stream[STARTED] = stream[STARTED] + 1
+
+        @pl.when(left > npb)
+        def _same_walk():
+            stream[BLOCK] = j + 1
+
+        @pl.when(jnp.logical_and(left <= npb, own == 0))
+        def _own_walk():
+            stream[OWN] = 1
+            stream[BLOCK] = 0
+
+        @pl.when(jnp.logical_and(left <= npb, own == 1))
+        def _next_row():
+            after = next_live(row + 1)
+            stream[ROW] = after
+            stream[OWN] = opens_alone(after)
+            stream[BLOCK] = 0
+
+    def wait(left, slot):
+        def turn(c, carry):
+            for i in range(chunk):
+                page_copy(0, slot, c * chunk + i).wait()
+            return carry
+
+        jax.lax.fori_loop(0, turns(left), turn, 0)
+
+    def attend(slot, pages, q, key0, limit, m_ref, l_ref, acc_ref):
+        """The block body: ``q [M, W]`` against the first ``pages`` pages of
+        buffer ``slot``, whose first key stands at position ``key0``; keys
+        past ``limit`` are masked (``None``: all are visible)."""
+        k = buf[slot, 0:pages].reshape(pages * page, w)  # as stored
+        s_blk = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale  # [M, keys]
+        if limit is not None:
+            kpos = key0 + jax.lax.broadcasted_iota(
+                jnp.int32, (1, pages * page), 1
+            )
+            # Every walked block's first key is visible, so the running max
+            # stays finite and no exp(NEG_INF - NEG_INF) row can arise.
+            s_blk = jnp.where(kpos <= limit, s_blk, NEG_INF)
+        m_prev = m_ref[:, :1]
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
+        p = jnp.exp(s_blk - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+        # The value is the key's own first columns: no second copy.
+        pv = jax.lax.dot_general(
+            p.astype(k.dtype), k[:, :v_width], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [M, v_width]
+        acc_ref[:] = acc_ref[:] * correction + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    def walk(p0, n_pages, q, limit, m_ref, l_ref, acc_ref):
+        """Carry the softmax state in ``m_ref / l_ref / acc_ref`` over pages
+        ``[p0, p0 + n_pages)`` of this row's table: the stream's next
+        ``cdiv(n_pages, npb)`` blocks."""
+
+        def block(j, carry):
+            for _ in range(n_buf - 1):
+
+                @pl.when(jnp.logical_and(
+                    stream[STARTED] < stream[DONE] + n_buf,
+                    stream[ROW] < slots))
+                def _run_ahead():
+                    start_next()
+
+            slot = stream[DONE] % n_buf
+            left = n_pages - j * npb
+            wait(left, slot)
+            key0 = (p0 + j * npb) * page
+
+            @pl.when(left > npb)
+            def _whole():
+                attend(slot, npb, q, key0, None, m_ref, l_ref, acc_ref)
+
+            fits = _fitting(left, widths)
+            for pages in widths:
+
+                @pl.when(jnp.logical_and(left <= npb, fits == pages))
+                def _last(pages=pages):
+                    attend(slot, pages, q, key0, limit, m_ref, l_ref, acc_ref)
+
+            stream[DONE] = stream[DONE] + 1
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(n_pages, npb), block, 0)
+
+    def empty(m_ref, l_ref, acc_ref):
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(b == 0)
+    def _open_the_stream():
+        first = next_live(0)
+        stream[ROW] = first
+        stream[OWN] = opens_alone(first)
+        stream[BLOCK] = 0
+        stream[STARTED] = 0
+        stream[DONE] = 0
+
+        @pl.when(first < slots)
+        def _first_block():
+            start_next()
 
     @pl.when(jnp.logical_not(is_live(b)))
     def _absent():
@@ -595,63 +878,56 @@ def _latent_decode_kernel(
 
     @pl.when(is_live(b))
     def _row():
-        pos = lens_ref[b]
-        n_blocks = jnp.minimum(pos // bkv + 1, pl.cdiv(pages_per_seq, npb))
-        prefetched = jnp.logical_and(b > 0, is_live(jnp.maximum(b - 1, 0)))
-        slot0 = jnp.where(prefetched, first_buf[0], 0)
+        shared = shared_ref[b]
 
-        @pl.when(jnp.logical_not(prefetched))
-        def _first():
-            start(b, 0, 0)
+        @pl.when(leads(b))
+        def _shared_walk():
+            # The group's rows in slot order: this one, then the rows after
+            # it that name it.
+            def find(r, count):
+                mine = lead_ref[r] == b
 
-        next_row = jnp.minimum(b + 1, slots - 1)
-        next_live = jnp.logical_and(b + 1 < slots, is_live(next_row))
+                @pl.when(mine)
+                def _member():
+                    members[count] = r
 
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+                return count + mine.astype(jnp.int32)
 
-        def block(j, carry):
-            slot = (slot0 + j) % 2
+            count = jax.lax.fori_loop(b, slots, find, 0)
+            for k in range(group_rows):
+                # A place no member takes computes on this row's query again;
+                # nobody reads what it gives.
+                r = jnp.where(k < count, members[k], b)
+                qg_scr[k * h : (k + 1) * h] = q_ref[r].astype(qg_scr.dtype)
+            # A pair rides at M = 2 H; only a wider group pays for more.
+            sizes = sorted({min(2, group_rows), group_rows})
+            for lo, rows in zip([0] + sizes, sizes):
+                m = rows * h
+                state = (mg_scr.at[0:m], lg_scr.at[0:m], accg_scr.at[0:m])
 
-            @pl.when(j + 1 < n_blocks)
-            def _next_block():
-                start(b, j + 1, 1 - slot)
+                @pl.when(jnp.logical_and(lo < count, count <= rows))
+                def _stacked(m=m, state=state):
+                    empty(*state)
+                    walk(0, shared, qg_scr[0:m], shared * page - 1, *state)
 
-            @pl.when(jnp.logical_and(j + 1 == n_blocks, next_live))
-            def _next_row():
-                start(next_row, 0, 1 - slot)
-                first_buf[0] = 1 - slot
+            for k in range(group_rows):
 
-            wait(slot)
-            k = buf[slot].reshape(bkv, w)  # as stored
-            q = q_ref[0].astype(k.dtype)  # [H, W]
-            s_blk = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * sm_scale  # [H, bkv]
-            kpos = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
-            s_blk = jnp.where(kpos <= pos, s_blk, NEG_INF)
-            m_prev = m_scr[:, :1]
-            l_prev = l_scr[:, :1]
-            m_new = jnp.maximum(
-                m_prev, jnp.max(s_blk, axis=-1, keepdims=True)
-            )
-            p = jnp.exp(s_blk - m_new)
-            correction = jnp.exp(m_prev - m_new)
-            l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-            # The value is the key's own first columns: no second copy.
-            pv = jax.lax.dot_general(
-                p.astype(k.dtype), k[:, :v_width], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [H, v_width]
-            acc_scr[:] = acc_scr[:] * correction + pv
-            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-            return carry
+                @pl.when(k < count)
+                def _hand_over(k=k):
+                    r = members[k]
+                    m_st[r] = mg_scr[k * h : (k + 1) * h]
+                    l_st[r] = lg_scr[k * h : (k + 1) * h]
+                    acc_st[r] = accg_scr[k * h : (k + 1) * h]
 
-        jax.lax.fori_loop(0, n_blocks, block, 0)
-        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+        @pl.when(shared == 0)
+        def _alone():
+            empty(m_st.at[b], l_st.at[b], acc_st.at[b])
+
+        walk(
+            shared, own_pages(b), q_ref[b].astype(buf.dtype), lens_ref[b],
+            m_st.at[b], l_st.at[b], acc_st.at[b],
+        )
+        o_ref[0] = (acc_st[b] / l_st[b][:, :1]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -659,37 +935,61 @@ def _latent_decode_kernel(
     static_argnames=("pages_per_block", "interpret", "sm_scale", "v_width"),
 )
 def _latent_flash(
-    q3, pool, block_tables, seq_lens, *, pages_per_block, interpret,
-    sm_scale, v_width,
+    q3, pool, block_tables, seq_lens, leader, shared, *, pages_per_block,
+    interpret, sm_scale, v_width,
 ):
     """The latent kernel's ``pallas_call`` for ``q3`` [S, H, W]: jitted and
     named for :func:`_paged_flash`'s reasons (one trace for a model's layers;
     a device trace shows ``attention._latent_decode_step`` whoever calls
     it). The pool stays in HBM and a page is copied as the ``[page, W]`` rows
-    it is stored as."""
+    it is stored as; the tables, the lengths and the rows' grouping ride as
+    scalar prefetch; every row's query is held in VMEM for the whole call (a
+    leader needs its members')."""
     s, h, w = q3.shape
     page = pool.shape[1]
     npb = int(pages_per_block)
+    m_rows = GROUP_ROWS * h
 
     def row_spec(shape):
         return pl.BlockSpec(
-            shape, lambda b, bt, lens: (b,) + (0,) * (len(shape) - 1),
+            shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1),
             memory_space=pltpu.VMEM,
         )
 
+    scratch = [
+        pltpu.VMEM((LATENT_BUFFERS, npb, page, w), pool.dtype),
+        pltpu.SemaphoreType.DMA((LATENT_BUFFERS,)),
+        pltpu.SMEM((5,), jnp.int32),  # where the stream of copies stands
+        pltpu.SMEM((GROUP_ROWS,), jnp.int32),  # a group's rows
+        pltpu.VMEM((m_rows, w), pool.dtype),  # a group's queries
+        pltpu.VMEM((m_rows, 128), jnp.float32),  # its running max m
+        pltpu.VMEM((m_rows, 128), jnp.float32),  # its denominator l
+        pltpu.VMEM((m_rows, v_width), jnp.float32),  # its accumulator
+        pltpu.VMEM((s, h, 128), jnp.float32),  # every row's m
+        pltpu.VMEM((s, h, 128), jnp.float32),  # every row's l
+        pltpu.VMEM((s, h, v_width), jnp.float32),  # every row's accumulator
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(s,),
-        in_specs=[row_spec((1, h, w)), pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=row_spec((1, h, v_width)),
-        scratch_shapes=[
-            pltpu.VMEM((2, npb, page, w), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SMEM((1,), jnp.int32),  # buffer of the row's first block
-            pltpu.VMEM((h, 128), jnp.float32),  # running max m
-            pltpu.VMEM((h, 128), jnp.float32),  # denominator l
-            pltpu.VMEM((h, v_width), jnp.float32),  # output accumulator
+        in_specs=[
+            pl.BlockSpec(
+                (s, h, w), lambda b, *_: (0, 0, 0), memory_space=pltpu.VMEM
+            ),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
+        out_specs=row_spec((1, h, v_width)),
+        scratch_shapes=scratch,
+    )
+    # What the kernel holds in VMEM: the page buffers and a group's queries,
+    # the softmax states, the rows' queries (twice: the pipeline's two
+    # buffers) and a block's scores and weights.
+    item = jnp.dtype(pool.dtype).itemsize
+    held = (
+        (LATENT_BUFFERS * npb * page + m_rows) * w * item
+        + 4 * (m_rows + s * h) * (256 + v_width)
+        + 2 * s * h * w * jnp.dtype(q3.dtype).itemsize
+        + 3 * m_rows * npb * page * 4
     )
     return pl.pallas_call(
         functools.partial(
@@ -699,11 +999,15 @@ def _latent_flash(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, v_width), q3.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(16 << 20, int(1.5 * held)),
         ),
         interpret=interpret,
         name="attention._latent_decode_step",
-    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q3, pool)
+    )(
+        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+        leader.astype(jnp.int32), shared.astype(jnp.int32), q3, pool,
+    )
 
 
 def paged_latent_attention(
@@ -716,6 +1020,7 @@ def paged_latent_attention(
     kernel="auto",
     pages_per_block: Optional[int] = None,
     sm_scale: Optional[float] = None,
+    row_groups=None,
 ) -> jnp.ndarray:
     """Paged attention of ``q`` [S, T_step, H, W] over ONE latent pool
     ``[num_pages, page, W]``: every head's key at a position is the pool's
@@ -724,7 +1029,10 @@ def paged_latent_attention(
     single-token step dispatches per ``kernel``, everything else takes
     :func:`paged_attention_reference`'s latent case; ``sm_scale`` ``None`` is
     ``W ** -0.5``; :func:`block_pages` looks the block up under the pool's
-    width."""
+    width. ``row_groups`` is :func:`shared_prefix_groups`' ``(leader,
+    shared)`` for these tables and lengths where the caller has worked it out
+    (a decode program does, once for all its layers: :func:`latent_row_groups`);
+    ``None`` works it out here. Only the kernel reads it."""
     s, t_step, h, w = q.shape
     if pool.ndim != 3 or pool.shape[2] != w:
         raise ValueError(
@@ -738,13 +1046,16 @@ def paged_latent_attention(
             q, pool, None, block_tables, seq_lens, sm_scale=sm_scale,
             v_width=v_width,
         )
+    npb = block_pages(
+        block_tables.shape[1], pool.shape[1], w, pool.dtype, pages_per_block
+    )
+    if row_groups is None:
+        row_groups = shared_prefix_groups(
+            block_tables, seq_lens, pool.shape[1], npb
+        )
     out3 = _latent_flash(
-        q.reshape(s, h, w), pool, block_tables, seq_lens,
-        pages_per_block=block_pages(
-            block_tables.shape[1], pool.shape[1], w, pool.dtype,
-            pages_per_block,
-        ),
-        interpret=(mode == "interpret"),
+        q.reshape(s, h, w), pool, block_tables, seq_lens, *row_groups,
+        pages_per_block=npb, interpret=(mode == "interpret"),
         sm_scale=float(w**-0.5 if sm_scale is None else sm_scale),
         v_width=int(v_width),
     )

@@ -165,6 +165,19 @@ pages). On every ``step`` slice that dispatched a decode the tracer carries,
 beside ``decode_kv_tokens_fetched`` / ``_visible``,
 ``decode_kv_tokens_distinct``: the key positions the rows could see with each
 PHYSICAL page counted once, so rows that share a document count it once.
+
+The latent decode kernel serves rows whose tables begin with the same
+physical pages (askers of one cached document) as a GROUP: the shared pages
+are copied out of the pool once for all of them. Who shares what is read off
+the staged tables and positions by ONE rule, ``ops/paged_attention.py``'s
+``shared_prefix_groups``: the decode program applies it once and tells its
+latent layers (:meth:`InferenceEngine._decode_state_kw`; no staged operand, no
+readback), and the host applies it to its own copies for the counters:
+``stats()["decode_rows_grouped"]`` (rows served in a group of two or more)
+and, on the ``step`` slice, ``decode_rows_grouped`` beside a
+``decode_kv_tokens_fetched`` that counts a group's shared pages once, so
+fetched / visible falls below 1 where rows share. Nothing switches it on or
+off: a dispatch whose tables share nothing groups nobody.
 """
 
 from __future__ import annotations
@@ -508,8 +521,12 @@ class InferenceEngine:
         # this many tokens; 0 is the gather path, which reads every slot's
         # whole table.
         self._kv_block_tokens = 0
-        self._decode_positions: List[np.ndarray] = []
-        self._decode_tables: List[np.ndarray] = []
+        # A traced step's decode dispatches: the rows' positions, their
+        # tables and, where the latent kernel groups rows, their grouping.
+        self._decode_dispatches: List[tuple] = []
+        # Rows the latent decode kernel served in a group of two or more
+        # (their tables begin with the same pages, read once for the group).
+        self.decode_rows_grouped = 0
         # Size the paged pool from abstract shapes only (eval_shape traces
         # init without running it); token length 1 — pool shapes depend only
         # on (num_pages, page_size), never on the init input.
@@ -1098,7 +1115,7 @@ class InferenceEngine:
             last_logits, cache, *routing = self._forward(
                 params, cache, tok[:, None],
                 block_tables=tables, seq_lens=lens,
-                **self._decode_state_kw(tables),
+                **self._decode_state_kw(tables, lens),
             )
             nxt = row_sample(last_logits, temps, fold_row_keys(keys), bias)
             return (nxt, cache, *routing)
@@ -1173,16 +1190,34 @@ class InferenceEngine:
         """Recurrent state held by the requests that own a slot."""
         return self.state_bytes_per_slot * len(self.scheduler.running)
 
-    def _decode_state_kw(self, tables) -> dict:
+    def _decode_state_kw(self, tables, lens) -> dict:
         """What the batched decode program tells a model with recurrent or
         routed layers (nothing to any other): row ``r`` carries slot ``r``'s
         state, and is routed to experts and counted, iff the row is in the
         dispatched group, which is iff its staged block table is not the
-        zeroed one."""
-        if not (self.state_layers or self.routed_layers):
-            return {}
-        rows = jnp.arange(self.max_slots, dtype=jnp.int32)
-        return {"state_slots": jnp.where(tables[:, 0] != NULL_PAGE, rows, -1)}
+        zeroed one. And a model with latent layers whose decode kernel is
+        on: which rows' tables begin with the same physical pages
+        (:meth:`_row_groups`), worked out here once for all its layers."""
+        kw = {}
+        if self.state_layers or self.routed_layers:
+            rows = jnp.arange(self.max_slots, dtype=jnp.int32)
+            kw["state_slots"] = jnp.where(tables[:, 0] != NULL_PAGE, rows, -1)
+        if self.latent_layers and self._kv_block_tokens:
+            kw["row_groups"] = self._row_groups(tables, lens)
+        return kw
+
+    def _row_groups(self, tables, lens):
+        """``shared_prefix_groups`` of a decode dispatch's tables and
+        positions, traced in the decode program (the kernel's operand) or on
+        the host's staged copies (the counters): one rule for both."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            shared_prefix_groups,
+        )
+
+        return shared_prefix_groups(
+            tables, lens, self.page_size,
+            self._kv_block_tokens // self.page_size,
+        )
 
     def _note_state_reset(self, slot: int, req: Request) -> None:
         """A row at position 0 is about to run: its state starts from
@@ -1880,9 +1915,19 @@ class InferenceEngine:
                     bias.fill(0.0)
                 bias[slot] = row
         self._stage_row_keys(slots)
+        groups = None
+        rows = sorted(slots)
+        if self.latent_layers and self._kv_block_tokens:
+            # The rule the program applies to the same tables (absent rows
+            # are in no group, so the live rows, in slot order, group alike).
+            groups = self._row_groups(
+                self._stage_tables[rows], self._stage_lens[rows]
+            )
+            self.decode_rows_grouped += int((groups[1] > 0).sum())
         if self.tracer.enabled:
-            self._decode_positions.append(self._stage_lens[slots])
-            self._decode_tables.append(self._stage_tables[slots])
+            self._decode_dispatches.append(
+                (self._stage_lens[rows], self._stage_tables[rows], groups)
+            )
         staged = (
             self._stage_tokens.nbytes
             + self._stage_use_prev.nbytes
@@ -1945,32 +1990,37 @@ class InferenceEngine:
             # no wait. This step's wait for the next trace (or a flush).
             self._flush_routing()
             self._routing_due = (self.tracer.step_index, self.routing_counts)
-        if self._decode_positions:
+        if self._decode_dispatches:
             # Over every decode dispatch of the step: the key positions its
             # rows could see, and the ones read for them.
             from distributed_pytorch_tpu.ops.paged_attention import (
                 kv_tokens_walked,
+                latent_tokens_fetched,
             )
 
             block = self._kv_block_tokens
             whole = self.max_slots * self.pages_per_seq * self.page_size
-            fetched = visible = 0
-            for pos in self._decode_positions:
+            fetched = visible = distinct = grouped = 0
+            for pos, tables, groups in self._decode_dispatches:
                 visible += int(pos.sum()) + len(pos)
-                fetched += (
-                    int(kv_tokens_walked(pos, block).sum()) if block
-                    else whole
-                )
+                distinct += self._distinct_kv_tokens(pos, tables)
+                if groups is not None:
+                    # The latent kernel: a group's shared pages once.
+                    fetched += latent_tokens_fetched(
+                        pos, *groups, self.page_size,
+                        block // self.page_size, self.pages_per_seq,
+                    )
+                    grouped += int((groups[1] > 0).sum())
+                elif block:
+                    fetched += int(kv_tokens_walked(pos, block).sum())
+                else:
+                    fetched += whole
             extra["decode_kv_tokens_fetched"] = fetched
             extra["decode_kv_tokens_visible"] = visible
-            extra["decode_kv_tokens_distinct"] = sum(
-                self._distinct_kv_tokens(pos, tables)
-                for pos, tables in zip(
-                    self._decode_positions, self._decode_tables
-                )
-            )
-            self._decode_positions.clear()
-            self._decode_tables.clear()
+            extra["decode_kv_tokens_distinct"] = distinct
+            if self.latent_layers:
+                extra["decode_rows_grouped"] = grouped
+            self._decode_dispatches.clear()
         self.tracer.end_step(
             decode_rows=len(plan.decode_slots),
             prefill_programs=len(plan.prefill),
@@ -2718,6 +2768,7 @@ class InferenceEngine:
         out["prefill_programs"] = self.prefill_programs
         out["prefill_tokens"] = self.prefill_tokens
         out["prefill_width"] = self.prefill_width
+        out["decode_rows_grouped"] = self.decode_rows_grouped
         out["page_bytes_per_token_layer"] = self.page_bytes_per_token_layer
         out["pages_free"] = self.allocator.num_free
         out["pages_allocated"] = self.allocator.num_allocated

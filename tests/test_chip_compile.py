@@ -134,9 +134,12 @@ CASES = {
 LATENT = dict(slots=32, heads=16, pages_per_seq=1024, num_pages=10241)
 
 
-def latent_case(chip, width, pages_per_block=None):
+def latent_case(chip, width, pages_per_block=None, groups_told=False):
     """``benchmarks/configs/deepseek-v2-lite.json``'s engine: 32 slots of
-    1,024 pages out of 10,241, 16 heads on one latent a token."""
+    1,024 pages out of 10,241, 16 heads on one latent a token. The kernel
+    serves rows that share pages as a group, at M = ``GROUP_ROWS`` rows'
+    heads at the widest, whoever works the groups out: its own wrapper, or
+    (``groups_told``) the caller, as the engine's decode program does."""
     from distributed_pytorch_tpu.ops.paged_attention import (
         paged_latent_attention,
     )
@@ -149,11 +152,13 @@ def latent_case(chip, width, pages_per_block=None):
         pages_per_block=pages_per_block or PAGED_DEFAULT_TABLE[KIND],
         sm_scale=0.114721,
     )
+    rows = arg((LATENT["slots"],), jnp.int32)
+    told = {"row_groups": (rows, rows)} if groups_told else {}
     return jax.jit(fn).lower(
         arg((LATENT["slots"], 1, LATENT["heads"], width), jnp.bfloat16),
         arg((LATENT["num_pages"], PAGE, width), jnp.bfloat16),
-        arg((LATENT["slots"], LATENT["pages_per_seq"]), jnp.int32),
-        arg((LATENT["slots"],), jnp.int32),
+        arg((LATENT["slots"], LATENT["pages_per_seq"]), jnp.int32), rows,
+        **told,
     )
 
 
@@ -191,7 +196,16 @@ def latent_program(chip, t_step, layers=2):
     pages_per_seq = engine["max_seq_len"] // engine["page_size"]
 
     def run(params, cache, tokens, tables, lens, valid):
-        kw = {} if t_step == 1 else {"valid_lens": valid}
+        kw = {"valid_lens": valid}
+        if t_step == 1:  # as the engine's decode program tells the model
+            from distributed_pytorch_tpu.ops.paged_attention import (
+                block_pages, shared_prefix_groups,
+            )
+
+            page = engine["page_size"]
+            kw = {"row_groups": shared_prefix_groups(
+                tables, lens, page,
+                block_pages(pages_per_seq, page, 640, jnp.bfloat16))}
         logits, updated = decode_model.apply(
             {"params": params, "cache": cache}, tokens, block_tables=tables,
             seq_lens=lens, state_slots=jnp.arange(rows, dtype=jnp.int32),
@@ -210,6 +224,9 @@ CASES.update({
     **{f"latent-640-npb{npb}": functools.partial(
         latent_case, width=640, pages_per_block=npb)
        for npb in (16, 64, PAGED_DEFAULT_TABLE[(KIND, 640)])},
+    "latent-640-groups-told": functools.partial(
+        latent_case, width=640,
+        pages_per_block=PAGED_DEFAULT_TABLE[(KIND, 640)], groups_told=True),
     "latent-cell-decode": functools.partial(latent_program, t_step=1),
     "latent-cell-prefill-64": functools.partial(latent_program, t_step=64),
     "latent-cell-prefill-512": functools.partial(latent_program, t_step=512),

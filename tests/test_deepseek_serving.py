@@ -201,10 +201,67 @@ def test_the_distinct_token_counter_on_steps_with_known_tables(program):
         visible, distinct = (a["decode_kv_tokens_visible"],
                              a["decode_kv_tokens_distinct"])
         assert visible - distinct == 24  # the document, counted once
-        assert a["decode_kv_tokens_fetched"] >= visible
+        # ... and read once: the kernel serves the two as a group.
+        assert a["decode_kv_tokens_fetched"] < visible
+        assert a["decode_rows_grouped"] == 2
     admits = [e["args"] for e in tracer.events if e["name"] == "admit"]
     assert [(a["prompt_tokens"], a["cached_tokens"]) for a in admits] == [
         (27, 24), (27, 24)]
+
+
+def test_askers_of_one_document_are_served_as_a_group(program):
+    """Three questions on one cached document (6 whole pages) decode side by
+    side: through the kernel (interpreted) the rows are served as a group,
+    the document's pages read once a step, and the greedy tokens are the
+    gather path's; a fourth asker, alone, is a row served alone."""
+    from distributed_pytorch_tpu.ops.paged_attention import pages_walked
+
+    document = tokens(24, seed=50)
+    askers = [document + tokens(3 + i, seed=51 + i) for i in range(3)]
+    late = document + tokens(5, seed=55)
+
+    def run(kernel):
+        tracer = Tracer()
+        engine = engine_for(program, tracer=tracer, paged_kernel=kernel)
+        serve(engine, [document + tokens(2, seed=49)], new_tokens=2)
+        tracer.events.clear()
+        served = serve(engine, askers, new_tokens=6)
+        together = engine.stats()["decode_rows_grouped"]
+        served += serve(engine, [late], new_tokens=4)
+        return served, together, engine.stats(), step_args(tracer)
+
+    served, together, stats, steps = run("interpret")
+    gathered, _, gather_stats, gather_steps = run("xla")
+    assert served == gathered
+    for prompt, generated in zip(askers + [late], served):
+        assert served_gap(program, prompt, generated).max() < LOGIT_TOL
+    side_by_side = [a for a in steps if a["decode_rows"] == 3]
+    assert side_by_side
+    for a in side_by_side:
+        assert a["decode_rows_grouped"] == 3
+        # The document once and a page or two a row, where the rows see
+        # the document three times over.
+        assert a["decode_kv_tokens_fetched"] < a["decode_kv_tokens_visible"]
+        assert a["decode_kv_tokens_fetched"] >= a["decode_kv_tokens_distinct"]
+    assert together == sum(
+        a.get("decode_rows_grouped", 0) for a in steps) > 0
+    # The late asker has no sharer: no group, and its row walks its own
+    # table from the start, whole blocks of 2 pages and a last one of 1 or 2
+    # (PR 35's kernel counted that last block whole).
+    lone = [a for a in steps if a["decode_rows"] == 1][-3:]
+    assert stats["decode_rows_grouped"] == together
+    for a in lone:
+        assert a["decode_rows_grouped"] == 0
+        pos = a["decode_kv_tokens_visible"] - 1
+        assert a["decode_kv_tokens_fetched"] == 4 * int(
+            pages_walked(np.asarray(pos // 4 + 1), 2))
+        assert a["decode_kv_tokens_fetched"] >= a["decode_kv_tokens_visible"]
+    # The gather path reads every slot's whole table, and groups nobody.
+    assert gather_stats["decode_rows_grouped"] == 0
+    assert all(a["decode_rows_grouped"] == 0 for a in gather_steps
+               if "decode_rows_grouped" in a)
+    assert all(a["decode_kv_tokens_fetched"] == 3 * 64 for a in gather_steps
+               if "decode_kv_tokens_fetched" in a)
 
 
 def test_distinct_tokens_of_a_handmade_dispatch(program):

@@ -903,3 +903,276 @@ class TestLatentPagedAttention:
         assert fa.lookup_paged_with_tier(
             4096, 16, 128, "bfloat16", device_kind=kind
         ) == (fa.PAGED_DEFAULT_TABLE["tpu v5 lite"], "shipped_table")
+
+
+# ------------------------------------------------- rows that share their pages
+#
+# The latent kernel serves rows whose tables begin with the same physical
+# pages as a group (``shared_prefix_groups``): the shared pages are copied
+# once, at the group's first row, and every member walks only what follows.
+
+
+def shared_latent_problem(rows, *, page=4, pages_per_seq=16, seed=0, h=16,
+                          w=20, copied=()):
+    """A dispatch built row by row: ``rows[i]`` is ``None`` (a row out of
+    the dispatch) or ``(document, shared_pages, pos)``: the row's table
+    begins with the first ``shared_pages`` pages of that document and goes
+    on with pages of its own up to the one that holds ``pos``. Physical
+    pages are dealt in a shuffled order. ``copied`` names ``(row, other,
+    index)``: ``row``'s page at ``index`` gets the contents of ``other``'s
+    there (a page copied on write: equal numbers under another number)."""
+    rng = np.random.default_rng(seed)
+    needed = sum(r[2] // page + 1 for r in rows if r) + 1
+    deal = iter(1 + rng.permutation(needed + 64))
+    documents = {}
+    tables = np.zeros((len(rows), pages_per_seq), np.int32)
+    lens = np.zeros((len(rows),), np.int32)
+    for i, row in enumerate(rows):
+        if row is None:
+            continue
+        doc, shared, pos = row
+        pages = documents.setdefault(doc, [])
+        while len(pages) < shared:
+            pages.append(next(deal))
+        held = pos // page + 1
+        assert shared < held <= pages_per_seq
+        tables[i, :shared] = pages[:shared]
+        tables[i, shared:held] = [next(deal) for _ in range(held - shared)]
+        lens[i] = pos
+    pool = rng.standard_normal((int(tables.max()) + 1, page, w))
+    for row, other, index in copied:
+        pool[tables[row, index]] = pool[tables[other, index]]
+    q = rng.standard_normal((len(rows), 1, h, w))
+    return (jnp.asarray(q, jnp.float32), jnp.asarray(pool, jnp.float32),
+            jnp.asarray(tables), jnp.asarray(lens))
+
+
+def doc_rows(*positions, document=0, shared=6):
+    return [None if p is None else (document, shared, p) for p in positions]
+
+
+SHARING = {
+    # name: (rows, pages a block, the (leader, shared) it must come to)
+    "one-row-alone": (doc_rows(27), 2, ([0], [0])),
+    "a-pair": (doc_rows(27, 33), 2, ([0, 0], [6, 6])),
+    "three": (doc_rows(25, 30, 41), 2, ([0, 0, 0], [6] * 3)),
+    "five-is-four-and-one": (
+        doc_rows(25, 26, 27, 28, 29), 2, ([0, 0, 0, 0, 4], [6] * 4 + [0])),
+    "nine-is-four-four-one": (
+        doc_rows(*range(25, 34)), 2,
+        ([0] * 4 + [4] * 4 + [8], [6] * 8 + [0])),
+    "shared-length-no-multiple-of-the-block": (
+        doc_rows(24, 37, shared=5), 4, ([0, 0], [5, 5])),
+    "shared-length-under-an-eighth-more": (
+        doc_rows(40, 45, shared=9), 8, ([0, 0], [9, 9])),
+    "tails-of-one-token-to-several-blocks": (
+        doc_rows(24, 25, 47, 63), 2, ([0] * 4, [6] * 4)),
+    "an-absent-row-between-members": (
+        doc_rows(27, None, 30, None, 29), 2,
+        ([0, 1, 0, 3, 0], [6, 0, 6, 0, 6])),
+    "two-documents-interleaved": (
+        doc_rows(27, None, 30) + doc_rows(22, 41, document=1, shared=4)
+        + doc_rows(35), 2,
+        ([0, 1, 0, 3, 3, 0], [6, 0, 6, 4, 4, 6])),
+    "a-leader-behind-its-members": (
+        # The first row stands in the document's fourth page: the group
+        # shares the three whole pages below its position.
+        [(0, 3, 13), (0, 6, 30), (0, 6, 33)], 2, ([0] * 3, [3] * 3)),
+    "a-member-that-parts-early": (
+        [(0, 6, 30), (0, 3, 20), (0, 6, 33)], 2, ([0] * 3, [3] * 3)),
+    "under-a-block-stays-alone": (
+        doc_rows(9, 14, shared=1), 2, ([0, 1], [0, 0])),
+    "no-sharing": (
+        [(0, 0, 27), (1, 0, 3), None, (2, 0, 0), (3, 0, 63), (4, 0, 32)], 2,
+        ([0, 1, 2, 3, 4, 5], [0] * 6)),
+}
+
+
+class TestLatentRowsThatShare:
+    V = 16
+
+    def attend(self, q, pool, bt, lens, npb, **kw):
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            paged_latent_attention,
+        )
+
+        return paged_latent_attention(
+            q, pool, bt, lens, v_width=self.V, kernel="interpret",
+            pages_per_block=npb, sm_scale=0.3, **kw)
+
+    @pytest.mark.parametrize("name", sorted(SHARING))
+    def test_grouped_rows_match_the_reference(self, name):
+        """Every row of every grouping attends to exactly its own keys: the
+        kernel against the gather path, and the grouping it worked out
+        against the one written down."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            shared_prefix_groups,
+        )
+
+        rows, npb, (leader, shared) = SHARING[name]
+        q, pool, bt, lens = shared_latent_problem(rows, seed=len(name))
+        got = shared_prefix_groups(
+            np.asarray(bt), np.asarray(lens), pool.shape[1], npb)
+        assert [list(map(int, g)) for g in got] == [leader, shared]
+        ref = paged_attention_reference(
+            q, pool, None, bt, lens, v_width=self.V, sm_scale=0.3)
+        assert_rows_match(self.attend(q, pool, bt, lens, npb), ref, bt)
+
+    @pytest.mark.parametrize("npb", [1, 3, 4, 16])
+    def test_every_block_size_serves_the_same_groups(self, npb):
+        rows = doc_rows(27, None, 30) + doc_rows(
+            22, 41, document=1, shared=4) + doc_rows(35)
+        q, pool, bt, lens = shared_latent_problem(rows, seed=npb)
+        ref = paged_attention_reference(
+            q, pool, None, bt, lens, v_width=self.V, sm_scale=0.3)
+        assert_rows_match(self.attend(q, pool, bt, lens, npb), ref, bt)
+
+    def test_a_page_copied_on_write_is_not_shared(self):
+        """Rows 0 and 1 hold the document's five whole pages and, at index
+        5, a copy each of its partial page: equal contents under two
+        physical numbers. The tables part there, so the group shares five
+        pages, and both rows read their own copy."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            shared_prefix_groups,
+        )
+
+        rows = doc_rows(25, 29, shared=5)
+        q, pool, bt, lens = shared_latent_problem(
+            rows, seed=11, copied=[(1, 0, 5)])
+        assert (np.asarray(pool[bt[0, 5]]) == np.asarray(pool[bt[1, 5]])).all()
+        assert bt[0, 5] != bt[1, 5]
+        leader, shared = shared_prefix_groups(
+            np.asarray(bt), np.asarray(lens), 4, 2)
+        assert list(shared) == [5, 5] and list(leader) == [0, 0]
+        ref = paged_attention_reference(
+            q, pool, None, bt, lens, v_width=self.V, sm_scale=0.3)
+        assert_rows_match(self.attend(q, pool, bt, lens, 2), ref, bt)
+        # The copy is read where it stands: poisoning row 1's changes row 1.
+        poisoned = pool.at[bt[1, 5]].set(7.0)
+        out = self.attend(q, poisoned, bt, lens, 2)
+        ref_p = paged_attention_reference(
+            q, poisoned, None, bt, lens, v_width=self.V, sm_scale=0.3)
+        assert_rows_match(out, ref_p, bt)
+        assert (np.asarray(out[0]) == np.asarray(
+            self.attend(q, pool, bt, lens, 2)[0])).all()
+
+    @pytest.mark.parametrize("npb", [2, 4])
+    def test_a_dispatch_with_no_sharing_gives_what_the_parent_kernel_gave(
+            self, npb):
+        """Every group a single row: the walk PR 35's kernel made (kept in
+        ``parent_latent_kernel.py``), bar the width of each row's last
+        block."""
+        from parent_latent_kernel import parent_latent_attention
+
+        rows, _, _ = SHARING["no-sharing"]
+        q, pool, bt, lens = shared_latent_problem(rows, seed=npb)
+        want = parent_latent_attention(
+            q, pool, bt, lens, v_width=self.V, pages_per_block=npb,
+            sm_scale=0.3)
+        assert_rows_match(self.attend(q, pool, bt, lens, npb), want, bt)
+
+    def test_groups_told_are_groups_worked_out(self):
+        """A caller that names the groups (the decode program, once for its
+        layers) and one that says nothing get the same bits; groups told
+        wrongly as rows alone still attend to the right keys."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            shared_prefix_groups,
+        )
+
+        rows, npb, _ = SHARING["two-documents-interleaved"]
+        q, pool, bt, lens = shared_latent_problem(rows, seed=3)
+        out = self.attend(q, pool, bt, lens, npb)
+        groups = shared_prefix_groups(bt, lens, pool.shape[1], npb)
+        assert isinstance(groups[0], jax.Array)
+        told = self.attend(q, pool, bt, lens, npb, row_groups=groups)
+        assert (np.asarray(told) == np.asarray(out)).all()
+        alone = (jnp.arange(len(rows), dtype=jnp.int32),
+                 jnp.zeros((len(rows),), jnp.int32))
+        ref = paged_attention_reference(
+            q, pool, None, bt, lens, v_width=self.V, sm_scale=0.3)
+        assert_rows_match(
+            self.attend(q, pool, bt, lens, npb, row_groups=alone), ref, bt)
+
+    def test_traced_and_numpy_groupings_agree(self):
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            shared_prefix_groups,
+        )
+
+        for name, (rows, npb, want) in SHARING.items():
+            _, pool, bt, lens = shared_latent_problem(rows, seed=1)
+            traced = jax.jit(
+                lambda t, p: shared_prefix_groups(t, p, 4, npb))(bt, lens)
+            assert [list(map(int, g)) for g in traced] == list(want), name
+
+    def test_bf16_pool_groups_in_its_own_type(self):
+        rows = doc_rows(27, 30, 41)
+        q, pool, bt, lens = shared_latent_problem(rows, seed=5)
+        q, pool = q.astype(jnp.bfloat16), pool.astype(jnp.bfloat16)
+        out = self.attend(q, pool, bt, lens, 2)
+        assert out.dtype == jnp.bfloat16
+        want = dense_latent(q, pool, bt, lens, self.V, 0.3)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), want, atol=0.06, rtol=0.06)
+
+
+GROUPINGS = {
+    # name: (tables, positions, min_pages, max_rows) -> (leader, shared,
+    # tokens the kernel fetches at 2 pages a block of 4 tokens)
+    "a-pair-shares-its-whole-pages": (
+        [[3, 5, 9, 2], [3, 5, 9, 7]], [13, 15], 2, 4,
+        # 3 shared pages once (a block of 2 and one of 1), a page a row
+        ([0, 0], [3, 3], (3 + 1 + 1) * 4)),
+    "tables-part-where-the-contents-do": (
+        [[3, 5, 9, 2], [3, 5, 8, 7]], [13, 15], 2, 4,
+        ([0, 0], [2, 2], (2 + 2 + 2) * 4)),
+    "no-page-at-or-past-a-members-position": (
+        # row 1 stands in the page both hold at index 2: not shared
+        [[3, 5, 9, 2], [3, 5, 9, 0]], [13, 10], 2, 4,
+        ([0, 0], [2, 2], (2 + 2 + 1) * 4)),
+    "the-null-page-starts-no-group": (
+        [[0, 0, 0, 0], [3, 5, 9, 2], [0, 0, 0, 0], [3, 5, 9, 7]],
+        [0, 13, 0, 15], 2, 4,
+        ([0, 1, 2, 1], [0, 3, 0, 3], (1 + 1 + 3 + 1 + 1) * 4)),
+    "under-min-pages-rows-stay-alone": (
+        [[3, 5, 9, 2], [3, 5, 8, 7]], [13, 15], 3, 4,
+        ([0, 1], [0, 0], (4 + 4) * 4)),
+    "a-group-wider-than-max-rows-is-split": (
+        [[3, 5, 9, 10 + i] for i in range(5)], [13] * 5, 2, 2,
+        # two shared walks of 3 pages, four rows' own page, a row alone
+        ([0, 0, 2, 2, 4], [3, 3, 3, 3, 0], (3 + 3 + 1 * 4 + 4) * 4)),
+    "two-documents": (
+        [[3, 5, 2, 0], [4, 6, 7, 0], [3, 5, 8, 0], [4, 6, 9, 0]],
+        [9, 9, 10, 11], 2, 4,
+        ([0, 1, 0, 1], [2, 2, 2, 2], (2 + 2 + 1 * 4) * 4)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUPINGS))
+def test_the_grouping_rule_on_handmade_tables(name):
+    """``shared_prefix_groups`` alone, and ``latent_tokens_fetched`` of what
+    it returns: that IS the engine's ``decode_kv_tokens_fetched``."""
+    from distributed_pytorch_tpu.ops.paged_attention import (
+        latent_tokens_fetched,
+        shared_prefix_groups,
+    )
+
+    tables, positions, min_pages, max_rows, want = GROUPINGS[name]
+    tables = np.asarray(tables, np.int32)
+    positions = np.asarray(positions, np.int32)
+    leader, shared = shared_prefix_groups(
+        tables, positions, 4, min_pages, max_rows)
+    assert leader.dtype == shared.dtype == np.int32
+    assert (list(leader), list(shared)) == want[:2]
+    assert latent_tokens_fetched(
+        positions, leader, shared, 4, 2, tables.shape[1]) == want[2]
+
+
+@pytest.mark.parametrize("pages, npb, walked", [
+    (0, 128, 0), (1, 128, 16), (16, 128, 16), (17, 128, 32), (33, 128, 64),
+    (65, 128, 128), (128, 128, 128), (129, 128, 144), (557, 128, 576),
+    (5, 2, 5), (3, 1, 3), (7, 6, 7), (8, 6, 9),
+])
+def test_pages_a_walk_copies(pages, npb, walked):
+    from distributed_pytorch_tpu.ops.paged_attention import pages_walked
+
+    assert int(pages_walked(np.asarray(pages), npb)) == walked

@@ -1,7 +1,7 @@
 """Request lifecycle tracer + engine and trainer step timeline,
 Perfetto-exportable.
 
-Three timelines, one clock:
+Four timelines, one clock:
 
 * **Request spans** — one async span per accepted request, opened at
   ``submit`` and closed at retire, with instant events for every lifecycle
@@ -26,6 +26,23 @@ Three timelines, one clock:
   ``recycle.fence`` after a step, where it waits for an earlier step to end
   before it hands that step's host arrays back to the loader. Both record to
   :func:`process_tracer` unless handed another.
+* **Set-up** — what a process does before its first step, always in
+  :func:`process_tracer` (set-up belongs to the process, not to a step or a
+  request, and an engine's own tracer may be off or emptied), kept in
+  ``Tracer.setup_events``, a list the ring does not reach, and written out
+  first: ``process.start`` (from the process's start as the OS has it to
+  the tracer's making: interpreter and imports; written by
+  :func:`process_tracer`), ``backend.open`` (a backend's opening, with its
+  ``platform``) and one ``compile`` a program JAX compiles (``fun_name``,
+  the parts ``trace_s`` / ``lower_s`` / ``backend_s``, ``cache``
+  ``hit`` / ``miss`` / ``off``, ``retrieval_s`` on a hit, ``written`` where
+  the persistent cache got a new entry; both written by ``obs/xla.py``'s one
+  ``jax.monitoring`` dispatcher, which ``utils/platform.py``
+  ``enable_compile_cache()`` installs), ``engine.init`` > ``engine.init.pools``
+  (``bytes``) and ``engine.build_prefill_programs`` (``programs``,
+  ``widths``; ``serving/engine.py``), ``trainer.init``
+  (``training/trainer.py``; the step's compile lies inside the first
+  ``epoch`` > ``step`` slices by time containment).
 
 Export is Chrome ``trace_event`` JSON (:meth:`Tracer.to_perfetto` /
 :meth:`Tracer.save`) — load it at https://ui.perfetto.dev or
@@ -33,8 +50,10 @@ Export is Chrome ``trace_event`` JSON (:meth:`Tracer.to_perfetto` /
 so they line up under the engine-step track.
 
 **The clock.** ``ts`` is microseconds of ``time.perf_counter`` counted from
-the tracer's construction, so it is NOT a ``jax.profiler`` trace's clock
-(that one counts nanoseconds from the profiler's own start). Every slice
+the tracer's construction (the process's tracer: from the process's start,
+so that set-up is one lane from ``ts`` 0 to the first step), so it is NOT
+a ``jax.profiler`` trace's clock (that one counts nanoseconds from the
+profiler's own start). Every slice
 (``ph: X``) therefore carries the raw ``perf_counter`` nanoseconds of its
 start as ``args["perf_counter_ns"]``: a holder of ``events`` alone places
 any event on ``perf_counter`` with :func:`perf_counter_offset_us`, and
@@ -70,6 +89,12 @@ _PID_ENGINE = 1
 _PID_REQUESTS = 2
 _PID_DOOR = 3
 _PID_ROUTER = 4
+
+# The set-up slices' thread of the engine lane: one lane from the process's
+# start to the first step.
+_TID_SETUP = 2
+#: How many set-up slices a tracer keeps out of its ring's reach.
+SETUP_EVENTS_MAX = 4096
 
 # Span category per lane — async events are matched by (cat, id), so the
 # door's stream #7 and the engine's request #7 never collide.
@@ -193,6 +218,19 @@ class _Phase:
         return False
 
 
+class _SetupPhase(_Phase):
+    """:class:`_Phase` for a set-up slice: written through
+    :meth:`Tracer.setup_slice` when it closes."""
+
+    __slots__ = ()
+
+    def __exit__(self, *exc):
+        tr = self._tracer
+        self.seconds = tr._clock() - self._t0
+        tr.setup_slice(self._name, self._t0, self.seconds, **self._args)
+        return False
+
+
 class Tracer:
     """Recording tracer. Construct one and hand it to
     ``InferenceEngine(..., tracer=tracer)``; after the run,
@@ -212,20 +250,27 @@ class Tracer:
         clock: Callable[[], float] = time.perf_counter,
         wall_clock: Callable[[], float] = time.time,
         max_events: Optional[int] = None,
+        epoch: Optional[float] = None,
     ):
+        """``epoch``: where ``ts`` 0 lies on ``clock``, if not now (the
+        process's tracer counts from the process's start)."""
         self._clock = clock
-        self._epoch = clock()
+        now = clock()
+        self._epoch = now if epoch is None else epoch
         # Wall-clock anchor for the monotonic epoch: ``ts`` microseconds
         # are relative to construction, so two independently-created
         # tracers (door, router, each replica) can only be merged onto one
         # timeline if each records WHEN its zero was. Exported in
         # :meth:`to_perfetto` metadata; `merge_traces` shifts by the epoch
         # deltas. Old saved traces without the field align at 0.0.
-        self.wall_epoch_s: float = wall_clock()
+        self.wall_epoch_s: float = wall_clock() - (now - self._epoch)
         self.events = (
             [] if max_events is None
             else collections.deque(maxlen=max_events)
         )
+        # Set-up slices (module docstring): a list of their own, which the
+        # ring does not reach, written out before ``events``.
+        self.setup_events: list = []
         self.step_index = -1
         self._step_t0 = self._epoch
         self.spans_opened = 0
@@ -283,6 +328,34 @@ class Tracer:
 
     def phase(self, name: str, **args) -> _Phase:
         return _Phase(self, name, args)
+
+    # -------------------------------------------------------------- set-up
+
+    def setup_phase(self, name: str, **args) -> _Phase:
+        """:meth:`phase` for a set-up slice (module docstring)."""
+        return _SetupPhase(self, name, args)
+
+    def setup_slice(
+        self, name: str, start_s: float, seconds: float, **args
+    ) -> None:
+        """A set-up slice whose times are known only afterwards: ``start_s``
+        on the tracer's clock (``perf_counter``), ``seconds`` long. Kept in
+        ``setup_events`` while that is short: a process that compiles
+        without end (a program a request's length) must not grow it without
+        bound, so past ``SETUP_EVENTS_MAX`` the slices go to the ring."""
+        kept = len(self.setup_events) < SETUP_EVENTS_MAX
+        (self.setup_events if kept else self.events).append(
+            {
+                "name": name,
+                "cat": "setup",
+                "ph": "X",
+                "ts": (start_s - self._epoch) * 1e6,
+                "dur": seconds * 1e6,
+                "pid": _PID_ENGINE,
+                "tid": _TID_SETUP,
+                "args": {"perf_counter_ns": int(start_s * 1e9), **args},
+            }
+        )
 
     # ------------------------------------------------------- request spans
 
@@ -458,6 +531,16 @@ class Tracer:
                 "args": {"name": "requests"},
             },
         ]
+        if self.setup_events:
+            meta.append(
+                {
+                    "name": "thread_name",
+                    "ph": "M",
+                    "pid": _PID_ENGINE,
+                    "tid": _TID_SETUP,
+                    "args": {"name": "set-up"},
+                }
+            )
         # Serving-layer lanes are labeled only when populated, so an
         # engine-only trace keeps its historical two-process shape.
         used_pids = {e.get("pid") for e in self.events}
@@ -472,7 +555,7 @@ class Tracer:
                     }
                 )
         return {
-            "traceEvents": meta + list(self.events),
+            "traceEvents": meta + self.setup_events + list(self.events),
             "displayTimeUnit": "ms",
             # Clock anchor for multi-tracer assembly (see `merge_traces`):
             # seconds-since-Unix-epoch at which this tracer's ts=0 was.
@@ -501,16 +584,46 @@ def perf_counter_offset_us(events: Iterable[dict]) -> Optional[float]:
 
 PROCESS_TRACER_EVENTS = 65_536
 _process_tracer: Optional[Tracer] = None
+_IMPORTED_AT = time.perf_counter()
+
+
+def process_start() -> tuple:
+    """``(perf_counter seconds, source)`` of this process's start: from the
+    OS (``/proc/self/stat``'s start time, in clock ticks since boot, against
+    ``CLOCK_BOOTTIME``: to a tick, 10 ms) where it says, else this module's
+    import (``source`` ``"import"``), which leaves the interpreter's own
+    start and the imports before this one out."""
+    try:
+        with open("/proc/self/stat") as f:
+            # The 22nd field; the 2nd, the command, may hold spaces.
+            ticks = int(f.read().rpartition(")")[2].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf(
+            "SC_CLK_TCK"
+        )
+        now = time.perf_counter()
+    except (OSError, ValueError, IndexError, AttributeError):
+        return _IMPORTED_AT, "import"
+    if age < 0.0 or now - age > _IMPORTED_AT:
+        return _IMPORTED_AT, "import"  # a clock the OS does not keep
+    return now - age, "proc_stat"
 
 
 def process_tracer() -> Tracer:
     """The process's one bounded :class:`Tracer`, made on first use: what
-    a ``Trainer`` and a ``ShardedLoader`` record to unless handed another.
-    Its ring holds the last ``PROCESS_TRACER_EVENTS`` events (a training
-    step writes five), so it costs a fixed few tens of MB however long the
-    process runs; ``process_tracer().save(path)`` writes the last steps'
-    phases out."""
+    a ``Trainer`` and a ``ShardedLoader`` record to unless handed another,
+    and where set-up is written (module docstring). Its ring holds the last
+    ``PROCESS_TRACER_EVENTS`` events (a training step writes five), so it
+    costs a fixed few tens of MB however long the process runs;
+    ``process_tracer().save(path)`` writes set-up and the last steps'
+    phases out. Its ``ts`` counts from the process's start, which the
+    ``process.start`` slice, the first it keeps, marks on ``perf_counter``."""
     global _process_tracer
     if _process_tracer is None:
-        _process_tracer = Tracer(max_events=PROCESS_TRACER_EVENTS)
+        started, source = process_start()
+        tracer = Tracer(max_events=PROCESS_TRACER_EVENTS, epoch=started)
+        tracer.setup_slice(
+            "process.start", started, time.perf_counter() - started,
+            source=source,
+        )
+        _process_tracer = tracer
     return _process_tracer

@@ -24,18 +24,29 @@ actually doing with the device:
   the sentinel trips on (a) any ledger signature miss — with the program
   name and offending shapes — and (b) any backend-compile event from
   ``jax.monitoring`` that is NOT attributed to a ledgered compile, which
-  catches compilations the ledger never saw. Each trip bumps a counter
-  (exported as ``engine_recompiles_total``), records a flight-recorder
-  event, drops a tracer instant, and latches an SLO-style firing gauge.
+  catches compilations the ledger never saw and names them: the trip
+  carries JAX's own name of the program and its ``compile`` slice's parts.
+  Each trip bumps a counter (exported as ``engine_recompiles_total``),
+  records a flight-recorder event, drops a tracer instant, and latches an
+  SLO-style firing gauge.
 
-``jax.monitoring`` has no per-listener removal API, so this module
-installs ONE process-wide dispatcher lazily and fans events out to a
-``WeakSet`` of armed sentinels — engines come and go, the listener stays
-inert when the set is empty.
+* **The set-up timeline.** This module holds the process's ONE
+  ``jax.monitoring`` dispatcher (:func:`install_dispatcher`, which
+  ``utils/platform.py`` ``enable_compile_cache()`` calls before an entry
+  point's first compile, and ``RecompileSentinel.arm()`` again). From then
+  on every program JAX compiles writes one ``compile`` slice into
+  :func:`~.tracer.process_tracer`'s set-up list, and every backend JAX
+  opens a ``backend.open`` slice (:class:`_CompileTimeline`,
+  :class:`_BackendOpenTap`). Nothing here runs unless JAX traces, lowers or
+  compiles: a step that compiles nothing fires nothing.
+
+The dispatcher fans the backend event out to a ``WeakSet`` of armed
+sentinels — engines come and go, the listeners stay.
 """
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 import weakref
@@ -43,13 +54,26 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-# Substring of the jax.monitoring event key fired once per real XLA
-# backend compilation (cached jit calls fire nothing).
-_COMPILE_EVENT = "/jax/core/compile/backend_compile"
+from distributed_pytorch_tpu.obs.tracer import process_tracer
+
+# The jax.monitoring keys (jax/_src/dispatch.py, compiler.py,
+# compilation_cache.py in JAX 0.9.0). The three durations carry ``fun_name``
+# and fire on the compiling thread in this order; the backend event fires
+# once a program that reaches XLA OR the persistent cache (a hit fires it
+# too, with the time the load took), never for a jit call whose executable
+# the process already holds.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+# Fired where JAX WRITES an entry, not where it misses: a program under the
+# cache's thresholds misses in every run and never fires it.
+_CACHE_WRITTEN = "/jax/compilation_cache/cache_misses"
 
 # ---------------------------------------------------------------------------
-# Process-wide compile-event dispatcher (jax.monitoring offers global
-# registration only — see module doc).
+# Process-wide dispatcher (jax.monitoring offers global registration only).
 # ---------------------------------------------------------------------------
 
 _armed_sentinels: "weakref.WeakSet" = weakref.WeakSet()
@@ -65,26 +89,162 @@ def _current_attribution() -> Optional[Tuple[str, tuple]]:
     return getattr(_attribution, "scope", None)
 
 
-def _on_monitoring_event(event: str, duration: float, **kwargs) -> None:
-    if _COMPILE_EVENT not in event:
-        return
-    for sentinel in list(_armed_sentinels):
-        sentinel._on_backend_compile(duration)
+class _CompileTimeline(threading.local):
+    """One thread's compile in the making: the parts JAX has reported since
+    the last ``compile`` slice, as ``(fun_name, start, seconds)`` on
+    ``perf_counter`` (an event fires when its part ENDS, so its start is the
+    arrival less the duration), and what the persistent cache said.
+
+    A trace event fires for every jitted function traced: the ones inside
+    another's trace before it (``jnp.add`` inside ``f`` inside ``step``),
+    and the ones a LOWERING traces (the functions it lowers through
+    ``jax``) before the lowering's own event. So the last trace of each
+    name is kept, and a lowering ``jit(f)`` takes ``f``'s if that ended
+    before the lowering began; a trace that no lowering follows
+    (``jax.eval_shape``) is dropped at the next lowering.
+    """
+
+    lower: Optional[Tuple[str, float, float]] = None
+    trace: Optional[Tuple[str, float, float]] = None
+    cache = "off"
+    retrieval_s: Optional[float] = None
+    written = False
+
+    def __init__(self) -> None:
+        self.traces: Dict[str, Tuple[str, float, float]] = {}
+
+    def reset(self) -> None:
+        self.traces.clear()
+        self.trace = self.lower = self.retrieval_s = None
+        self.cache, self.written = "off", False
+
+    def on_duration(self, event: str, seconds: float, fun_name: str) -> None:
+        now = time.perf_counter()
+        part = (fun_name, now - seconds, seconds)
+        if event == _TRACE_EVENT:
+            if len(self.traces) >= 256:  # names nobody lowered
+                self.traces.clear()
+            self.traces[fun_name] = part
+        elif event == _LOWER_EVENT:
+            # ``jit(f)``: the name of the function inside the API's
+            traced = self.traces.get(fun_name[fun_name.find("(") + 1:-1])
+            self.reset()
+            self.lower = part
+            if traced is not None and traced[1] + traced[2] <= part[1]:
+                self.trace = traced
+        elif event == _COMPILE_EVENT:
+            self.finish(part, now)
+
+    def finish(self, backend: Tuple[str, float, float], now: float) -> None:
+        """The backend's part closes the program: write its slice, from the
+        start of its first part to now, and tell the armed sentinels."""
+        fun_name, start, backend_s = backend
+        args = {"fun_name": fun_name, "trace_s": 0.0, "lower_s": 0.0,
+                "backend_s": backend_s, "cache": self.cache}
+        if self.lower is not None and self.lower[0] == fun_name:
+            start, args["lower_s"] = self.lower[1], self.lower[2]
+            if self.trace is not None:
+                start, args["trace_s"] = self.trace[1], self.trace[2]
+        if self.retrieval_s is not None:
+            args["retrieval_s"] = self.retrieval_s
+        if self.written:
+            args["written"] = True
+        self.reset()
+        process_tracer().setup_slice("compile", start, now - start, **args)
+        for sentinel in list(_armed_sentinels):
+            sentinel._on_backend_compile(args)
 
 
-def _install_dispatcher() -> bool:
-    global _dispatcher_installed
+_timeline = _CompileTimeline()
+
+
+def _on_duration_event(event: str, duration: float, **kwargs) -> None:
+    fun_name = kwargs.get("fun_name")
+    if fun_name is not None:
+        _timeline.on_duration(event, duration, fun_name)
+    elif event == _CACHE_RETRIEVAL:
+        _timeline.retrieval_s = duration
+
+
+def _on_event(event: str, **kwargs) -> None:
+    if event == _CACHE_ASKED:
+        # JAX "asks" a cache that has no directory too, and gets nothing.
+        if jax.config.jax_compilation_cache_dir:
+            _timeline.cache = "miss"  # until a hit says otherwise
+    elif event == _CACHE_HIT:
+        _timeline.cache = "hit"
+    elif event == _CACHE_WRITTEN:
+        _timeline.written = True
+
+
+class _BackendOpenTap(logging.Filter):
+    """Writes a ``backend.open`` slice for every backend JAX opens.
+
+    JAX reports a backend's opening only as two DEBUG records of the logger
+    ``jax._src.xla_bridge`` (``"Initializing backend '%s'"``, ``"Backend
+    '%s' initialized"``). So the tap sets that logger to DEBUG and, as a
+    filter ON the logger, reads the two records and then drops every record
+    the logger would not have made at the level it would have without the
+    tap (its own where it had one, else its parent's, read anew for every
+    record): nothing reaches a handler that would not have, and the process
+    prints what it would have printed.
+    """
+
+    LOGGER = "jax._src.xla_bridge"
+    OPENING, OPENED = "Initializing backend '%s'", "Backend '%s' initialized"
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.logger = logging.getLogger(self.LOGGER)
+        self.level_before = self.logger.level
+        self.opening: Dict[str, float] = {}
+        self.logger.addFilter(self)
+        self.logger.setLevel(logging.DEBUG)
+
+    def filter(self, record: logging.LogRecord) -> bool:
+        if record.msg == self.OPENING and record.args:
+            self.opening[str(record.args[0])] = time.perf_counter()
+        elif record.msg == self.OPENED and record.args:
+            platform = str(record.args[0])
+            start = self.opening.pop(platform, None)
+            if start is not None:
+                process_tracer().setup_slice(
+                    "backend.open", start, time.perf_counter() - start,
+                    platform=platform,
+                )
+        if self.logger.level != logging.DEBUG:
+            return True  # somebody set a level of their own since: theirs holds
+        return record.levelno >= (
+            self.level_before or self.logger.parent.getEffectiveLevel()
+        )
+
+
+_backend_tap: Optional[_BackendOpenTap] = None
+
+
+def install_dispatcher() -> bool:
+    """Install the process's one ``jax.monitoring`` dispatcher and the
+    backend tap; once, however often it is called. The set-up timeline
+    starts here: the process's tracer is made now, so that its
+    ``process.start`` slice ends where the program takes over from the
+    interpreter and the imports. False where this JAX has no
+    ``jax.monitoring``."""
+    global _dispatcher_installed, _backend_tap
     with _dispatcher_lock:
         if _dispatcher_installed:
             return True
+        process_tracer()
         try:
             from jax import monitoring
 
             monitoring.register_event_duration_secs_listener(
-                _on_monitoring_event
+                _on_duration_event
             )
+            monitoring.register_event_listener(_on_event)
         except Exception:
             return False
+        if _backend_tap is None:
+            _backend_tap = _BackendOpenTap()
         _dispatcher_installed = True
         return True
 
@@ -426,7 +586,7 @@ class RecompileSentinel:
     def arm(self) -> None:
         """Start treating every new compilation as an incident."""
         self.armed = True
-        self.monitoring_available = _install_dispatcher()
+        self.monitoring_available = install_dispatcher()
         _armed_sentinels.add(self)
 
     def disarm(self) -> None:
@@ -439,7 +599,8 @@ class RecompileSentinel:
         if self.armed:
             self._trip(program=name, shapes=_shape_str(sig), source="ledger")
 
-    def _on_backend_compile(self, duration: float) -> None:
+    def _on_backend_compile(self, parts: Dict[str, Any]) -> None:
+        """``parts``: the ``args`` of the program's ``compile`` slice."""
         if not self.armed:
             return
         if _current_attribution() is not None:
@@ -447,10 +608,12 @@ class RecompileSentinel:
             # miss already tripped (or will) with the program's name.
             return
         self._trip(
-            program="unattributed",
-            shapes="<unknown>",
+            program=parts["fun_name"],
             source="monitoring",
-            compile_seconds=duration,
+            compile_seconds=parts["backend_s"],
+            trace_s=parts["trace_s"],
+            lower_s=parts["lower_s"],
+            cache=parts["cache"],
         )
 
     # -------------------------------------------------------------- fan-out
@@ -504,6 +667,7 @@ class RecompileSentinel:
 
 
 __all__ = [
+    "install_dispatcher",
     "ProgramLedger",
     "ProgramRecord",
     "RecompileSentinel",

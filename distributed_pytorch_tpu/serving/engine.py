@@ -217,7 +217,11 @@ from distributed_pytorch_tpu.obs.regress import RegressionDetector
 from distributed_pytorch_tpu.obs.roofline import RooflineModel
 from distributed_pytorch_tpu.obs.slo import SLOMonitor, SLObjective
 from distributed_pytorch_tpu.obs.timeseries import TimeSeriesDB
-from distributed_pytorch_tpu.obs.tracer import NULL_TRACER, _PID_REQUESTS
+from distributed_pytorch_tpu.obs.tracer import (
+    NULL_TRACER,
+    _PID_REQUESTS,
+    process_tracer,
+)
 from distributed_pytorch_tpu.obs.xla import ProgramLedger, RecompileSentinel
 from distributed_pytorch_tpu.serving.admission import (
     AdmissionController,
@@ -383,6 +387,11 @@ class InferenceEngine:
         paged_kernel=False,
         kv_quant: Optional[str] = None,
     ):
+        # Set-up slices go to the process's tracer, whatever ``tracer`` is
+        # (made first, so that its ``process.start`` ends before them):
+        # ``engine.init`` is written at the end of this constructor.
+        setup = process_tracer()
+        t_init = time.perf_counter()
         if max_seq_len % page_size:
             raise ValueError(
                 f"max_seq_len {max_seq_len} must be a multiple of "
@@ -544,6 +553,7 @@ class InferenceEngine:
         # — same page ids, same block tables, one allocator — so every page
         # lifecycle decision moves both pools in lockstep. Head/width can
         # differ freely; only the page GEOMETRY must match.
+        t_pools = time.perf_counter()
         pools = {"target": _zero_cache(self.decode_model)}
         # What the target's pages hold, read from the pools it declared
         # (leaves ``[num_pages, page_size, ...]``), whatever kind they are:
@@ -622,6 +632,15 @@ class InferenceEngine:
                 self.pools[name] = jax.device_put(
                     self.pools[name], self._pool_shardings[name]
                 )
+        setup.setup_slice(
+            "engine.init.pools", t_pools, time.perf_counter() - t_pools,
+            bytes=sum(
+                leaf.nbytes
+                for name in self.pools.names
+                for leaf in jax.tree_util.tree_leaves(self.pools[name])
+            ),
+            state_bytes=self.state_bytes_per_slot * max_slots,
+        )
 
         # Zero-cost-when-disabled observability handle: one shared null
         # object serves every untraced engine — no timestamps, no dicts,
@@ -825,6 +844,10 @@ class InferenceEngine:
         self._inflight: Optional[
             Tuple[jax.Array, List[int], List[Request]]
         ] = None
+        setup.setup_slice(
+            "engine.init", t_init, time.perf_counter() - t_init,
+            slots=max_slots, pages=num_pages,
+        )
 
     def _default_goodput(self, model) -> GoodputTracker:
         """A :class:`GoodputTracker` configured from the engine's own
@@ -1291,21 +1314,26 @@ class InferenceEngine:
         )
         no_slot = (jnp.asarray([-1], jnp.int32),) if self.state_layers else ()
         programs = {}
-        for width in range(
-            granule, self.scheduler.max_prefill_chunk + 1, granule
-        ):
-            target = self._prefill_step(width)
-            draft = (
-                self._draft_prefill_step(width) if self.speculative else None
-            )
-            tokens = jnp.asarray(np.zeros((1, width), np.int32))
-            out = target(self.params, self.cache, tokens, *rest, *no_slot)
-            self.cache = out[0] if self.routed_layers else out
-            if draft is not None:
-                self.draft_cache = draft(
-                    self.draft_params, self.draft_cache, tokens, *rest
+        with process_tracer().setup_phase(
+            "engine.build_prefill_programs"
+        ) as built:
+            for width in range(
+                granule, self.scheduler.max_prefill_chunk + 1, granule
+            ):
+                target = self._prefill_step(width)
+                draft = (
+                    self._draft_prefill_step(width)
+                    if self.speculative else None
                 )
-            programs[width] = (target, draft)
+                tokens = jnp.asarray(np.zeros((1, width), np.int32))
+                out = target(self.params, self.cache, tokens, *rest, *no_slot)
+                self.cache = out[0] if self.routed_layers else out
+                if draft is not None:
+                    self.draft_cache = draft(
+                        self.draft_params, self.draft_cache, tokens, *rest
+                    )
+                programs[width] = (target, draft)
+            built.note(programs=len(programs), widths=sorted(programs))
         return programs
 
     def _prefill_piece(self, slot: int, tokens: int) -> None:
@@ -2601,7 +2629,8 @@ class InferenceEngine:
 
     def arm_recompile_sentinel(self) -> RecompileSentinel:
         """Declare warmup over: from here on, every new XLA compilation —
-        a ledger signature miss or an unattributed backend-compile event —
+        a ledger signature miss or a backend-compile event no ledgered
+        program accounts for (named by JAX's own name of the program) —
         bumps ``serving_engine_recompiles_total``, records a ``recompile``
         flight event with the program name + shapes, and latches the
         firing gauge. Requires ``xla_ledger`` (programs must have been

@@ -25,6 +25,7 @@ import collections
 import os
 import signal
 import threading
+import time
 from typing import Callable, Optional
 
 import jax
@@ -122,6 +123,11 @@ class Trainer:
         registry=None,
         tracer=None,
     ):
+        # ``trainer.init``, a set-up slice, is written at this constructor's
+        # end, to the process's tracer whatever ``tracer`` is (made first, so
+        # that its ``process.start`` ends before the slice begins).
+        setup = process_tracer()
+        t_init = time.perf_counter()
         self.model = model
         self.train_data = train_data
         # (inputs, targets, loss) of the steps last dispatched: _hand_back
@@ -296,6 +302,10 @@ class Trainer:
                 signal.signal(signal.SIGTERM, self._on_sigterm)
             except (ValueError, OSError):
                 pass  # embedded in a host that owns signals: file-poll only
+        setup.setup_slice(
+            "trainer.init", t_init, time.perf_counter() - t_init,
+            resumed_at_epoch=self.epochs_run,
+        )
 
     # ---------------------------------------------------------------- persistence
 

@@ -60,7 +60,15 @@ def enable_compile_cache() -> str:
     the checkout, ``<repo>/.jax_cache`` — the path is part of the cache key,
     so it is never derived from a temporary name, a pid or the time — and
     the variable is exported so child processes use the same directory.
+
+    Being what every entry point passes before its first compile, this is
+    also where the process's set-up timeline starts: from here on every
+    compile and every backend's opening writes a slice
+    (``obs/xla.py`` ``install_dispatcher``).
     """
+    from distributed_pytorch_tpu.obs.xla import install_dispatcher
+
+    install_dispatcher()
     path = os.environ.get(COMPILE_CACHE_ENV)
     if path:
         return path

@@ -25,6 +25,38 @@ jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+import contextlib  # noqa: E402
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def dispatcher_off():
+    """A context manager under which ``obs/xla.py``'s ``jax.monitoring``
+    listeners are not registered: a process before its
+    ``enable_compile_cache()``, for the tests that hold a run with the
+    set-up timeline against one without."""
+    from jax import monitoring
+
+    from distributed_pytorch_tpu.obs import xla
+
+    @contextlib.contextmanager
+    def off():
+        was = xla._dispatcher_installed
+        if was:
+            monitoring.unregister_event_duration_listener(
+                xla._on_duration_event)
+            monitoring.unregister_event_listener(xla._on_event)
+            xla._dispatcher_installed = False
+        try:
+            yield
+        finally:
+            if was:
+                assert xla.install_dispatcher()
+
+    return off
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process / subprocess integration tests"

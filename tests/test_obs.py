@@ -9,6 +9,7 @@ equals completed requests, and registry counters equal engine ground truth.
 
 import json
 import math
+import time
 
 import jax
 import jax.numpy as jnp
@@ -377,10 +378,11 @@ class TestEngineObservability:
     @pytest.mark.parametrize(
         "mode", ["overlap", "sync", "speculative", "accounted"]
     )
-    def test_tracing_does_not_change_tokens(self, mode):
-        """Acceptance: with tracing enabled, greedy outputs are
-        bitwise-identical to the untraced engine, and the trace holds
-        every child slice inside its parent: one ``dispatch.key`` a
+    def test_tracing_does_not_change_tokens(self, mode, dispatcher_off):
+        """Acceptance: with tracing enabled and the set-up timeline's
+        dispatcher installed, greedy outputs are bitwise-identical to the
+        untraced engine of a process without the dispatcher, and the trace
+        holds every child slice inside its parent: one ``dispatch.key`` a
         launch, covering all of the step's decode rows."""
         kw = {
             "overlap": dict,
@@ -388,10 +390,27 @@ class TestEngineObservability:
             "speculative": _draft_kw,
             "accounted": lambda: dict(flight=FlightRecorder(64)),
         }[mode]
-        plain = _run_all(_tiny_engine(**kw()))
+        from distributed_pytorch_tpu.obs.xla import install_dispatcher
+
+        def compiles_since(mark):
+            """The ``compile`` slices the process's tracer took since."""
+            pt = process_tracer()
+            return [e for e in pt.setup_events + list(pt.events)
+                    if e["name"] == "compile"
+                    and e["args"]["perf_counter_ns"] >= mark]
+
+        mark = time.perf_counter_ns()
+        with dispatcher_off():
+            plain = _run_all(_tiny_engine(**kw()))
+            assert not compiles_since(mark)
+        assert install_dispatcher()
         tr = Tracer()
         traced = _run_all(_tiny_engine(tracer=tr, **kw()))
         assert traced == plain
+        # the second engine's programs are new jit objects: JAX lowered and
+        # compiled them again, and each wrote its slice
+        assert compiles_since(mark)
+        assert not [e for e in tr.events if e["name"] == "compile"]
 
         slices = [e for e in tr.events if e["ph"] == "X"]
         by_step = {}

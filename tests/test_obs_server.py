@@ -458,6 +458,22 @@ class TestRecompileSentinel:
         assert status["firing"] and status["count"] == sentinel.count
         sentinel.acknowledge()
         assert not sentinel.firing and sentinel.count >= 1
+        # A compilation the ledger never saw trips with JAX's own name of
+        # the program and the parts of its ``compile`` slice.
+        seen = sentinel.count
+
+        def a_stray_program(x):
+            return x * 2 + 1
+
+        jax.jit(a_stray_program)(jnp.ones(7))
+        stray = [t for t in sentinel.trips[seen:]
+                 if t["program"] == "jit(a_stray_program)"]
+        assert len(stray) == 1 and stray[0]["source"] == "monitoring"
+        assert stray[0]["cache"] in ("off", "miss", "hit")
+        assert stray[0]["compile_seconds"] > 0 and stray[0]["trace_s"] > 0
+        assert stray[0]["lower_s"] > 0
+        assert sentinel.firing
+        assert not any(t["program"] == "unattributed" for t in sentinel.trips)
         eng.close()
         assert not sentinel.armed  # close() disarms
 

@@ -461,20 +461,26 @@ def _traced_run(tmp_path, tracer, name, mesh=None, epochs=2):
 
 
 @pytest.mark.parametrize("mesh", [False, True], ids=["serial", "mesh"])
-def test_trainer_writes_its_host_phases_and_trains_the_same(tmp_path, mesh):
+def test_trainer_writes_its_host_phases_and_trains_the_same(
+    tmp_path, mesh, dispatcher_off
+):
     """Per step one each of ``loader.index``, ``loader.stack``, ``step`` >
     ``put_batch`` + ``step.dispatch``; per epoch one ``epoch`` holding all
     of them and one ``epoch.loss_fetch``; a ``recycle.fence`` after every
     step from the third on, where the Trainer waits for the step two back
     before it hands that step's arrays to the loader; and the state a Trainer
-    reaches does not depend on who records."""
+    reaches does not depend on who records, nor on whether the set-up
+    timeline's dispatcher is installed."""
     from distributed_pytorch_tpu.obs.tracer import NULL_TRACER, Tracer
+    from distributed_pytorch_tpu.obs.xla import install_dispatcher
     from distributed_pytorch_tpu.training.trainer import HOST_BATCHES
 
     mesh = make_mesh() if mesh else None
     tr = Tracer()
+    assert install_dispatcher()
     traced = _traced_run(tmp_path, tr, "traced", mesh)
-    silent = _traced_run(tmp_path, NULL_TRACER, "silent", mesh)
+    with dispatcher_off():
+        silent = _traced_run(tmp_path, NULL_TRACER, "silent", mesh)
     for a, b in zip(jax.tree_util.tree_leaves(traced.state),
                     jax.tree_util.tree_leaves(silent.state)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -41,10 +41,20 @@ Work is proportional to the routed pairs, not to tokens x experts: the pairs
 are sorted by expert (held ones first, absent ones and those of rows that
 carry nothing behind them), the rows gathered in that order, and the two
 projections are grouped matrix products over the held experts
-(``jax.lax.ragged_dot``, which the TPU compiler lowers to a Mosaic grouped
-matmul that reads an expert's weights only if a row reached it; the device
-trace names it ``ragged-dot``). No capacity, so no token is dropped at any
-load: the products' row count is ``tokens x top_k`` whatever the routing.
+(``ops/grouped_matmul.py``). How they are computed follows the block's
+``paged_kernel`` as the attention kernels do (:func:`product_mode`; no option
+of the layer's own): on a TPU a weight-stationary Pallas kernel that streams
+each REACHED expert's weights once, in wide column blocks double-buffered
+behind the matmuls, against that expert's own rows in tiles of 16, and never
+visits a row of no group (the device trace names it
+``ragged-dot-stationary``; for it the rows are gathered into a power of two's
+worth of tokens, so that a model's prefill widths share a few traces of the
+kernel); elsewhere, and as the plain form the tests hold
+the kernel to, ``jax.lax.ragged_dot`` (on a TPU the compiler's own grouped
+matmul, ``ragged-dot``, whose time grows with the rows it is handed: PERF.md
+section 6). No capacity, so no token is dropped at any load: the products'
+row count is ``tokens x top_k`` (the kernel's: that of the next power of
+two's tokens) whatever the routing.
 The layer also counts the tokens routed to each of the ``n_experts``
 (``[n_experts] int32``, sown into the ``"routing"`` collection when the
 caller makes it mutable): the serving engine's routing counters.
@@ -60,6 +70,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distributed_pytorch_tpu.ops.grouped_matmul import gated_experts
+from distributed_pytorch_tpu.ops.paged_attention import resolve_kernel
 from distributed_pytorch_tpu.parallel.partitioning import Rules
 
 F32 = jnp.float32
@@ -242,6 +254,20 @@ def route(scores: jnp.ndarray, top_k: int, gating: str = GATINGS[0]):
     )
 
 
+def product_mode(paged_kernel, d_model: int, d_ff: int) -> str:
+    """How :class:`RoutedExperts` computes its grouped products under a
+    block's ``paged_kernel``: ``ops/paged_attention.resolve_kernel``'s answer
+    (``"pallas"`` on a TPU, ``"interpret"`` for tests, ``"xla"``), and
+    ``"xla"``, ``jax.lax.ragged_dot``, where the block names no kernel. The
+    ONE place where the call's static shapes decide: the compiled kernel
+    copies rows and weight blocks in whole lanes (Mosaic refuses a slice of
+    32 of 128), so widths that are no multiples of 128 take the plain form."""
+    mode = resolve_kernel(paged_kernel) if paged_kernel else "xla"
+    if mode == "pallas" and (d_model % 128 or d_ff % 128):
+        return "xla"
+    return mode
+
+
 class RoutedExperts(nn.Module):
     """Dropless top-k routed gated-SiLU experts over a held range (module
     docstring). ``[B, T, d_model] -> [B, T, d_model]``.
@@ -261,6 +287,7 @@ class RoutedExperts(nn.Module):
     held: Optional[tuple] = None  # (lo, hi): experts lo..hi-1; None = all
     dtype: Any = F32
     gating: str = GATINGS[0]  # one of GATINGS
+    paged_kernel: str = ""  # the block's (see Attention): the products' mode
 
     @nn.compact
     def __call__(
@@ -318,18 +345,26 @@ class RoutedExperts(nn.Module):
             (n_held, self.d_ff, d),
         )
         with jax.named_scope("moe.experts"):
-            rows = flat.astype(self.dtype)[order // k]  # [tokens k, d]
-            sizes = sizes.astype(jnp.int32)
-            gate_up = jax.lax.ragged_dot(
-                rows, w_in.astype(self.dtype), sizes,
-                preferred_element_type=F32,
+            # Initialising only asks for shapes: the plain form gives them
+            # without a trace of the kernel.
+            mode = "xla" if self.is_initializing() else product_mode(
+                self.paged_kernel, d, self.d_ff
             )
-            g, u = jnp.split(gate_up, 2, axis=-1)
-            act = (nn.silu(g) * u).astype(self.dtype)
-            out = jax.lax.ragged_dot(
-                act, w_out.astype(self.dtype), sizes,
-                preferred_element_type=F32,
-            )
+            take, room = order // k, tokens
+            if mode != "xla":
+                # Every program that calls the kernel traces and lowers it
+                # for its own row count (set-up time: PERF.md section 6), so
+                # the rows are gathered into a power of two's worth of
+                # tokens: a model's prefill widths share a few kernels. The
+                # rows past the pairs stand in no group and are never read.
+                room = 1 << (tokens - 1).bit_length()
+                take = jax.lax.pad(take, 0, [(0, (room - tokens) * k, 0)])
+            rows = flat.astype(self.dtype)[take]  # [tokens k or more, d]
+            # A token stands in a group once: no group outgrows the tokens.
+            out = gated_experts(
+                rows, w_in.astype(self.dtype), w_out.astype(self.dtype),
+                sizes, mode=mode, max_group=room,
+            )[: tokens * k]
         with jax.named_scope("moe.combine"):
             # Rows past the held pairs belong to no group: whatever the
             # product left there is not a result.

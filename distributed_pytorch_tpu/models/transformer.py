@@ -785,7 +785,8 @@ class TransformerBlock(nn.Module):
 
             fed = RoutedExperts(
                 d_ff=self.d_ff, d_model=self.d_model, dtype=self.dtype,
-                name="experts", **dict(self.routed),
+                paged_kernel=self.paged_kernel, name="experts",
+                **dict(self.routed),
             )(normed, live=live_tokens(state_slots, valid_lens, x.shape[1]))
             if self.shared_d_ff:
                 fed = fed + MLPBlock(

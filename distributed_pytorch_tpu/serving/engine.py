@@ -136,10 +136,19 @@ reading them waits for nothing and the overlap a traced run measures is the
 untraced one) and are written as one ``moe.routing`` instant that names its
 step: ``moe_programs``,
 ``moe_pairs_held`` and ``moe_pairs_absent`` (routed (token, expert) pairs on
-experts this model holds and on the others), ``moe_experts_hit`` (held
+experts this model holds and on the others), ``moe_rows_computed`` (rows the
+grouped-product kernel multiplied for them: whole row tiles a reached expert,
+a program and layer, by ``ops/grouped_matmul.py``'s ``rows_computed``, the
+kernel's own tiling rule applied to these counts, so padding's share of the
+MXU work is ``moe_rows_computed / moe_pairs_held``; 0 where the programs were
+built with ``jax.lax.ragged_dot``), ``moe_experts_hit`` (held
 experts with at least one token, summed over programs and layers) and
 ``moe_tokens_per_expert_max`` / ``_mean`` (over the held experts of a layer,
-summed likewise). ``finish_inflight`` writes the last step's. A mesh is
+summed likewise). ``finish_inflight`` writes the last step's.
+``stats()`` carries ``moe_product``, how the programs compute the grouped
+products (``models/moe.py`` ``product_mode`` of the engine's ``paged_kernel``
+and the model's widths: ``"pallas"``, ``"interpret"`` or ``"xla"``), and the
+instants' summed ``moe_pairs_held`` and ``moe_rows_computed``. A mesh is
 refused for such a model (no expert axis on the serving mesh yet). The decode
 program tells such a model, recurrent layers or none, which rows are in the
 dispatched group (``state_slots``): a row outside it reaches no expert and is
@@ -202,6 +211,7 @@ from distributed_pytorch_tpu.generation import (
     truncate_logits,
 )
 from distributed_pytorch_tpu.models.mamba import STATE_KEYS
+from distributed_pytorch_tpu.models.moe import product_mode
 from distributed_pytorch_tpu.obs import MetricsRegistry, Tracer
 from distributed_pytorch_tpu.obs.flight import (
     NULL_FLIGHT_RECORDER,
@@ -223,6 +233,7 @@ from distributed_pytorch_tpu.obs.tracer import (
     process_tracer,
 )
 from distributed_pytorch_tpu.obs.xla import ProgramLedger, RecompileSentinel
+from distributed_pytorch_tpu.ops.grouped_matmul import rows_computed
 from distributed_pytorch_tpu.serving.admission import (
     AdmissionController,
     ServingMetrics,
@@ -479,6 +490,9 @@ class InferenceEngine:
             )
         self.routing_counts: List[jax.Array] = []  # the last step's programs'
         self._routing_due: Optional[Tuple[int, List[jax.Array]]] = None
+        # Totals of the ``moe.routing`` instants (a tracer's runs only).
+        self.moe_pairs_held = 0
+        self.moe_rows_computed = 0
 
         # Mesh geometry is engine-static, like top_k/top_p: it is compiled
         # into every program and fingerprinted into elastic snapshots.
@@ -511,6 +525,11 @@ class InferenceEngine:
         self.paged_kernel = (
             "" if not paged_kernel
             else ("auto" if paged_kernel is True else str(paged_kernel))
+        )
+        # How the programs compute the routed layers' grouped products.
+        self.moe_product = (
+            product_mode(self.paged_kernel, model.d_model, model.d_ff)
+            if self.routed_layers else ""
         )
         clone_kw = {}
         if self.paged_kernel:
@@ -1200,9 +1219,14 @@ class InferenceEngine:
         counts = np.stack([np.asarray(a) for a in arrays]).astype(np.int64)
         lo, hi = self.decode_model.experts_held or (0, counts.shape[-1])
         held = counts[..., lo:hi]  # [programs, layers, held experts]
+        # The held experts' counts ARE the grouped products' group sizes:
+        # the rows the kernel multiplied, by its own tiling rule.
+        computed = rows_computed(held) if self.moe_product != "xla" else 0
+        self.moe_pairs_held += int(held.sum())
+        self.moe_rows_computed += computed
         self.tracer.instant(
             "moe.routing", step=step, moe_programs=len(arrays),
-            moe_pairs_held=int(held.sum()),
+            moe_pairs_held=int(held.sum()), moe_rows_computed=computed,
             moe_pairs_absent=int(counts.sum() - held.sum()),
             moe_experts_hit=int((held > 0).sum()),
             moe_tokens_per_expert_max=int(held.max(axis=-1).sum()),
@@ -2798,6 +2822,10 @@ class InferenceEngine:
         out["prefill_tokens"] = self.prefill_tokens
         out["prefill_width"] = self.prefill_width
         out["decode_rows_grouped"] = self.decode_rows_grouped
+        if self.routed_layers:
+            out["moe_product"] = self.moe_product
+            out["moe_pairs_held"] = self.moe_pairs_held
+            out["moe_rows_computed"] = self.moe_rows_computed
         out["page_bytes_per_token_layer"] = self.page_bytes_per_token_layer
         out["pages_free"] = self.allocator.num_free
         out["pages_allocated"] = self.allocator.num_allocated

@@ -233,6 +233,53 @@ CASES.update({
 })
 
 
+# ------------------------------------------------ the experts' grouped product
+#
+# ``ops/grouped_matmul.py``'s weight-stationary kernel at the shapes of the two
+# cells that run ``RoutedExperts``: both products of a decode program (64 rows
+# x top 10 on 36 held experts of 4096 x 2*768; 32 x top 6 on 8 of 2048 x
+# 2*1408) and of the widest prefill piece, 512 tokens (its rows' room in VMEM
+# is what the compiler is asked about there).
+
+GROUPED = {
+    "granite": dict(held=36, top_k=10, d=4096, f=768, slots=64),
+    "deepseek": dict(held=8, top_k=6, d=2048, f=1408, slots=32),
+}
+
+
+def grouped_case(chip, model, tokens, second):
+    from distributed_pytorch_tpu.ops.grouped_matmul import grouped_matmul
+
+    m = GROUPED[model]
+    k, n = (m["f"], m["d"]) if second else (m["d"], 2 * m["f"])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    fn = functools.partial(grouped_matmul, mode="pallas", max_group=tokens)
+    return jax.jit(fn).lower(
+        arg((tokens * m["top_k"], k), jnp.bfloat16),
+        arg((m["held"], k, n), jnp.bfloat16), arg((m["held"],), jnp.int32),
+    )
+
+
+CASES.update({
+    f"grouped-{model}-{'decode' if tokens is None else f'prefill-{tokens}'}-"
+    f"{'out' if second else 'in'}": functools.partial(
+        grouped_case, model=model, tokens=tokens or GROUPED[model]["slots"],
+        second=second)
+    for model in GROUPED for tokens in (None, 512) for second in (False, True)
+})
+
+
+def test_the_grouped_product_is_named_for_the_benchmarks_readers(chip):
+    """``benchmarks/harness/moe_hybrid.py`` counts an instruction whose NAME
+    holds ``ragged-dot`` to the expert layers: the kernel's does."""
+    text = grouped_case(chip, "granite", 64, False).compile().as_text()
+    call = next(line for line in text.splitlines() if "tpu_custom_call" in line)
+    assert "ragged-dot" in call.split(" = ", 1)[0]
+
+
 def test_a_latent_pool_of_576_is_refused_by_mosaic(chip):
     """Why the pool's rows are 640 wide: the chip stores a ``[.., 576]`` bf16
     array in tiles of 128 lanes (640 a token in HBM whatever the shape says),

@@ -152,6 +152,41 @@ def test_the_routing_counters_on_steps_with_known_routing(program):
             e["moe_pairs_held"] / 4)
 
 
+@pytest.mark.parametrize("kernel", ["xla", "interpret"])
+def test_the_products_kernel_follows_the_engines_and_counts_its_rows(
+        program, kernel):
+    """``paged_kernel`` settles the grouped products' mode as it settles the
+    attention kernels' (no option of their own): the engine says which
+    (``moe_product``), serves tokens the reference agrees with either way, and
+    counts the rows the kernel multiplied by the kernel's own tiling rule. A
+    one-token program's groups are single rows inside the first tile: a tile
+    a reached expert and layer."""
+    from distributed_pytorch_tpu.ops.grouped_matmul import ROW_TILE
+
+    tracer = Tracer()
+    engine = engine_for(program, tracer=tracer, paged_kernel=kernel)
+    prompt = tokens(9, seed=12)
+    (generated,) = serve(engine, [prompt], new_tokens=4)
+    engine.finish_inflight()
+    assert served_gap(program, prompt, generated).max() < LOGIT_TOL
+    events = routing_events(tracer)
+    stats = engine.stats()
+    assert stats["moe_product"] == kernel
+    assert stats["moe_pairs_held"] == sum(e["moe_pairs_held"] for e in events)
+    assert stats["moe_rows_computed"] == sum(
+        e["moe_rows_computed"] for e in events)
+    if kernel == "xla":  # the compiler's product walks no tile of this rule
+        assert stats["moe_rows_computed"] == 0
+        return
+    for e in events[1:]:
+        assert e["moe_rows_computed"] == ROW_TILE * e["moe_experts_hit"]
+    first = events[0]  # 8 tokens: whole tiles, no fewer rows than pairs
+    assert first["moe_rows_computed"] % ROW_TILE == 0
+    assert first["moe_rows_computed"] >= max(
+        first["moe_pairs_held"], ROW_TILE * first["moe_experts_hit"])
+    assert first["moe_rows_computed"] <= ROW_TILE * 2 * first["moe_experts_hit"]
+
+
 def known_routing(cfg, weights, toks):
     """What the counters of ``toks`` (8 in a chunk, then one by one) have to
     add up to, from the reference's own routing."""

@@ -44,9 +44,16 @@ def tokens_in(n, seed=1):
         np.random.default_rng(seed).standard_normal((n, D)), jnp.float32)
 
 
-def layer_out(w, x, held=None, live=None, counts=False):
+@pytest.fixture(params=["xla", "interpret"])
+def mode(request):
+    """How the layer computes its grouped products: ``jax.lax.ragged_dot``,
+    or ``ops/grouped_matmul.py``'s kernel through the Pallas interpreter."""
+    return request.param
+
+
+def layer_out(w, x, held=None, live=None, counts=False, mode="xla"):
     lo, hi = held or (0, E)
-    layer = RoutedExperts(E, K, F, D, held=held)
+    layer = RoutedExperts(E, K, F, D, held=held, paged_kernel=mode)
     params = {"router_kernel": w["router"], "in_kernel": w["we_in"][lo:hi],
               "out_kernel": w["we_out"][lo:hi]}
     out, sown = layer.apply(
@@ -62,15 +69,15 @@ def reference_out(w, x, held=None):
         x, cut, cfg=share((lo, hi)), einsum=jnp.einsum)[0])
 
 
-def test_all_experts_held_matches_the_reference():
+def test_all_experts_held_matches_the_reference(mode):
     w, x = weights(), tokens_in(40)
     want = reference_out(w, x)
     assert np.abs(want).max() > 0.1
-    assert np.abs(layer_out(w, x) - want).max() < TOL
+    assert np.abs(layer_out(w, x, mode=mode) - want).max() < TOL
 
 
 @pytest.mark.parametrize("cut", [4, 1, 7])
-def test_the_shares_add_up(cut):
+def test_the_shares_add_up(cut, mode):
     """The parts computed with experts ``0..cut-1`` and ``cut..E-1`` held,
     each against the reference given the same share, sum to the uncut
     reference's layer (the shared expert is no part of this layer: the
@@ -78,7 +85,7 @@ def test_the_shares_add_up(cut):
     w, x = weights(2), tokens_in(33, seed=3)
     parts = []
     for held in ((0, cut), (cut, E)):
-        got = layer_out(w, x, held)
+        got = layer_out(w, x, held, mode=mode)
         assert np.abs(got - reference_out(w, x, held)).max() < TOL
         parts.append(got)
     whole = reference_out(w, x)
@@ -115,15 +122,16 @@ def pointing_router(expert_order):
     return router
 
 
-def test_no_token_is_dropped_when_every_token_goes_to_one_expert():
+def test_no_token_is_dropped_when_every_token_goes_to_one_expert(mode):
     """The load a capacity would drop at: all 64 tokens choose expert 5
-    first (and 6, 2 behind it). Every one of them gets expert 5's part."""
+    first (and 6, 2 behind it). Every one of them gets expert 5's part
+    (the kernel: groups of four row tiles, and experts no row reached)."""
     w = weights(4, router=pointing_router([5, 6, 2, 0, 1, 3, 4, 7]))
     x = jnp.abs(tokens_in(64, seed=5))
-    got, counts = layer_out(w, x, counts=True)
+    got, counts = layer_out(w, x, counts=True, mode=mode)
     assert counts.tolist() == [0, 0, 64, 0, 0, 64, 64, 0]
     assert np.abs(got - reference_out(w, x)).max() < TOL
-    only = layer_out(w, x, held=(5, 6))
+    only = layer_out(w, x, held=(5, 6), mode=mode)
     assert (np.abs(only).max(axis=-1) > 1e-4).all()  # no row left out
 
 
@@ -140,10 +148,10 @@ def test_a_capacity_that_drops_is_caught(monkeypatch):
     assert np.abs(layer_out(w, x) - reference_out(w, x)).max() > 100 * TOL
 
 
-def test_no_token_to_a_held_expert_gives_zeros():
+def test_no_token_to_a_held_expert_gives_zeros(mode):
     w = weights(6, router=pointing_router([0, 1, 2, 3, 4, 5, 6, 7]))
     x = jnp.abs(tokens_in(16, seed=7))
-    got, counts = layer_out(w, x, held=(4, 8), counts=True)
+    got, counts = layer_out(w, x, held=(4, 8), counts=True, mode=mode)
     assert counts.tolist() == [16, 16, 16, 0, 0, 0, 0, 0]
     assert (got == 0).all()
     assert (reference_out(w, x, held=(4, 8)) == 0).all()
@@ -168,9 +176,9 @@ def test_gates_sum_to_one_over_the_chosen_whatever_is_held(held):
     assert ((ref > 0).sum(-1) == K).all()
 
 
-def test_rows_that_carry_no_request_are_computed_by_nobody():
+def test_rows_that_carry_no_request_are_computed_by_nobody(mode):
     w, x = weights(10), tokens_in(6, seed=11)
-    layer = RoutedExperts(E, K, F, D)
+    layer = RoutedExperts(E, K, F, D, paged_kernel=mode)
     params = {"router_kernel": w["router"], "in_kernel": w["we_in"],
               "out_kernel": w["we_out"]}
     live = jnp.asarray([True, False, True, True, False, True])
@@ -224,9 +232,10 @@ def ds_share(held):
                 n_routed_experts_published=E)
 
 
-def ds_layer_out(w, x, held=None):
+def ds_layer_out(w, x, held=None, mode="xla"):
     lo, hi = held or (0, E)
-    layer = RoutedExperts(E, K, F, D, held=held, gating="top_k_of_softmax")
+    layer = RoutedExperts(E, K, F, D, held=held, gating="top_k_of_softmax",
+                          paged_kernel=mode)
     params = {"router_kernel": w["router"], "in_kernel": w["we_in"][lo:hi],
               "out_kernel": w["we_out"][lo:hi]}
     return np.asarray(layer.apply({"params": params}, x[None])[0])
@@ -266,14 +275,16 @@ def test_an_unknown_gating_rule_is_refused():
         ds.init(jax.random.PRNGKey(0), jnp.zeros((1, 2, D)))
 
 
-def test_unrenormalised_layer_matches_the_reference():
+def test_unrenormalised_layer_matches_the_reference(mode):
     w, x = weights(21), tokens_in(40, seed=22)
-    assert np.abs(ds_layer_out(w, x) - ds_reference_out(w, x)).max() < TOL
+    assert np.abs(
+        ds_layer_out(w, x, mode=mode) - ds_reference_out(w, x)).max() < TOL
     # ... and is not the default rule's layer.
     assert np.abs(ds_layer_out(w, x) - layer_out(w, x)).max() > 0.01
 
 
-def test_the_eight_shares_and_the_shared_expert_counted_once_are_the_whole_layer():
+def test_the_eight_shares_and_the_shared_expert_counted_once_are_the_whole_layer(
+        mode):
     """What the eight chips of the deployment compute of one layer: each its
     one expert's part (the toy's eighth), and all of them the shared experts
     alike. The eight parts and ONE shared output add up to the uncut
@@ -292,7 +303,7 @@ def test_the_eight_shares_and_the_shared_expert_counted_once_are_the_whole_layer
                         "down": {"kernel": ws["ws_down"]}}}, x))
     parts = []
     for e in range(E):
-        got = ds_layer_out(w, x, (e, e + 1))
+        got = ds_layer_out(w, x, (e, e + 1), mode=mode)
         assert np.abs(got - ds_reference_out(w, x, (e, e + 1))).max() < TOL
         parts.append(got)
     ref = deepseek_toy.reference
@@ -300,3 +311,127 @@ def test_the_eight_shares_and_the_shared_expert_counted_once_are_the_whole_layer
         x, ws["ws_gate"], ws["ws_up"], ws["ws_down"], jnp.einsum))
     assert np.abs(sum(parts) + shared - whole).max() < TOL
     assert sum(np.abs(p).max() > 1e-3 for p in parts) == E
+
+
+# ------------------------- the grouped products' kernel (ops/grouped_matmul.py)
+#
+# Through the Pallas interpreter against ``jax.lax.ragged_dot`` on the same
+# sorted rows, at toy sizes of the two configurations' products (a first
+# product wider than it is deep, a second one deeper, a width that is whole
+# lanes and one that is not). float32 operands: the differences are the order
+# of the sums.
+
+from distributed_pytorch_tpu.ops import grouped_matmul as gm  # noqa: E402
+
+TILE = gm.ROW_TILE
+GROUPS = {
+    "an-expert-no-row-reached": [5, 0, 9, 0, 3],
+    "the-first-experts-unreached": [0, 0, 7, 11, 0],
+    "all-rows-on-absent-experts": [0, 0, 0, 0, 0],
+    "a-group-larger-than-a-tile": [3, 2 * TILE + 5, 4, 0, 1],
+    "sizes-no-multiple-of-the-tile": [TILE + 1, TILE - 1, 2 * TILE + 3, 1, 0],
+    "groups-that-end-on-a-tile": [TILE, 2 * TILE, 0, TILE, 0],
+    "one-group-is-all-the-rows": [0, 0, 6 * TILE, 0, 0],
+}
+WIDTHS = {"in-32x48": (32, 48), "out-24x32": (24, 32), "lanes-128x256": (128, 256)}
+
+
+def sorted_rows(sizes, k, n, rows=6 * TILE, seed=0):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((rows, k)), jnp.float32),
+            jnp.asarray(rng.standard_normal((len(sizes), k, n)), jnp.float32),
+            jnp.asarray(sizes, jnp.int32))
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("groups", sorted(GROUPS))
+def test_the_kernel_is_ragged_dot_on_the_rows_of_a_group(groups, widths):
+    sizes = GROUPS[groups]
+    x, w, s = sorted_rows(sizes, *WIDTHS[widths])
+    want = np.asarray(jax.lax.ragged_dot(x, w, s))
+    got = np.asarray(gm.grouped_matmul(x, w, s, mode="interpret"))
+    held = sum(sizes)
+    assert got.shape == want.shape and got.dtype == np.float32
+    np.testing.assert_allclose(got[:held], want[:held], atol=2e-5)
+    if held:
+        assert np.abs(want[:held]).max() > 1.0
+
+
+@pytest.mark.parametrize("groups", sorted(GROUPS))
+def test_the_hosts_rule_counts_the_tiles_the_kernel_walks(groups):
+    """``moe_rows_computed`` is ``rows_computed`` of the routing counts: the
+    tiles the kernel says it multiplied, a reached expert, are that rule's."""
+    sizes = GROUPS[groups]
+    x, w, s = sorted_rows(sizes, 32, 48)
+    _, walked = gm._stationary(x, w, s, interpret=True)
+    walked = np.asarray(walked)
+    assert int(walked.sum()) * TILE == gm.rows_computed(sizes)
+    assert ((walked > 0) == (np.asarray(sizes) > 0)).all()
+    # No tile for less than a row, none twice over.
+    assert (walked * TILE >= np.asarray(sizes)).all()
+    assert (walked <= -(-np.asarray(sizes) // TILE) + 1).all()
+    # A batch of programs and layers is summed over.
+    assert gm.rows_computed([[sizes, sizes]]) == 2 * gm.rows_computed(sizes)
+
+
+def test_rows_that_are_no_whole_tiles_are_padded_and_cut_again():
+    x, w, s = sorted_rows([3, 0, 4], 32, 48, rows=TILE + 5)
+    want = np.asarray(jax.lax.ragged_dot(x, w, s))
+    got = np.asarray(gm.grouped_matmul(x, w, s, mode="interpret"))
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got[:7], want[:7], atol=2e-5)
+
+
+def test_bfloat16_operands_accumulate_in_float32():
+    """The configurations' arithmetic: bf16 operands, float32 sums and
+    results. Against the float32 product of the SAME bf16 numbers."""
+    x, w, s = sorted_rows([TILE + 3, 0, 9], 128, 256)
+    xb, wb = x.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    got = np.asarray(gm.grouped_matmul(xb, wb, s, mode="interpret"))
+    want = np.asarray(jax.lax.ragged_dot(
+        xb.astype(jnp.float32), wb.astype(jnp.float32), s))
+    assert got.dtype == np.float32
+    np.testing.assert_allclose(got[: TILE + 12], want[: TILE + 12], atol=1e-4)
+
+
+@pytest.mark.parametrize("gating", moe.GATINGS)
+def test_masked_tokens_of_a_piece_reach_no_expert_under_either_gating(
+        gating, mode):
+    """A prefill piece's padding (``live [B, T]``): the same result for the
+    tokens that count, zeros for the rest, and the same counts, whichever
+    product computes them."""
+    w, x = weights(30), tokens_in(24, seed=31)
+    live = jnp.asarray(np.arange(24) < 17)[None]
+    params = {"router_kernel": w["router"], "in_kernel": w["we_in"][2:7],
+              "out_kernel": w["we_out"][2:7]}
+
+    def run(kernel):
+        layer = RoutedExperts(E, K, F, D, held=(2, 7), gating=gating,
+                              paged_kernel=kernel)
+        out, sown = layer.apply(
+            {"params": params}, x[None], live=live, mutable=["routing"])
+        return np.asarray(out[0]), np.asarray(sown["routing"]["counts"][0])
+
+    (got, counts), (want, want_counts) = run(mode), run("xla")
+    assert np.abs(got - want).max() < TOL
+    assert (got[17:] == 0).all() and np.abs(got[:17]).max() > 0.01
+    assert counts.tolist() == want_counts.tolist()
+    assert int(counts.sum()) == 17 * K
+
+
+def test_the_products_mode_follows_the_blocks_kernel(monkeypatch):
+    from distributed_pytorch_tpu.ops import paged_attention
+
+    assert moe.product_mode("", 256, 128) == "xla"
+    assert moe.product_mode("xla", 256, 128) == "xla"
+    assert moe.product_mode("interpret", D, F) == "interpret"
+    assert moe.product_mode("auto", 256, 128) == "xla"  # no TPU here
+    with pytest.raises(ValueError, match="unknown paged-attention kernel"):
+        moe.product_mode("mosaic", 256, 128)
+    # On a TPU: the kernel, but for widths that are no whole lanes (the
+    # compiled kernel copies whole lanes; test_chip_compile.py compiles it).
+    monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
+    assert moe.product_mode("auto", 4096, 768) == "pallas"
+    assert moe.product_mode("auto", 2048, 1408) == "pallas"
+    assert moe.product_mode("auto", D, F) == "xla"
+    assert moe.product_mode("pallas", 256, 64) == "xla"

@@ -182,7 +182,8 @@ def decode_token_step(
     )
     out = logits[:, -1, :], updated["cache"]
     if len(mutable) > 1:
-        out += ({k: updated[k] for k in mutable if k != "cache"},)
+        # A collection nobody wrote to in this call is not in ``updated``.
+        out += ({k: updated.get(k, {}) for k in mutable if k != "cache"},)
     return out
 
 

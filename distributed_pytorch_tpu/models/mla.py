@@ -57,6 +57,32 @@ goes to ``ops/paged_attention.py``'s latent kernel instead
 (``attention._latent_decode_step``), which copies the pages that several rows
 hold in common (a document the prefix trie handed to each asker) once for all
 of them.
+
+**What a layer may add**, each by a field that defaults to none of it:
+
+* ``q_lora_rank``: the query through a latent of its own, ``c_q =
+  RMSNorm(x W_qa)``, ``q = c_q W_qb`` (``q_a``, ``q_norm``, ``query``).
+* ``lora_rescale``: ``c_q`` and ``c`` times ``sqrt(d_model / rank)`` after
+  their norms (the cached ``c`` is the rescaled one).
+* ``gate``: ``g = sigmoid(x W_g)``, one number a head, on the head's output
+  before ``W_o``.
+* ``window``: a query at ``t`` sees the keys ``(t - window, t]``. A decode
+  step walks only the pages that meet the window (the kernel is handed the
+  row's table from the window's first live page on), a prefill piece only
+  the blocks that do. The pages behind the window stay where they are.
+* an INDEXER (``index_heads``, ``index_dim``, ``index_top_k``; needs
+  ``q_lora_rank``): ``q^I_h = c_q W_Iq`` (``index_heads`` of ``index_dim``),
+  ``k^I = LayerNorm(x W_Ik)`` (one ``index_dim``-wide key a token), rotary on
+  the first ``dr`` of each, ``w = x W_Iw``; ``I(t, s) = sum_h w_{t,h}
+  index_heads ** -0.5 relu(q^I_{t,h} . k^I_s) index_dim ** -0.5`` in float32;
+  a query attends over the ``index_top_k`` positions ``s <= t`` of largest
+  ``I(t, s)`` alone (all of them while ``t < index_top_k``; exact:
+  ``ops/paged_attention.py`` ``top_k_mask``). Such a layer keeps a SECOND
+  pool, ``cached_index [num_pages, page_size, index_dim in whole lanes]``,
+  under the same tables. A decode step scores the row's index-key pages
+  (``attention._index_scores``), selects, gathers the selected tokens' latents
+  and attends over them (``attention._sparse_latent_decode_step``); a prefill
+  piece masks the blocked walk by each query's selection.
 """
 
 from __future__ import annotations
@@ -186,12 +212,21 @@ def latent_row(c, k_pe, width: int):
     return _lanes(jnp.concatenate([c, k_pe], axis=-1), width)
 
 
+def index_row(k_idx, width: int):
+    """What a token's row of the index-key pool holds: ``[k^I | zeros]``."""
+    return _lanes(k_idx, width)
+
+
 class LatentAttention(nn.Module):
     """The module docstring's layer. ``[B, T, d_model] -> [B, T, d_model]``.
 
     Parameters: ``query/kernel [d, H, dn + dr]``, ``kv_a/kernel [d, r + dr]``,
     ``kv_norm/scale [r]``, ``kv_b [r, H, dn + dv]``, ``out/kernel [H, dv, d]``;
-    no biases. Without ``decode`` (and on the cache-init pass) a call is the
+    no biases. With ``q_lora_rank`` ``rq``: ``q_a/kernel [d, rq]``,
+    ``q_norm/scale [rq]`` and ``query/kernel [rq, H, dn + dr]``; with ``gate``
+    ``gate/kernel [d, H]``; with an indexer ``index_q/kernel [rq, Hi, Di]``,
+    ``index_k/kernel [d, Di]``, ``index_k_norm/scale, bias [Di]`` and
+    ``index_w/kernel [d, Hi]``. Without ``decode`` (and on the cache-init pass) a call is the
     plain causal forward over its own tokens, expanded. With ``decode`` and a
     ``page_size`` it is a step against the paged latent pool and must be told
     ``block_tables [S, pages_per_seq]`` and ``seq_lens [S]`` (and, for a padded
@@ -216,6 +251,14 @@ class LatentAttention(nn.Module):
     page_size: int = 0
     num_pages: int = 0
     paged_kernel: str = ""  # see models.transformer.Attention
+    # What a layer may add (module docstring); the defaults add nothing.
+    q_lora_rank: int = 0
+    lora_rescale: bool = False
+    gate: bool = False
+    window: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_top_k: int = 0
 
     @property
     def latent_width(self) -> int:
@@ -226,6 +269,11 @@ class LatentAttention(nn.Module):
     def pool_width(self) -> int:
         """What a token's row of the pool holds: whole lanes."""
         return -(-self.latent_width // LANES) * LANES
+
+    @property
+    def index_pool_width(self) -> int:
+        """What a token's row of the index-key pool holds: whole lanes."""
+        return -(-self.index_dim // LANES) * LANES
 
     @nn.compact
     def __call__(
@@ -269,6 +317,19 @@ class LatentAttention(nn.Module):
             )
 
             resolve_kernel(self.paged_kernel)
+        indexed = self.index_top_k > 0
+        if indexed and (
+            min(self.index_heads, self.index_dim) < 1
+            or self.index_dim < dr or not self.q_lora_rank or self.window
+        ):
+            raise ValueError(
+                "an indexer needs index_heads, an index_dim of at least the "
+                "rotary width, the query's latent (q_lora_rank) and no "
+                f"window, got heads={self.index_heads} dim={self.index_dim} "
+                f"q_lora_rank={self.q_lora_rank} window={self.window}"
+            )
+        if self.window < 0:
+            raise ValueError(f"window must be >= 0, got {self.window}")
         yarn = dict(self.yarn) if self.yarn else None
         freqs = yarn_frequencies(dr, self.rope_theta, yarn)
         multiplier = rope_multiplier(yarn)
@@ -277,6 +338,10 @@ class LatentAttention(nn.Module):
         query = nn.DenseGeneral(
             (h, dn + dr), dtype=self.dtype, use_bias=False, name="query"
         )
+        rq = self.q_lora_rank
+        if rq:
+            q_a = nn.Dense(rq, dtype=self.dtype, use_bias=False, name="q_a")
+            q_norm = nn.RMSNorm(epsilon=self.norm_eps, dtype=F32, name="q_norm")
         kv_a = nn.Dense(
             r + dr, dtype=self.dtype, use_bias=False, name="kv_a"
         )
@@ -288,6 +353,25 @@ class LatentAttention(nn.Module):
             self.d_model, axis=(-2, -1), dtype=self.dtype, use_bias=False,
             name="out",
         )
+
+        if self.gate:
+            gate = nn.Dense(h, dtype=self.dtype, use_bias=False, name="gate")
+        if indexed:
+            index_q = nn.DenseGeneral(
+                (self.index_heads, self.index_dim), dtype=self.dtype,
+                use_bias=False, name="index_q",
+            )
+            index_k = nn.Dense(
+                self.index_dim, dtype=self.dtype, use_bias=False,
+                name="index_k",
+            )
+            index_k_norm = nn.LayerNorm(
+                epsilon=self.norm_eps, dtype=F32, name="index_k_norm"
+            )
+            index_w = nn.Dense(
+                self.index_heads, dtype=self.dtype, use_bias=False,
+                name="index_w",
+            )
 
         paged = self.decode and self.has_variable("cache", "cached_latent")
         batch, t_step = x.shape[:2]
@@ -310,14 +394,52 @@ class LatentAttention(nn.Module):
                     (self.num_pages, self.page_size, self.pool_width),
                     self.dtype,
                 )
+                if indexed:
+                    self.variable(
+                        "cache", "cached_index", jnp.zeros,
+                        (self.num_pages, self.page_size,
+                         self.index_pool_width),
+                        self.dtype,
+                    )
+
+        def rescaled(y, rank):
+            if not self.lora_rescale:
+                return y
+            return y * jnp.asarray(math.sqrt(self.d_model / rank), y.dtype)
 
         with jax.named_scope("mla.project"):
-            q = query(x)  # [B, T, H, dn + dr]
+            c_q = x
+            if rq:
+                c_q = rescaled(q_norm(q_a(x)), rq).astype(self.dtype)
+            q = query(c_q)  # [B, T, H, dn + dr]
             q_nope = q[..., :dn]
             q_pe = rotate(q[..., dn:], positions, freqs, multiplier)
             latent = kv_a(x)  # [B, T, r + dr]
-            c = kv_norm(latent[..., :r]).astype(self.dtype)
+            c = rescaled(kv_norm(latent[..., :r]), r).astype(self.dtype)
             k_pe = rotate(latent[..., r:], positions, freqs, multiplier)
+        if indexed:
+            with jax.named_scope("dsa.project"):
+                def first_rotated(y):
+                    return jnp.concatenate([
+                        rotate(y[..., :dr], positions, freqs, multiplier),
+                        y[..., dr:],
+                    ], axis=-1)
+
+                q_idx = first_rotated(index_q(c_q))  # [B, T, Hi, Di]
+                k_idx = first_rotated(
+                    index_k_norm(index_k(x)).astype(self.dtype)
+                )  # [B, T, Di]
+                # The scores' two scales ride on the head weights.
+                w_idx = index_w(x).astype(F32) * (
+                    self.index_heads**-0.5 * self.index_dim**-0.5
+                )  # [B, T, Hi]
+
+        def gated(out):
+            """``out [B, T, H, dv]`` under the layer's head-wise gate."""
+            if not self.gate:
+                return out
+            g = jax.nn.sigmoid(gate(x).astype(F32)).astype(out.dtype)
+            return out * g[..., None]
 
         if not paged:
             with jax.named_scope("mla.expand"):
@@ -331,12 +453,32 @@ class LatentAttention(nn.Module):
                     "bthd,bkd->bhtk", q_pe, k_pe, preferred_element_type=F32
                 )
             ) * scale
-            causal = positions[:, None, :, None] >= positions[:, None, None, :]
+            causal = positions[:, :, None] >= positions[:, None, :]  # [B, T, K]
+            if self.window:
+                causal &= (
+                    positions[:, :, None] - positions[:, None, :] < self.window
+                )
+            if indexed:
+                from distributed_pytorch_tpu.ops.paged_attention import (
+                    top_k_mask,
+                )
+
+                scores = jnp.einsum(
+                    "bthk,bth->btk",
+                    jax.nn.relu(jnp.einsum(
+                        "bthd,bkd->bthk", q_idx, k_idx,
+                        preferred_element_type=F32,
+                    )),
+                    w_idx,
+                )
+                causal &= top_k_mask(
+                    jnp.where(causal, scores, -jnp.inf), self.index_top_k
+                )
             weights = jax.nn.softmax(
-                jnp.where(causal, logits, NEG_INF), axis=-1
+                jnp.where(causal[:, None], logits, NEG_INF), axis=-1
             ).astype(self.dtype)
             out = jnp.einsum("bhtk,bkhv->bthv", weights, kv[..., dn:])
-            return out_proj(out)
+            return out_proj(gated(out))
 
         pool = self.variable("cache", "cached_latent", lambda: None)
         page = self.page_size
@@ -359,6 +501,15 @@ class LatentAttention(nn.Module):
             pool.value = pool.value.at[phys, flat_pos % page].set(
                 row.astype(pool.value.dtype)
             )
+            if indexed:
+                index_pool = self.variable("cache", "cached_index", lambda: None)
+                index_pool.value = index_pool.value.at[
+                    phys, flat_pos % page
+                ].set(
+                    index_row(k_idx, self.index_pool_width).reshape(
+                        batch * t_step, self.index_pool_width
+                    ).astype(index_pool.value.dtype)
+                )
 
         # The decode kernel attends over the latent itself: absorbed, as the
         # arithmetic says of any single-token call.
@@ -375,17 +526,62 @@ class LatentAttention(nn.Module):
             q_row = _lanes(
                 jnp.concatenate([q_lat, q_pe], axis=-1), self.pool_width
             )
-            mixed = paged_latent_attention(
-                q_row, pool.value, block_tables, seq_lens, v_width=r,
-                kernel=self.paged_kernel, sm_scale=scale,
-                row_groups=row_groups,
-            )
+            if indexed:
+                from distributed_pytorch_tpu.ops.paged_attention import (
+                    paged_index_scores,
+                    selected_positions,
+                    sparse_latent_attention,
+                    top_k_mask,
+                )
+
+                with jax.named_scope("dsa.select"):
+                    scores = paged_index_scores(
+                        _lanes(q_idx[:, 0], self.index_pool_width),
+                        w_idx[:, 0], index_pool.value, block_tables, seq_lens,
+                        kernel=self.paged_kernel,
+                    )  # [B, table tokens] float32
+                    chosen, real = selected_positions(
+                        top_k_mask(scores, self.index_top_k),
+                        min(self.index_top_k, scores.shape[-1]),
+                    )
+                    # For who asks what was selected (the serving engine's
+                    # ``selected_positions``): -1 past a row's own.
+                    self.sow(
+                        "selection", "positions", jnp.where(real, chosen, -1)
+                    )
+                mixed = sparse_latent_attention(
+                    q_row, pool.value, block_tables, chosen, real, v_width=r,
+                    kernel=self.paged_kernel, sm_scale=scale,
+                )
+            else:
+                mixed = paged_latent_attention(
+                    q_row, pool.value, block_tables, seq_lens, v_width=r,
+                    kernel=self.paged_kernel, sm_scale=scale,
+                    row_groups=row_groups,
+                    **({"window": self.window} if self.window else {}),
+                )
         else:
             held = seq_lens + (t_step if valid_lens is None else valid_lens)
+            more = {}
+            if self.window:
+                more["window"] = self.window
+            if indexed:
+                from distributed_pytorch_tpu.ops.paged_attention import (
+                    top_k_mask,
+                )
+
+                with jax.named_scope("dsa.select"):
+                    more["selected"] = top_k_mask(
+                        _index_scores_blocks(
+                            q_idx, w_idx, index_pool.value, block_tables,
+                            positions, jnp.max(held),
+                        ),
+                        self.index_top_k,
+                    )
             mixed = _attend_blocks(
                 q_lat if absorbed else q_nope, q_pe, pool.value, block_tables,
                 positions, jnp.max(held), scale=scale, rank=r, dr=dr,
-                w_kvb=None if absorbed else w_kvb, dn=dn,
+                w_kvb=None if absorbed else w_kvb, dn=dn, **more,
             )
         if absorbed:
             with jax.named_scope("mla.absorb"):
@@ -395,12 +591,56 @@ class LatentAttention(nn.Module):
                 )
         else:
             out = mixed.astype(self.dtype)
-        return out_proj(out)
+        return out_proj(gated(out))
+
+
+def _block_geometry(pool, block_tables):
+    """``(pages a block, tokens a block, blocks a table, the tables padded to
+    whole blocks)`` of the gather loops over a row's pages."""
+    page = pool.shape[1]
+    pages_per_seq = block_tables.shape[1]
+    bp = max(1, min(ATTEND_BLOCK_TOKENS // page, pages_per_seq))
+    n_blocks_max = -(-pages_per_seq // bp)
+    tables = jnp.pad(
+        block_tables, ((0, 0), (0, n_blocks_max * bp - pages_per_seq))
+    )
+    return bp, bp * page, n_blocks_max, tables
+
+
+def _index_scores_blocks(q_idx, w_idx, pool, block_tables, positions, n_keys):
+    """The indexer's scores of ``[B, T, Hi, Di]`` index queries (head weights
+    ``w_idx [B, T, Hi]``, the scales folded in) against the rows' paged index
+    keys, a block of pages at a time over the first ``n_keys`` (traced) key
+    positions: float32 ``[B, T, table tokens in whole blocks]``, ``-inf`` at
+    every key a query does not see and at every block not walked."""
+    batch, t_step = q_idx.shape[:2]
+    bp, bkv, n_blocks_max, tables = _block_geometry(pool, block_tables)
+    n_blocks = jnp.clip((n_keys + bkv - 1) // bkv, 1, n_blocks_max)
+    width = q_idx.shape[-1]
+
+    def block(j, scores):
+        ids = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
+        keys = pool[ids].reshape(batch, bkv, pool.shape[-1])[..., :width]
+        s = jnp.einsum(
+            "bthk,bth->btk",
+            jax.nn.relu(jnp.einsum(
+                "bthd,bkd->bthk", q_idx, keys, preferred_element_type=F32
+            )),
+            w_idx,
+        )
+        k_abs = j * bkv + jnp.arange(bkv, dtype=jnp.int32)
+        s = jnp.where(k_abs[None, None, :] <= positions[:, :, None], s, -jnp.inf)
+        return jax.lax.dynamic_update_slice_in_dim(scores, s, j * bkv, axis=2)
+
+    return jax.lax.fori_loop(
+        0, n_blocks, block,
+        jnp.full((batch, t_step, n_blocks_max * bkv), -jnp.inf, F32),
+    )
 
 
 def _attend_blocks(
     q_main, q_pe, pool, block_tables, positions, n_keys, *, scale, rank, dr,
-    w_kvb, dn,
+    w_kvb, dn, window: int = 0, selected=None,
 ):
     """Attention of ``[B, T, H, .]`` queries over the rows' paged latents, a
     block of pages at a time with an online softmax, over the first
@@ -408,18 +648,23 @@ def _attend_blocks(
     form: ``q_main`` is ``q~ [B, T, H, r]`` and the result the weighted sum of
     ``c`` ``[B, T, H, r]`` (float32). Else the expanded form: ``q_main`` is
     ``q_nope``, every block's keys and values are rebuilt from its ``c``, and
-    the result is ``[B, T, H, dv]``."""
+    the result is ``[B, T, H, dv]``.
+
+    ``window`` keeps a query at ``t`` to the keys ``(t - window, t]`` and
+    starts the walk at the first block that any query's window meets;
+    ``selected [B, T, table tokens in whole blocks]`` (bool) keeps a query to
+    the keys it marks. Under either a query may see nothing in a block: its
+    weights there are zeroed, not left to the running max."""
     batch, t_step, h = q_main.shape[:3]
-    page = pool.shape[1]
-    pages_per_seq = block_tables.shape[1]
-    bp = max(1, min(ATTEND_BLOCK_TOKENS // page, pages_per_seq))
-    bkv = bp * page
-    n_blocks_max = -(-pages_per_seq // bp)
-    tables = jnp.pad(
-        block_tables, ((0, 0), (0, n_blocks_max * bp - pages_per_seq))
-    )
+    bp, bkv, n_blocks_max, tables = _block_geometry(pool, block_tables)
     width = rank if w_kvb is None else w_kvb.shape[-1] - dn
     n_blocks = jnp.clip((n_keys + bkv - 1) // bkv, 1, n_blocks_max)
+    narrowed = bool(window) or selected is not None
+    first_block = 0
+    if window:
+        first_block = jnp.clip(
+            (jnp.min(positions) - (window - 1)) // bkv, 0, n_blocks - 1
+        )
 
     def block(j, carry):
         m_prev, l_prev, acc = carry
@@ -446,11 +691,19 @@ def _attend_blocks(
         ) * scale
         k_abs = j * bkv + jnp.arange(bkv, dtype=jnp.int32)
         visible = k_abs[None, None, :] <= positions[:, :, None]  # [B, T, K]
+        if window:
+            visible &= k_abs[None, None, :] > positions[:, :, None] - window
+        if selected is not None:
+            visible &= jax.lax.dynamic_slice_in_dim(
+                selected, j * bkv, bkv, axis=2
+            )
         s = jnp.where(visible[:, None], s, NEG_INF)
         # Block 0 holds key 0, which every query sees: the running max is
         # finite from the first block on.
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
+        if narrowed:
+            p = jnp.where(visible[:, None], p, 0.0)
         correction = jnp.exp(m_prev - m_new)
         l_new = l_prev * correction + jnp.sum(p, axis=-1)
         p = p.astype(values.dtype)
@@ -469,5 +722,9 @@ def _attend_blocks(
         jnp.zeros((batch, h, t_step), F32),
         jnp.zeros((batch, h, t_step, width), F32),
     )
-    _, l_fin, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+    _, l_fin, acc = jax.lax.fori_loop(first_block, n_blocks, block, init)
+    if narrowed:
+        # A row that carries no request (a dead decode row, a piece's
+        # padding) may see no key at all: its output is nobody's.
+        l_fin = jnp.maximum(l_fin, jnp.finfo(F32).tiny)
     return (acc / l_fin[..., None]).transpose(0, 2, 1, 3)  # [B, T, H, .]

@@ -239,16 +239,28 @@ def route_top_k(scores: jnp.ndarray, top_k: int):
 #: ``"top_k_of_softmax"``: a softmax over ALL the scores, then the ``top_k``
 #: largest probabilities as they are, NOT renormalised (a configuration's
 #: ``scoring_func: softmax`` with ``norm_topk_prob: false``).
-GATINGS = ("softmax_of_top_k", "top_k_of_softmax")
+#: ``"sigmoid_biased"``: ``s = sigmoid(scores)``, the ``top_k`` experts of
+#: largest ``s + bias`` (a correction bias an expert, used for the CHOICE
+#: alone), gates ``s_e / sum of the chosen s`` (``scoring_func: sigmoid``,
+#: ``topk_method: noaux_tc`` with one group, ``norm_topk_prob: true``).
+GATINGS = ("softmax_of_top_k", "top_k_of_softmax", "sigmoid_biased")
 
 
-def route(scores: jnp.ndarray, top_k: int, gating: str = GATINGS[0]):
-    """``(gates, experts)`` as :func:`route_top_k` gives them, under either
-    of :data:`GATINGS`."""
+def route(
+    scores: jnp.ndarray, top_k: int, gating: str = GATINGS[0], bias=None
+):
+    """``(gates, experts)`` as :func:`route_top_k` gives them, under any of
+    :data:`GATINGS`. ``bias [E]`` is ``"sigmoid_biased"``'s correction bias
+    (``None``: zeros); the other rules take none."""
     if gating == "softmax_of_top_k":
         return route_top_k(scores, top_k)
     if gating == "top_k_of_softmax":
         return jax.lax.top_k(jax.nn.softmax(scores, axis=-1), top_k)
+    if gating == "sigmoid_biased":
+        s = jax.nn.sigmoid(scores)
+        _, experts = jax.lax.top_k(s if bias is None else s + bias, top_k)
+        chosen = jnp.take_along_axis(s, experts, axis=-1)
+        return chosen / jnp.sum(chosen, axis=-1, keepdims=True), experts
     raise ValueError(
         f"unknown gating rule {gating!r} (expected one of {GATINGS})"
     )
@@ -272,7 +284,8 @@ class RoutedExperts(nn.Module):
     """Dropless top-k routed gated-SiLU experts over a held range (module
     docstring). ``[B, T, d_model] -> [B, T, d_model]``.
 
-    Parameters: ``router/kernel [d_model, n_experts]`` (no bias),
+    Parameters: ``router_kernel [d_model, n_experts]`` (no bias; under the
+    ``"sigmoid_biased"`` rule also ``router_bias [n_experts]``, float32),
     ``in_kernel [held, d_model, 2 d_ff]`` (``[gate, up]``) and ``out_kernel
     [held, d_ff, d_model]``. ``live`` (optional) marks what carries a
     request: ``[B]`` whole batch rows (the batched decode step's rows inside
@@ -317,7 +330,13 @@ class RoutedExperts(nn.Module):
                 precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=ROUTER_DTYPE,
             )
-            gates, experts = route(scores, k, self.gating)  # [tokens, k]
+            bias = None
+            if self.gating == "sigmoid_biased":
+                bias = self.param(
+                    "router_bias", nn.initializers.zeros, (self.n_experts,),
+                    F32,
+                ).astype(ROUTER_DTYPE)
+            gates, experts = route(scores, k, self.gating, bias)  # [tokens, k]
             if live is None:
                 alive = jnp.ones((tokens,), bool)
             elif live.ndim == 1:

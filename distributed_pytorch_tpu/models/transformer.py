@@ -205,8 +205,9 @@ class Attention(nn.Module):
                 )
             if self.window:
                 raise ValueError(
-                    "paged decode does not compose with sliding-window "
-                    "attention yet"
+                    "a window in a K/V (non-latent) layer is not served "
+                    "through pages yet; a latent layer's window is "
+                    "(models/mla.py)"
                 )
             if self.kv_quant not in ("", "int8"):
                 raise ValueError(
@@ -632,9 +633,15 @@ class MLPBlock(nn.Module):
         return dense(self.d_model, "down")(h)
 
 
-LAYER_TYPES = ("attention", "mamba", "mamba2", "latent")
+LAYER_TYPES = (
+    "attention", "mamba", "mamba2", "latent", "latent_sparse", "latent_window"
+)
 #: The layer types that keep a per-slot recurrent state in decode mode.
 RECURRENT_TYPES = ("mamba", "mamba2")
+#: The layer types that are models/mla.py's LatentAttention: the plain one,
+#: one with an indexer (learned sparse attention; a second page pool), one
+#: with a window. The last two take their sizes from ``latent_variants``.
+LATENT_TYPES = ("latent", "latent_sparse", "latent_window")
 FFN_TYPES = ("dense", "routed")
 
 
@@ -745,12 +752,14 @@ class TransformerBlock(nn.Module):
                 self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
                 decode=self.decode, name="mamba", **dict(self.mamba),
             )(normed, seq_lens=seq_lens, state_slots=state_slots, **piece_kw)
-        elif self.mixer == "latent":
+        elif self.mixer in LATENT_TYPES:
             from distributed_pytorch_tpu.models.mla import LatentAttention
 
+            # ``latent`` holds the layer type's own heads and rotary base
+            # (TransformerLM.latent_sizes).
             mixed = LatentAttention(
-                self.n_heads, self.d_model, dtype=self.dtype,
-                norm_eps=self.norm_eps, rope_theta=self.rope_theta,
+                d_model=self.d_model, dtype=self.dtype,
+                norm_eps=self.norm_eps,
                 decode=self.decode, page_size=self.page_size,
                 num_pages=self.num_pages, paged_kernel=self.paged_kernel,
                 name="mla", **dict(self.latent),
@@ -995,6 +1004,14 @@ class TransformerLM(nn.Module):
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_yarn: Optional[tuple] = None
+    # The other LATENT_TYPES' own sizes: ``((type, ((field, value), ...)),
+    # ...)``, LatentAttention's fields for the layers of that type. What a
+    # variant names replaces the model's (``n_heads``, ``rope_theta``, the
+    # four sizes above); its ``window``, ``q_lora_rank``, ``gate``,
+    # ``lora_rescale`` and indexer (``index_heads``, ``index_dim``,
+    # ``index_top_k``) are the layer's own: a window and a rotary base are a
+    # layer's, not the model's.
+    latent_variants: Optional[tuple] = None
 
     @property
     def recurrent_layers(self) -> int:
@@ -1003,8 +1020,28 @@ class TransformerLM(nn.Module):
 
     @property
     def latent_layers(self) -> int:
-        """How many layers keep latent pages (one pool, no head axis)."""
-        return sum(t == "latent" for t in self.layer_types or ())
+        """How many layers keep latent pages (pools with no head axis)."""
+        return sum(t in LATENT_TYPES for t in self.layer_types or ())
+
+    def latent_sizes(self, layer_type: str) -> dict:
+        """LatentAttention's sizes for the layers of ``layer_type`` (one of
+        LATENT_TYPES): the model's, under the variant's own."""
+        sizes = dict(
+            n_heads=self.n_heads, rope_theta=self.rope_theta,
+            kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, yarn=self.rope_yarn,
+        )
+        if layer_type != "latent":
+            variants = dict(self.latent_variants or ())
+            if layer_type not in variants:
+                raise ValueError(
+                    f"a model with {layer_type!r} layers needs that type's "
+                    f"sizes in latent_variants"
+                )
+            sizes.update(dict(variants[layer_type]))
+        return sizes
 
     @property
     def routed_layers(self) -> int:
@@ -1106,13 +1143,12 @@ class TransformerLM(nn.Module):
                 ("d_state", self.mamba_d_state), ("d_conv", self.mamba_d_conv),
                 ("n_groups", self.mamba_n_groups),
             ),
-            "latent": (
-                ("kv_lora_rank", self.kv_lora_rank),
-                ("qk_nope_head_dim", self.qk_nope_head_dim),
-                ("qk_rope_head_dim", self.qk_rope_head_dim),
-                ("v_head_dim", self.v_head_dim), ("yarn", self.rope_yarn),
-            ),
         }
+        for latent_type in LATENT_TYPES:
+            if latent_type in (types or ()):
+                mixer_kw[latent_type] = tuple(
+                    sorted(self.latent_sizes(latent_type).items())
+                )
         routed = (
             ("n_experts", self.routed_experts),
             ("top_k", self.routed_top_k), ("held", self.experts_held),
@@ -1127,7 +1163,7 @@ class TransformerLM(nn.Module):
             moe = self.n_experts if (i + 1) % self.moe_every == 0 else 0
             layer_kw = block_kw
             if types is not None and types[i] != "attention":
-                sizes = "latent" if types[i] == "latent" else "mamba"
+                sizes = "latent" if types[i] in LATENT_TYPES else "mamba"
                 layer_kw = dict(
                     block_kw, mixer=types[i],
                     **{sizes: mixer_kw.get(types[i], ())},

@@ -126,6 +126,7 @@ def paged_attention_reference(
     v_scale: Optional[jnp.ndarray] = None,
     sm_scale: Optional[float] = None,
     v_width: Optional[int] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """The XLA gather path: op-for-op the read side of
     ``_paged_decode_step`` (gather each row's pages into its contiguous
@@ -141,7 +142,9 @@ def paged_attention_reference(
     ``v_pool=None`` is the latent case (``models/mla.py``): ``k_pool`` is
     ONE pool ``[num_pages, page, W]`` with no head axis, ``q`` is ``[S,
     T_step, H, W]``, and a token's value is the first ``v_width`` numbers of
-    its key; the result is ``[S, T_step, H, v_width]``."""
+    its key; the result is ``[S, T_step, H, v_width]``. ``window`` (the
+    latent case only) keeps a query at ``t`` to the keys ``(t - window, t]``;
+    0 is none."""
     s, t_step, h, d = q.shape
     page = k_pool.shape[1]
     pages_per_seq = block_tables.shape[1]
@@ -160,6 +163,8 @@ def paged_attention_reference(
         scale = d**-0.5 if sm_scale is None else sm_scale
         k_abs = jnp.arange(kv_len)[None, None, :]
         visible = k_abs <= positions[:, :, None]  # [S, T_step, K]
+        if window:
+            visible &= k_abs > positions[:, :, None] - window
         logits = jnp.einsum("bqhd,bkd->bhqk", q, keys) * scale
         logits = jnp.where(visible[:, None], logits, NEG_INF)
         weights = jax.nn.softmax(
@@ -661,9 +666,8 @@ def latent_tokens_fetched(
 
 
 def _latent_decode_kernel(
-    bt_ref, lens_ref, lead_ref, shared_ref, q_ref, pool_hbm, o_ref, buf,
-    sems, stream, members, qg_scr, mg_scr, lg_scr, accg_scr, m_st, l_st,
-    acc_st, *, npb, v_width, sm_scale,
+    bt_ref, lens_ref, lead_ref, shared_ref, *refs, npb, v_width, sm_scale,
+    windowed=False,
 ):
     """One slot (grid step) of the latent flash-decode kernel: the frame of
     :func:`_decode_kernel` (walk the row's own blocks and only those, the
@@ -697,7 +701,15 @@ def _latent_decode_kernel(
     (pages past its live ones repeat the last live one and die in the mask,
     so nothing unowned or stale is ever read), and only it needs a mask. The
     products take the pool's type as it is stored (bf16 on the chip: the
-    MXU's own) and accumulate in float32."""
+    MXU's own) and accumulate in float32.
+
+    ``windowed`` (a sliding layer's call): a fifth scalar operand ``lo_ref``
+    gives each row the first key position it sees, and every block is masked
+    on both sides (the tables such a call is handed begin at the window's
+    first live page, so that position lies in the row's first page)."""
+    lo_ref, refs = (refs[0], refs[1:]) if windowed else (None, refs)
+    (q_ref, pool_hbm, o_ref, buf, sems, stream, members, qg_scr, mg_scr,
+     lg_scr, accg_scr, m_st, l_st, acc_st) = refs
     b = pl.program_id(0)
     slots, pages_per_seq = bt_ref.shape
     h, w = q_ref.shape[1:]
@@ -803,7 +815,12 @@ def _latent_decode_kernel(
             )
             # Every walked block's first key is visible, so the running max
             # stays finite and no exp(NEG_INF - NEG_INF) row can arise.
-            s_blk = jnp.where(kpos <= limit, s_blk, NEG_INF)
+            seen = kpos <= limit
+            if windowed:
+                # A window's first block holds the window's first key: it too
+                # has a visible key.
+                seen = jnp.logical_and(seen, kpos >= lo_ref[b])
+            s_blk = jnp.where(seen, s_blk, NEG_INF)
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
@@ -840,7 +857,10 @@ def _latent_decode_kernel(
 
             @pl.when(left > npb)
             def _whole():
-                attend(slot, npb, q, key0, None, m_ref, l_ref, acc_ref)
+                attend(
+                    slot, npb, q, key0, limit if windowed else None, m_ref,
+                    l_ref, acc_ref,
+                )
 
             fits = _fitting(left, widths)
             for pages in widths:
@@ -935,8 +955,8 @@ def _latent_decode_kernel(
     static_argnames=("pages_per_block", "interpret", "sm_scale", "v_width"),
 )
 def _latent_flash(
-    q3, pool, block_tables, seq_lens, leader, shared, *, pages_per_block,
-    interpret, sm_scale, v_width,
+    q3, pool, block_tables, seq_lens, leader, shared, first_key=None, *,
+    pages_per_block, interpret, sm_scale, v_width,
 ):
     """The latent kernel's ``pallas_call`` for ``q3`` [S, H, W]: jitted and
     named for :func:`_paged_flash`'s reasons (one trace for a model's layers;
@@ -944,11 +964,20 @@ def _latent_flash(
     it). The pool stays in HBM and a page is copied as the ``[page, W]`` rows
     it is stored as; the tables, the lengths and the rows' grouping ride as
     scalar prefetch; every row's query is held in VMEM for the whole call (a
-    leader needs its members')."""
+    leader needs its members'). ``first_key [S]`` makes it the WINDOWED call
+    (:func:`_latent_decode_kernel`), which the device trace shows under a name
+    of its own, ``attention._window_latent_decode_step``."""
     s, h, w = q3.shape
     page = pool.shape[1]
     npb = int(pages_per_block)
     m_rows = GROUP_ROWS * h
+    windowed = first_key is not None
+    scalars = [
+        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+        leader.astype(jnp.int32), shared.astype(jnp.int32),
+    ]
+    if windowed:
+        scalars.append(first_key.astype(jnp.int32))
 
     def row_spec(shape):
         return pl.BlockSpec(
@@ -970,7 +999,7 @@ def _latent_flash(
         pltpu.VMEM((s, h, v_width), jnp.float32),  # every row's accumulator
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(scalars),
         grid=(s,),
         in_specs=[
             pl.BlockSpec(
@@ -994,7 +1023,7 @@ def _latent_flash(
     return pl.pallas_call(
         functools.partial(
             _latent_decode_kernel, npb=npb, v_width=v_width,
-            sm_scale=sm_scale,
+            sm_scale=sm_scale, **({"windowed": True} if windowed else {}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, v_width), q3.dtype),
@@ -1003,11 +1032,8 @@ def _latent_flash(
             vmem_limit_bytes=max(16 << 20, int(1.5 * held)),
         ),
         interpret=interpret,
-        name="attention._latent_decode_step",
-    )(
-        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-        leader.astype(jnp.int32), shared.astype(jnp.int32), q3, pool,
-    )
+        name=WINDOW_KERNEL if windowed else "attention._latent_decode_step",
+    )(*scalars, q3, pool)
 
 
 def paged_latent_attention(
@@ -1021,6 +1047,7 @@ def paged_latent_attention(
     pages_per_block: Optional[int] = None,
     sm_scale: Optional[float] = None,
     row_groups=None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Paged attention of ``q`` [S, T_step, H, W] over ONE latent pool
     ``[num_pages, page, W]``: every head's key at a position is the pool's
@@ -1032,7 +1059,12 @@ def paged_latent_attention(
     width. ``row_groups`` is :func:`shared_prefix_groups`' ``(leader,
     shared)`` for these tables and lengths where the caller has worked it out
     (a decode program does, once for all its layers: :func:`latent_row_groups`);
-    ``None`` works it out here. Only the kernel reads it."""
+    ``None`` works it out here. Only the kernel reads it.
+
+    ``window`` keeps a query at ``t`` to the keys ``(t - window, t]``. The
+    kernel is then handed each row's table FROM the window's first live page
+    (:func:`window_tables`: ``window_pages`` entries, whatever the table's
+    width) and walks those pages alone, every row by itself."""
     s, t_step, h, w = q.shape
     if pool.ndim != 3 or pool.shape[2] != w:
         raise ValueError(
@@ -1044,8 +1076,15 @@ def paged_latent_attention(
     if mode == "xla" or t_step != 1:
         return paged_attention_reference(
             q, pool, None, block_tables, seq_lens, sm_scale=sm_scale,
-            v_width=v_width,
+            v_width=v_width, **({"window": window} if window else {}),
         )
+    first_key = ()
+    if window:
+        block_tables, seq_lens, lo = window_tables(
+            block_tables, seq_lens, pool.shape[1], window
+        )
+        rows = jnp.arange(s, dtype=jnp.int32)
+        row_groups, first_key = (rows, jnp.zeros_like(rows)), (lo,)
     npb = block_pages(
         block_tables.shape[1], pool.shape[1], w, pool.dtype, pages_per_block
     )
@@ -1055,8 +1094,398 @@ def paged_latent_attention(
         )
     out3 = _latent_flash(
         q.reshape(s, h, w), pool, block_tables, seq_lens, *row_groups,
-        pages_per_block=npb, interpret=(mode == "interpret"),
+        *first_key, pages_per_block=npb, interpret=(mode == "interpret"),
         sm_scale=float(w**-0.5 if sm_scale is None else sm_scale),
+        v_width=int(v_width),
+    )
+    return out3.reshape(s, 1, h, v_width)
+
+
+# ------------------------------------------------------------ windowed latent
+#
+# A sliding layer's decode reads the pages that meet its window and no other:
+# the kernel above, handed each row's table from the window's first live page
+# on (the block loop's "first live block").
+
+WINDOW_KERNEL = "attention._window_latent_decode_step"
+
+
+def window_pages(window: int, page: int) -> int:
+    """Pages that a window of ``window`` key positions (the query's own among
+    them) can meet: its first key may stand last in its page."""
+    return 1 + -(-(window - 1) // page)
+
+
+def window_tokens_read(positions, window: int, page: int):
+    """Key positions the windowed kernel copies for decode rows at
+    ``positions`` (NumPy): the whole pages from the one that holds the
+    window's first key to the one that holds ``pos``."""
+    first = np.maximum(positions - (window - 1), 0) // page
+    return (positions // page + 1 - first) * page
+
+
+def window_tables(block_tables, seq_lens, page: int, window: int):
+    """``(tables, lens, first_key)`` of a windowed decode call: row ``r``'s
+    table from the page that holds its window's first key ``max(pos - window
+    + 1, 0)`` on, ``window_pages`` entries (past the table's end: its last
+    entry again, which lies past ``pos`` there and is never copied), and its
+    position and that first key counted from that page's first token. A row
+    out of the dispatch keeps a table that starts at the null page."""
+    lens = seq_lens.astype(jnp.int32)
+    first = jnp.maximum(lens - (window - 1), 0)
+    page0 = first // page
+    index = jnp.minimum(
+        page0[:, None]
+        + jnp.arange(window_pages(window, page), dtype=jnp.int32),
+        block_tables.shape[1] - 1,
+    )
+    tables = jnp.take_along_axis(block_tables.astype(jnp.int32), index, axis=1)
+    return tables, lens - page0 * page, first - page0 * page
+
+
+# ---------------------------------------------------- learned sparse attention
+#
+# A full layer of a model with an INDEXER (``models/mla.py``) keeps a second
+# pool a layer, ``[num_pages, page, index width]``: one index key a token. A
+# decode row scores every cached token with it (:func:`paged_index_scores`),
+# keeps the ``k`` best (:func:`top_k_mask`, :func:`selected_positions`: exact)
+# and attends over those tokens' latents alone
+# (:func:`sparse_latent_attention`).
+
+INDEX_KERNEL = "attention._index_scores"
+SPARSE_KERNEL = "attention._sparse_latent_decode_step"
+#: Pages the index kernel copies and scores at a time (512 keys of 128 at 16
+#: a page: a 128 KB block in bf16).
+INDEX_BLOCK_PAGES = 32
+
+
+def index_scores_reference(q, w, pool, block_tables, seq_lens):
+    """:func:`paged_index_scores` on the gather path: every row's index keys
+    through its table, ``sum_h w_h relu(q_h . k)``, ``-inf`` past ``pos``."""
+    s = q.shape[0]
+    keys = pool[block_tables].reshape(s, -1, pool.shape[-1])
+    logits = jnp.einsum(
+        "shd,skd->shk", q, keys, preferred_element_type=jnp.float32
+    )
+    scores = jnp.einsum(
+        "shk,sh->sk", jax.nn.relu(logits), w.astype(jnp.float32)
+    )
+    seen = jnp.arange(keys.shape[1])[None, :] <= seq_lens[:, None]
+    return jnp.where(seen, scores, -jnp.inf)
+
+
+def _index_scores_kernel(
+    bt_ref, lens_ref, q_ref, w_ref, pool_hbm, o_ref, buf, sems, *, npb
+):
+    """One slot (grid step): the frame of :func:`_decode_kernel` over the
+    index-key pool. The row's pages up to the one that holds ``pos`` are
+    copied a block of ``npb`` at a time into one of two buffers (the next
+    block's copies started before this one is scored); a block's ``[keys,
+    D]`` meet the row's ``[H, D]`` index queries on the MXU, and ReLU, the
+    head weights and the sum over heads leave ONE float32 a key, written to
+    the row's ``[blocks, block keys]`` result. Keys past ``pos``, and whole
+    blocks past it, read ``-inf``; a row out of the dispatch reads ``-inf``
+    everywhere."""
+    b = pl.program_id(0)
+    pages_per_seq = bt_ref.shape[1]
+    page, d = buf.shape[2:]
+    bkv = npb * page
+    n_blocks_max = o_ref.shape[1]
+    o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, o_ref.dtype)
+
+    @pl.when(bt_ref[b, 0] != NULL_PAGE)
+    def _row():
+        pos = lens_ref[b]
+        last = jnp.minimum(pos // page, pages_per_seq - 1)
+        n_blocks = jnp.minimum(pos // bkv + 1, n_blocks_max)
+
+        def copies(blk, slot):
+            return [
+                pltpu.make_async_copy(
+                    pool_hbm.at[bt_ref[b, jnp.minimum(blk * npb + n, last)]],
+                    buf.at[slot, n], sems.at[slot],
+                )
+                for n in range(npb)
+            ]
+
+        for copy in copies(0, 0):
+            copy.start()
+        q = q_ref[0].astype(buf.dtype)  # [H, D]
+        w = w_ref[0]  # [H, 1] float32
+
+        def block(j, carry):
+            slot = j % 2
+
+            @pl.when(j + 1 < n_blocks)
+            def _next_block():
+                for copy in copies(j + 1, 1 - slot):
+                    copy.start()
+
+            for copy in copies(j, slot):
+                copy.wait()
+            k = buf[slot].reshape(bkv, d)
+            s_blk = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [H, keys]
+            total = jnp.sum(
+                jnp.maximum(s_blk, 0.0) * w, axis=0, keepdims=True
+            )
+            kpos = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
+            o_ref[0, pl.ds(j, 1), :] = jnp.where(
+                kpos <= pos, total, -jnp.inf
+            )
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, block, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
+def _index_flash(q, w, pool, block_tables, seq_lens, *, pages_per_block,
+                 interpret):
+    """The index kernel's ``pallas_call`` for ``q [S, H, D]`` and ``w [S,
+    H]``: jitted and named for :func:`_paged_flash`'s reasons."""
+    s, h, d = q.shape
+    page = pool.shape[1]
+    npb = int(pages_per_block)
+    nblk = -(-block_tables.shape[1] // npb)
+
+    def row_spec(shape):
+        return pl.BlockSpec(
+            shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1),
+            memory_space=pltpu.VMEM,
+        )
+
+    out = pl.pallas_call(
+        functools.partial(_index_scores_kernel, npb=npb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s,),
+            in_specs=[
+                row_spec((1, h, d)), row_spec((1, h, 1)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=row_spec((1, nblk, npb * page)),
+            scratch_shapes=[
+                pltpu.VMEM((2, npb, page, d), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, nblk, npb * page), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+        name=INDEX_KERNEL,
+    )(
+        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q,
+        w.astype(jnp.float32)[..., None], pool,
+    )
+    return out.reshape(s, nblk * npb * page)[:, : block_tables.shape[1] * page]
+
+
+def paged_index_scores(
+    q: jnp.ndarray,
+    w: jnp.ndarray,
+    pool: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    seq_lens: jnp.ndarray,
+    *,
+    kernel="auto",
+) -> jnp.ndarray:
+    """A decode row's index score of every cached token: ``q [S, H, D]`` index
+    queries and ``w [S, H]`` head weights (the scores' scales folded in)
+    against the index-key pool ``[num_pages, page, D]`` through the rows'
+    tables: float32 ``[S, pages_per_seq * page]``, ``sum_h w_h relu(q_h .
+    k_s)`` at ``s <= pos`` and ``-inf`` past it. Dispatches per ``kernel``
+    as :func:`paged_attention` does."""
+    if pool.ndim != 3 or pool.shape[2] != q.shape[-1]:
+        raise ValueError(
+            f"an index-key pool is [num_pages, page, {q.shape[-1]}], got "
+            f"{pool.shape}"
+        )
+    mode = resolve_kernel(kernel)
+    if mode == "xla":
+        return index_scores_reference(q, w, pool, block_tables, seq_lens)
+    return _index_flash(
+        q, w, pool, block_tables, seq_lens,
+        pages_per_block=min(INDEX_BLOCK_PAGES, block_tables.shape[1]),
+        interpret=(mode == "interpret"),
+    )
+
+
+def _ordered_bits(scores):
+    """float32 scores as uint32 that order as the scores do."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+#: Bits of the ``k``-th largest score that one pass of :func:`top_k_mask`
+#: settles: a pass counts the scores at or above ``2 ** SELECT_BITS - 1``
+#: trial values at once, and 32 / SELECT_BITS passes follow one another.
+SELECT_BITS = 4
+
+
+def top_k_mask(scores, k: int):
+    """The EXACT top ``k`` of ``scores [..., N]`` along the last axis, as a
+    mask: true at the ``k`` largest, equal scores by lowest index
+    (``jax.lax.top_k``'s order), and never at ``-inf`` (fewer than ``k``
+    where fewer are finite). No sort: the ``k``-th largest is found
+    ``SELECT_BITS`` bits a pass from the top (a pass counts the scores, as
+    ordered integers, at or above every value those bits can make it), then
+    the ties at it are counted off."""
+    n = scores.shape[-1]
+    if k >= n:
+        return scores > -jnp.inf
+    bits = _ordered_bits(scores)
+    trials = jnp.arange(1, 1 << SELECT_BITS, dtype=jnp.uint32)
+
+    def refine(i, kth):
+        shift = (32 - SELECT_BITS * (i + 1)).astype(jnp.uint32)
+        trial = kth[..., None] | (trials << shift)  # [..., trials], rising
+        enough = jnp.sum(
+            bits[..., None, :] >= trial[..., None], axis=-1
+        ) >= k
+        best = jnp.sum(enough, axis=-1).astype(jnp.uint32)  # trials that hold
+        return kth | (best << shift)
+
+    kth = jax.lax.fori_loop(
+        0, 32 // SELECT_BITS, refine,
+        jnp.zeros(scores.shape[:-1], jnp.uint32),
+    )[..., None]
+    above = bits > kth
+    tied = bits == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    chosen = above | (tied & (jnp.cumsum(tied, axis=-1) <= room))
+    return chosen & (scores > -jnp.inf)
+
+
+def selected_positions(mask, k: int):
+    """The positions at which ``mask [S, N]`` is true, ascending, as ``[S,
+    k]`` int32 and which of them are real (a row with fewer than ``k`` pads
+    with position 0, marked false). No sort, no loop: the ``j``-th true
+    position lies in the block of 128 lanes at which the blocks' running
+    count first reaches ``j``, at the lane at which that block's own count
+    first reaches what is left."""
+    s, n = mask.shape
+    lanes = 128
+    blocks = -(-n // lanes)
+    held = jnp.pad(mask, ((0, 0), (0, blocks * lanes - n))).reshape(
+        s, blocks, lanes
+    ).astype(jnp.int32)
+    inside = jnp.cumsum(held, axis=-1)  # a block's own running count
+    upto = jnp.cumsum(inside[..., -1], axis=-1)  # [S, blocks], inclusive
+    want = jnp.arange(1, k + 1, dtype=jnp.int32)
+    block = jnp.minimum(
+        jnp.sum(upto[:, None, :] < want[None, :, None], axis=-1), blocks - 1
+    )  # [S, k]
+    before = jnp.take_along_axis(upto - inside[..., -1], block, axis=1)
+    counts = jnp.take_along_axis(inside, block[..., None], axis=1)
+    lane = jnp.sum(counts < (want[None, :] - before)[..., None], axis=-1)
+    real = want[None, :] <= upto[:, -1:]
+    where = block * lanes + jnp.minimum(lane, lanes - 1)
+    return jnp.where(real, where, 0).astype(jnp.int32), real
+
+
+def _rows_at(pool, block_tables, positions):
+    """The pool's rows of the token ``positions [S, k]`` through the rows'
+    tables: ``[S, k, W]`` (XLA's gather)."""
+    page = pool.shape[1]
+    phys = jnp.take_along_axis(
+        block_tables.astype(jnp.int32), positions // page, axis=1
+    )
+    return pool[phys, positions % page]
+
+
+def _sparse_decode_kernel(q_ref, k_ref, real_ref, o_ref, *, v_width, sm_scale):
+    """One slot: ``[H, W]`` absorbed queries against the row's ``[k, W]``
+    gathered latents, one softmax over the real ones, and their first
+    ``v_width`` columns as values."""
+    keys = k_ref[0]
+    s_all = jax.lax.dot_general(
+        q_ref[0].astype(keys.dtype), keys, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * sm_scale  # [H, k]
+    s_all = jnp.where(real_ref[0] > 0, s_all, NEG_INF)
+    p = jnp.exp(s_all - jnp.max(s_all, axis=-1, keepdims=True))
+    pv = jax.lax.dot_general(
+        p.astype(keys.dtype), keys[:, :v_width], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    o_ref[0] = (pv / jnp.sum(p, axis=-1, keepdims=True)).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "sm_scale", "v_width")
+)
+def _sparse_flash(q3, pool, block_tables, positions, real, *, interpret,
+                  sm_scale, v_width):
+    """Gather the selected tokens' latents through the tables (XLA's gather:
+    ``[S, k, W]``), then the sparse kernel's ``pallas_call`` over them,
+    named as the others are."""
+    s, h, w = q3.shape
+    k = positions.shape[1]
+    keys = _rows_at(pool, block_tables, positions)
+
+    def row_spec(shape):
+        return pl.BlockSpec(
+            shape, lambda b: (b,) + (0,) * (len(shape) - 1),
+            memory_space=pltpu.VMEM,
+        )
+
+    item = jnp.dtype(pool.dtype).itemsize
+    held = 2 * k * w * item + 3 * h * k * 4 + 4 * h * (w + v_width) * 4
+    return pl.pallas_call(
+        functools.partial(
+            _sparse_decode_kernel, v_width=v_width, sm_scale=sm_scale
+        ),
+        grid=(s,),
+        in_specs=[
+            row_spec((1, h, w)), row_spec((1, k, w)), row_spec((1, 1, k)),
+        ],
+        out_specs=row_spec((1, h, v_width)),
+        out_shape=jax.ShapeDtypeStruct((s, h, v_width), q3.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=max(16 << 20, int(1.5 * held)),
+        ),
+        interpret=interpret,
+        name=SPARSE_KERNEL,
+    )(q3, keys, real.astype(jnp.int32)[:, None, :])
+
+
+def sparse_latent_attention(
+    q: jnp.ndarray,
+    pool: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    positions: jnp.ndarray,
+    real: jnp.ndarray,
+    *,
+    v_width: int,
+    kernel="auto",
+    sm_scale: Optional[float] = None,
+) -> jnp.ndarray:
+    """Absorbed latent attention of decode rows ``q [S, 1, H, W]`` over a LIST
+    of cached token positions a row (``positions [S, k]``, of which ``real
+    [S, k]`` count) instead of the row's whole table: ``[S, 1, H,
+    v_width]``. The tokens' latents are gathered out of the pool ``[num_pages,
+    page, W]`` through the tables; nothing else of the pool is read."""
+    s, t_step, h, w = q.shape
+    if t_step != 1:
+        raise ValueError("a list of selected positions serves decode rows")
+    mode = resolve_kernel(kernel)
+    scale = float(w**-0.5 if sm_scale is None else sm_scale)
+    if mode == "xla":
+        keys = _rows_at(pool, block_tables, positions)
+        logits = jnp.einsum("bqhd,bkd->bhqk", q, keys) * scale
+        logits = jnp.where(real[:, None, None, :], logits, NEG_INF)
+        weights = jax.nn.softmax(
+            logits.astype(jnp.float32), axis=-1
+        ).astype(q.dtype)
+        return jnp.einsum("bhqk,bkd->bqhd", weights, keys[..., :v_width])
+    out3 = _sparse_flash(
+        q.reshape(s, h, w), pool, block_tables, positions, real,
+        interpret=(mode == "interpret"), sm_scale=scale,
         v_width=int(v_width),
     )
     return out3.reshape(s, 1, h, v_width)

@@ -154,11 +154,18 @@ program tells such a model, recurrent layers or none, which rows are in the
 dispatched group (``state_slots``): a row outside it reaches no expert and is
 in no count.
 
-Latent layers (a model whose ``layer_types`` name ``"latent"`` layers,
-``models/mla.py``'s ``LatentAttention``): a second KIND of page. Such a layer
-keeps ONE pool, ``cached_latent [num_pages, page_size, W]`` (a token's ``[c |
+Latent layers (a model whose ``layer_types`` name layers of
+``models/transformer.py``'s ``LATENT_TYPES``, ``models/mla.py``'s
+``LatentAttention``): a second KIND of page. Such a layer
+keeps a pool ``cached_latent [num_pages, page_size, W]`` (a token's ``[c |
 k_pe]`` in whole lanes, no head axis), where an attention layer keeps a K and
-a V pool of ``[num_pages, page_size, Hkv, D]``. The engine reads
+a V pool of ``[num_pages, page_size, Hkv, D]``. A layer may own MORE THAN ONE
+pool, and a model's layers pools of different widths: a ``"latent_sparse"``
+layer keeps ``cached_index [num_pages, page_size, index width]`` (its
+indexer's key a token) beside its latent pool, a ``"latent_window"`` layer a
+latent pool of its own rank. All of them live under the ONE allocator, block
+table a sequence, trie and copy-on-write, because none of those asks what a
+page holds or how many arrays it spans. The engine reads
 ``latent_layers`` from the model and the pools' geometry from the cache tree
 it builds (a page pool is a leaf whose first two axes are ``(num_pages,
 page_size)``; ``stats()["page_bytes_per_token_layer"]`` is what one layer's
@@ -174,6 +181,26 @@ pages). On every ``step`` slice that dispatched a decode the tracer carries,
 beside ``decode_kv_tokens_fetched`` / ``_visible``,
 ``decode_kv_tokens_distinct``: the key positions the rows could see with each
 PHYSICAL page counted once, so rows that share a document count it once.
+
+Learned sparse attention and windows (``"latent_sparse"``, ``"latent_window"``
+layers): a sparse layer's decode scores EVERY visible token's index key and
+attends over the ``index_top_k`` best tokens' latents; a window layer's reads
+the pages that meet its window. The pages behind a window stay allocated and
+are never read (one table a sequence serves every layer). What a dispatch
+reads is counted from its rows' positions alone, with or without a tracer
+(``stats()``, and on the ``step`` slice the step's own): a layer's
+``decode_index_tokens_scored`` (``pos + 1`` a row) and, under a tracer,
+``decode_index_tokens_scored_distinct`` (each physical page once),
+``decode_kv_tokens_selected`` (``min(pos + 1, index_top_k)`` a row),
+``decode_window_tokens_visible`` (``min(pos + 1, window)``) and
+``decode_window_tokens_read`` (the whole pages that hold them); a model with
+such layers counts as ``decode_kv_tokens_fetched`` the latent rows a layer
+reads on average (selected, and a window's pages). A ``dsa.select`` instant a
+decode program carries the rows, the visible and selected tokens and their
+share. The decode program also returns the positions each sparse layer
+selected, ``selected_positions`` (the last step's, ``[sparse layers, slots,
+index_top_k]``, -1 past a row's own); nobody reads them back but a caller
+that asks.
 
 The latent decode kernel serves rows whose tables begin with the same
 physical pages (askers of one cached document) as a GROUP: the shared pages
@@ -461,6 +488,28 @@ class InferenceEngine:
         # with no head axis. Read from the model; what knows a K and a V pool
         # of one head size is refused with its reason.
         self.latent_layers = int(getattr(model, "latent_layers", 0))
+        kinds = tuple(getattr(model, "layer_types", None) or ())
+        # The plain latent layers' decode kernel groups rows that share a
+        # document; a sparse layer selects and a window layer walks a row's
+        # own last pages.
+        self._grouping_layers = kinds.count("latent")
+        self.sparse_layers = kinds.count("latent_sparse")
+        self.window_layers = kinds.count("latent_window")
+        self._index_top_k = (
+            model.latent_sizes("latent_sparse")["index_top_k"]
+            if self.sparse_layers else 0
+        )
+        self._window = (
+            model.latent_sizes("latent_window")["window"]
+            if self.window_layers else 0
+        )
+        # Totals of what the decode dispatches read (module docstring).
+        self.decode_index_tokens_scored = 0
+        self.decode_index_tokens_scored_distinct = 0  # a tracer's runs only
+        self.decode_kv_tokens_selected = 0
+        self.decode_window_tokens_visible = 0
+        self.decode_window_tokens_read = 0
+        self.selected_positions: List[jax.Array] = []  # the last step's
         if self.latent_layers:
             for given, what, why in (
                 (mesh is not None, "mesh",
@@ -1194,20 +1243,37 @@ class InferenceEngine:
         cache)``, and for a model with routed layers a third result, the
         layers' routing counts ``[routed layers, n_experts]`` in layer
         order."""
-        if not self.routed_layers:
+        if not (self.routed_layers or self.sparse_layers):
             return decode_token_step(
                 self.decode_model, params, cache, tokens, **kw
             )
+        wanted = ("routing",) * bool(self.routed_layers) + (
+            "selection",) * bool(self.sparse_layers)
         last_logits, cache, sown = decode_token_step(
             self.decode_model, params, cache, tokens,
-            mutable=("cache", "routing"), **kw,
+            mutable=("cache",) + wanted, **kw,
         )
-        sown = sown["routing"]
-        counts = jnp.stack([
-            sown[f"block_{i}"]["experts"]["counts"][0]
-            for i in range(self.decode_model.n_layers) if f"block_{i}" in sown
-        ])
-        return last_logits, cache, counts
+
+        def by_layer(collection, *path):
+            found = sown.get(collection, {})
+            out = []
+            for i in range(self.decode_model.n_layers):
+                leaf = found.get(f"block_{i}")
+                for key in path:
+                    leaf = None if leaf is None else leaf.get(key)
+                if leaf is not None:
+                    out.append(leaf[0])
+            return out
+
+        extras = ()
+        if self.routed_layers:
+            extras += (jnp.stack(by_layer("routing", "experts", "counts")),)
+        # Only a decode program's sparse layers write their selection (a
+        # prefill piece masks and keeps no list).
+        chosen = by_layer("selection", "mla", "positions")
+        if chosen:
+            extras += (jnp.stack(chosen),)
+        return (last_logits, cache) + extras
 
     def _flush_routing(self) -> None:
         """Write the ``moe.routing`` instant of the step whose counts are
@@ -1249,7 +1315,7 @@ class InferenceEngine:
         if self.state_layers or self.routed_layers:
             rows = jnp.arange(self.max_slots, dtype=jnp.int32)
             kw["state_slots"] = jnp.where(tables[:, 0] != NULL_PAGE, rows, -1)
-        if self.latent_layers and self._kv_block_tokens:
+        if self._grouping_layers and self._kv_block_tokens:
             kw["row_groups"] = self._row_groups(tables, lens)
         return kw
 
@@ -1287,11 +1353,13 @@ class InferenceEngine:
 
         def run(params, cache, tokens, table, length, valid, *slot):
             state_kw = {"state_slots": slot[0]} if slot else {}
-            _, cache, *routing = self._forward(
+            _, cache, *extras = self._forward(
                 params, cache, tokens, block_tables=table, seq_lens=length,
                 valid_lens=valid, **state_kw,
             )
-            return (cache, *routing) if routing else cache
+            # The routing counts; what a one-token piece of a sparse layer
+            # selected is nobody's to read.
+            return (cache, extras[0]) if self.routed_layers else cache
 
         name = f"prefill_step_c{width}"
         if self.mesh is None:
@@ -1969,7 +2037,9 @@ class InferenceEngine:
         self._stage_row_keys(slots)
         groups = None
         rows = sorted(slots)
-        if self.latent_layers and self._kv_block_tokens:
+        if self.sparse_layers or self.window_layers:
+            self._count_narrowed_reads(self._stage_lens[rows])
+        if self._grouping_layers and self._kv_block_tokens:
             # The rule the program applies to the same tables (absent rows
             # are in no group, so the live rows, in slot order, group alike).
             groups = self._row_groups(
@@ -2004,12 +2074,51 @@ class InferenceEngine:
             temps = _staged(self._stage_temps)
             keys = _staged(self._stage_keys)
         with self._phase("dispatch.launch"):
-            nxt, self.cache, *routing = decode_step(
+            nxt, self.cache, *extras = decode_step(
                 params, self.cache, tokens, prev, use_prev, tables, lens,
                 temps, keys, bias_arr,
             )
-        self.routing_counts.extend(routing)
+        if self.sparse_layers and self.paged_kernel:
+            # The kernel path selects a list of positions (the gather path
+            # masks, and keeps none).
+            self.selected_positions.append(extras.pop())
+        self.routing_counts.extend(extras)
         return nxt
+
+    def _narrowed_reads(self, positions) -> Tuple[int, int, int]:
+        """What a sparse and a window layer read for decode rows at
+        ``positions`` (module docstring): the tokens selected, the tokens
+        inside the windows, and the tokens of the pages that hold those."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            window_tokens_read,
+        )
+
+        selected = in_window = read = 0
+        if self.sparse_layers:
+            selected = int(np.minimum(positions + 1, self._index_top_k).sum())
+        if self.window_layers:
+            in_window = int(np.minimum(positions + 1, self._window).sum())
+            read = int(
+                window_tokens_read(positions, self._window, self.page_size).sum()
+            )
+        return selected, in_window, read
+
+    def _count_narrowed_reads(self, positions) -> None:
+        """Add a decode dispatch's rows to the totals of what its sparse and
+        window layers read, and write its ``dsa.select`` instant."""
+        selected, in_window, read = self._narrowed_reads(positions)
+        visible = int(positions.sum()) + len(positions)
+        if self.sparse_layers:
+            self.decode_index_tokens_scored += visible
+            self.decode_kv_tokens_selected += selected
+            if self.tracer.enabled:
+                self.tracer.instant(
+                    "dsa.select", rows=len(positions), visible=visible,
+                    selected=selected, layers=self.sparse_layers,
+                    selected_share=selected / max(1, visible),
+                )
+        self.decode_window_tokens_visible += in_window
+        self.decode_window_tokens_read += read
 
     def _end_step_trace(self, plan) -> None:
         """Close the tracer's step slice with the per-step gauges: batch
@@ -2053,10 +2162,22 @@ class InferenceEngine:
             block = self._kv_block_tokens
             whole = self.max_slots * self.pages_per_seq * self.page_size
             fetched = visible = distinct = grouped = 0
+            selected = in_window = window_read = 0
+            narrowed = self.sparse_layers + self.window_layers
             for pos, tables, groups in self._decode_dispatches:
                 visible += int(pos.sum()) + len(pos)
                 distinct += self._distinct_kv_tokens(pos, tables)
-                if groups is not None:
+                if narrowed:
+                    # A sparse layer reads the latents it selected, a window
+                    # layer the pages that meet its window: a layer's mean.
+                    chosen, inside, read = self._narrowed_reads(pos)
+                    selected += chosen
+                    in_window += inside
+                    window_read += read
+                    fetched += (
+                        self.sparse_layers * chosen + self.window_layers * read
+                    ) // narrowed
+                elif groups is not None:
                     # The latent kernel: a group's shared pages once.
                     fetched += latent_tokens_fetched(
                         pos, *groups, self.page_size,
@@ -2072,6 +2193,14 @@ class InferenceEngine:
             extra["decode_kv_tokens_distinct"] = distinct
             if self.latent_layers:
                 extra["decode_rows_grouped"] = grouped
+            if self.sparse_layers:
+                extra["decode_index_tokens_scored"] = visible
+                extra["decode_index_tokens_scored_distinct"] = distinct
+                extra["decode_kv_tokens_selected"] = selected
+                self.decode_index_tokens_scored_distinct += distinct
+            if self.window_layers:
+                extra["decode_window_tokens_visible"] = in_window
+                extra["decode_window_tokens_read"] = window_read
             self._decode_dispatches.clear()
         self.tracer.end_step(
             decode_rows=len(plan.decode_slots),
@@ -2248,6 +2377,8 @@ class InferenceEngine:
         tr.begin_step()
         if self.routed_layers:
             self.routing_counts = []  # the last step's stay with who took them
+        if self.sparse_layers:
+            self.selected_positions = []
         with self._phase("schedule"):
             plan = self.scheduler.schedule()
         if self._acct is not None:
@@ -2822,6 +2953,17 @@ class InferenceEngine:
         out["prefill_tokens"] = self.prefill_tokens
         out["prefill_width"] = self.prefill_width
         out["decode_rows_grouped"] = self.decode_rows_grouped
+        if self.sparse_layers:
+            out["decode_index_tokens_scored"] = self.decode_index_tokens_scored
+            out["decode_index_tokens_scored_distinct"] = (
+                self.decode_index_tokens_scored_distinct
+            )
+            out["decode_kv_tokens_selected"] = self.decode_kv_tokens_selected
+        if self.window_layers:
+            out["decode_window_tokens_visible"] = (
+                self.decode_window_tokens_visible
+            )
+            out["decode_window_tokens_read"] = self.decode_window_tokens_read
         if self.routed_layers:
             out["moe_product"] = self.moe_product
             out["moe_pairs_held"] = self.moe_pairs_held

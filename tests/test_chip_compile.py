@@ -233,6 +233,121 @@ CASES.update({
 })
 
 
+# ------------------------------------------- learned sparse attention, windows
+#
+# ``benchmarks/configs/dots3-note-prev.json``'s engine: 32 slots of 3,104
+# pages out of 18,433, three pool widths under one table. The three kernels
+# that configuration added (``ops/paged_attention.py``) and its serving
+# programs, at their own shapes.
+
+SPARSE = dict(slots=32, pages_per_seq=3104, num_pages=18433, top_k=2048)
+
+
+def sparse_kernel_case(chip, which):
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    s = SPARSE
+    tables = arg((s["slots"], s["pages_per_seq"]), jnp.int32)
+    lens = arg((s["slots"],), jnp.int32)
+    if which == "index":  # 64 index heads of 128 on one 128-wide key a token
+        return jax.jit(
+            functools.partial(pa.paged_index_scores, kernel="pallas")
+        ).lower(
+            arg((s["slots"], 64, 128), jnp.bfloat16),
+            arg((s["slots"], 64), jnp.float32),
+            arg((s["num_pages"], PAGE, 128), jnp.bfloat16), tables, lens)
+    if which == "sparse":  # 128 heads over 2,048 selected latents of 640
+        return jax.jit(functools.partial(
+            pa.sparse_latent_attention, v_width=512, kernel="pallas",
+            sm_scale=0.0721688,
+        )).lower(
+            arg((s["slots"], 1, 128, 640), jnp.bfloat16),
+            arg((s["num_pages"], PAGE, 640), jnp.bfloat16), tables,
+            arg((s["slots"], s["top_k"]), jnp.int32),
+            arg((s["slots"], s["top_k"]), jnp.bool_))
+    # 64 heads on a latent of 1,088 held as 1,152, a window of 513
+    return jax.jit(functools.partial(
+        pa.paged_latent_attention, v_width=1024, kernel="pallas",
+        sm_scale=0.0625, window=513,
+    )).lower(
+        arg((s["slots"], 1, 64, 1152), jnp.bfloat16),
+        arg((s["num_pages"], PAGE, 1152), jnp.bfloat16), tables, lens)
+
+
+def sparse_program(chip, t_step):
+    """A serving program of the ``dots3-note-prev`` configuration at its own
+    shapes, lowered for the described chip on abstract operands, as
+    ``latent_program`` lowers ``deepseek-v2-lite``'s: the decode step over
+    all 32 slots or a prefill piece of ``t_step`` tokens. Layers 0-2 of the
+    six: full + dense, full + experts, sliding + experts, which is every
+    shape the compiler is asked about."""
+    import json
+
+    from dots3_toy import ROOT, driver, reference
+
+    with open(os.path.join(
+            ROOT, "benchmarks", "configs", "dots3-note-prev.json")) as f:
+        cfg = json.load(f)
+    cfg = dict(cfg, num_hidden_layers=3, layer_types=cfg["layer_types"][:3])
+    engine = cfg["assumed"]["engine"]
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+            tree)
+
+    weights = jax.eval_shape(lambda: reference.make_weights(cfg, 0))
+    model, params = driver.build_program(cfg, weights)
+    decode_model = model.clone(
+        decode=True, page_size=engine["page_size"],
+        num_pages=engine["num_pages"], paged_kernel="pallas")
+    rows = engine["max_slots"] if t_step == 1 else 1
+    cache = jax.eval_shape(
+        decode_model.init, jax.random.PRNGKey(0),
+        jnp.zeros((rows, 1), jnp.int32))["cache"]
+    pages_per_seq = engine["max_seq_len"] // engine["page_size"]
+
+    def run(params, cache, tokens, tables, lens, valid):
+        kw = {} if t_step == 1 else {"valid_lens": valid}
+        logits, updated = decode_model.apply(
+            {"params": params, "cache": cache}, tokens, block_tables=tables,
+            seq_lens=lens, state_slots=jnp.arange(rows, dtype=jnp.int32),
+            mutable=["cache", "routing", "selection"], **kw)
+        return logits[:, -1], updated["cache"]
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    return jax.jit(run, donate_argnums=(1,)).lower(
+        abstract(params), abstract(cache), arg((rows, t_step)),
+        arg((rows, pages_per_seq)), arg((rows,)), arg((rows,)))
+
+
+CASES.update({
+    **{f"sparse-kernel-{which}": functools.partial(
+        sparse_kernel_case, which=which)
+       for which in ("index", "sparse", "window")},
+    "sparse-cell-decode": functools.partial(sparse_program, t_step=1),
+    "sparse-cell-prefill-64": functools.partial(sparse_program, t_step=64),
+    "sparse-cell-prefill-512": functools.partial(sparse_program, t_step=512),
+})
+
+
+def test_the_new_kernels_are_named_for_the_benchmarks_readers(chip):
+    """``benchmarks/harness/dsa.py`` tells the three kernels by their names."""
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    for which, name in (("index", pa.INDEX_KERNEL), ("sparse", pa.SPARSE_KERNEL),
+                        ("window", pa.WINDOW_KERNEL)):
+        text = sparse_kernel_case(chip, which).compile().as_text()
+        calls = [line.split(" = ", 1)[0] for line in text.splitlines()
+                 if "tpu_custom_call" in line]
+        assert any(name in call for call in calls), (which, calls)
+
+
 # ------------------------------------------------ the experts' grouped product
 #
 # ``ops/grouped_matmul.py``'s weight-stationary kernel at the shapes of the two

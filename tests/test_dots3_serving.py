@@ -1,0 +1,165 @@
+"""A model with learned sparse attention, windowed latent attention and
+sigmoid-routed experts through ``InferenceEngine``: two pools a full layer and
+pools of three widths under the ONE allocator, block table, prefix trie and
+copy-on-write; the chip's share of the experts; the counters of what a decode
+dispatch reads; the selected positions for who asks; what is refused.
+Compared with ``benchmarks/reference/dots3_note.py`` on seeded weights at toy
+widths, through logits (``dots3_toy``)."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from dots3_toy import (  # noqa: E402
+    LOGIT_TOL, SEED, TOY, reference, share, slice_experts, tokens, toy_program,
+)
+from distributed_pytorch_tpu.models import mla  # noqa: E402
+from distributed_pytorch_tpu.obs.tracer import Tracer  # noqa: E402
+from distributed_pytorch_tpu.ops import paged_attention as pa  # noqa: E402
+from distributed_pytorch_tpu.serving import (  # noqa: E402
+    InferenceEngine, SamplingParams,
+)
+
+
+ENGINE = dict(max_slots=3, max_seq_len=64, page_size=4, max_prefill_chunk=8,
+              token_budget=11, prefix_cache=True)
+HELD = (2, 5)  # this chip's share of the toy's 8 experts
+
+
+@pytest.fixture(scope="module")
+def held_program():
+    cfg = share(HELD)
+    weights = slice_experts(reference.make_weights(TOY, SEED), HELD)
+    return (cfg, *toy_program(cfg, weights))
+
+
+def engine_for(program, **kw):
+    _, _, model, params = program
+    return InferenceEngine(model, params, **{**ENGINE, **kw})
+
+
+def serve(engine, prompts, new_tokens=8):
+    ids = [engine.submit(p, SamplingParams(max_new_tokens=new_tokens))
+           for p in prompts]
+    engine.run()
+    out = []
+    for rid in ids:
+        status = engine.poll(rid)
+        assert status.state == "finished"
+        out.append(list(status.generated))
+    return out
+
+
+def served_gap(program, prompt, generated):
+    cfg, weights = program[:2]
+    rows = [len(prompt) - 1 + i for i in range(len(generated))]
+    logits = np.asarray(reference.logits_at(
+        cfg, weights, list(prompt) + list(generated), rows))
+    return logits.max(-1) - logits[np.arange(len(generated)), generated]
+
+
+@pytest.mark.parametrize("kernel", [False, "xla", "interpret"])
+@pytest.mark.parametrize("chunk", [1, 4, 32])
+def test_chunked_prefill_then_paged_decode_matches_the_reference(
+        held_program, chunk, kernel):
+    prompt = tokens(38, seed=chunk)
+    engine = engine_for(
+        held_program, max_prefill_chunk=chunk, token_budget=chunk + 3,
+        paged_kernel=kernel)
+    (generated,) = serve(engine, [prompt], new_tokens=10)
+    assert served_gap(held_program, prompt, generated).max() < LOGIT_TOL
+
+
+def test_the_engine_finds_every_pool_of_every_width(held_program):
+    """Two pools a full layer, one a sliding layer, three widths (the toy's
+    16, 8 and 28 numbers, each in 128 lanes of float32): one allocator."""
+    engine = engine_for(held_program, num_pages=9)
+    leaves = jax.tree_util.tree_flatten_with_path(engine.cache)[0]
+    names = sorted(path[-1].key for path, _ in leaves)
+    assert names == ["cached_index"] * 2 + ["cached_latent"] * 4
+    assert {leaf.shape for _, leaf in leaves} == {(9, 4, 128)}
+    assert engine.latent_layers == 4 and engine.sparse_layers == 2
+    assert engine.window_layers == 2 and engine.routed_layers == 3
+    # Six pools over four layers: 1.5 pools of 128 float32 lanes a layer.
+    assert engine.stats()["page_bytes_per_token_layer"] == 6 * 128 * 4 // 4
+
+
+@pytest.mark.parametrize("kernel", [False, "interpret"])
+def test_a_prefix_hit_and_a_copy_on_write_serve_what_a_cold_engine_serves(
+        held_program, kernel):
+    """Two askers of one document side by side: the trie hands both the
+    document's pages in ALL the pools (a hit that skipped the index keys
+    would select among zeros), and the first to write on the shared last
+    page copies it, in all of them."""
+    document = tokens(27, seed=20)  # 6 whole pages and 3 tokens
+    asks = [document + tokens(5, seed=21), document + tokens(6, seed=22)]
+    engine = engine_for(held_program, paged_kernel=kernel)
+    serve(engine, [document], new_tokens=1)
+    before = engine.stats()
+    served = serve(engine, asks, new_tokens=9)
+    after = engine.stats()
+    assert after["prefix_tokens_hit"] - before["prefix_tokens_hit"] >= 48
+    assert after["cow_copies"] > before["cow_copies"]
+    for prompt, generated in zip(asks, served):
+        assert served_gap(held_program, prompt, generated).max() < LOGIT_TOL
+    cold = engine_for(held_program, paged_kernel=kernel, prefix_cache=False)
+    assert serve(cold, asks, new_tokens=9) == served
+
+
+def test_the_counters_of_what_a_dispatch_reads(held_program):
+    tracer = Tracer()
+    engine = engine_for(held_program, paged_kernel="interpret", tracer=tracer)
+    prompt = tokens(30, seed=5)
+    serve(engine, [prompt], new_tokens=6)
+    stats = engine.stats()
+    # Decode feeds positions 29..34 (the prompt's last token, then five).
+    positions = np.arange(29, 35)
+    assert stats["decode_index_tokens_scored"] == int((positions + 1).sum())
+    assert stats["decode_kv_tokens_selected"] == 7 * len(positions)
+    assert stats["decode_window_tokens_visible"] == 9 * len(positions)
+    assert stats["decode_window_tokens_read"] == int(
+        pa.window_tokens_read(positions, 9, 4).sum())
+    steps = [e["args"] for e in tracer.events
+             if e["name"] == "step" and e.get("ph") == "X"
+             and "decode_index_tokens_scored" in e["args"]]
+    assert len(steps) == len(positions)
+    assert sum(a["decode_kv_tokens_selected"] for a in steps) == 42
+    assert all(a["decode_index_tokens_scored_distinct"]
+               == a["decode_index_tokens_scored"] for a in steps)
+    # A layer's mean of the latents read: (2 x 7 + 2 x 12) / 4 at position 29.
+    assert steps[0]["decode_kv_tokens_fetched"] == (2 * 7 + 2 * 12) // 4
+    chosen = [e["args"] for e in tracer.events if e["name"] == "dsa.select"]
+    assert len(chosen) == len(positions)
+    assert chosen[0]["selected_share"] == pytest.approx(7 / 30)
+    assert stats["decode_index_tokens_scored_distinct"] == sum(
+        a["decode_index_tokens_scored"] for a in steps)
+
+
+def test_the_selected_positions_come_back_to_who_asks(held_program):
+    engine = engine_for(held_program, paged_kernel="interpret")
+    rid = engine.submit(tokens(20, seed=8), SamplingParams(max_new_tokens=3))
+    seen = []
+    while not engine.poll(rid).finished:
+        engine.step()
+        seen.extend(np.asarray(a) for a in engine.selected_positions)
+    assert seen and seen[0].shape == (2, ENGINE["max_slots"], 7)
+    row = seen[0][:, engine.requests[rid].slot or 0]
+    assert ((row >= 0) & (row <= 19)).all()
+    assert all(len(set(layer.tolist())) == 7 for layer in row)
+    gather = engine_for(held_program)  # the gather path masks, keeps no list
+    serve(gather, [tokens(20, seed=8)], new_tokens=3)
+    assert gather.selected_positions == []
+
+
+@pytest.mark.parametrize("given, what", [
+    (dict(kv_quant="int8"), "kv_quant"), (dict(host_pages=8), "host_pages"),
+])
+def test_what_knows_one_kind_of_page_is_refused(held_program, given, what):
+    with pytest.raises(ValueError, match=f"latent layers.*{what}"):
+        engine_for(held_program, **given)

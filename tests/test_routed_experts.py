@@ -395,7 +395,7 @@ def test_bfloat16_operands_accumulate_in_float32():
 
 
 @pytest.mark.parametrize("gating", moe.GATINGS)
-def test_masked_tokens_of_a_piece_reach_no_expert_under_either_gating(
+def test_masked_tokens_of_a_piece_reach_no_expert_under_any_gating(
         gating, mode):
     """A prefill piece's padding (``live [B, T]``): the same result for the
     tokens that count, zeros for the rest, and the same counts, whichever
@@ -404,6 +404,9 @@ def test_masked_tokens_of_a_piece_reach_no_expert_under_either_gating(
     live = jnp.asarray(np.arange(24) < 17)[None]
     params = {"router_kernel": w["router"], "in_kernel": w["we_in"][2:7],
               "out_kernel": w["we_out"][2:7]}
+    if gating == "sigmoid_biased":  # the rule that has a correction bias
+        params["router_bias"] = jnp.asarray(
+            0.2 * np.random.default_rng(32).standard_normal(E), jnp.float32)
 
     def run(kernel):
         layer = RoutedExperts(E, K, F, D, held=(2, 7), gating=gating,

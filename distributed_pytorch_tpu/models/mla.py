@@ -231,10 +231,11 @@ class LatentAttention(nn.Module):
     ``page_size`` it is a step against the paged latent pool and must be told
     ``block_tables [S, pages_per_seq]`` and ``seq_lens [S]`` (and, for a padded
     prefill piece, ``valid_lens [S]``), as :class:`models.transformer.Attention`
-    is. ``row_groups`` is for the decode kernel alone: which rows share their
-    tables' first pages (``ops/paged_attention.py`` ``shared_prefix_groups``),
-    where the program has worked that out once for all its layers; a call
-    that says nothing lets the kernel's wrapper work it out. A contiguous
+    is. ``row_groups`` is for the decode kernels alone (the latent kernel and
+    an indexed layer's index kernel): which rows share their tables' first
+    pages (``ops/paged_attention.py`` ``shared_prefix_groups``), where the
+    program has worked that out once for all its layers; a call that says
+    nothing lets the kernel's wrapper work it out. A contiguous
     decode cache is not built: latent layers are served through pages."""
 
     n_heads: int
@@ -538,7 +539,7 @@ class LatentAttention(nn.Module):
                     scores = paged_index_scores(
                         _lanes(q_idx[:, 0], self.index_pool_width),
                         w_idx[:, 0], index_pool.value, block_tables, seq_lens,
-                        kernel=self.paged_kernel,
+                        kernel=self.paged_kernel, row_groups=row_groups,
                     )  # [B, table tokens] float32
                     chosen, real = selected_positions(
                         top_k_mask(scores, self.index_top_k),

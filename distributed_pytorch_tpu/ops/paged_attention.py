@@ -1174,111 +1174,266 @@ def index_scores_reference(q, w, pool, block_tables, seq_lens):
     return jnp.where(seen, scores, -jnp.inf)
 
 
+def index_block_pages(pages_per_seq: int) -> int:
+    """Pages the index kernel copies and scores at a time for a table of
+    ``pages_per_seq``: :data:`INDEX_BLOCK_PAGES`, or the whole of a narrower
+    table."""
+    return min(INDEX_BLOCK_PAGES, pages_per_seq)
+
+
+def index_tokens_fetched(
+    positions, leader, shared, page: int, npb: int, pages_per_seq: int
+) -> int:
+    """Index keys the index kernel copies out of the pool for a decode
+    dispatch grouped as :func:`shared_prefix_groups` says (NumPy): every walk
+    is of whole blocks of ``npb`` pages, a group's shared whole blocks are
+    walked once, at its leader's turn (what is left of ``shared`` under a
+    whole block is each member's own), and every row walks its own blocks
+    from there to the one that holds its ``pos``."""
+    last = np.minimum(positions // page, pages_per_seq - 1)
+    ahead = np.where(leader == np.arange(len(leader)), 0, shared // npb)
+    return int((last // npb + 1 - ahead).sum()) * npb * page
+
+
+def index_rows_grouped(shared, npb: int) -> int:
+    """Rows of a dispatch grouped as :func:`shared_prefix_groups` says that
+    the index kernel serves in a group: the ones whose group shares a whole
+    block of ``npb`` pages (NumPy)."""
+    return int((shared >= npb).sum())
+
+
 def _index_scores_kernel(
-    bt_ref, lens_ref, q_ref, w_ref, pool_hbm, o_ref, buf, sems, *, npb
+    bt_ref, lens_ref, lead_ref, shared_ref, q_ref, w_ref, pool_hbm, o_ref,
+    buf, sems, first_slot, members, qg_scr, wg_scr, *, npb,
 ):
     """One slot (grid step): the frame of :func:`_decode_kernel` over the
-    index-key pool. The row's pages up to the one that holds ``pos`` are
-    copied a block of ``npb`` at a time into one of two buffers (the next
-    block's copies started before this one is scored); a block's ``[keys,
-    D]`` meet the row's ``[H, D]`` index queries on the MXU, and ReLU, the
-    head weights and the sum over heads leave ONE float32 a key, written to
-    the row's ``[blocks, block keys]`` result. Keys past ``pos``, and whole
-    blocks past it, read ``-inf``; a row out of the dispatch reads ``-inf``
-    everywhere."""
+    index-key pool, for rows grouped as :func:`shared_prefix_groups` says
+    (``lead_ref``, ``shared_ref``), as :func:`_latent_decode_kernel`'s are.
+
+    A step is ONE walk over blocks of ``npb`` pages of the row's table, up to
+    the block that holds ``pos``: a block's pages are copied into one of two
+    buffers (the next block's copies, or the next live row's first block's,
+    started before this one is scored), its ``[keys, D]`` meet index queries
+    on the MXU, and ReLU, the head weights and the sum over a row's heads
+    leave ONE float32 a key and row, written to that row's ``[blocks, block
+    keys]`` of the result, which stays in VMEM for the whole call. Keys past
+    ``pos``, and whole blocks past it, read ``-inf``; a row out of the
+    dispatch reads ``-inf`` everywhere.
+
+    A row served alone (``shared`` 0, or under a whole block) walks from its
+    table's first block and scores every block against its own ``[H, D]``
+    queries. A group's LEADER (its first row) walks from the first block
+    too, but scores the group's shared whole blocks, ``shared // npb`` of
+    them, against the members' queries STACKED (``[rows x H, D]``: a pair's
+    at M = 2 H, a wider group's at ``GROUP_ROWS`` x H, a place no member
+    takes being the leader's again), each member's part weighted and summed
+    over its own heads and written to that member's row: the same products,
+    ReLU, weights and sums a row alone makes, so the same bits. Every shared key lies below every member's position: no mask.
+    A member then walks from the first block after the shared ones. Every
+    row's queries and weights are held for the whole call (a leader needs
+    its members')."""
     b = pl.program_id(0)
-    pages_per_seq = bt_ref.shape[1]
+    slots, pages_per_seq = bt_ref.shape
+    h = q_ref.shape[1]
     page, d = buf.shape[2:]
     bkv = npb * page
     n_blocks_max = o_ref.shape[1]
-    o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, o_ref.dtype)
+    group_rows = members.shape[0]
 
-    @pl.when(bt_ref[b, 0] != NULL_PAGE)
-    def _row():
-        pos = lens_ref[b]
-        last = jnp.minimum(pos // page, pages_per_seq - 1)
-        n_blocks = jnp.minimum(pos // bkv + 1, n_blocks_max)
+    def is_live(row):
+        return bt_ref[row, 0] != NULL_PAGE
 
-        def copies(blk, slot):
-            return [
-                pltpu.make_async_copy(
-                    pool_hbm.at[bt_ref[b, jnp.minimum(blk * npb + n, last)]],
-                    buf.at[slot, n], sems.at[slot],
-                )
-                for n in range(npb)
-            ]
+    def walk_of(row):
+        """``(first, end)`` blocks of ``row``'s step: a member starts after
+        its group's shared blocks, which its leader has scored."""
+        end = jnp.minimum(lens_ref[row] // bkv + 1, n_blocks_max)
+        ahead = jnp.where(lead_ref[row] == row, 0, shared_ref[row] // npb)
+        return jnp.minimum(ahead, end - 1), end
 
-        for copy in copies(0, 0):
-            copy.start()
-        q = q_ref[0].astype(buf.dtype)  # [H, D]
-        w = w_ref[0]  # [H, 1] float32
+    def page_copy(phys, slot, n):
+        return pltpu.make_async_copy(
+            pool_hbm.at[phys], buf.at[slot, n], sems.at[slot]
+        )
 
-        def block(j, carry):
-            slot = j % 2
+    def start(row, blk, slot):
+        """Start the copies of ``row``'s block ``blk``. A logical page past
+        the row's last live one clamps to that one, as in
+        :func:`_decode_kernel`."""
+        last = jnp.minimum(lens_ref[row] // page, pages_per_seq - 1)
+        for n in range(npb):
+            phys = bt_ref[row, jnp.minimum(blk * npb + n, last)]
+            page_copy(phys, slot, n).start()
 
-            @pl.when(j + 1 < n_blocks)
-            def _next_block():
-                for copy in copies(j + 1, 1 - slot):
-                    copy.start()
+    def keys(slot):
+        """Wait for the block in buffer ``slot``: its ``[keys, D]``."""
+        for n in range(npb):  # a wait takes a copy's size, not its source
+            page_copy(0, slot, n).wait()
+        return buf[slot].reshape(bkv, d)
 
-            for copy in copies(j, slot):
-                copy.wait()
-            k = buf[slot].reshape(bkv, d)
-            s_blk = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [H, keys]
-            total = jnp.sum(
-                jnp.maximum(s_blk, 0.0) * w, axis=0, keepdims=True
-            )
-            kpos = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
-            o_ref[0, pl.ds(j, 1), :] = jnp.where(
-                kpos <= pos, total, -jnp.inf
-            )
+    def products(q, k):
+        """``q [M, D]`` against ``k [keys, D]``: ``[M, keys]`` float32."""
+        return jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    def weighted(s_blk, w):
+        """``sum_h w_h relu(s_h)`` over one row's ``[H, keys]`` products and
+        ``[H, 1]`` weights: ``[1, keys]``."""
+        return jnp.sum(jnp.maximum(s_blk, 0.0) * w, axis=0, keepdims=True)
+
+    @pl.when(b == 0)
+    def _nothing_scored_yet():
+        def fill(r, carry):
+            o_ref[r] = jnp.full(o_ref.shape[1:], -jnp.inf, o_ref.dtype)
             return carry
 
-        jax.lax.fori_loop(0, n_blocks, block, 0)
+        jax.lax.fori_loop(0, slots, fill, 0)
+
+    @pl.when(is_live(b))
+    def _row():
+        pos = lens_ref[b]
+        first, end = walk_of(b)
+        leads = jnp.logical_and(lead_ref[b] == b, shared_ref[b] >= npb)
+        stacked = jnp.where(leads, shared_ref[b] // npb, 0)
+        # The row before, if live, started this row's first block while it
+        # scored its own last one; else this row starts it itself.
+        prefetched = jnp.logical_and(b > 0, is_live(jnp.maximum(b - 1, 0)))
+        slot0 = jnp.where(prefetched, first_slot[0], 0)
+
+        @pl.when(jnp.logical_not(prefetched))
+        def _first():
+            start(b, first, 0)
+
+        @pl.when(leads)
+        def _shared_walk():
+            # The group's rows in slot order: this one, then the rows after
+            # it that name it.
+            def find(r, count):
+                mine = lead_ref[r] == b
+
+                @pl.when(mine)
+                def _member():
+                    members[count] = r
+
+                return count + mine.astype(jnp.int32)
+
+            count = jax.lax.fori_loop(b, slots, find, 0)
+            for m in range(group_rows):
+                # A place no member takes is this row's again: it scores and
+                # writes what this row's own place does (a branch a member
+                # inside the block loop cost a quarter of the call).
+                r = jnp.where(m < count, members[m], b)
+                members[m] = r
+                qg_scr[m * h : (m + 1) * h] = q_ref[r].astype(qg_scr.dtype)
+                wg_scr[m * h : (m + 1) * h] = w_ref[r]
+            # A pair rides at M = 2 H; only a wider group pays for more.
+            sizes = sorted({min(2, group_rows), group_rows})
+            for lo, rows in zip([0] + sizes, sizes):
+
+                @pl.when(jnp.logical_and(lo < count, count <= rows))
+                def _stacked(rows=rows):
+                    q = qg_scr[0 : rows * h]
+
+                    def block(j, carry):
+                        slot = (slot0 + j) % 2
+                        # The leader's own blocks follow the shared ones.
+                        start(b, j + 1, 1 - slot)
+                        s_blk = products(q, keys(slot))  # [rows x H, keys]
+                        for m in range(rows):
+                            part = slice(m * h, (m + 1) * h)
+                            o_ref[members[m], pl.ds(j, 1), :] = weighted(
+                                s_blk[part], wg_scr[part]
+                            )
+                        return carry
+
+                    jax.lax.fori_loop(0, stacked, block, 0)
+
+        q = q_ref[b].astype(buf.dtype)
+        w = w_ref[b]
+        next_row = jnp.minimum(b + 1, slots - 1)
+        next_live = jnp.logical_and(b + 1 < slots, is_live(next_row))
+
+        def block(j, carry):
+            slot = (slot0 + j - first) % 2
+
+            @pl.when(j + 1 < end)
+            def _next_block():
+                start(b, j + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(j + 1 == end, next_live))
+            def _next_row():
+                start(next_row, walk_of(next_row)[0], 1 - slot)
+                first_slot[0] = 1 - slot
+
+            total = weighted(products(q, keys(slot)), w)
+            kpos = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
+            o_ref[b, pl.ds(j, 1), :] = jnp.where(kpos <= pos, total, -jnp.inf)
+            return carry
+
+        jax.lax.fori_loop(jnp.maximum(first, stacked), end, block, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
-def _index_flash(q, w, pool, block_tables, seq_lens, *, pages_per_block,
-                 interpret):
+def _index_flash(q, w, pool, block_tables, seq_lens, leader, shared, *,
+                 pages_per_block, interpret):
     """The index kernel's ``pallas_call`` for ``q [S, H, D]`` and ``w [S,
-    H]``: jitted and named for :func:`_paged_flash`'s reasons."""
+    H]``: jitted and named for :func:`_paged_flash`'s reasons. The tables,
+    the lengths and the rows' grouping ride as scalar prefetch; every row's
+    queries and weights, and the whole ``[S, blocks, block keys]`` result (a
+    leader writes its members' rows), stay in VMEM for the call."""
     s, h, d = q.shape
     page = pool.shape[1]
     npb = int(pages_per_block)
     nblk = -(-block_tables.shape[1] // npb)
+    m_rows = GROUP_ROWS * h
 
-    def row_spec(shape):
+    def whole(shape):
         return pl.BlockSpec(
-            shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1),
-            memory_space=pltpu.VMEM,
+            shape, lambda b, *_: (0,) * len(shape), memory_space=pltpu.VMEM
         )
 
+    # What the kernel holds in VMEM: the result, the rows' queries and
+    # weights (each twice: the pipeline's two buffers; a weight takes a
+    # lane's 128), the page buffers, a group's queries and weights and a
+    # stacked block's scores.
+    item = jnp.dtype(pool.dtype).itemsize
+    held = (
+        2 * s * (-(-nblk // 8) * 8) * npb * page * 4
+        + 2 * s * h * (d * jnp.dtype(q.dtype).itemsize + 128 * 4)
+        + 2 * npb * page * d * item
+        + m_rows * (d * item + 128 * 4)
+        + 3 * m_rows * npb * page * 4
+    )
     out = pl.pallas_call(
         functools.partial(_index_scores_kernel, npb=npb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=4,
             grid=(s,),
             in_specs=[
-                row_spec((1, h, d)), row_spec((1, h, 1)),
+                whole((s, h, d)), whole((s, h, 1)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=row_spec((1, nblk, npb * page)),
+            out_specs=whole((s, nblk, npb * page)),
             scratch_shapes=[
                 pltpu.VMEM((2, npb, page, d), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),  # where a row's first block is
+                pltpu.SMEM((GROUP_ROWS,), jnp.int32),  # a group's rows
+                pltpu.VMEM((m_rows, d), pool.dtype),  # a group's queries
+                pltpu.VMEM((m_rows, 1), jnp.float32),  # its head weights
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s, nblk, npb * page), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(16 << 20, int(1.5 * held)),
         ),
         interpret=interpret,
         name=INDEX_KERNEL,
     )(
-        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q,
+        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+        leader.astype(jnp.int32), shared.astype(jnp.int32), q,
         w.astype(jnp.float32)[..., None], pool,
     )
     return out.reshape(s, nblk * npb * page)[:, : block_tables.shape[1] * page]
@@ -1292,13 +1447,19 @@ def paged_index_scores(
     seq_lens: jnp.ndarray,
     *,
     kernel="auto",
+    row_groups=None,
 ) -> jnp.ndarray:
     """A decode row's index score of every cached token: ``q [S, H, D]`` index
     queries and ``w [S, H]`` head weights (the scores' scales folded in)
     against the index-key pool ``[num_pages, page, D]`` through the rows'
     tables: float32 ``[S, pages_per_seq * page]``, ``sum_h w_h relu(q_h .
     k_s)`` at ``s <= pos`` and ``-inf`` past it. Dispatches per ``kernel``
-    as :func:`paged_attention` does."""
+    as :func:`paged_attention` does. The kernel scores the index keys of
+    pages that rows share (askers of one cached document) once for the rows
+    that share them: ``row_groups`` is :func:`shared_prefix_groups`' ``(leader,
+    shared)`` for these tables and lengths, as :func:`paged_latent_attention`
+    takes it; ``None`` works it out here. The scores are the same bits
+    however the rows are grouped."""
     if pool.ndim != 3 or pool.shape[2] != q.shape[-1]:
         raise ValueError(
             f"an index-key pool is [num_pages, page, {q.shape[-1]}], got "
@@ -1307,10 +1468,14 @@ def paged_index_scores(
     mode = resolve_kernel(kernel)
     if mode == "xla":
         return index_scores_reference(q, w, pool, block_tables, seq_lens)
+    npb = index_block_pages(block_tables.shape[1])
+    if row_groups is None:
+        row_groups = shared_prefix_groups(
+            block_tables, seq_lens, pool.shape[1], npb
+        )
     return _index_flash(
-        q, w, pool, block_tables, seq_lens,
-        pages_per_block=min(INDEX_BLOCK_PAGES, block_tables.shape[1]),
-        interpret=(mode == "interpret"),
+        q, w, pool, block_tables, seq_lens, *row_groups,
+        pages_per_block=npb, interpret=(mode == "interpret"),
     )
 
 

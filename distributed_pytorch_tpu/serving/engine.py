@@ -189,7 +189,10 @@ the pages that meet its window. The pages behind a window stay allocated and
 are never read (one table a sequence serves every layer). What a dispatch
 reads is counted from its rows' positions alone, with or without a tracer
 (``stats()``, and on the ``step`` slice the step's own): a layer's
-``decode_index_tokens_scored`` (``pos + 1`` a row) and, under a tracer,
+``decode_index_tokens_scored`` (``pos + 1`` a row),
+``decode_index_tokens_fetched`` (the index keys the index kernel copies:
+whole blocks of pages, a group's shared blocks once, below; every slot's whole
+table on the gather path) and, under a tracer,
 ``decode_index_tokens_scored_distinct`` (each physical page once),
 ``decode_kv_tokens_selected`` (``min(pos + 1, index_top_k)`` a row),
 ``decode_window_tokens_visible`` (``min(pos + 1, window)``) and
@@ -202,18 +205,23 @@ selected, ``selected_positions`` (the last step's, ``[sparse layers, slots,
 index_top_k]``, -1 past a row's own); nobody reads them back but a caller
 that asks.
 
-The latent decode kernel serves rows whose tables begin with the same
-physical pages (askers of one cached document) as a GROUP: the shared pages
-are copied out of the pool once for all of them. Who shares what is read off
-the staged tables and positions by ONE rule, ``ops/paged_attention.py``'s
-``shared_prefix_groups``: the decode program applies it once and tells its
-latent layers (:meth:`InferenceEngine._decode_state_kw`; no staged operand, no
-readback), and the host applies it to its own copies for the counters:
-``stats()["decode_rows_grouped"]`` (rows served in a group of two or more)
-and, on the ``step`` slice, ``decode_rows_grouped`` beside a
-``decode_kv_tokens_fetched`` that counts a group's shared pages once, so
-fetched / visible falls below 1 where rows share. Nothing switches it on or
-off: a dispatch whose tables share nothing groups nobody.
+The latent decode kernel, and a sparse layer's index kernel, serve rows whose
+tables begin with the same physical pages (askers of one cached document) as
+a GROUP: the shared pages are copied out of the pool once for all of them
+(the index kernel scores them against the members' index queries stacked, in
+whole blocks of its own). Who shares what is read off the staged tables and
+positions by ONE rule, ``ops/paged_attention.py``'s ``shared_prefix_groups``:
+the decode program applies it once and tells its latent layers
+(:meth:`InferenceEngine._decode_state_kw`; no staged operand, no readback),
+and the host applies it to its own copies for the counters:
+``stats()["decode_rows_grouped"]`` (rows served in a group of two or more: by
+the index kernel in a model with sparse layers, by the latent kernel
+otherwise) and, on the ``step`` slice, ``decode_rows_grouped`` beside a
+``decode_kv_tokens_fetched`` (the latent kernel's) or a
+``decode_index_tokens_fetched`` (the index kernel's) that counts a group's
+shared pages once, so fetched / visible falls below 1 where rows share.
+Nothing switches it on or off: a dispatch whose tables share nothing groups
+nobody.
 """
 
 from __future__ import annotations
@@ -489,12 +497,13 @@ class InferenceEngine:
         # of one head size is refused with its reason.
         self.latent_layers = int(getattr(model, "latent_layers", 0))
         kinds = tuple(getattr(model, "layer_types", None) or ())
-        # The plain latent layers' decode kernel groups rows that share a
-        # document; a sparse layer selects and a window layer walks a row's
-        # own last pages.
-        self._grouping_layers = kinds.count("latent")
+        # The plain latent layers' decode kernel, and a sparse layer's index
+        # kernel, group rows that share a document; a sparse layer attends
+        # over what it selected and a window layer walks a row's own last
+        # pages.
         self.sparse_layers = kinds.count("latent_sparse")
         self.window_layers = kinds.count("latent_window")
+        self._grouping_layers = kinds.count("latent") + self.sparse_layers
         self._index_top_k = (
             model.latent_sizes("latent_sparse")["index_top_k"]
             if self.sparse_layers else 0
@@ -505,6 +514,7 @@ class InferenceEngine:
         )
         # Totals of what the decode dispatches read (module docstring).
         self.decode_index_tokens_scored = 0
+        self.decode_index_tokens_fetched = 0
         self.decode_index_tokens_scored_distinct = 0  # a tracer's runs only
         self.decode_kv_tokens_selected = 0
         self.decode_window_tokens_visible = 0
@@ -601,8 +611,8 @@ class InferenceEngine:
         # A traced step's decode dispatches: the rows' positions, their
         # tables and, where the latent kernel groups rows, their grouping.
         self._decode_dispatches: List[tuple] = []
-        # Rows the latent decode kernel served in a group of two or more
-        # (their tables begin with the same pages, read once for the group).
+        # Rows a decode kernel served in a group of two or more (their
+        # tables begin with the same pages, read once for the group).
         self.decode_rows_grouped = 0
         # Size the paged pool from abstract shapes only (eval_shape traces
         # init without running it); token length 1 — pool shapes depend only
@@ -1308,8 +1318,8 @@ class InferenceEngine:
         routed layers (nothing to any other): row ``r`` carries slot ``r``'s
         state, and is routed to experts and counted, iff the row is in the
         dispatched group, which is iff its staged block table is not the
-        zeroed one. And a model with latent layers whose decode kernel is
-        on: which rows' tables begin with the same physical pages
+        zeroed one. And a model with latent or sparse layers whose decode
+        kernels are on: which rows' tables begin with the same physical pages
         (:meth:`_row_groups`), worked out here once for all its layers."""
         kw = {}
         if self.state_layers or self.routed_layers:
@@ -1330,6 +1340,39 @@ class InferenceEngine:
         return shared_prefix_groups(
             tables, lens, self.page_size,
             self._kv_block_tokens // self.page_size,
+        )
+
+    def _rows_grouped(self, shared) -> int:
+        """Rows of a dispatch grouped as :meth:`_row_groups` says (its
+        ``shared``) that a decode kernel serves in a group of two or more:
+        the latent kernel's, whose group shares anything; in a model with
+        sparse layers the index kernel's, whose group shares a whole block
+        of its own."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            index_block_pages,
+            index_rows_grouped,
+        )
+
+        if self.sparse_layers:
+            return index_rows_grouped(
+                shared, index_block_pages(self.pages_per_seq)
+            )
+        return int((shared > 0).sum())
+
+    def _index_tokens_fetched(self, positions, groups) -> int:
+        """Index keys a sparse layer's scoring copies for decode rows at
+        ``positions``: the index kernel's walks of rows grouped as ``groups``
+        says, or (``None``: the gather path) every slot's whole table."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            index_block_pages,
+            index_tokens_fetched,
+        )
+
+        if groups is None:
+            return self.max_slots * self.pages_per_seq * self.page_size
+        return index_tokens_fetched(
+            positions, *groups, self.page_size,
+            index_block_pages(self.pages_per_seq), self.pages_per_seq,
         )
 
     def _note_state_reset(self, slot: int, req: Request) -> None:
@@ -2037,15 +2080,15 @@ class InferenceEngine:
         self._stage_row_keys(slots)
         groups = None
         rows = sorted(slots)
-        if self.sparse_layers or self.window_layers:
-            self._count_narrowed_reads(self._stage_lens[rows])
         if self._grouping_layers and self._kv_block_tokens:
             # The rule the program applies to the same tables (absent rows
             # are in no group, so the live rows, in slot order, group alike).
             groups = self._row_groups(
                 self._stage_tables[rows], self._stage_lens[rows]
             )
-            self.decode_rows_grouped += int((groups[1] > 0).sum())
+            self.decode_rows_grouped += self._rows_grouped(groups[1])
+        if self.sparse_layers or self.window_layers:
+            self._count_narrowed_reads(self._stage_lens[rows], groups)
         if self.tracer.enabled:
             self._decode_dispatches.append(
                 (self._stage_lens[rows], self._stage_tables[rows], groups)
@@ -2103,13 +2146,17 @@ class InferenceEngine:
             )
         return selected, in_window, read
 
-    def _count_narrowed_reads(self, positions) -> None:
-        """Add a decode dispatch's rows to the totals of what its sparse and
-        window layers read, and write its ``dsa.select`` instant."""
+    def _count_narrowed_reads(self, positions, groups) -> None:
+        """Add a decode dispatch's rows (grouped as ``groups`` says) to the
+        totals of what its sparse and window layers read, and write its
+        ``dsa.select`` instant."""
         selected, in_window, read = self._narrowed_reads(positions)
         visible = int(positions.sum()) + len(positions)
         if self.sparse_layers:
             self.decode_index_tokens_scored += visible
+            self.decode_index_tokens_fetched += self._index_tokens_fetched(
+                positions, groups
+            )
             self.decode_kv_tokens_selected += selected
             if self.tracer.enabled:
                 self.tracer.instant(
@@ -2162,7 +2209,7 @@ class InferenceEngine:
             block = self._kv_block_tokens
             whole = self.max_slots * self.pages_per_seq * self.page_size
             fetched = visible = distinct = grouped = 0
-            selected = in_window = window_read = 0
+            selected = in_window = window_read = index_fetched = 0
             narrowed = self.sparse_layers + self.window_layers
             for pos, tables, groups in self._decode_dispatches:
                 visible += int(pos.sum()) + len(pos)
@@ -2177,13 +2224,20 @@ class InferenceEngine:
                     fetched += (
                         self.sparse_layers * chosen + self.window_layers * read
                     ) // narrowed
+                    if self.sparse_layers:
+                        # The index kernel: a group's shared blocks once.
+                        index_fetched += self._index_tokens_fetched(
+                            pos, groups
+                        )
+                        if groups is not None:
+                            grouped += self._rows_grouped(groups[1])
                 elif groups is not None:
                     # The latent kernel: a group's shared pages once.
                     fetched += latent_tokens_fetched(
                         pos, *groups, self.page_size,
                         block // self.page_size, self.pages_per_seq,
                     )
-                    grouped += int((groups[1] > 0).sum())
+                    grouped += self._rows_grouped(groups[1])
                 elif block:
                     fetched += int(kv_tokens_walked(pos, block).sum())
                 else:
@@ -2195,6 +2249,7 @@ class InferenceEngine:
                 extra["decode_rows_grouped"] = grouped
             if self.sparse_layers:
                 extra["decode_index_tokens_scored"] = visible
+                extra["decode_index_tokens_fetched"] = index_fetched
                 extra["decode_index_tokens_scored_distinct"] = distinct
                 extra["decode_kv_tokens_selected"] = selected
                 self.decode_index_tokens_scored_distinct += distinct
@@ -2955,6 +3010,9 @@ class InferenceEngine:
         out["decode_rows_grouped"] = self.decode_rows_grouped
         if self.sparse_layers:
             out["decode_index_tokens_scored"] = self.decode_index_tokens_scored
+            out["decode_index_tokens_fetched"] = (
+                self.decode_index_tokens_fetched
+            )
             out["decode_index_tokens_scored_distinct"] = (
                 self.decode_index_tokens_scored_distinct
             )

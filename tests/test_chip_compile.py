@@ -243,7 +243,11 @@ CASES.update({
 SPARSE = dict(slots=32, pages_per_seq=3104, num_pages=18433, top_k=2048)
 
 
-def sparse_kernel_case(chip, which):
+def sparse_kernel_case(chip, which, groups_told=False):
+    """One of the three kernels at the cell's shapes. The index kernel serves
+    rows that share pages as a group (M = ``GROUP_ROWS`` x 64 heads at the
+    widest, the whole ``[32, 97, 512]`` result in VMEM), whoever works the
+    groups out: its own wrapper, or (``groups_told``) the caller."""
     from distributed_pytorch_tpu.ops import paged_attention as pa
 
     def arg(shape, dtype):
@@ -253,12 +257,14 @@ def sparse_kernel_case(chip, which):
     tables = arg((s["slots"], s["pages_per_seq"]), jnp.int32)
     lens = arg((s["slots"],), jnp.int32)
     if which == "index":  # 64 index heads of 128 on one 128-wide key a token
+        told = {"row_groups": (lens, lens)} if groups_told else {}
         return jax.jit(
             functools.partial(pa.paged_index_scores, kernel="pallas")
         ).lower(
             arg((s["slots"], 64, 128), jnp.bfloat16),
             arg((s["slots"], 64), jnp.float32),
-            arg((s["num_pages"], PAGE, 128), jnp.bfloat16), tables, lens)
+            arg((s["num_pages"], PAGE, 128), jnp.bfloat16), tables, lens,
+            **told)
     if which == "sparse":  # 128 heads over 2,048 selected latents of 640
         return jax.jit(functools.partial(
             pa.sparse_latent_attention, v_width=512, kernel="pallas",
@@ -311,7 +317,16 @@ def sparse_program(chip, t_step):
     pages_per_seq = engine["max_seq_len"] // engine["page_size"]
 
     def run(params, cache, tokens, tables, lens, valid):
-        kw = {} if t_step == 1 else {"valid_lens": valid}
+        kw = {"valid_lens": valid}
+        if t_step == 1:  # as the engine's decode program tells the model
+            from distributed_pytorch_tpu.ops.paged_attention import (
+                block_pages, shared_prefix_groups,
+            )
+
+            page = engine["page_size"]
+            kw = {"row_groups": shared_prefix_groups(
+                tables, lens, page,
+                block_pages(pages_per_seq, page, 640, jnp.bfloat16))}
         logits, updated = decode_model.apply(
             {"params": params, "cache": cache}, tokens, block_tables=tables,
             seq_lens=lens, state_slots=jnp.arange(rows, dtype=jnp.int32),
@@ -330,6 +345,8 @@ CASES.update({
     **{f"sparse-kernel-{which}": functools.partial(
         sparse_kernel_case, which=which)
        for which in ("index", "sparse", "window")},
+    "sparse-kernel-index-groups-told": functools.partial(
+        sparse_kernel_case, which="index", groups_told=True),
     "sparse-cell-decode": functools.partial(sparse_program, t_step=1),
     "sparse-cell-prefill-64": functools.partial(sparse_program, t_step=64),
     "sparse-cell-prefill-512": functools.partial(sparse_program, t_step=512),

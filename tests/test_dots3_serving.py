@@ -121,6 +121,10 @@ def test_the_counters_of_what_a_dispatch_reads(held_program):
     # Decode feeds positions 29..34 (the prompt's last token, then five).
     positions = np.arange(29, 35)
     assert stats["decode_index_tokens_scored"] == int((positions + 1).sum())
+    # A row alone: the table is narrower than the index kernel's block, ONE
+    # block of its 16 pages of 4.
+    assert stats["decode_index_tokens_fetched"] == 64 * len(positions)
+    assert stats["decode_rows_grouped"] == 0
     assert stats["decode_kv_tokens_selected"] == 7 * len(positions)
     assert stats["decode_window_tokens_visible"] == 9 * len(positions)
     assert stats["decode_window_tokens_read"] == int(
@@ -139,6 +143,72 @@ def test_the_counters_of_what_a_dispatch_reads(held_program):
     assert chosen[0]["selected_share"] == pytest.approx(7 / 30)
     assert stats["decode_index_tokens_scored_distinct"] == sum(
         a["decode_index_tokens_scored"] for a in steps)
+
+
+def test_askers_of_one_document_are_scored_as_a_group(
+        held_program, monkeypatch):
+    """Two questions on one cached document (6 whole pages: three of the
+    index kernel's blocks at 2 pages a block) and one prompt of its own
+    decode side by side. Through the kernels (interpreted) the index kernel
+    scores the document's keys once for the two, and the tokens and every
+    step's selected positions are those of the gather path's scores."""
+    monkeypatch.setattr(pa, "INDEX_BLOCK_PAGES", 2)
+    document = tokens(24, seed=60)
+    prompts = [document + tokens(3, seed=61), document + tokens(4, seed=62),
+               tokens(22, seed=63)]
+
+    def run(kernel):
+        tracer = Tracer()
+        engine = engine_for(held_program, tracer=tracer, paged_kernel=kernel)
+        serve(engine, [document + tokens(2, seed=59)], new_tokens=1)
+        tracer.events.clear()
+        before = engine.stats()["decode_index_tokens_fetched"]
+        ids = [engine.submit(p, SamplingParams(max_new_tokens=6))
+               for p in prompts]
+        selected = []
+        while not all(engine.poll(rid).finished for rid in ids):
+            engine.step()
+            selected.extend(np.asarray(a) for a in engine.selected_positions)
+        steps = [e["args"] for e in tracer.events
+                 if e["name"] == "step" and e.get("ph") == "X"
+                 and "decode_index_tokens_scored" in e["args"]]
+        served = [list(engine.poll(rid).generated) for rid in ids]
+        stats = engine.stats()
+        stats["decode_index_tokens_fetched"] -= before
+        return served, selected, steps, stats
+
+    served, selected, steps, stats = run("interpret")
+    by_gather, gathered, gather_steps, gather_stats = run("xla")
+    assert served == by_gather == run(False)[0]
+    assert len(selected) == len(gathered) > 0
+    for ours, theirs in zip(selected, gathered):
+        # A row out of the dispatch selects nothing through the kernel (the
+        # gather path scores its null page's first key).
+        live = (ours >= 0).any(axis=(0, 2))
+        assert live.any()
+        assert np.array_equal(ours[:, live], theirs[:, live])
+    for prompt, generated in zip(prompts, served):
+        assert served_gap(held_program, prompt, generated).max() < LOGIT_TOL
+    together = [a for a in steps if a["decode_rows"] == 3]
+    assert together
+    for a in together:
+        assert a["decode_rows_grouped"] == 2
+        # The document's 24 keys once, not twice: whole blocks of 8 keys,
+        # so more than the distinct keys and fewer than the rows see.
+        assert (a["decode_index_tokens_scored_distinct"]
+                <= a["decode_index_tokens_fetched"]
+                < a["decode_index_tokens_scored"])
+        assert (a["decode_index_tokens_scored"]
+                - a["decode_index_tokens_scored_distinct"]) == 24
+    assert stats["decode_rows_grouped"] == sum(
+        a["decode_rows_grouped"] for a in steps) >= 2 * len(together)
+    assert stats["decode_index_tokens_fetched"] == sum(
+        a["decode_index_tokens_fetched"] for a in steps)
+    # The gather path's scoring groups nobody and reads every slot's table.
+    assert gather_stats["decode_rows_grouped"] == 0
+    whole = ENGINE["max_slots"] * ENGINE["max_seq_len"]
+    assert all(a["decode_index_tokens_fetched"] == whole
+               and a["decode_rows_grouped"] == 0 for a in gather_steps)
 
 
 def test_the_selected_positions_come_back_to_who_asks(held_program):

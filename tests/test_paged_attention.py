@@ -1176,3 +1176,185 @@ def test_pages_a_walk_copies(pages, npb, walked):
     from distributed_pytorch_tpu.ops.paged_attention import pages_walked
 
     assert int(pages_walked(np.asarray(pages), npb)) == walked
+
+
+# ------------------------------------------- index keys of rows that share pages
+#
+# The index kernel scores a group's shared index keys once, at the group's
+# first row, against the members' queries stacked (``paged_index_scores``,
+# grouped by the same ``shared_prefix_groups``): whole blocks of its own, so
+# what is left of ``shared`` under a block is each member's own walk.
+
+
+def shared_index_problem(rows, npb, monkeypatch, **kw):
+    """``shared_latent_problem``'s dispatch as an index kernel's: ``q [S, H,
+    D]``, ``w [S, H]``, the index-key pool, the tables and the positions,
+    with the kernel's block set to ``npb`` pages."""
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "INDEX_BLOCK_PAGES", npb)
+    q, pool, bt, lens = shared_latent_problem(rows, h=6, **kw)
+    w = np.random.default_rng(len(rows)).standard_normal(q.shape[::2])
+    return q[:, 0], jnp.asarray(w, jnp.float32), pool, bt, lens
+
+
+def singletons(n):
+    return jnp.arange(n, dtype=jnp.int32), jnp.zeros((n,), jnp.int32)
+
+
+def assert_index_scores(got, q, w, pool, bt, lens):
+    """The reference's scores at every ``s <= pos``, ``-inf`` past it and
+    everywhere in a row out of the dispatch."""
+    from distributed_pytorch_tpu.ops.paged_attention import (
+        index_scores_reference,
+    )
+
+    got = np.asarray(got)
+    want = np.asarray(index_scores_reference(q, w, pool, bt, lens))
+    assert got.shape == want.shape
+    for r, pos in enumerate(np.asarray(lens)):
+        if bt[r, 0] == 0:
+            assert np.isneginf(got[r]).all()
+            continue
+        np.testing.assert_allclose(
+            got[r, :pos + 1], want[r, :pos + 1], atol=1e-4, rtol=1e-5)
+        assert np.isneginf(got[r, pos + 1:]).all()
+
+
+class TestIndexRowsThatShare:
+    @pytest.mark.parametrize("name", sorted(SHARING))
+    def test_grouped_rows_score_what_the_reference_scores(
+            self, name, monkeypatch):
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            paged_index_scores,
+        )
+
+        rows, npb, _ = SHARING[name]
+        q, w, pool, bt, lens = shared_index_problem(
+            rows, npb, monkeypatch, seed=len(name))
+        got = paged_index_scores(q, w, pool, bt, lens, kernel="interpret")
+        assert_index_scores(got, q, w, pool, bt, lens)
+
+    @pytest.mark.parametrize("name", sorted(SHARING))
+    def test_grouped_scores_are_the_bits_of_rows_served_alone(
+            self, name, monkeypatch):
+        """The exact top-k downstream turns a changed score into a changed
+        selection: a group's stacked product has to give every member the
+        bits its own walk gives."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            paged_index_scores,
+            shared_prefix_groups,
+        )
+
+        rows, npb, (leader, shared) = SHARING[name]
+        q, w, pool, bt, lens = shared_index_problem(
+            rows, npb, monkeypatch, seed=len(name))
+        groups = shared_prefix_groups(bt, lens, pool.shape[1], npb)
+        assert [list(map(int, g)) for g in groups] == [leader, shared]
+        told = paged_index_scores(
+            q, w, pool, bt, lens, kernel="interpret", row_groups=groups)
+        alone = paged_index_scores(
+            q, w, pool, bt, lens, kernel="interpret",
+            row_groups=singletons(len(rows)))
+        assert np.array_equal(np.asarray(told), np.asarray(alone))
+        worked_out = paged_index_scores(
+            q, w, pool, bt, lens, kernel="interpret")
+        assert np.array_equal(np.asarray(worked_out), np.asarray(told))
+
+    def test_a_page_copied_on_write_is_scored_where_it_stands(
+            self, monkeypatch):
+        """``TestLatentRowsThatShare``'s copied page: the group shares the
+        five pages before it (two whole blocks of two; the fifth is each
+        row's own), and each row scores its own copy."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            paged_index_scores,
+        )
+
+        q, w, pool, bt, lens = shared_index_problem(
+            doc_rows(25, 29, shared=5), 2, monkeypatch, seed=11,
+            copied=[(1, 0, 5)])
+        assert bt[0, 5] != bt[1, 5]
+        got = paged_index_scores(q, w, pool, bt, lens, kernel="interpret")
+        assert_index_scores(got, q, w, pool, bt, lens)
+        poisoned = pool.at[bt[1, 5]].set(7.0)
+        out = paged_index_scores(
+            q, w, poisoned, bt, lens, kernel="interpret")
+        assert_index_scores(out, q, w, poisoned, bt, lens)
+        assert np.array_equal(np.asarray(out[0]), np.asarray(got[0]))
+        assert not np.array_equal(np.asarray(out[1]), np.asarray(got[1]))
+
+    @pytest.mark.parametrize("npb", [3, 32])
+    def test_a_table_of_no_whole_blocks(self, npb, monkeypatch):
+        """Blocks of 3 pages leave the table's 16 a last block of one page;
+        at the kernel's own 32 the table is narrower than a block and is ONE
+        block of its 16 pages, so nobody shares a whole one."""
+        from distributed_pytorch_tpu.ops import paged_attention as pa
+
+        rows, _, _ = SHARING["two-documents-interleaved"]
+        q, w, pool, bt, lens = shared_index_problem(
+            rows, npb, monkeypatch, seed=npb)
+        assert pa.index_block_pages(bt.shape[1]) == min(npb, 16)
+        got = pa.paged_index_scores(q, w, pool, bt, lens, kernel="interpret")
+        assert_index_scores(got, q, w, pool, bt, lens)
+        alone = pa.paged_index_scores(
+            q, w, pool, bt, lens, kernel="interpret",
+            row_groups=singletons(len(rows)))
+        assert np.array_equal(np.asarray(got), np.asarray(alone))
+
+    def test_bf16_keys_score_the_same_bits_grouped_and_alone(
+            self, monkeypatch):
+        rows, npb, _ = SHARING["five-is-four-and-one"]
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            paged_index_scores,
+        )
+
+        q, w, pool, bt, lens = shared_index_problem(
+            rows, npb, monkeypatch, seed=7)
+        q, pool = q.astype(jnp.bfloat16), pool.astype(jnp.bfloat16)
+        told = paged_index_scores(q, w, pool, bt, lens, kernel="interpret")
+        assert told.dtype == jnp.float32
+        alone = paged_index_scores(
+            q, w, pool, bt, lens, kernel="interpret",
+            row_groups=singletons(len(rows)))
+        assert np.array_equal(np.asarray(told), np.asarray(alone))
+
+
+INDEX_COPIES = {
+    # SHARING's name: blocks the walks copy, counted by hand: a row alone or
+    # a leader ``pos // block tokens + 1``, a member that less the group's
+    # shared whole blocks
+    "one-row-alone": 4,
+    "a-pair": 4 + (5 - 3),
+    "three": 4 + (4 - 3) + (6 - 3),
+    "five-is-four-and-one": 4 + 3 * (4 - 3) + 4,
+    "shared-length-no-multiple-of-the-block": 2 + (3 - 1),
+    "shared-length-under-an-eighth-more": 2 + (2 - 1),
+    "an-absent-row-between-members": 4 + (4 - 3) + (4 - 3),
+    "a-leader-behind-its-members": 2 + (4 - 1) + (5 - 1),
+    "under-a-block-stays-alone": 2 + 2,
+    "no-sharing": 4 + 1 + 1 + 8 + 5,
+}
+
+
+@pytest.mark.parametrize("name", sorted(INDEX_COPIES))
+def test_index_keys_the_kernel_copies(name):
+    """``index_tokens_fetched`` and ``index_rows_grouped`` of a dispatch's
+    live rows: the engine's ``decode_index_tokens_fetched`` and, in a model
+    with sparse layers, its ``decode_rows_grouped``."""
+    from distributed_pytorch_tpu.ops.paged_attention import (
+        index_rows_grouped,
+        index_tokens_fetched,
+        shared_prefix_groups,
+    )
+
+    rows, npb, (_, shared) = SHARING[name]
+    _, pool, bt, lens = shared_latent_problem(rows)
+    live = np.asarray(bt)[:, 0] != 0
+    tables, positions = np.asarray(bt)[live], np.asarray(lens)[live]
+    page = pool.shape[1]
+    groups = shared_prefix_groups(tables, positions, page, npb)
+    assert index_tokens_fetched(
+        positions, *groups, page, npb, tables.shape[1]
+    ) == INDEX_COPIES[name] * npb * page
+    assert index_rows_grouped(groups[1], npb) == sum(
+        s >= npb for s in shared)

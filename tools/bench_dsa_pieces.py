@@ -2,13 +2,19 @@
 """Time, on the chip, the pieces of a sparse layer's decode step at the
 ``dots3-note-prev`` cell's shapes (32 rows at ~33,000 cached tokens, a table of
 3,104 pages, 2,048 selected), each alone, and a pass of the plain reference
-layer by layer. Host clock around ``block_until_ready``; the traced cell's
-readers have the device time. Writes ``chiprun_out/dsa_pieces.json``.
+layer by layer. The index kernel on three dispatches: four askers a document
+(the cell's), 32 rows of the same lengths of which NOBODY shares a page with
+another, and one row alone. Host clock around ``block_until_ready``; the
+traced cell's readers have the device time. Writes
+``chiprun_out/dsa_pieces.json``, or ``--out``: a copy of this file in another
+commit's tree times that commit's kernels.
 
     chiprun --timeout 1500 -- python3 tools/bench_dsa_pieces.py [--reference]
 """
 
 import argparse
+import functools
+import inspect
 import json
 import os
 import sys
@@ -19,13 +25,13 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 
-def timed(fn, *args, calls=10):
+def timed(fn, *args, calls=10, **kw):
     import jax
 
-    jax.block_until_ready(fn(*args))
+    jax.block_until_ready(fn(*args, **kw))
     t0 = time.perf_counter()
     for _ in range(calls):
-        out = fn(*args)
+        out = fn(*args, **kw)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / calls * 1e3
 
@@ -39,12 +45,23 @@ def pieces() -> dict:
 
     rng = np.random.default_rng(0)
     slots, pages_per_seq, num_pages, page, k = 32, 3104, 18433, 16, 2048
-    positions = rng.integers(16384, 49152, size=slots).astype(np.int32)
+    # The cell's dispatch: 8 documents of 16,430-49,152 tokens, four askers
+    # each, an asker a question and an answer so far (32-384 tokens) past
+    # its document; the document's whole pages are the same physical pages in
+    # its askers' tables, what follows them is each asker's own.
+    documents = rng.integers(16430, 49152, size=slots // 4)
+    positions = (
+        np.repeat(documents, 4) + rng.integers(32, 384, size=slots)
+    ).astype(np.int32)
     tables = np.zeros((slots, pages_per_seq), np.int32)
-    for r, pos in enumerate(positions):  # four askers a document
-        n = pos // page + 1
-        tables[r, :n] = 1 + (r // 4) * 2300 + np.arange(n) % 2300
-    tables, positions = jnp.asarray(tables), jnp.asarray(positions)
+    apart = np.zeros_like(tables)
+    for r, n in enumerate(positions // page + 1):
+        whole = documents[r // 4] // page
+        tables[r, :whole] = 1 + ((r // 4) * 2300 + np.arange(whole)) % 17000
+        tables[r, whole:n] = 17001 + r * 32 + np.arange(n - whole)
+        # no two rows hold the same page at any index of their tables
+        apart[r, :n] = 1 + (r * 571 + np.arange(n)) % (num_pages - 1)
+    lone = np.where(np.arange(slots)[:, None] == 0, apart, 0)
     key = jax.random.PRNGKey(0)
     index_pool = jax.random.normal(key, (num_pages, page, 128), jnp.bfloat16)
     pool = jax.random.normal(key, (num_pages, page, 640), jnp.bfloat16)
@@ -54,12 +71,46 @@ def pieces() -> dict:
     q = jax.random.normal(key, (slots, 1, 128, 640), jnp.bfloat16)
     q_w = jax.random.normal(key, (slots, 1, 64, 1152), jnp.bfloat16)
     out = {}
-    scores_of = jax.jit(lambda: pa.paged_index_scores(
-        q_i, w_i, index_pool, tables, positions, kernel="pallas"))
-    out["index_scores_kernel_ms"] = timed(scores_of)
-    out["index_scores_xla_ms"] = timed(jax.jit(lambda: pa.paged_index_scores(
-        q_i, w_i, index_pool, tables, positions, kernel="xla")), calls=3)
-    scores = scores_of()
+    index_scores = jax.jit(
+        pa.paged_index_scores, static_argnames=("kernel",))
+    grouping = "row_groups" in inspect.signature(
+        pa.paged_index_scores).parameters
+
+    def told(held_by):
+        """The rows' grouping worked out beforehand, as a decode program
+        works it out once for its layers (a kernel that takes one)."""
+        if not grouping:
+            return {}
+        return {"row_groups": tuple(map(jnp.asarray, pa.shared_prefix_groups(
+            held_by, positions, page, pa.index_block_pages(pages_per_seq))))}
+
+    dispatches = {
+        "index_scores_kernel_ms": tables,
+        "index_scores_kernel_no_sharing_ms": apart,
+        "index_scores_kernel_one_row_ms": lone,
+    }
+    for name, held_by in dispatches.items():
+        out[name] = timed(
+            index_scores, q_i, w_i, index_pool, jnp.asarray(held_by),
+            jnp.asarray(positions), kernel="pallas", calls=50, **told(held_by))
+    groups = told(tables)
+    tables, positions = jnp.asarray(tables), jnp.asarray(positions)
+    scores_of = functools.partial(
+        index_scores, q_i, w_i, index_pool, tables, positions)
+    out["index_scores_xla_ms"] = timed(scores_of, kernel="xla", calls=3)
+    scores = scores_of(kernel="pallas", **groups)
+    by_gather = scores_of(kernel="xla")
+    out["index_scores_kernel_off_xla_max"] = float(jnp.max(jnp.abs(jnp.where(
+        jnp.isfinite(by_gather), scores - by_gather, 0.0))))
+    if grouping:
+        rows = jnp.arange(slots, dtype=jnp.int32)
+        alone = scores_of(
+            kernel="pallas", row_groups=(rows, jnp.zeros_like(rows)))
+        out["index_scores_grouped_are_the_bits_of_rows_alone"] = bool(
+            jnp.all(scores == alone))
+        out["index_rows_grouped"] = pa.index_rows_grouped(
+            np.asarray(groups["row_groups"][1]),
+            pa.index_block_pages(pages_per_seq))
     mask_of = jax.jit(lambda s: pa.top_k_mask(s, k))
     out["top_k_mask_ms"] = timed(mask_of, scores)
     mask = mask_of(scores)
@@ -128,6 +179,8 @@ def reference_pass() -> dict:
 def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--reference", action="store_true")
+    parser.add_argument(
+        "--out", default=os.path.join(ROOT, "chiprun_out", "dsa_pieces.json"))
     args = parser.parse_args()
     from distributed_pytorch_tpu.utils.platform import init_platform
 
@@ -136,8 +189,8 @@ def main() -> None:
     print(json.dumps(out), flush=True)
     if args.reference:
         out["reference"] = reference_pass()
-    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "dsa_pieces.json"), "w") as f:
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
 

@@ -76,6 +76,22 @@ def apply_rope(
     return rotated.astype(x.dtype)
 
 
+def pool_kv_heads(kv_heads: int) -> int:
+    """KV heads a page pool ``[num_pages, page_size, heads, D]`` holds for a
+    layer of ``kv_heads``: a count above 8 that is no multiple of 8 is padded
+    to the next one (30 -> 32; the padding heads hold zeros, are attended to
+    by padding query heads, and are sliced off the result). The chip stores a
+    bf16 array in tiles of 16 x 128 over its last two sizes: with 30 heads
+    there the compiler keeps the pool in a layout of its own and copies the
+    WHOLE pool, there and back, round every write and every kernel call (8
+    copies of a 378 MB pool a layer and decode step, 1.2 GB of temporaries,
+    compiled for a described v5e: tests/test_chip_compile.py); with 1, 2, 8
+    or 32 it copies nothing."""
+    if kv_heads <= 8 or kv_heads % 8 == 0:
+        return kv_heads
+    return -(-kv_heads // 8) * 8
+
+
 class Attention(nn.Module):
     """Multi-head attention with RoPE and a pluggable core.
 
@@ -156,6 +172,12 @@ class Attention(nn.Module):
     # What the scores are multiplied by before the softmax, on every path
     # below and in the paged kernel; None is the usual ``head_dim ** -0.5``.
     score_scale: Optional[float] = None
+    # An RMSNorm over the WHOLE query and the whole key projection (all heads
+    # together, a scale an element) before the heads are split, the rotation
+    # and the cache (the Olmo 2 family's QK-norm), statistics in float32 at
+    # ``norm_eps``.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     def _scale(self, head_dim: int) -> float:
         if self.score_scale is None:
@@ -245,6 +267,13 @@ class Attention(nn.Module):
         q_raw = dense(self.n_heads, "query")(x)
         k_raw = dense(kv_heads, "key")(x)
         v = dense(kv_heads, "value")(x)
+        if self.qk_norm:
+            whole = lambda name: nn.RMSNorm(  # noqa: E731
+                epsilon=self.norm_eps, dtype=jnp.float32, name=name,
+                reduction_axes=(-2, -1), feature_axes=(-2, -1),
+            )
+            q_raw = whole("q_norm")(q_raw).astype(self.dtype)
+            k_raw = whole("k_norm")(k_raw).astype(self.dtype)
 
         if self.decode and self.has_variable("cache", "cached_key"):
             if self.page_size:
@@ -264,7 +293,10 @@ class Attention(nn.Module):
             # length in contiguous mode, to the global page pool in paged
             # mode — then fall through to the normal causal forward.
             if self.page_size:
-                pool = (self.num_pages, self.page_size, kv_heads, head_dim)
+                pool = (
+                    self.num_pages, self.page_size, pool_kv_heads(kv_heads),
+                    head_dim,
+                )
                 pool_dtype = jnp.int8 if self.kv_quant else k_raw.dtype
                 self.variable("cache", "cached_key", jnp.zeros, pool, pool_dtype)
                 self.variable("cache", "cached_value", jnp.zeros, pool, pool_dtype)
@@ -481,6 +513,21 @@ class Attention(nn.Module):
         positions = seq_lens[:, None] + jnp.arange(t_step, dtype=jnp.int32)
         q = self._rope(q_raw, positions)
         k = self._rope(k_raw, positions)
+        heads_out = h
+        held = cached_key.value.shape[2]
+        if held != kv_heads:
+            # The pool holds padding heads (``pool_kv_heads``): zeros for
+            # K and V, and zero query heads to attend to them, kv-major as
+            # the grouped mapping is, so that the real heads come first.
+            def widen(x, group):
+                x = x.reshape(s, t_step, kv_heads, group, d)
+                x = jnp.pad(
+                    x, ((0, 0), (0, 0), (0, held - kv_heads), (0, 0), (0, 0))
+                )
+                return x.reshape(s, t_step, held * group, d)
+
+            q, k, v = widen(q, h // kv_heads), widen(k, 1), widen(v, 1)
+            h, kv_heads = held * (h // kv_heads), held
 
         # Scatter this step's K/V into (physical page, in-page offset). A
         # position at or past the row's table capacity — a speculative
@@ -545,7 +592,7 @@ class Attention(nn.Module):
                 v_scale=None if value_scale is None else value_scale.value,
                 kernel=self.paged_kernel, mesh=self.mesh,
                 sm_scale=self.score_scale,
-            )
+            )[:, :, :heads_out]
 
         # Gather each row's pages into its contiguous logical view. K below
         # is pages_per_seq * page_size — the row's maximum context, not the
@@ -576,7 +623,7 @@ class Attention(nn.Module):
             logits.astype(jnp.float32), axis=-1
         ).astype(q.dtype)
         out = jnp.einsum("bhgqk,bkhd->bqhgd", weights, values)
-        return out.reshape(s, t_step, h, d)
+        return out.reshape(s, t_step, h, d)[:, :, :heads_out]
 
     def _update_quantized_cache(self, cached_key, cached_value, k, v, index):
         """Write this step's k/v as int8 + per-(token, head) float32 scales,
@@ -634,10 +681,11 @@ class MLPBlock(nn.Module):
 
 
 LAYER_TYPES = (
-    "attention", "mamba", "mamba2", "latent", "latent_sparse", "latent_window"
+    "attention", "mamba", "mamba2", "latent", "latent_sparse", "latent_window",
+    "gated_delta",
 )
 #: The layer types that keep a per-slot recurrent state in decode mode.
-RECURRENT_TYPES = ("mamba", "mamba2")
+RECURRENT_TYPES = ("mamba", "mamba2", "gated_delta")
 #: The layer types that are models/mla.py's LatentAttention: the plain one,
 #: one with an indexer (learned sparse attention; a second page pool), one
 #: with a window. The last two take their sizes from ``latent_variants``.
@@ -706,6 +754,11 @@ class TransformerBlock(nn.Module):
     ffn: str = "dense"  # one of FFN_TYPES
     routed: tuple = ()  # RoutedExperts' sizes as (field, value) pairs
     shared_d_ff: int = 0  # a routed layer's shared gated-SiLU MLP; 0 = none
+    # Where the block's two norms stand: "input" is ``x + f(norm(x))``,
+    # "output" the Olmo 2 family's ``x + norm(f(x))`` (no norm on the input;
+    # the same two parameters, ``ln_attn`` and ``ln_mlp``).
+    norm_placement: str = "input"
+    qk_norm: bool = False  # see Attention
 
     @nn.compact
     def __call__(
@@ -737,7 +790,14 @@ class TransformerBlock(nn.Module):
         # A padded prefill piece says how many of a row's tokens are its
         # own; every other call says nothing and lowers as it always did.
         piece_kw = {} if valid_lens is None else {"valid_lens": valid_lens}
-        normed = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
+        if self.norm_placement not in ("input", "output"):
+            raise ValueError(
+                f"unknown norm_placement {self.norm_placement!r} "
+                "(expected 'input' or 'output')"
+            )
+        on_output = self.norm_placement == "output"
+        ln_attn = make_norm(self.norm, self.norm_eps, "ln_attn")
+        normed = x if on_output else ln_attn(x)
         if self.mixer == "mamba":
             from distributed_pytorch_tpu.models.mamba import MambaMixer
 
@@ -751,6 +811,16 @@ class TransformerBlock(nn.Module):
             mixed = Mamba2Mixer(
                 self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
                 decode=self.decode, name="mamba", **dict(self.mamba),
+            )(normed, seq_lens=seq_lens, state_slots=state_slots, **piece_kw)
+        elif self.mixer == "gated_delta":
+            from distributed_pytorch_tpu.models.gated_delta import (
+                GatedDeltaMixer,
+            )
+
+            mixed = GatedDeltaMixer(
+                self.d_model, dtype=self.dtype, norm_eps=self.norm_eps,
+                decode=self.decode, kernel=self.paged_kernel,
+                name="gated_delta", **dict(self.mamba),
             )(normed, seq_lens=seq_lens, state_slots=state_slots, **piece_kw)
         elif self.mixer in LATENT_TYPES:
             from distributed_pytorch_tpu.models.mla import LatentAttention
@@ -775,7 +845,8 @@ class TransformerBlock(nn.Module):
                 page_size=self.page_size, num_pages=self.num_pages,
                 paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
                 rope=self.rope, use_bias=self.use_bias,
-                score_scale=self.score_scale, name="attention",
+                score_scale=self.score_scale, qk_norm=self.qk_norm,
+                norm_eps=self.norm_eps, name="attention",
             )(normed, **paged_kw, **piece_kw)
         else:
             raise ValueError(
@@ -787,8 +858,11 @@ class TransformerBlock(nn.Module):
                 return branch
             return branch * jnp.asarray(self.residual_multiplier, branch.dtype)
 
+        if on_output:
+            mixed = ln_attn(mixed).astype(x.dtype)
         x = x + drop(scaled(mixed))
-        normed = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
+        ln_mlp = make_norm(self.norm, self.norm_eps, "ln_mlp")
+        normed = x if on_output else ln_mlp(x)
         if self.ffn == "routed":
             from distributed_pytorch_tpu.models.moe import RoutedExperts
 
@@ -819,6 +893,8 @@ class TransformerBlock(nn.Module):
                 self.d_ff, self.d_model, self.dtype, kind=self.mlp,
                 use_bias=self.use_bias, name="mlp",
             )(normed)
+        if on_output:
+            fed = ln_mlp(fed).astype(x.dtype)
         return x + drop(scaled(fed))
 
 
@@ -971,6 +1047,18 @@ class TransformerLM(nn.Module):
     mamba_n_heads: int = 0  # required where a layer is "mamba2"
     mamba_d_head: int = 0
     mamba_n_groups: int = 1
+    # A "gated_delta" layer is models/gated_delta.py's mixer (the gated
+    # delta rule: a MATRIX state [heads, d_k, d_v] a slot, read before it is
+    # written); ``linear_neg_eigval`` is its ``beta``'s factor 2.
+    linear_n_heads: int = 0  # required where a layer is "gated_delta"
+    linear_d_k: int = 0
+    linear_d_v: int = 0
+    linear_d_conv: int = 4
+    linear_neg_eigval: bool = True
+    # The Olmo 2 family's block (see TransformerBlock and Attention): the
+    # norms on each sublayer's OUTPUT, and an RMSNorm over the whole q and k.
+    norm_placement: str = "input"  # "input" | "output"
+    qk_norm: bool = False
     # Scalars some families put on the residual stream (defaults: none).
     # The scores' scale (None = head_dim ** -0.5) reaches every attention path
     # and the paged kernel; the others multiply the embedding, each branch
@@ -1075,6 +1163,13 @@ class TransformerLM(nn.Module):
                 "a model with mamba2 layers needs mamba_n_heads and "
                 "mamba_d_head"
             )
+        if "gated_delta" in (types or ()) and min(
+            self.linear_n_heads, self.linear_d_k, self.linear_d_v
+        ) < 1:
+            raise ValueError(
+                "a model with gated_delta layers needs linear_n_heads, "
+                "linear_d_k and linear_d_v"
+            )
         if "latent" in (types or ()) and min(
             self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim,
             self.v_head_dim,
@@ -1132,6 +1227,7 @@ class TransformerLM(nn.Module):
             use_bias=self.use_bias, rope=self.rope,
             score_scale=self.attention_multiplier,
             residual_multiplier=self.residual_multiplier,
+            norm_placement=self.norm_placement, qk_norm=self.qk_norm,
         )
         mixer_kw = {
             "mamba": (
@@ -1142,6 +1238,11 @@ class TransformerLM(nn.Module):
                 ("n_heads", self.mamba_n_heads), ("d_head", self.mamba_d_head),
                 ("d_state", self.mamba_d_state), ("d_conv", self.mamba_d_conv),
                 ("n_groups", self.mamba_n_groups),
+            ),
+            "gated_delta": (
+                ("n_heads", self.linear_n_heads), ("d_k", self.linear_d_k),
+                ("d_v", self.linear_d_v), ("d_conv", self.linear_d_conv),
+                ("neg_eigval", self.linear_neg_eigval),
             ),
         }
         for latent_type in LATENT_TYPES:

@@ -79,19 +79,30 @@ token-identical to the plain engine; sampled rows follow Leviathan et
 al.'s residual-resampling rule, keeping every emitted token exactly
 target-distributed.
 
-Recurrent layers (a model whose ``layer_types`` name ``"mamba"`` or
-``"mamba2"`` layers, ``models/mamba.py`` and ``models/mamba2.py``): a request
+Recurrent layers (a model whose ``layer_types`` name ``"mamba"``,
+``"mamba2"`` or ``"gated_delta"`` layers, ``models/mamba.py``,
+``models/mamba2.py`` and ``models/gated_delta.py``): a request
 then owns, beside its pages, ONE fixed-size state, the row of its slot in
 every such layer's two ``cache`` variables (``STATE_KEYS``: for ``"mamba"``
 ``conv_state [max_slots, K-1, d_inner]`` and ``scan_state [max_slots, N,
 d_inner]`` float32; for ``"mamba2"`` ``conv_state [max_slots, K-1, d_inner +
 2 G N]`` and ``scan_state [max_slots, H, P, N]`` float32, 4 MB a slot and
-layer at 128 x 64 x 128). The engine reads that the model has them from the
-model's ``recurrent_layers`` and from the cache tree it builds, whatever the
-states' shapes; there is no argument for it, and nothing below changed when
-the second kind of state arrived: the mask, the reset rule, the prefill
-operand, the byte count and the refusals are keyed on ``STATE_KEYS`` and on
-the leaves' leading ``max_slots`` alone. The one design:
+layer at 128 x 64 x 128; for ``"gated_delta"`` ``conv_state [max_slots, K-1,
+2 H d_k + H d_v]`` and the delta rule's MATRIX state ``scan_state
+[max_slots, H / p, d_k, p d_v]`` float32, ``p`` heads side by side on the
+lanes, 2.2 MB a slot and layer at 30 x 96 x 192). The engine reads that the
+model has them from the model's ``recurrent_layers`` and from the cache tree
+it builds, whatever the states' shapes; there is no argument for it, and
+nothing below changed when the second kind of state arrived, nor the third:
+the mask, the reset rule, the prefill operand, the byte count and the
+refusals are keyed on ``STATE_KEYS`` and on the leaves' leading ``max_slots``
+alone. (What the third added is counters: a model with gated-delta layers'
+``step`` slice and ``stats()`` carry ``state_slots_updated``, live rows x
+such layers of the step's decode programs, and ``state_bytes_moved``, each of
+those states once in and once out, which is what the decode kernel
+``linear_attention._gated_delta_step`` moves; its ``prefill.chunk`` slice
+carries ``state_blocks``, the blocks of 64 tokens a layer evaluated for the
+piece.) The one design:
 
 * **decode** runs all ``max_slots`` rows, row ``r`` on slot ``r``'s state in
   place. A row outside the dispatched group has a zeroed block table; the
@@ -512,6 +523,24 @@ class InferenceEngine:
             model.latent_sizes("latent_window")["window"]
             if self.window_layers else 0
         )
+        # Gated-delta layers (``models/gated_delta.py``): the step slice says
+        # how many (row, layer) states its decode program updated and the
+        # bytes that moved by the kernel's own rule (each state once in, once
+        # out: ``ops/linear_attention.state_bytes_moved``); a prefill piece
+        # says how many blocks a layer evaluated.
+        self.delta_layers = kinds.count("gated_delta")
+        self._delta_state_bytes = 0
+        if self.delta_layers:
+            from distributed_pytorch_tpu.models.mamba import STATE_DTYPE
+            from distributed_pytorch_tpu.ops import linear_attention as la
+
+            self._delta_state_bytes = la.state_bytes_moved(
+                1, model.linear_n_heads, model.linear_d_k, model.linear_d_v,
+                jnp.dtype(STATE_DTYPE).itemsize,
+            )
+            self._delta_block = la.BLOCK
+        self.state_slots_updated = 0
+        self.state_bytes_moved = 0
         # Totals of what the decode dispatches read (module docstring).
         self.decode_index_tokens_scored = 0
         self.decode_index_tokens_fetched = 0
@@ -1482,8 +1511,12 @@ class InferenceEngine:
         if self._acct is not None and req.rework_until > start:
             self._note_rework(req, start, tokens)
         target, draft = self._prefill_programs[width]
+        blocks = (
+            {"state_blocks": -(-width // min(self._delta_block, width))}
+            if self.delta_layers else {}
+        )
         with self._phase(
-            "prefill.chunk", tokens=tokens, start=start, width=width
+            "prefill.chunk", tokens=tokens, start=start, width=width, **blocks
         ):
             tok = np.zeros((1, width), np.int32)
             tok[0, :tokens] = req.tokens[start : start + tokens]
@@ -2078,6 +2111,10 @@ class InferenceEngine:
                     bias.fill(0.0)
                 bias[slot] = row
         self._stage_row_keys(slots)
+        if self.delta_layers:
+            updated = len(slots) * self.delta_layers
+            self.state_slots_updated += updated
+            self.state_bytes_moved += updated * self._delta_state_bytes
         groups = None
         rows = sorted(slots)
         if self._grouping_layers and self._kv_block_tokens:
@@ -2193,6 +2230,11 @@ class InferenceEngine:
         if self.state_layers:
             extra["state_slots_in_use"] = len(self.scheduler.running)
             extra["state_bytes"] = self._state_bytes()
+        if self.delta_layers:
+            extra["state_slots_updated"] = updated = (
+                len(plan.decode_slots) * self.delta_layers
+            )
+            extra["state_bytes_moved"] = updated * self._delta_state_bytes
         if self.routed_layers:
             # The step before this one has been read back: its counts cost
             # no wait. This step's wait for the next trace (or a flush).
@@ -3008,6 +3050,9 @@ class InferenceEngine:
         out["prefill_tokens"] = self.prefill_tokens
         out["prefill_width"] = self.prefill_width
         out["decode_rows_grouped"] = self.decode_rows_grouped
+        if self.delta_layers:
+            out["state_slots_updated"] = self.state_slots_updated
+            out["state_bytes_moved"] = self.state_bytes_moved
         if self.sparse_layers:
             out["decode_index_tokens_scored"] = self.decode_index_tokens_scored
             out["decode_index_tokens_fetched"] = (

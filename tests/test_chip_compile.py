@@ -412,6 +412,145 @@ def test_the_grouped_product_is_named_for_the_benchmarks_readers(chip):
     assert "ragged-dot" in call.split(" = ", 1)[0]
 
 
+# ------------------------------------------- the gated delta rule, 30 KV heads
+#
+# ``benchmarks/configs/olmo-hybrid-7b.json``'s engine: 96 slots of 128 pages
+# out of 3,073, a matrix state ``[15, 96, 384]`` float32 a slot and layer (two
+# heads of 96 x 192 side by side on the lanes) and K/V pools of 32 heads for
+# the model's 30. The decode kernel of ``ops/linear_attention.py``, the paged
+# kernel at one query head a KV head, and the cell's serving programs.
+
+OLMO = dict(slots=96, heads=30, d_k=96, d_v=192, pages_per_seq=128,
+            num_pages=3073)
+
+
+def gdn_step_case(chip):
+    from distributed_pytorch_tpu.ops import linear_attention as la
+
+    b, h, dk, dv = OLMO["slots"], OLMO["heads"], OLMO["d_k"], OLMO["d_v"]
+    pack = la.lane_pack(h, dv)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    fn = functools.partial(la.gated_delta_step, pack=pack, kernel="pallas")
+    return jax.jit(fn, donate_argnums=(5,)).lower(
+        arg((b, h, dk)), arg((b, h, dk)), arg((b, h, dv)), arg((b, h)),
+        arg((b, h)), arg((b, h // pack, dk, pack * dv)), arg((b,), jnp.int32))
+
+
+def olmo_program(chip, t_step, layers=4):
+    """A serving program of the ``olmo-hybrid-7b`` configuration at its own
+    shapes (published widths, the cell's engine), lowered for the described
+    chip on abstract operands: the decode step over all 96 slots (``t_step``
+    1, through both kernels) or a prefill piece of ``t_step`` tokens.
+    ``layers`` of the 16: one whole period, every shape the compiler is asked
+    about (the whole depth is compiled by hand, for its memory: PERF.md 6)."""
+    import json
+
+    from hybrid_toy import ROOT, load_by_path
+
+    reference = load_by_path("benchmarks/reference/olmo_hybrid.py")
+    driver = load_by_path("benchmarks/drivers/serve_linear_hybrid.py")
+    with open(os.path.join(
+            ROOT, "benchmarks", "configs", "olmo-hybrid-7b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=layers)
+    engine = cfg["assumed"]["engine"]
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+            tree)
+
+    weights = jax.eval_shape(lambda: reference.make_weights(cfg, 0))
+    model, params = driver.build_program(cfg, weights)
+    decode_model = model.clone(
+        decode=True, page_size=engine["page_size"],
+        num_pages=engine["num_pages"], paged_kernel="pallas")
+    slots = engine["max_slots"]
+    rows = slots if t_step == 1 else 1
+    cache = jax.eval_shape(
+        decode_model.init, jax.random.PRNGKey(0),
+        jnp.zeros((slots, 1), jnp.int32))["cache"]
+    pages_per_seq = engine["max_seq_len"] // engine["page_size"]
+
+    def run(params, cache, tokens, tables, lens, valid, state_slots):
+        kw = {} if t_step == 1 else {"valid_lens": valid}
+        logits, updated = decode_model.apply(
+            {"params": params, "cache": cache}, tokens, block_tables=tables,
+            seq_lens=lens, state_slots=state_slots, mutable=["cache"], **kw)
+        return logits[:, -1], updated["cache"]
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    return jax.jit(run, donate_argnums=(1,)).lower(
+        abstract(params), abstract(cache), arg((rows, t_step)),
+        arg((rows, pages_per_seq)), arg((rows,)), arg((rows,)), arg((rows,)))
+
+
+CASES.update({
+    "gdn-step-96x30x96x192": gdn_step_case,
+    # The pool holds 32 heads for the model's 30 (``pool_kv_heads``).
+    "paged-olmo-hybrid-7b": functools.partial(
+        paged_case, heads=32, kv_heads=32, quantized=False,
+        slots=OLMO["slots"], pages_per_seq=OLMO["pages_per_seq"],
+        num_pages=OLMO["num_pages"]),
+    "olmo-cell-decode": functools.partial(olmo_program, t_step=1),
+})
+
+
+@pytest.mark.parametrize("t_step", [64, 512])
+def test_the_olmo_cells_prefill_programs_compile_for_v5e(chip, t_step):
+    """The blocked form is plain XLA operations (a unit-triangular solve a
+    block among them) and a prefill piece attends through the gather path:
+    no kernel to look for, so what is held is that the compiler takes the
+    program, and what it needs beside the weights and the pools."""
+    compiled = olmo_program(chip, t_step).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def test_the_gated_delta_kernel_is_named_and_updates_the_states_in_place(chip):
+    """``benchmarks/harness/linear.py`` tells the decode kernel by its name;
+    the slot table's states (212 MB a layer) alias the kernel's result."""
+    from distributed_pytorch_tpu.ops.linear_attention import STEP_KERNEL
+
+    compiled = gdn_step_case(chip).compile()
+    call = next(line for line in compiled.as_text().splitlines()
+                if "tpu_custom_call" in line)
+    assert STEP_KERNEL in call.split(" = ", 1)[0]
+    memory = compiled.memory_analysis()
+    state = 96 * 15 * 96 * 384 * 4
+    assert memory.alias_size_in_bytes == state
+    assert memory.temp_size_in_bytes < state // 8
+
+
+def whole_pool_copies(compiled, num_pages):
+    import re
+
+    return [line for line in compiled.as_text().splitlines()
+            if re.search(rf" = bf16\[{num_pages},[\d,]*\]\S* copy\(", line)]
+
+
+def test_thirty_kv_heads_are_held_as_thirty_two(chip, monkeypatch, t_step=1):
+    """Why ``pool_kv_heads`` pads: a pool ``[pages, 16, 30, 128]`` bf16 is
+    copied WHOLE, there and back, round every write and kernel call (the
+    compiler keeps it in a layout of its own); as 32 heads it is copied
+    nowhere. One period of the configuration, its decode step (a prefill
+    piece shows the same: four copies a full layer)."""
+    from distributed_pytorch_tpu.models import transformer
+
+    padded = olmo_program(chip, t_step, layers=4).compile()
+    assert not whole_pool_copies(padded, OLMO["num_pages"])
+    monkeypatch.setattr(transformer, "pool_kv_heads", lambda n: n)
+    plain = olmo_program(chip, t_step, layers=4).compile()
+    copies = whole_pool_copies(plain, OLMO["num_pages"])
+    assert len(copies) >= 4, len(copies)
+    assert (plain.memory_analysis().temp_size_in_bytes
+            > padded.memory_analysis().temp_size_in_bytes + 300e6)
+
+
 def test_a_latent_pool_of_576_is_refused_by_mosaic(chip):
     """Why the pool's rows are 640 wide: the chip stores a ``[.., 576]`` bf16
     array in tiles of 128 lanes (640 a token in HBM whatever the shape says),

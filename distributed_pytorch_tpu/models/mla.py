@@ -544,6 +544,7 @@ class LatentAttention(nn.Module):
                     chosen, real = selected_positions(
                         top_k_mask(scores, self.index_top_k),
                         min(self.index_top_k, scores.shape[-1]),
+                        kernel=self.paged_kernel,
                     )
                     # For who asks what was selected (the serving engine's
                     # ``selected_positions``): -1 past a row's own.

@@ -1154,6 +1154,7 @@ def window_tables(block_tables, seq_lens, page: int, window: int):
 
 INDEX_KERNEL = "attention._index_scores"
 SPARSE_KERNEL = "attention._sparse_latent_decode_step"
+POSITIONS_KERNEL = "attention._selected_positions"
 #: Pages the index kernel copies and scores at a time (512 keys of 128 at 16
 #: a page: a 128 KB block in bf16).
 INDEX_BLOCK_PAGES = 32
@@ -1525,29 +1526,119 @@ def top_k_mask(scores, k: int):
     return chosen & (scores > -jnp.inf)
 
 
-def selected_positions(mask, k: int):
+def _positions_kernel(held_ref, where_ref, real_ref):
+    """One row of the mask, ``[blocks, lanes]`` of 0/1, and every wanted rank
+    ``j`` (on the lanes, so that what comes out is a row): the row's two
+    running counts by products with triangles of ones, the block of every
+    ``j`` by comparisons with the blocks' counts, the block's own running
+    count by a one-hot product, and the lane by comparisons with it. Every
+    operand of a product is 0, 1 or a count of at most ``lanes``, exact in
+    bfloat16; the sums are float32, exact."""
+    blocks, lanes = held_ref.shape[1:]
+    k = where_ref.shape[-1]
+    held = held_ref[0]
+
+    def ones_where(shape, keep):
+        rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return jnp.where(keep(rows, cols), 1.0, 0.0).astype(jnp.bfloat16)
+
+    # inside[l, b]: the true positions of block b at lanes <= l
+    inside = jax.lax.dot_general(
+        ones_where((lanes, lanes), lambda l, m: m <= l), held,
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+    ).astype(jnp.bfloat16)
+    # upto[b]: the true positions of the blocks <= b (lane by lane, then summed)
+    upto = jnp.sum(jnp.dot(
+        ones_where((blocks, blocks), lambda b, c: c <= b), held,
+        preferred_element_type=jnp.float32,
+    ), axis=1, keepdims=True)  # [blocks, 1]
+    want = (
+        1 + jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    ).astype(jnp.float32)
+    below = upto < want  # [blocks, k]: the blocks that end before j
+    block = jnp.minimum(
+        jnp.sum(below.astype(jnp.int32), axis=0, keepdims=True), blocks - 1
+    )
+    before = jnp.max(jnp.where(below, upto, 0.0), axis=0, keepdims=True)
+    onehot = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (blocks, k), 0) == block, 1.0, 0.0
+    ).astype(jnp.bfloat16)
+    counts = jnp.dot(
+        inside, onehot, preferred_element_type=jnp.float32
+    )  # [lanes, k]: the running count of j's block
+    lane = jnp.sum(
+        (counts < want - before).astype(jnp.int32), axis=0, keepdims=True
+    )
+    real = want <= upto[blocks - 1:, :]
+    where = block * lanes + jnp.minimum(lane, lanes - 1)
+    where_ref[0] = jnp.where(real, where, 0)
+    real_ref[0] = real.astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _positions_flash(held, *, k, interpret):
+    """The positions kernel's ``pallas_call`` over ``held [S, blocks,
+    lanes]`` bfloat16, a row a grid step, named as the file's others are."""
+    s, blocks, lanes = held.shape
+
+    def row_spec(shape):
+        return pl.BlockSpec(
+            shape, lambda r: (r, 0, 0), memory_space=pltpu.VMEM
+        )
+
+    where, real = pl.pallas_call(
+        _positions_kernel,
+        grid=(s,),
+        in_specs=[row_spec((1, blocks, lanes))],
+        out_specs=[row_spec((1, 1, k))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((s, 1, k), jnp.int32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # a handful of [blocks, k] arrays of four bytes at a time
+            vmem_limit_bytes=max(16 << 20, 40 * blocks * k),
+        ),
+        interpret=interpret,
+        name=POSITIONS_KERNEL,
+    )(held)
+    return where[:, 0], real[:, 0] > 0
+
+
+def selected_positions(mask, k: int, *, kernel="auto"):
     """The positions at which ``mask [S, N]`` is true, ascending, as ``[S,
     k]`` int32 and which of them are real (a row with fewer than ``k`` pads
-    with position 0, marked false). No sort, no loop: the ``j``-th true
-    position lies in the block of 128 lanes at which the blocks' running
-    count first reaches ``j``, at the lane at which that block's own count
-    first reaches what is left."""
+    with position 0, marked false). No sort, no loop, no gather: the ``j``-th
+    true position lies in the block of 128 lanes at which the blocks' running
+    count first reaches ``j`` (comparisons of every ``j`` with every block's
+    count), at the lane at which that block's own running count first reaches
+    what is left (the block's counts by a ONE-HOT PRODUCT, exact: one term a
+    sum). Dispatches per ``kernel`` as :func:`paged_attention` does: the
+    kernel on the chip, the same arithmetic in XLA elsewhere."""
     s, n = mask.shape
     lanes = 128
-    blocks = -(-n // lanes)
+    mode = resolve_kernel(kernel)
+    # the kernel holds the blocks on its lanes too: whole tiles of them
+    blocks = -(-n // lanes) if mode == "xla" else -(-n // lanes**2) * lanes
     held = jnp.pad(mask, ((0, 0), (0, blocks * lanes - n))).reshape(
         s, blocks, lanes
-    ).astype(jnp.int32)
-    inside = jnp.cumsum(held, axis=-1)  # a block's own running count
-    upto = jnp.cumsum(inside[..., -1], axis=-1)  # [S, blocks], inclusive
-    want = jnp.arange(1, k + 1, dtype=jnp.int32)
-    block = jnp.minimum(
-        jnp.sum(upto[:, None, :] < want[None, :, None], axis=-1), blocks - 1
-    )  # [S, k]
-    before = jnp.take_along_axis(upto - inside[..., -1], block, axis=1)
-    counts = jnp.take_along_axis(inside, block[..., None], axis=1)
-    lane = jnp.sum(counts < (want[None, :] - before)[..., None], axis=-1)
-    real = want[None, :] <= upto[:, -1:]
+    )
+    if mode != "xla":
+        return _positions_flash(
+            held.astype(jnp.bfloat16), k=int(k),
+            interpret=(mode == "interpret"),
+        )
+    inside = jnp.cumsum(held.astype(jnp.int32), axis=-1)  # a block's own count
+    upto = jnp.cumsum(inside[..., -1], axis=-1)[:, None, :]  # [S, 1, blocks]
+    want = jnp.arange(1, k + 1, dtype=jnp.int32)[None, :, None]
+    below = upto < want  # [S, k, blocks]: the blocks that end before j
+    block = jnp.minimum(jnp.sum(below, axis=-1), blocks - 1)  # [S, k]
+    before = jnp.max(jnp.where(below, upto, 0), axis=-1, keepdims=True)
+    counts = jnp.einsum(
+        "skb,sbl->skl", jax.nn.one_hot(block, blocks, dtype=jnp.float32),
+        inside.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST,
+    )
+    lane = jnp.sum(counts < want - before, axis=-1)
+    real = want[..., 0] <= upto[..., -1]
     where = block * lanes + jnp.minimum(lane, lanes - 1)
     return jnp.where(real, where, 0).astype(jnp.int32), real
 

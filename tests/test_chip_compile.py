@@ -274,6 +274,10 @@ def sparse_kernel_case(chip, which, groups_told=False):
             arg((s["num_pages"], PAGE, 640), jnp.bfloat16), tables,
             arg((s["slots"], s["top_k"]), jnp.int32),
             arg((s["slots"], s["top_k"]), jnp.bool_))
+    if which == "positions":  # 2,048 of a table of 49,664 tokens a row
+        return jax.jit(functools.partial(
+            pa.selected_positions, k=s["top_k"], kernel="pallas",
+        )).lower(arg((s["slots"], s["pages_per_seq"] * PAGE), jnp.bool_))
     # 64 heads on a latent of 1,088 held as 1,152, a window of 513
     return jax.jit(functools.partial(
         pa.paged_latent_attention, v_width=1024, kernel="pallas",
@@ -344,7 +348,7 @@ def sparse_program(chip, t_step):
 CASES.update({
     **{f"sparse-kernel-{which}": functools.partial(
         sparse_kernel_case, which=which)
-       for which in ("index", "sparse", "window")},
+       for which in ("index", "sparse", "window", "positions")},
     "sparse-kernel-index-groups-told": functools.partial(
         sparse_kernel_case, which="index", groups_told=True),
     "sparse-cell-decode": functools.partial(sparse_program, t_step=1),
@@ -353,16 +357,68 @@ CASES.update({
 })
 
 
+@functools.lru_cache(maxsize=None)
+def compiled_text(chip, name):
+    """A case's compiled text, compiled once for the tests that read it."""
+    return CASES[name](chip).compile().as_text()
+
+
 def test_the_new_kernels_are_named_for_the_benchmarks_readers(chip):
-    """``benchmarks/harness/dsa.py`` tells the three kernels by their names."""
+    """``benchmarks/harness/dsa.py`` tells three kernels by their names (the
+    positions' by its result's sizes: the test below)."""
     from distributed_pytorch_tpu.ops import paged_attention as pa
 
     for which, name in (("index", pa.INDEX_KERNEL), ("sparse", pa.SPARSE_KERNEL),
-                        ("window", pa.WINDOW_KERNEL)):
-        text = sparse_kernel_case(chip, which).compile().as_text()
+                        ("window", pa.WINDOW_KERNEL),
+                        ("positions", pa.POSITIONS_KERNEL)):
+        text = compiled_text(chip, f"sparse-kernel-{which}")
         calls = [line.split(" = ", 1)[0] for line in text.splitlines()
                  if "tpu_custom_call" in line]
         assert any(name in call for call in calls), (which, calls)
+
+
+def test_the_position_search_is_still_read_as_selection_and_gathers_nothing(
+        chip):
+    """The decode program of the cell: ``benchmarks/harness/dsa.py`` counts
+    every instruction of the position search (a result with a size of the
+    2,048 selected or of the 32 x 2,048, other than the latents' gather) as
+    ``select``, the kernel's among them, so ``dsa.device_ms_per_step`` and
+    ``dsa.sparse_decode_roofline_share`` read what they read; and no gather
+    of the blocks' running counts is left, 65,536 rows of 128."""
+    import json
+    import re
+
+    from dots3_toy import ROOT
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    from harness import dsa
+
+    with open(os.path.join(
+            ROOT, "benchmarks", "configs", "dots3-note-prev.json")) as f:
+        cfg = json.load(f)
+    s = dsa.sizes(cfg)
+    rows, top_k = SPARSE["slots"], SPARSE["top_k"]
+    assert s["topk"] == top_k and s["full"] == 640
+    text = compiled_text(chip, "sparse-cell-decode")
+    entry = text[text.index("\nENTRY "):]
+    searched = {}
+    for line in entry.splitlines():
+        line = line.strip()
+        if " = " not in line or line.startswith("ROOT"):
+            continue
+        dims = dsa.result_shapes(line)
+        if any({top_k, rows * top_k} & set(d) for d in dims):
+            searched[line.split(" = ", 1)[0]] = (dsa.kind_of(line, s), dims)
+    kinds = {kind for kind, _ in searched.values()}
+    assert kinds == {"select", "gather"}, searched
+    for name, (kind, dims) in searched.items():
+        is_latents = any(d and d[-1] == s["full"] for d in dims)
+        assert kind == ("gather" if is_latents else "select"), (name, dims)
+    kernels = [n for n in searched if pa.POSITIONS_KERNEL in n]
+    assert len(kernels) == 2, searched  # the two full layers of the three
+    assert not re.search(
+        rf"s32\[({rows},{top_k}|{rows * top_k}),128\]\S* gather\(", text)
 
 
 # ------------------------------------------------ the experts' grouped product
@@ -561,5 +617,4 @@ def test_a_latent_pool_of_576_is_refused_by_mosaic(chip):
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(chip, name):
-    compiled = CASES[name](chip).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in compiled_text(chip, name)

@@ -54,11 +54,76 @@ def test_the_top_k_is_exact_and_breaks_ties_as_lax_top_k_does(k):
         want[np.asarray(where)[: min(k, finite)]] = True
         assert (mask[r] == want).all(), (k, r)
     if k <= 300:
-        where, real = (np.asarray(a) for a in pa.selected_positions(
-            jnp.asarray(mask), k))
-        for r in range(len(x)):
-            assert (np.flatnonzero(mask[r]) == where[r][real[r]]).all()
-            assert (where[r][~real[r]] == 0).all()
+        held_to_flatnonzero(mask, k)
+
+
+@functools.cache
+def masks_by_hand():
+    """name -> (mask [rows, N], k): what a top-k's mask can hold (never more
+    than ``k`` true a row), by hand and at random."""
+    rng = np.random.default_rng(5)
+
+    def drawn(n, counts):
+        mask = np.zeros((len(counts), n), bool)
+        for row, count in zip(mask, counts):
+            row[rng.choice(n, count, replace=False)] = True
+        return mask
+
+    def at(n, *rows):
+        mask = np.zeros((len(rows), n), bool)
+        for row, where in zip(mask, rows):
+            row[list(where)] = True
+        return mask
+
+    return {
+        # (i) no true position, one, exactly k, fewer than k
+        "none-one-k-fewer": (drawn(1000, [0, 1, 64, 37]), 64),
+        # (ii) all in one block of 128, all in the last block, lanes 0 and 127
+        "one-block": (at(1024, range(384, 512, 3), range(1000, 1024),
+                         [0, 127, 128, 255, 896, 1023]), 48),
+        # (iii) N not a multiple of 128, N under 128, k = 1, k = N
+        "n-300": (drawn(300, [0, 9, 20, 299]), 299),
+        "n-300-k-n": (drawn(300, [300, 0, 150]), 300),
+        "n-48": (drawn(48, [0, 1, 8, 5]), 8),
+        "n-48-k-n": (drawn(48, [48, 47, 1]), 48),
+        "k-1": (at(700, [], [0], [127], [128], [699]), 1),
+        # the kernel's blocks over several tiles of its lanes
+        "tiles": (drawn(40000, [1024, 1000, 0, 513, 512]), 1024),
+    }
+
+
+def held_to_flatnonzero(mask, k, **kw):
+    where, real = (np.asarray(a) for a in pa.selected_positions(
+        jnp.asarray(mask), k, **kw))
+    assert where.dtype == np.int32 and where.shape == (len(mask), k)
+    assert real.dtype == bool and real.shape == where.shape
+    for r, row in enumerate(mask):
+        true = np.flatnonzero(row)
+        assert real[r].sum() == len(true) and real[r][:len(true)].all(), r
+        assert (where[r][real[r]] == true).all(), r  # EQUAL, not close
+        assert (where[r][~real[r]] == 0).all(), r
+    return where, real
+
+
+@pytest.mark.parametrize("kernel", ["xla", "interpret"])
+@pytest.mark.parametrize("case", sorted(masks_by_hand()))
+def test_the_selected_positions_are_the_masks_true_positions(case, kernel):
+    mask, k = masks_by_hand()[case]
+    held_to_flatnonzero(mask, k, kernel=kernel)
+
+
+def test_the_selected_positions_at_the_cells_shape():
+    """``[32, 49664]`` with 2,048 true a row (one row short of them), through
+    the CPU's form and through the kernel interpreted: the same integers."""
+    rng = np.random.default_rng(6)
+    mask = np.zeros((32, 49664), bool)
+    for r, row in enumerate(mask):
+        true = rng.choice(16430 + 1000 * r, 2048 - (r == 7), replace=False)
+        row[true] = True
+    by_xla = held_to_flatnonzero(mask, 2048, kernel="xla")
+    by_kernel = held_to_flatnonzero(mask, 2048, kernel="interpret")
+    for a, b in zip(by_xla, by_kernel):
+        assert (a == b).all()
 
 
 def paged_rows(seed, width, lens, page=4, pages_per_seq=12, num_pages=64):

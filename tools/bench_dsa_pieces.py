@@ -7,7 +7,8 @@ layer by layer. The index kernel on three dispatches: four askers a document
 another, and one row alone. Host clock around ``block_until_ready``; the
 traced cell's readers have the device time. Writes
 ``chiprun_out/dsa_pieces.json``, or ``--out``: a copy of this file in another
-commit's tree times that commit's kernels.
+commit's tree times that commit's kernels. ``selected_positions_device_ms``
+alone is the device's time, from a trace (``bench_grouped_matmul.device_time``).
 
     chiprun --timeout 1500 -- python3 tools/bench_dsa_pieces.py [--reference]
 """
@@ -114,10 +115,20 @@ def pieces() -> dict:
     mask_of = jax.jit(lambda s: pa.top_k_mask(s, k))
     out["top_k_mask_ms"] = timed(mask_of, scores)
     mask = mask_of(scores)
-    out["selected_positions_ms"] = timed(
-        jax.jit(lambda m: pa.selected_positions(m, k)), mask)
+    positions_of = jax.jit(lambda m: pa.selected_positions(m, k))
+    out["selected_positions_ms"] = timed(positions_of, mask)
+    # This host dispatches a call in ~0.4 ms: under that only the device's
+    # own time (a trace of 20 calls, the operations' durations) says anything.
+    from bench_grouped_matmul import device_time
+
+    out["selected_positions_device_ms"] = device_time(
+        positions_of, (mask,), 20)[0] * 1e3
     out["lax_top_k_ms"] = timed(jax.jit(lambda s: jax.lax.top_k(s, k)), scores)
     where, real = pa.selected_positions(mask, k)
+    out["selected_positions_are_flatnonzero"] = bool(
+        np.asarray(real).all() and all(
+            (np.flatnonzero(row) == found).all()
+            for row, found in zip(np.asarray(mask), np.asarray(where))))
     _, by_sort = jax.lax.top_k(scores, k)
     same = jnp.all(jnp.sort(by_sort, axis=-1) == where)
     out["selection_agrees_with_lax_top_k"] = bool(same)

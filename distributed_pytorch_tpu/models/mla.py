@@ -202,6 +202,11 @@ def value_of(latent, rank: int):
     return latent[..., :rank]
 
 
+def whole_lanes(width: int) -> int:
+    """``width`` numbers as a pool's row holds them: whole lanes."""
+    return -(-width // LANES) * LANES
+
+
 def _lanes(x, width: int):
     """``x`` with its last axis padded with zeros to ``width``."""
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - x.shape[-1]),))
@@ -269,12 +274,12 @@ class LatentAttention(nn.Module):
     @property
     def pool_width(self) -> int:
         """What a token's row of the pool holds: whole lanes."""
-        return -(-self.latent_width // LANES) * LANES
+        return whole_lanes(self.latent_width)
 
     @property
     def index_pool_width(self) -> int:
         """What a token's row of the index-key pool holds: whole lanes."""
-        return -(-self.index_dim // LANES) * LANES
+        return whole_lanes(self.index_dim)
 
     @nn.compact
     def __call__(

@@ -62,6 +62,27 @@ runs per-shard under ``shard_map`` with the KV-head dim split over the
 ``model`` axis — the same placement ``serving/mesh.py:KV_POOL_SPEC`` gives
 the pools, so no collective is added beyond what the weight split implies.
 
+The latent and the index kernel (below) copy a RUN of pages as one DMA. A
+turn of their copy loops names a few table entries (16 pages of the latent
+kernel's block of 128, the index kernel's whole block of 32); where those
+entries are NEIGHBOURING pages of the pool, ``entry[i] == entry[0] + i``
+(:func:`is_run`: one rule for both kernels and for the host's count of their
+copies, :func:`latent_copies_started` / :func:`index_copies_started`), the
+pages stand side by side in HBM and ONE copy of the whole stretch takes the
+place of a copy a page: the same bytes into the same places of the same
+buffer, so the result is the same bits whatever the tables name. A resident
+document's pages, prefilled in one go off a free list that deals ascending
+numbers, are neighbours from its first page on, so what a kernel is told
+(:func:`latent_runs`, :func:`index_runs`: worked out from the tables before
+the call, a scalar-prefetch operand) is how many LEADING turns of a block, or
+blocks of a row's table, are runs, and it copies them in a loop of their own
+before the loop that copies the rest a page at a time: a test a turn, in the
+kernel's one instruction stream, cost a dispatch with no runs 5-10% (PERF.md
+section 6, PR 44), two loops cost it nothing. A WAIT counts bytes on the
+buffer's semaphore, not copies: one wait a turn serves a run's copy and a
+copy a page alike. This kernel, ``_decode_kernel``, copies a page at a time:
+its tables are a chat's, grown a page at a time.
+
 Block sizing (``pages_per_block``) comes from the ``ops/flash_autotune``
 harness' ``paged_decode`` family: measured winners on real hardware, a
 seeded table entry for CPU/interpret so CI never autotunes. A block is also
@@ -665,9 +686,123 @@ def latent_tokens_fetched(
     return int(pages) * page
 
 
+def is_run(entries):
+    """THE rule of what a RUN is, for the two kernels that copy pages by runs
+    (:func:`_latent_decode_kernel`, :func:`_index_scores_kernel`: their
+    operands :func:`latent_runs` and :func:`index_runs`) and for the host that
+    counts their copies (:func:`latent_copies_started`,
+    :func:`index_copies_started`). ``entries`` are the table entries of one
+    turn of a copy loop, first to last (arrays of one shape: a turn an
+    element). They are a run where they name NEIGHBOURING pages of the pool,
+    ``entries[i] == entries[0] + i``: pages that stand side by side in HBM,
+    which ONE copy of ``len(entries)`` pages moves. A turn of one page is no
+    run: it is one copy as it is."""
+    if len(entries) < 2:
+        return False
+    run = entries[1] == entries[0] + 1
+    for i in range(2, len(entries)):
+        run = run & (entries[i] == entries[0] + i)
+    return run
+
+
+def _turn_runs(entries, pages, turn: int):
+    """Which turns of walks are runs: ``entries [S, turns * turn]`` are each
+    row's table entries from its walk's first page on, ``pages [S]`` the
+    pages its walk has. Turn ``t`` holds the walk's pages ``[t * turn, (t + 1)
+    * turn)``; it is a run where all of them are the walk's own (a turn that
+    reaches past the walk's last page repeats that page in the kernel: no
+    run) and :func:`is_run`. ``[S, turns]`` bool, NumPy or traced."""
+    xp = np if isinstance(entries, np.ndarray) else jnp
+    slots = entries.shape[0]
+    turns = entries.reshape(slots, -1, turn)
+    whole = (xp.arange(turns.shape[1]) + 1) * turn <= pages[:, None]
+    return is_run([turns[..., i] for i in range(turn)]) & whole
+
+
+def _leading(runs):
+    """How many of ``runs [..., turns]`` hold from the first on, up to the
+    first that does not: int32, NumPy or traced."""
+    xp = np if isinstance(runs, np.ndarray) else jnp
+    return xp.cumprod(runs.astype(xp.int32), axis=-1).sum(axis=-1)
+
+
+def _tables_from(tables, first, width: int):
+    """Each row's table from index ``first[r]`` on, ``width`` entries (the
+    null page past the table's end), NumPy or traced."""
+    if isinstance(tables, np.ndarray):
+        padded = np.pad(tables, ((0, 0), (0, width)))
+        return padded[
+            np.arange(len(tables))[:, None], first[:, None] + np.arange(width)
+        ]
+    padded = jnp.pad(tables, ((0, 0), (0, width)))
+    # A slice a row, not a gather an entry.
+    return jax.vmap(
+        lambda row, at: jax.lax.dynamic_slice(row, (at,), (width,))
+    )(padded, first)
+
+
+def latent_turns(pages_per_seq: int, npb: int) -> tuple:
+    """``(pages a turn, turns)`` of the latent kernel's copy loop at blocks
+    of ``npb`` pages: a block is copied its widths' common divisor at a time,
+    and the whole blocks that a table of ``pages_per_seq`` pages meets take
+    this many turns."""
+    chunk = math.gcd(*block_widths(npb))
+    return chunk, -(-pages_per_seq // npb) * (npb // chunk)
+
+
+def latent_runs(tables, positions, leader, shared, page: int, npb: int):
+    """How many LEADING turns of each block of the latent kernel's walks go
+    as ONE copy each, for a decode dispatch grouped as
+    :func:`shared_prefix_groups` says: ``[2 S, blocks]`` int32, row ``r`` the
+    blocks of ``r``'s SHARED walk (its table's first ``shared[r]`` pages; all
+    0 but at a group's leader) and row ``S + r`` those of its OWN walk (from
+    ``shared[r]`` to the page that holds its ``pos``). A block's turns are
+    counted from its first up to the first that is no run
+    (:func:`_turn_runs`): a resident document's blocks are runs from their
+    first page on, up to the document's last pages, and the kernel copies a
+    block as two loops, its leading runs and then the rest a copy a page, with
+    no test a turn. Worked out once, from the tables alone, NumPy or traced:
+    the kernel's operand and the host's count of its copies
+    (:func:`latent_copies_started`) both come from here."""
+    xp = np if isinstance(tables, np.ndarray) else jnp
+    slots, width = tables.shape
+    chunk, turns = latent_turns(width, npb)
+    last = xp.minimum(positions // page, width - 1)
+    leads = (leader == xp.arange(slots)) & (shared > 0)
+    whole_blocks = xp.pad(tables, ((0, 0), (0, turns * chunk - width)))
+    runs = xp.concatenate([
+        _turn_runs(whole_blocks, xp.where(leads, shared, 0), chunk),
+        _turn_runs(
+            _tables_from(tables, shared, turns * chunk), last + 1 - shared,
+            chunk,
+        ),
+    ])
+    return _leading(runs.reshape(2 * slots, -1, npb // chunk))
+
+
+def latent_copies_started(tables, positions, leader, shared, page: int,
+                          npb: int):
+    """``(copies, pages in runs)``: the copy descriptors the latent kernel
+    starts for a decode dispatch's live rows (``tables [S, pages_per_seq]``,
+    grouped as :func:`shared_prefix_groups` says; NumPy) and the pages among
+    them that went as part of a run. The kernel's walks (a group's shared
+    pages once, at its leader; every row's own) in the kernel's turns (a
+    block's pages ``gcd(block_widths)`` at a time, a walk's last block at the
+    width that holds it): a turn that :func:`latent_runs` counts is one
+    copy, any other a copy a page."""
+    slots, width = tables.shape
+    chunk, _ = latent_turns(width, npb)
+    last = np.minimum(positions // page, width - 1)
+    leads = (leader == np.arange(slots)) & (shared > 0)
+    pages = np.concatenate([last + 1 - shared, shared[leads]])
+    turns = int(pages_walked(pages, npb).sum()) // chunk
+    runs = int(latent_runs(tables, positions, leader, shared, page, npb).sum())
+    return runs + (turns - runs) * chunk, runs * chunk
+
+
 def _latent_decode_kernel(
-    bt_ref, lens_ref, lead_ref, shared_ref, *refs, npb, v_width, sm_scale,
-    windowed=False,
+    bt_ref, lens_ref, lead_ref, shared_ref, runs_ref, *refs, npb, v_width,
+    sm_scale, windowed=False,
 ):
     """One slot (grid step) of the latent flash-decode kernel: the frame of
     :func:`_decode_kernel` (walk the row's own blocks and only those, the
@@ -702,6 +837,15 @@ def _latent_decode_kernel(
     so nothing unowned or stale is ever read), and only it needs a mask. The
     products take the pool's type as it is stored (bf16 on the chip: the
     MXU's own) and accumulate in float32.
+
+    A block is copied a TURN of ``chunk`` pages at a time (the widths'
+    common divisor: 16 of a block of 128). A turn whose pages are a RUN,
+    neighbouring pages of the pool, goes as ONE copy of the stretch:
+    ``runs_ref`` (:func:`latent_runs`) says how many of a block's LEADING
+    turns are, and the block is two loops, those and then the rest a copy a
+    page (a short block's clamped tail among them), with no test a turn.
+    Either way the same bytes land in the same places, and the wait, which
+    counts bytes on the buffer's semaphore and not copies, is one a turn.
 
     ``windowed`` (a sliding layer's call): a fifth scalar operand ``lo_ref``
     gives each row the first key position it sees, and every block is masked
@@ -749,6 +893,18 @@ def _latent_decode_kernel(
             pool_hbm.at[phys], buf.at[slot, n], sems.at[slot]
         )
 
+    def run_copy(first, slot, n):
+        """ONE copy of the ``chunk`` neighbouring pages of the pool from
+        ``first`` on, into the buffer's pages from ``n`` on: the bytes of
+        ``chunk`` page copies in the same places."""
+        return pltpu.make_async_copy(
+            pool_hbm.at[pl.ds(first, chunk)],
+            buf.at[slot, pl.ds(n, chunk)], sems.at[slot],
+        )
+
+    # A pool of fewer pages than a turn holds no run (nor a slice to wait by).
+    by_runs = 1 < chunk <= pool_hbm.shape[0]
+
     def turns(left):
         """Turns of the copy loop for a block of a walk with ``left`` pages
         to go: start and wait have to agree on it."""
@@ -766,6 +922,10 @@ def _latent_decode_kernel(
         live = jnp.minimum(left, npb)
         slot = stream[STARTED] % n_buf
 
+        def a_run(c, carry):
+            run_copy(bt_ref[row, p0 + c * chunk], slot, c * chunk).start()
+            return carry
+
         def turn(c, carry):
             for i in range(chunk):
                 n = c * chunk + i
@@ -773,7 +933,11 @@ def _latent_decode_kernel(
                 page_copy(phys, slot, n).start()
             return carry
 
-        jax.lax.fori_loop(0, turns(left), turn, 0)
+        # The block's leading turns that are runs go as one copy each, the
+        # rest a copy a page: two loops, no test a turn.
+        runs = runs_ref[own * slots + row, j] if by_runs else 0
+        jax.lax.fori_loop(0, runs, a_run, 0)
+        jax.lax.fori_loop(runs, turns(left), turn, 0)
         stream[STARTED] = stream[STARTED] + 1
 
         @pl.when(left > npb)
@@ -793,9 +957,14 @@ def _latent_decode_kernel(
             stream[BLOCK] = 0
 
     def wait(left, slot):
+        # A wait counts BYTES on the buffer's semaphore, whatever copies
+        # brought them: one wait a turn, for a run's copy or a copy a page.
         def turn(c, carry):
-            for i in range(chunk):
-                page_copy(0, slot, c * chunk + i).wait()
+            if by_runs:
+                run_copy(0, slot, c * chunk).wait()
+            else:
+                for i in range(chunk):
+                    page_copy(0, slot, c * chunk + i).wait()
             return carry
 
         jax.lax.fori_loop(0, turns(left), turn, 0)
@@ -955,16 +1124,17 @@ def _latent_decode_kernel(
     static_argnames=("pages_per_block", "interpret", "sm_scale", "v_width"),
 )
 def _latent_flash(
-    q3, pool, block_tables, seq_lens, leader, shared, first_key=None, *,
-    pages_per_block, interpret, sm_scale, v_width,
+    q3, pool, block_tables, seq_lens, leader, shared, runs, first_key=None,
+    *, pages_per_block, interpret, sm_scale, v_width,
 ):
     """The latent kernel's ``pallas_call`` for ``q3`` [S, H, W]: jitted and
     named for :func:`_paged_flash`'s reasons (one trace for a model's layers;
     a device trace shows ``attention._latent_decode_step`` whoever calls
     it). The pool stays in HBM and a page is copied as the ``[page, W]`` rows
-    it is stored as; the tables, the lengths and the rows' grouping ride as
-    scalar prefetch; every row's query is held in VMEM for the whole call (a
-    leader needs its members'). ``first_key [S]`` makes it the WINDOWED call
+    it is stored as; the tables, the lengths, the rows' grouping and the
+    turns that are runs (:func:`latent_runs`) ride as scalar prefetch; every
+    row's query is held in VMEM for the whole call (a leader needs its
+    members'). ``first_key [S]`` makes it the WINDOWED call
     (:func:`_latent_decode_kernel`), which the device trace shows under a name
     of its own, ``attention._window_latent_decode_step``."""
     s, h, w = q3.shape
@@ -975,6 +1145,7 @@ def _latent_flash(
     scalars = [
         block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
         leader.astype(jnp.int32), shared.astype(jnp.int32),
+        runs.astype(jnp.int32),
     ]
     if windowed:
         scalars.append(first_key.astype(jnp.int32))
@@ -1058,8 +1229,11 @@ def paged_latent_attention(
     ``W ** -0.5``; :func:`block_pages` looks the block up under the pool's
     width. ``row_groups`` is :func:`shared_prefix_groups`' ``(leader,
     shared)`` for these tables and lengths where the caller has worked it out
-    (a decode program does, once for all its layers: :func:`latent_row_groups`);
-    ``None`` works it out here. Only the kernel reads it.
+    (a decode program does, once for all its layers: the engine's
+    ``_decode_state_kw``);
+    ``None`` works it out here; a third member is :func:`latent_runs` of the
+    same dispatch, for a caller that has worked that out once too. Only the
+    kernel reads either.
 
     ``window`` keeps a query at ``t`` to the keys ``(t - window, t]``. The
     kernel is then handed each row's table FROM the window's first live page
@@ -1092,6 +1266,10 @@ def paged_latent_attention(
         row_groups = shared_prefix_groups(
             block_tables, seq_lens, pool.shape[1], npb
         )
+    if len(row_groups) < 3:
+        row_groups = (*row_groups, latent_runs(
+            block_tables, seq_lens, *row_groups, pool.shape[1], npb
+        ))
     out3 = _latent_flash(
         q.reshape(s, h, w), pool, block_tables, seq_lens, *row_groups,
         *first_key, pages_per_block=npb, interpret=(mode == "interpret"),
@@ -1130,16 +1308,19 @@ def window_tables(block_tables, seq_lens, page: int, window: int):
     + 1, 0)`` on, ``window_pages`` entries (past the table's end: its last
     entry again, which lies past ``pos`` there and is never copied), and its
     position and that first key counted from that page's first token. A row
-    out of the dispatch keeps a table that starts at the null page."""
-    lens = seq_lens.astype(jnp.int32)
-    first = jnp.maximum(lens - (window - 1), 0)
+    out of the dispatch keeps a table that starts at the null page. NumPy or
+    traced, as :func:`shared_prefix_groups` is (the host counts the windowed
+    call's copies from the same tables)."""
+    xp = np if isinstance(block_tables, np.ndarray) else jnp
+    lens = seq_lens.astype(xp.int32)
+    first = xp.maximum(lens - (window - 1), 0)
     page0 = first // page
-    index = jnp.minimum(
+    index = xp.minimum(
         page0[:, None]
-        + jnp.arange(window_pages(window, page), dtype=jnp.int32),
+        + xp.arange(window_pages(window, page), dtype=xp.int32),
         block_tables.shape[1] - 1,
     )
-    tables = jnp.take_along_axis(block_tables.astype(jnp.int32), index, axis=1)
+    tables = xp.take_along_axis(block_tables.astype(xp.int32), index, axis=1)
     return tables, lens - page0 * page, first - page0 * page
 
 
@@ -1203,9 +1384,44 @@ def index_rows_grouped(shared, npb: int) -> int:
     return int((shared >= npb).sum())
 
 
+def index_runs(tables, positions, page: int, npb: int):
+    """How many LEADING blocks of each row's table the index kernel copies
+    as ONE copy each: ``[S]`` int32, the blocks of ``npb`` pages (the
+    kernel's turn) from the table's first up to the first that is no run or
+    does not lie wholly at or below the page that holds the row's ``pos``
+    (:func:`_turn_runs`). A resident document's blocks are runs from its
+    first page on, so the kernel walks a row's blocks as two loops, those
+    below this count and then the rest a copy a page, with no test a block.
+    NumPy or traced: the kernel's operand and the host's count
+    (:func:`index_copies_started`), as :func:`latent_runs` is."""
+    xp = np if isinstance(tables, np.ndarray) else jnp
+    width = tables.shape[1]
+    last = xp.minimum(positions // page, width - 1)
+    padded = xp.pad(tables, ((0, 0), (0, -width % npb)))
+    return _leading(_turn_runs(padded, last + 1, npb))
+
+
+def index_copies_started(tables, positions, leader, shared, page: int,
+                         npb: int):
+    """``(copies, pages in runs)``: the copy descriptors the index kernel
+    starts for a decode dispatch's live rows and the pages among them that
+    went as part of a run, as :func:`latent_copies_started` counts the latent
+    kernel's: the blocks :func:`index_tokens_fetched` counts (NumPy), one
+    copy a block below the row's :func:`index_runs`, else a copy a page."""
+    end = np.minimum(
+        positions // (npb * page) + 1, -(-tables.shape[1] // npb)
+    )
+    first = np.minimum(
+        np.where(leader == np.arange(len(tables)), 0, shared // npb), end - 1
+    )
+    known = np.clip(index_runs(tables, positions, page, npb), first, end)
+    runs = int((known - first).sum())
+    return runs + (int((end - first).sum()) - runs) * npb, runs * npb
+
+
 def _index_scores_kernel(
-    bt_ref, lens_ref, lead_ref, shared_ref, q_ref, w_ref, pool_hbm, o_ref,
-    buf, sems, first_slot, members, qg_scr, wg_scr, *, npb,
+    bt_ref, lens_ref, lead_ref, shared_ref, runs_ref, q_ref, w_ref, pool_hbm,
+    o_ref, buf, sems, first_slot, members, qg_scr, wg_scr, *, npb,
 ):
     """One slot (grid step): the frame of :func:`_decode_kernel` over the
     index-key pool, for rows grouped as :func:`shared_prefix_groups` says
@@ -1214,7 +1430,12 @@ def _index_scores_kernel(
     A step is ONE walk over blocks of ``npb`` pages of the row's table, up to
     the block that holds ``pos``: a block's pages are copied into one of two
     buffers (the next block's copies, or the next live row's first block's,
-    started before this one is scored), its ``[keys, D]`` meet index queries
+    started before this one is scored; ONE copy where the block is among
+    the row's LEADING blocks whose pages are runs, neighbouring pages of the
+    pool, of which ``runs_ref`` holds the count (:func:`index_runs`), else a
+    copy a page, and one wait either way: a wait counts bytes, not copies;
+    a walk is two loops, its blocks below that count and the rest, with no
+    test a block), its ``[keys, D]`` meet index queries
     on the MXU, and ReLU, the head weights and the sum over a row's heads
     leave ONE float32 a key and row, written to that row's ``[blocks, block
     keys]`` of the result, which stays in VMEM for the whole call. Keys past
@@ -1256,8 +1477,22 @@ def _index_scores_kernel(
             pool_hbm.at[phys], buf.at[slot, n], sems.at[slot]
         )
 
-    def start(row, blk, slot):
-        """Start the copies of ``row``'s block ``blk``. A logical page past
+    def run_copy(first, slot):
+        """ONE copy of the block's ``npb`` neighbouring pages of the pool
+        from ``first`` on: the bytes of its page copies in the same places."""
+        return pltpu.make_async_copy(
+            pool_hbm.at[pl.ds(first, npb)], buf.at[slot], sems.at[slot]
+        )
+
+    # A pool of fewer pages than a block holds no run (nor a slice to wait by).
+    by_runs = 1 < npb <= pool_hbm.shape[0]
+
+    def a_run(row, blk, slot):
+        """Start ``row``'s block ``blk``, a run, as ONE copy."""
+        run_copy(bt_ref[row, blk * npb], slot).start()
+
+    def a_copy_a_page(row, blk, slot):
+        """Start ``row``'s block ``blk`` a copy a page. A logical page past
         the row's last live one clamps to that one, as in
         :func:`_decode_kernel`."""
         last = jnp.minimum(lens_ref[row] // page, pages_per_seq - 1)
@@ -1265,10 +1500,29 @@ def _index_scores_kernel(
             phys = bt_ref[row, jnp.minimum(blk * npb + n, last)]
             page_copy(phys, slot, n).start()
 
+    def runs_of(row):
+        """The blocks of ``row``'s table, from the first, that are runs."""
+        return runs_ref[row] if by_runs else 0
+
+    def start(row, blk, slot):
+        """Start the copies of ``row``'s block ``blk``, whichever it is."""
+        if by_runs:
+            jax.lax.cond(
+                blk < runs_of(row), lambda: a_run(row, blk, slot),
+                lambda: a_copy_a_page(row, blk, slot),
+            )
+        else:
+            a_copy_a_page(row, blk, slot)
+
     def keys(slot):
-        """Wait for the block in buffer ``slot``: its ``[keys, D]``."""
-        for n in range(npb):  # a wait takes a copy's size, not its source
-            page_copy(0, slot, n).wait()
+        """Wait for the block in buffer ``slot``: its ``[keys, D]``. A wait
+        counts BYTES on the buffer's semaphore, whatever copies brought
+        them: one wait for a run's copy or a copy a page."""
+        if by_runs:
+            run_copy(0, slot).wait()
+        else:
+            for n in range(npb):
+                page_copy(0, slot, n).wait()
         return buf[slot].reshape(bkv, d)
 
     def products(q, k):
@@ -1306,6 +1560,22 @@ def _index_scores_kernel(
         def _first():
             start(b, first, 0)
 
+        def two_loops(lo, hi, last_start, block):
+            """``block(j)`` for ``j`` in ``[lo, hi)``, each starting the
+            copies of block ``j + 1`` (none past ``last_start``): first the
+            ``j`` whose next block is among the row's leading runs, ONE copy,
+            then the rest, a copy a page: no test a block."""
+            split = jnp.clip(
+                jnp.minimum(runs_of(b) - 1, last_start), lo, hi
+            )
+            if by_runs:
+                jax.lax.fori_loop(
+                    lo, split, functools.partial(block, next_is_run=True), 0
+                )
+            jax.lax.fori_loop(
+                split, hi, functools.partial(block, next_is_run=False), 0
+            )
+
         @pl.when(leads)
         def _shared_walk():
             # The group's rows in slot order: this one, then the rows after
@@ -1336,10 +1606,12 @@ def _index_scores_kernel(
                 def _stacked(rows=rows):
                     q = qg_scr[0 : rows * h]
 
-                    def block(j, carry):
+                    def block(j, carry, next_is_run):
                         slot = (slot0 + j) % 2
                         # The leader's own blocks follow the shared ones.
-                        start(b, j + 1, 1 - slot)
+                        (a_run if next_is_run else a_copy_a_page)(
+                            b, j + 1, 1 - slot
+                        )
                         s_blk = products(q, keys(slot))  # [rows x H, keys]
                         for m in range(rows):
                             part = slice(m * h, (m + 1) * h)
@@ -1348,31 +1620,35 @@ def _index_scores_kernel(
                             )
                         return carry
 
-                    jax.lax.fori_loop(0, stacked, block, 0)
+                    two_loops(0, stacked, stacked, block)
 
         q = q_ref[b].astype(buf.dtype)
         w = w_ref[b]
         next_row = jnp.minimum(b + 1, slots - 1)
         next_live = jnp.logical_and(b + 1 < slots, is_live(next_row))
 
-        def block(j, carry):
+        def block(j, carry, next_is_run):
             slot = (slot0 + j - first) % 2
+            if next_is_run:
+                # Below the row's runs a next block follows, and is one.
+                a_run(b, j + 1, 1 - slot)
+            else:
 
-            @pl.when(j + 1 < end)
-            def _next_block():
-                start(b, j + 1, 1 - slot)
+                @pl.when(j + 1 < end)
+                def _next_block():
+                    a_copy_a_page(b, j + 1, 1 - slot)
 
-            @pl.when(jnp.logical_and(j + 1 == end, next_live))
-            def _next_row():
-                start(next_row, walk_of(next_row)[0], 1 - slot)
-                first_slot[0] = 1 - slot
+                @pl.when(jnp.logical_and(j + 1 == end, next_live))
+                def _next_row():
+                    start(next_row, walk_of(next_row)[0], 1 - slot)
+                    first_slot[0] = 1 - slot
 
             total = weighted(products(q, keys(slot)), w)
             kpos = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
             o_ref[b, pl.ds(j, 1), :] = jnp.where(kpos <= pos, total, -jnp.inf)
             return carry
 
-        jax.lax.fori_loop(jnp.maximum(first, stacked), end, block, 0)
+        two_loops(jnp.maximum(first, stacked), end, end - 1, block)
 
 
 @functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
@@ -1380,7 +1656,9 @@ def _index_flash(q, w, pool, block_tables, seq_lens, leader, shared, *,
                  pages_per_block, interpret):
     """The index kernel's ``pallas_call`` for ``q [S, H, D]`` and ``w [S,
     H]``: jitted and named for :func:`_paged_flash`'s reasons. The tables,
-    the lengths and the rows' grouping ride as scalar prefetch; every row's
+    the lengths, the rows' grouping and the blocks that are runs
+    (:func:`index_runs`, worked out here: a reshape and a few comparisons of
+    the table) ride as scalar prefetch; every row's
     queries and weights, and the whole ``[S, blocks, block keys]`` result (a
     leader writes its members' rows), stay in VMEM for the call."""
     s, h, d = q.shape
@@ -1409,7 +1687,7 @@ def _index_flash(q, w, pool, block_tables, seq_lens, leader, shared, *,
     out = pl.pallas_call(
         functools.partial(_index_scores_kernel, npb=npb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(s,),
             in_specs=[
                 whole((s, h, d)), whole((s, h, 1)),
@@ -1434,7 +1712,8 @@ def _index_flash(q, w, pool, block_tables, seq_lens, leader, shared, *,
         name=INDEX_KERNEL,
     )(
         block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-        leader.astype(jnp.int32), shared.astype(jnp.int32), q,
+        leader.astype(jnp.int32), shared.astype(jnp.int32),
+        index_runs(block_tables, seq_lens, page, npb), q,
         w.astype(jnp.float32)[..., None], pool,
     )
     return out.reshape(s, nblk * npb * page)[:, : block_tables.shape[1] * page]
@@ -1475,7 +1754,7 @@ def paged_index_scores(
             block_tables, seq_lens, pool.shape[1], npb
         )
     return _index_flash(
-        q, w, pool, block_tables, seq_lens, *row_groups,
+        q, w, pool, block_tables, seq_lens, *row_groups[:2],
         pages_per_block=npb, interpret=(mode == "interpret"),
     )
 

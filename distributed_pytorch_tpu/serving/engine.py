@@ -233,6 +233,22 @@ otherwise) and, on the ``step`` slice, ``decode_rows_grouped`` beside a
 shared pages once, so fetched / visible falls below 1 where rows share.
 Nothing switches it on or off: a dispatch whose tables share nothing groups
 nobody.
+
+Both kernels copy a RUN of neighbouring pages of the pool as one DMA (a turn
+of their copy loops whose table entries are ``first, first + 1, ...``:
+``ops/paged_attention.py`` ``is_run``; the leading such turns of a block, or
+blocks of a table); a document prefilled in one go holds such pages. The
+decode program works out which (``latent_runs``, beside the grouping, once
+for its layers). Beside ``decode_rows_grouped``, ``stats()`` and the ``step`` slice
+of a model with latent layers carry ``decode_page_copies``, the copy
+descriptors that ONE call of each such kernel starts for the dispatch (the
+latent kernel's; in a model with sparse and window layers the index kernel's
+and the windowed latent call's), and ``decode_pages_in_runs``, the pages among
+them that went as part of a run: by the host from its staged tables with the
+kernels' own rule (``latent_copies_started`` / ``index_copies_started``), as
+the tokens fetched are. A descriptor moves a run's pages or one page, so the
+pages copied are ``decode_page_copies`` less the runs plus
+``decode_pages_in_runs``; 0 on the gather path.
 """
 
 from __future__ import annotations
@@ -514,7 +530,8 @@ class InferenceEngine:
         # pages.
         self.sparse_layers = kinds.count("latent_sparse")
         self.window_layers = kinds.count("latent_window")
-        self._grouping_layers = kinds.count("latent") + self.sparse_layers
+        self._plain_latent_layers = kinds.count("latent")
+        self._grouping_layers = self._plain_latent_layers + self.sparse_layers
         self._index_top_k = (
             model.latent_sizes("latent_sparse")["index_top_k"]
             if self.sparse_layers else 0
@@ -643,6 +660,12 @@ class InferenceEngine:
         # Rows a decode kernel served in a group of two or more (their
         # tables begin with the same pages, read once for the group).
         self.decode_rows_grouped = 0
+        # Copy descriptors the latent and index kernels start, a layer's call
+        # of each, and the pages among them that went as part of a run of
+        # neighbouring pages (module docstring); the windowed call's block.
+        self.decode_page_copies = 0
+        self.decode_pages_in_runs = 0
+        self._window_block_pages = 0
         # Size the paged pool from abstract shapes only (eval_shape traces
         # init without running it); token length 1 — pool shapes depend only
         # on (num_pages, page_size), never on the init input.
@@ -692,6 +715,19 @@ class InferenceEngine:
                     else model.d_model // model.n_heads,
                     model.dtype,
                 )
+                if self.window_layers:
+                    # As the windowed call looks its block up: under the
+                    # width of the window layers' own pool (whole lanes).
+                    from distributed_pytorch_tpu.models.mla import whole_lanes
+
+                    sizes = model.latent_sizes("latent_window")
+                    self._window_block_pages = pa.block_pages(
+                        pa.window_pages(self._window, page_size), page_size,
+                        whole_lanes(
+                            sizes["kv_lora_rank"] + sizes["qk_rope_head_dim"]
+                        ),
+                        model.dtype,
+                    )
         # Bytes of recurrent state one slot owns, over every layer.
         self.state_bytes_per_slot = sum(
             leaf.nbytes // max_slots
@@ -1355,7 +1391,19 @@ class InferenceEngine:
             rows = jnp.arange(self.max_slots, dtype=jnp.int32)
             kw["state_slots"] = jnp.where(tables[:, 0] != NULL_PAGE, rows, -1)
         if self._grouping_layers and self._kv_block_tokens:
-            kw["row_groups"] = self._row_groups(tables, lens)
+            groups = self._row_groups(tables, lens)
+            if self._plain_latent_layers:
+                # Plain latent layers: which turns of their kernel's copy
+                # loop are runs of neighbouring pages, once for all of them.
+                from distributed_pytorch_tpu.ops.paged_attention import (
+                    latent_runs,
+                )
+
+                groups += (latent_runs(
+                    tables, lens, *groups, self.page_size,
+                    self._kv_block_tokens // self.page_size,
+                ),)
+            kw["row_groups"] = groups
         return kw
 
     def _row_groups(self, tables, lens):
@@ -1387,6 +1435,37 @@ class InferenceEngine:
                 shared, index_block_pages(self.pages_per_seq)
             )
         return int((shared > 0).sum())
+
+    def _page_copies(self, tables, positions, groups) -> Tuple[int, int]:
+        """``(copies, pages in runs)`` of a decode dispatch's live rows: the
+        copy descriptors that ONE call of each kernel that copies pages by
+        runs starts (the latent kernel's, or in a model with sparse and
+        window layers the index kernel's and the windowed latent call's), and
+        the pages among them that went as part of a run of neighbouring
+        pages, by the kernels' own rule (``ops/paged_attention.py``
+        ``is_run``) on the host's staged tables."""
+        from distributed_pytorch_tpu.ops import paged_attention as pa
+
+        page = self.page_size
+        counts = []
+        if self.sparse_layers:
+            counts.append(pa.index_copies_started(
+                tables, positions, *groups, page,
+                pa.index_block_pages(self.pages_per_seq),
+            ))
+        if self._plain_latent_layers:
+            counts.append(pa.latent_copies_started(
+                tables, positions, *groups, page,
+                self._kv_block_tokens // page,
+            ))
+        if self.window_layers:
+            windows = pa.window_tables(tables, positions, page, self._window)
+            rows = np.arange(len(tables), dtype=np.int32)
+            counts.append(pa.latent_copies_started(
+                *windows[:2], rows, np.zeros_like(rows), page,
+                self._window_block_pages,
+            ))
+        return tuple(map(sum, zip(*counts))) if counts else (0, 0)
 
     def _index_tokens_fetched(self, positions, groups) -> int:
         """Index keys a sparse layer's scoring copies for decode rows at
@@ -2124,11 +2203,19 @@ class InferenceEngine:
                 self._stage_tables[rows], self._stage_lens[rows]
             )
             self.decode_rows_grouped += self._rows_grouped(groups[1])
+        copies = (0, 0)
+        if self.latent_layers and self._kv_block_tokens:
+            copies = self._page_copies(
+                self._stage_tables[rows], self._stage_lens[rows], groups
+            )
+            self.decode_page_copies += copies[0]
+            self.decode_pages_in_runs += copies[1]
         if self.sparse_layers or self.window_layers:
             self._count_narrowed_reads(self._stage_lens[rows], groups)
         if self.tracer.enabled:
             self._decode_dispatches.append(
-                (self._stage_lens[rows], self._stage_tables[rows], groups)
+                (self._stage_lens[rows], self._stage_tables[rows], groups,
+                 copies)
             )
         staged = (
             self._stage_tokens.nbytes
@@ -2253,7 +2340,10 @@ class InferenceEngine:
             fetched = visible = distinct = grouped = 0
             selected = in_window = window_read = index_fetched = 0
             narrowed = self.sparse_layers + self.window_layers
-            for pos, tables, groups in self._decode_dispatches:
+            page_copies = pages_in_runs = 0
+            for pos, tables, groups, copies in self._decode_dispatches:
+                page_copies += copies[0]
+                pages_in_runs += copies[1]
                 visible += int(pos.sum()) + len(pos)
                 distinct += self._distinct_kv_tokens(pos, tables)
                 if narrowed:
@@ -2289,6 +2379,8 @@ class InferenceEngine:
             extra["decode_kv_tokens_distinct"] = distinct
             if self.latent_layers:
                 extra["decode_rows_grouped"] = grouped
+                extra["decode_page_copies"] = page_copies
+                extra["decode_pages_in_runs"] = pages_in_runs
             if self.sparse_layers:
                 extra["decode_index_tokens_scored"] = visible
                 extra["decode_index_tokens_fetched"] = index_fetched
@@ -3050,6 +3142,9 @@ class InferenceEngine:
         out["prefill_tokens"] = self.prefill_tokens
         out["prefill_width"] = self.prefill_width
         out["decode_rows_grouped"] = self.decode_rows_grouped
+        if self.latent_layers:
+            out["decode_page_copies"] = self.decode_page_copies
+            out["decode_pages_in_runs"] = self.decode_pages_in_runs
         if self.delta_layers:
             out["state_slots_updated"] = self.state_slots_updated
             out["state_bytes_moved"] = self.state_bytes_moved

@@ -274,3 +274,54 @@ def test_distinct_tokens_of_a_handmade_dispatch(program):
     # pages 5, 6 whole (8), page 7 at the most any row sees (4), page 9 (1)
     assert engine._distinct_kv_tokens(positions, tables) == 8 + 4 + 1
     assert int(positions.sum()) + 3 == 11 + 9 + 4
+
+
+def test_a_documents_neighbouring_pages_are_copied_in_runs(
+        program, monkeypatch):
+    """A document prefilled in ONE go holds neighbouring pages of the pool
+    (the free list deals ascending numbers), and the latent kernel copies a
+    turn of neighbours as one copy: at a block of 16 pages (turns of 2) the
+    engine's ``decode_page_copies`` / ``decode_pages_in_runs``, in
+    ``stats()`` and on the ``step`` slice, are what the kernel's copy loop
+    walked a page at a time starts on the dispatch's staged tables, and the
+    tokens are the gather path's."""
+    from page_copy_loops import latent_copies_by_loop
+
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(
+        pa, "block_pages", lambda pages_per_seq, *a, **kw: min(16, pages_per_seq))
+    document = tokens(24, seed=70)
+    askers = [document + tokens(3, seed=71), document + tokens(4, seed=72)]
+    tracer = Tracer()
+    engine = engine_for(
+        program, tracer=tracer, paged_kernel="interpret",
+        max_prefill_chunk=32, token_budget=35)
+    assert engine._kv_block_tokens == 16 * 4
+    dispatches, count = [], engine._page_copies
+
+    def counted(tables, positions, groups):
+        dispatches.append((tables.copy(), positions.copy(), groups))
+        return count(tables, positions, groups)
+
+    engine._page_copies = counted
+    serve(engine, [document + tokens(2, seed=69)], new_tokens=1)
+    served = serve(engine, askers, new_tokens=6)
+    stats = engine.stats()
+    steps = [a for a in step_args(tracer) if "decode_page_copies" in a]
+    assert len(steps) == len(dispatches) > 0
+    for a, (tables, positions, groups) in zip(steps, dispatches):
+        assert (a["decode_page_copies"], a["decode_pages_in_runs"]) == (
+            latent_copies_by_loop(tables, positions, *groups, 4, 16))
+        # The document's six pages stand side by side: three runs a row.
+        assert (np.diff(tables[:, :6], axis=1) == 1).all()
+        assert a["decode_pages_in_runs"] >= 6 * len(tables)
+    assert stats["decode_page_copies"] == sum(
+        a["decode_page_copies"] for a in steps)
+    assert stats["decode_pages_in_runs"] == sum(
+        a["decode_pages_in_runs"] for a in steps) > 0
+    gather = engine_for(program, max_prefill_chunk=32, token_budget=35)
+    serve(gather, [document + tokens(2, seed=69)], new_tokens=1)
+    assert serve(gather, askers, new_tokens=6) == served
+    # The gather path starts no page copy.
+    assert gather.stats()["decode_page_copies"] == 0

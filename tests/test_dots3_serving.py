@@ -233,3 +233,58 @@ def test_the_selected_positions_come_back_to_who_asks(held_program):
 def test_what_knows_one_kind_of_page_is_refused(held_program, given, what):
     with pytest.raises(ValueError, match=f"latent layers.*{what}"):
         engine_for(held_program, **given)
+
+
+def test_a_documents_index_keys_are_copied_in_runs(held_program, monkeypatch):
+    """A document prefilled in ONE go holds neighbouring pages, in every
+    pool (one table a sequence): the index kernel copies a block of
+    neighbours (2 pages here) as one copy, the windowed latent call its
+    window's 3 pages one by one (a block of 2: turns of one page), and the
+    engine's ``decode_page_copies`` / ``decode_pages_in_runs``, in
+    ``stats()`` and on the ``step`` slice, are a call of each counted by the
+    kernels' copy loops walked a page at a time on the staged tables."""
+    from page_copy_loops import index_copies_by_loop, latent_copies_by_loop
+
+    monkeypatch.setattr(pa, "INDEX_BLOCK_PAGES", 2)
+    document = tokens(24, seed=80)
+    askers = [document + tokens(3, seed=81), document + tokens(4, seed=82)]
+    tracer = Tracer()
+    engine = engine_for(
+        held_program, tracer=tracer, paged_kernel="interpret",
+        max_prefill_chunk=32, token_budget=35)
+    assert engine._window_block_pages == 2
+    dispatches, count = [], engine._page_copies
+
+    def counted(tables, positions, groups):
+        dispatches.append((tables.copy(), positions.copy(), groups))
+        return count(tables, positions, groups)
+
+    engine._page_copies = counted
+    serve(engine, [document + tokens(2, seed=79)], new_tokens=1)
+    served = serve(engine, askers, new_tokens=6)
+    stats = engine.stats()
+    steps = [e["args"] for e in tracer.events
+             if e["name"] == "step" and e.get("ph") == "X"
+             and "decode_page_copies" in e["args"]]
+    assert len(steps) == len(dispatches) > 0
+    for a, (tables, positions, groups) in zip(steps, dispatches):
+        windows = pa.window_tables(tables, positions, 4, 9)
+        rows = np.arange(len(tables), dtype=np.int32)
+        index = index_copies_by_loop(tables, positions, *groups, 4, 2)
+        window = latent_copies_by_loop(
+            *windows[:2], rows, np.zeros_like(rows), 4, 2)
+        assert window[1] == 0 and window[0] == 3 * len(tables)
+        assert (a["decode_page_copies"], a["decode_pages_in_runs"]) == (
+            index[0] + window[0], index[1])
+        # The document's three blocks of two neighbours, at least, in runs
+        # (once where the askers are served as a group).
+        assert (np.diff(tables[:, :6], axis=1) == 1).all()
+        assert a["decode_pages_in_runs"] >= 6
+    assert stats["decode_page_copies"] == sum(
+        a["decode_page_copies"] for a in steps)
+    assert stats["decode_pages_in_runs"] == sum(
+        a["decode_pages_in_runs"] for a in steps) > 0
+    gather = engine_for(held_program, max_prefill_chunk=32, token_budget=35)
+    serve(gather, [document + tokens(2, seed=79)], new_tokens=1)
+    assert serve(gather, askers, new_tokens=6) == served
+    assert gather.stats()["decode_page_copies"] == 0

@@ -1358,3 +1358,244 @@ def test_index_keys_the_kernel_copies(name):
     ) == INDEX_COPIES[name] * npb * page
     assert index_rows_grouped(groups[1], npb) == sum(
         s >= npb for s in shared)
+
+
+# ------------------------------------------------ neighbouring pages as ONE copy
+#
+# The latent and index kernels copy a turn of their copy loop whose pages are
+# neighbours in the pool as ONE copy (``is_run``). The same bytes land in the
+# same places: whatever physical pages a table names, the result is the same
+# bits, and the host counts the copies by the kernels' own rule.
+
+RUN_PAGE = 4
+#: name: a row is ``None`` or (the document's pages it shares, pages of its
+#: own, tokens into its last page). Rows 0 and 1 ask of one document.
+RUN_ROWS = {
+    "base": [(36, 3, 1), (36, 5, 2), None, (0, 21, 3)],
+    # Rows 0 and 3 stand in the LAST page of a turn of every kernel.
+    "ends": [(36, 4, 1), (36, 5, 2), None, (0, 24, 1)],
+}
+
+
+def _no_neighbours(phys):
+    """Physical pages dealt so that no two neighbours stay neighbours, among
+    pages that ``run_problem`` deals to nobody."""
+    return 300 + (np.asarray(phys) * 7) % 199
+
+
+def _runs(doc, own):
+    return doc, own
+
+
+def _permuted(doc, own):
+    return _no_neighbours(doc), {r: _no_neighbours(p) for r, p in own.items()}
+
+
+def _broken(doc, own):
+    """The document jumps at index 20, an edge of every kernel's turns (it
+    costs nothing), and two pages change places in the MIDDLE of turns: the
+    document's at index 5 and row 3's at index 9."""
+    doc, own = doc.copy(), {r: p.copy() for r, p in own.items()}
+    doc[20:] += 40
+    doc[5], own[3][9] = own[3][9], doc[5]
+    return doc, own
+
+
+def _scattered_tails(doc, own):
+    return doc, {
+        r: _no_neighbours(p) if r in (0, 1) else p for r, p in own.items()
+    }
+
+
+#: name: (rows, layout) and, by hand, ``(copies, pages in runs)`` of the
+#: latent kernel (16 pages a block: turns of 2), of its windowed call (a
+#: window of 61 tokens: 16 pages from the window's first) and of the index
+#: kernel (blocks of 4 pages, a block a turn). Worked out in
+#: ``test_runs_go_as_one_copy``'s docstring.
+RUN_TABLES = {
+    "all-runs": ("base", _runs, (41, 62), (29, 38), (27, 60)),
+    "no-two-neighbours": ("base", _permuted, (72, 0), (48, 0), (72, 0)),
+    "broken-in-the-middle-of-a-turn": (
+        "base", _broken, (51, 42), (35, 26), (63, 12)),
+    "a-run-ends-at-the-last-live-page": (
+        "ends", _runs, (38, 68), (27, 42), (21, 68)),
+    "a-shared-walk-then-scattered-tails": (
+        "base", _scattered_tails, (44, 56), (29, 38), (30, 56)),
+}
+
+
+def run_problem(rows, layout, *, h=4, w=20, pages_per_seq=64):
+    """``(q, pool, tables, lens)``: the rows' LOGICAL pages (a document's, a
+    row's own) always hold the same numbers; ``layout`` says under which
+    physical pages. In ``_runs`` the document's pages are neighbours, and so
+    are each row's own."""
+    rng = np.random.default_rng(0)
+    document = 10 + np.arange(36)
+    own = {
+        r: 100 + 50 * r + np.arange(row[1])
+        for r, row in enumerate(rows) if row
+    }
+    content = {"doc": rng.standard_normal((36, RUN_PAGE, w))}
+    for r, pages in own.items():
+        content[r] = rng.standard_normal((len(pages), RUN_PAGE, w))
+    document, own = layout(document, own)
+    pool = np.zeros((512, RUN_PAGE, w))
+    pool[document] = content["doc"]
+    tables = np.zeros((len(rows), pages_per_seq), np.int32)
+    lens = np.zeros((len(rows),), np.int32)
+    for r, row in enumerate(rows):
+        if row is None:
+            continue
+        shared, mine, into = row
+        pool[own[r]] = content[r]
+        tables[r, :shared] = document[:shared]
+        tables[r, shared:shared + mine] = own[r]
+        lens[r] = (shared + mine - 1) * RUN_PAGE + into
+    q = rng.standard_normal((len(rows), 1, h, w))
+    return (jnp.asarray(q, jnp.float32), jnp.asarray(pool, jnp.float32),
+            jnp.asarray(tables), jnp.asarray(lens))
+
+
+def _run_kernel(kind, q, pool, bt, lens, **kw):
+    """``(result, the gather path's (the index kernel: its head weights),
+    (copies, pages in runs) by the host)`` of one of the kernels that copy by
+    runs on a dispatch; the host's count is held to the loop's."""
+    from page_copy_loops import index_copies_by_loop, latent_copies_by_loop
+
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    live = np.asarray(bt)[:, 0] != 0
+    tables, positions = np.asarray(bt)[live], np.asarray(lens)[live]
+    alone = np.arange(len(tables), dtype=np.int32), np.zeros(
+        len(tables), np.int32)
+    if kind == "index":
+        w = jnp.asarray(np.random.default_rng(1).standard_normal(
+            q.shape[::2]), jnp.float32)
+        out = pa.paged_index_scores(
+            q[:, 0], w, pool, bt, lens, kernel="interpret", **kw)
+        groups = pa.shared_prefix_groups(tables, positions, RUN_PAGE, 4)
+        copies = pa.index_copies_started(
+            tables, positions, *groups, RUN_PAGE, 4)
+        assert copies == index_copies_by_loop(
+            tables, positions, *groups, RUN_PAGE, 4)
+        return out, w, copies
+    window = {"window": 61} if kind == "latent-windowed" else {}
+    out = pa.paged_latent_attention(
+        q, pool, bt, lens, v_width=16, kernel="interpret", sm_scale=0.3,
+        pages_per_block=16, **window, **kw)
+    ref = paged_attention_reference(
+        q, pool, None, bt, lens, v_width=16, sm_scale=0.3, **window)
+    if window:
+        tables, positions, _ = pa.window_tables(tables, positions, RUN_PAGE, 61)
+        groups = alone
+    else:
+        groups = pa.shared_prefix_groups(tables, positions, RUN_PAGE, 16)
+    copies = pa.latent_copies_started(tables, positions, *groups, RUN_PAGE, 16)
+    assert copies == latent_copies_by_loop(
+        tables, positions, *groups, RUN_PAGE, 16)
+    return out, ref, copies
+
+
+@pytest.mark.parametrize("name", sorted(RUN_TABLES))
+@pytest.mark.parametrize("kind", ["latent", "latent-windowed", "index"])
+def test_runs_go_as_one_copy(kind, name, monkeypatch):
+    """Every table gives, bit for bit, what the SAME logical pages under
+    physical pages of which no two are neighbours give (there every copy is a
+    page's), and for the latent kernel what PR 35's page-by-page kernel gives
+    rows served alone; and the host counts the copies the kernel starts.
+
+    By hand, on ``base``: rows 0 and 1 hold a document's 36 pages and 3 and
+    5 of their own, row 3 holds 21. *Latent* (blocks of 16 pages in turns of
+    2; a walk's last block at the width that holds it, clamped to the walk's
+    last page; a block's LEADING turns that are runs go as one copy each, the
+    rest a copy a page): the shared walk 16 + 16 + 4 pages = 18 turns; row
+    0's own 3 pages at a width of 4 = turns (36, 37), (38, 38); row 1's 5 at
+    8 = (36, 37), (38, 39), (40, 40), (40, 40); row 3's 16 + 5 at 8 = 8 + 4
+    turns of which the last two repeat page 20: 36 turns. All runs: 18 + (1 +
+    2) + (2 + 2 + 2) + (10 + 2 + 2) = 41 copies, 31 runs. Broken: the
+    document's turn (4, 5) is none, so its first block is 2 runs and 6 turns
+    of 2 copies, and row 3's turn (8, 9) leaves its first block 4 runs and 4
+    turns: (2 + 12 + 8 + 2) + 3 + 6 + (4 + 8 + 6) = 51, 21 runs; the jump at
+    index 20 is a turn's edge and costs nothing. Tails scattered: rows 0 and
+    1 a copy a page: 18 + 4 + 8 + 14. *Windowed* (every row alone, ONE block
+    of 16 pages from the page that holds ``pos - 60``): row 0 pages 23-38,
+    row 1 25-40, row 3 5-20: 8 turns each; the turn (35, 36) crosses from the
+    document into a row's own pages and ends the block's leading runs: 6 +
+    2 x 2, 5 + 3 x 2 and 8 copies, scattered tails or not. Broken: row 3's
+    turn (9, 10) is its third: 2 + 6 x 2. *Index* (a block of 4 pages a turn;
+    the LEADING blocks of a row's table that are runs go as one copy each):
+    row 0 leads and walks blocks 0-9 (the last 36, 37, 38, 38), row 1 blocks
+    9 and 10 (40 four times), row 3 blocks 0-5 (the last 20 four times): 18
+    blocks, all runs 9 + 4, 1 + 4, 5 + 4 copies. Broken: the document's
+    block 1 and row 3's block 2 are none, so rows 0 and 1 have ONE leading
+    run and row 3 two: (1 + 36) + 8 + (2 + 16). Tails scattered: row 1's
+    block 9 is none: 13 + 8 + 9. On ``ends`` rows 0 and 3 hold 40 and 24
+    pages: latent 18 + 2 + 6 + 12; windowed row 0 pages 24-39 (8 runs: the
+    document ends at a turn's edge), row 1 as before, row 3 8-23; index 10 +
+    (1 + 4) + 6."""
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "INDEX_BLOCK_PAGES", 4)
+    rows, layout, *counted = RUN_TABLES[name]
+    q, pool, bt, lens = run_problem(RUN_ROWS[rows], layout)
+    out, ref, copies = _run_kernel(kind, q, pool, bt, lens)
+    want = dict(zip(("latent", "latent-windowed", "index"), counted))[kind]
+    assert copies == want
+    q_p, pool_p, bt_p, _ = run_problem(RUN_ROWS[rows], _permuted)
+    apart, _, none = _run_kernel(kind, q_p, pool_p, bt_p, lens)
+    assert none[1] == 0
+    assert np.array_equal(np.asarray(out), np.asarray(apart))
+    if kind == "index":
+        assert_index_scores(out, q[:, 0], ref, pool, bt, lens)
+    else:
+        assert_rows_match(out, ref, bt)
+    if kind == "latent":
+        from parent_latent_kernel import parent_latent_attention
+
+        alone = _run_kernel(
+            kind, q, pool, bt, lens, row_groups=singletons(len(bt)))[0]
+        parent = parent_latent_attention(
+            q, pool, bt, lens, v_width=16, pages_per_block=16, sm_scale=0.3)
+        assert_rows_match(alone, parent, bt)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_hosts_count_of_copies_is_the_loops(seed):
+    """``latent_copies_started`` and ``index_copies_started`` (arrays)
+    against the kernels' copy loops walked a page at a time, on dispatches of
+    rows that share documents laid out partly in runs: at the benchmark's
+    block (128 pages: turns of 16) and the index kernel's (32)."""
+    from page_copy_loops import index_copies_by_loop, latent_copies_by_loop
+
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng(seed)
+    page, width, slots = 16, 1024, 12
+    tables = np.zeros((slots, width), np.int32)
+    positions = np.zeros((slots,), np.int32)
+    nxt = 1
+    for d in range(slots // 3):
+        whole = int(rng.integers(130, 600))
+        document = nxt + np.arange(whole)
+        nxt += whole
+        if d % 2:  # a document that was prefilled among others: short runs
+            cuts = np.sort(rng.choice(whole, 12, replace=False))
+            document = np.concatenate(
+                [part[::-1] if i % 3 == 0 else part
+                 for i, part in enumerate(np.split(document, cuts))])
+        for r in range(3 * d, 3 * d + 3):
+            own = int(rng.integers(1, 40))
+            tables[r, :whole] = document
+            tables[r, whole:whole + own] = nxt + rng.permutation(own) * (
+                1 if r % 2 else 0) + (0 if r % 2 else np.arange(own))
+            nxt += own
+            positions[r] = (whole + own) * page - int(rng.integers(1, page))
+    for npb, started, loop in (
+        (128, pa.latent_copies_started, latent_copies_by_loop),
+        (32, pa.index_copies_started, index_copies_by_loop),
+    ):
+        groups = pa.shared_prefix_groups(tables, positions, page, npb)
+        assert (groups[1] > 0).any()
+        got = started(tables, positions, *groups, page, npb)
+        assert got == loop(tables, positions, *groups, page, npb)
+        assert 0 < got[1] and got[0] > got[1] // npb

@@ -4,11 +4,18 @@
 3,104 pages, 2,048 selected), each alone, and a pass of the plain reference
 layer by layer. The index kernel on three dispatches: four askers a document
 (the cell's), 32 rows of the same lengths of which NOBODY shares a page with
-another, and one row alone. Host clock around ``block_until_ready``; the
-traced cell's readers have the device time. Writes
-``chiprun_out/dsa_pieces.json``, or ``--out``: a copy of this file in another
-commit's tree times that commit's kernels. ``selected_positions_device_ms``
-alone is the device's time, from a trace (``bench_grouped_matmul.device_time``).
+another, and one row alone; each a second time over PERMUTED physical pages
+(the same tables and contents, no two neighbouring pages left: the kernels
+copy a run of neighbours as one DMA, and there is none). Host clock around
+``block_until_ready``; the traced cell's readers have the device time. Beside
+them the latent decode kernel at ``deepseek-v2-lite-docqa``'s dispatch (32
+rows, the traffic file's 16 documents, two askers each, 640 lanes, 16 heads)
+by DEVICE time, in three dispatches: documents in runs, the same over
+permuted pages, nobody sharing. Every dispatch prints the copies its kernel
+starts and the share of its pages that go in runs, where the tree counts
+them. Writes ``chiprun_out/dsa_pieces.json``, or ``--out``: a copy of this
+file in another commit's tree times that commit's kernels. ``*_device_ms`` is
+the device's time, from a trace (``bench_grouped_matmul.device_time``).
 
     chiprun --timeout 1500 -- python3 tools/bench_dsa_pieces.py [--reference]
 """
@@ -17,6 +24,7 @@ import argparse
 import functools
 import inspect
 import json
+import math
 import os
 import sys
 import time
@@ -35,6 +43,126 @@ def timed(fn, *args, calls=10, **kw):
         out = fn(*args, **kw)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / calls * 1e3
+
+
+def no_neighbours(num_pages: int):
+    """A permutation of the pool's physical pages (the null page stays) under
+    which no two neighbours stay neighbours: ``perm[p]`` is where page ``p``
+    goes."""
+    import numpy as np
+
+    step = next(
+        m for m in range(7, num_pages) if np.gcd(m, num_pages - 1) == 1)
+    perm = 1 + (np.arange(num_pages) - 1) * step % (num_pages - 1)
+    perm[0] = 0
+    return perm
+
+
+def moved(pool, perm):
+    """``pool``'s pages where ``perm`` sends them."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    return pool[jnp.asarray(np.argsort(perm))]
+
+
+def copies(count, tables, positions, page, npb, turn):
+    """What ``count`` (the tree's ``latent_copies_started`` or
+    ``index_copies_started``; ``None`` in a tree that has none) says of a
+    dispatch whose kernel copies ``turn`` pages a turn of its copy loop: the
+    descriptors started and the share of the copied pages that go in runs (a
+    descriptor is a run's ``turn`` pages or one page)."""
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    if count is None:
+        return {}
+    live = tables[:, 0] != 0
+    tables, positions = tables[live], positions[live]
+    started, in_runs = count(
+        tables, positions,
+        *pa.shared_prefix_groups(tables, positions, page, npb), page, npb)
+    pages = started - in_runs // turn + in_runs
+    return {"copies_started": started, "pages_in_runs": in_runs,
+            "share_in_runs": in_runs / max(1, pages)}
+
+
+def latent_pieces() -> dict:
+    """The latent decode kernel at ``deepseek-v2-lite-docqa``'s dispatch."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench_grouped_matmul import device_time
+    from distributed_pytorch_tpu.ops import paged_attention as pa
+
+    with open(os.path.join(
+            ROOT, "benchmarks", "traffic", "docqa_closed_c32.json")) as f:
+        documents = np.asarray(json.load(f)["documents"])
+    rng = np.random.default_rng(0)
+    slots, pages_per_seq, num_pages, page = 32, 1024, 10241, 16
+    positions = (
+        np.repeat(documents, 2) + rng.integers(32, 384, size=slots)
+    ).astype(np.int32)
+    # A document's whole pages stand side by side in the pool, as set-up
+    # prefills them; an asker's own pages follow one another too.
+    tables = np.zeros((slots, pages_per_seq), np.int32)
+    apart = np.zeros_like(tables)
+    base = 1
+    for d, n in enumerate(documents // page):
+        tables[2 * d : 2 * d + 2, :n] = base + np.arange(n)
+        base += n
+    for r, n in enumerate(positions // page + 1):
+        whole = documents[r // 2] // page
+        tables[r, whole:n] = base + np.arange(n - whole)
+        base += n - whole
+    assert base <= num_pages
+    # 32 rows of these lengths, no page held twice: half the pool's worth.
+    lengths = positions // 2
+    start = 1
+    for r, n in enumerate(lengths // page + 1):
+        apart[r, :n] = start + np.arange(n)
+        start += n
+    perm = no_neighbours(num_pages)
+    key = jax.random.PRNGKey(1)
+    pool = jax.random.normal(key, (num_pages, page, 640), jnp.bfloat16)
+    q = jax.random.normal(key, (slots, 1, 16, 640), jnp.bfloat16)
+    npb = pa.block_pages(pages_per_seq, page, 640, jnp.bfloat16)
+    attend = jax.jit(functools.partial(
+        pa.paged_latent_attention, v_width=512, kernel="pallas",
+        sm_scale=0.1147))
+    count = getattr(pa, "latent_copies_started", None)
+    out = {"latent_block_pages": int(npb)}
+    results = {}
+    for name, held_by, at, held in (
+        ("latent_decode_runs", tables, positions, pool),
+        ("latent_decode_permuted", perm[tables], positions,
+         moved(pool, perm)),
+        ("latent_decode_no_sharing", apart, lengths, pool),
+        ("latent_decode_no_sharing_permuted", perm[apart], lengths,
+         moved(pool, perm)),
+    ):
+        # The grouping, and the turns that are runs where the tree has
+        # them, beforehand: a decode program works them out once for its
+        # layers.
+        groups = pa.shared_prefix_groups(held_by, at, page, npb)
+        if hasattr(pa, "latent_runs"):
+            groups += (pa.latent_runs(held_by, at, *groups, page, npb),)
+        args = (q, held, jnp.asarray(held_by), jnp.asarray(at))
+        told = {"row_groups": tuple(map(jnp.asarray, groups))}
+        seconds, ops = device_time(lambda *a: attend(*a, **told), args, 20)
+        out[name + "_device_ms"] = seconds * 1e3
+        out[name + "_kernel_ms"] = 1e3 * sum(
+            t for op, t in ops.items() if "latent_decode" in op)
+        out[name] = copies(
+            count, held_by, at, page, npb, math.gcd(*pa.block_widths(npb)))
+        results[name] = np.asarray(attend(*args, **told))
+    out["latent_permuted_pages_give_the_same_bits"] = bool(
+        np.array_equal(
+            results["latent_decode_runs"], results["latent_decode_permuted"])
+        and np.array_equal(
+            results["latent_decode_no_sharing"],
+            results["latent_decode_no_sharing_permuted"]))
+    return out
 
 
 def pieces() -> dict:
@@ -86,14 +214,30 @@ def pieces() -> dict:
             held_by, positions, page, pa.index_block_pages(pages_per_seq))))}
 
     dispatches = {
-        "index_scores_kernel_ms": tables,
-        "index_scores_kernel_no_sharing_ms": apart,
-        "index_scores_kernel_one_row_ms": lone,
+        "index_scores_kernel": tables,
+        "index_scores_kernel_no_sharing": apart,
+        "index_scores_kernel_one_row": lone,
     }
+    perm = no_neighbours(num_pages)
+    index_pool_moved = moved(index_pool, perm)
+    count = getattr(pa, "index_copies_started", None)
+    same_bits = True
     for name, held_by in dispatches.items():
-        out[name] = timed(
-            index_scores, q_i, w_i, index_pool, jnp.asarray(held_by),
-            jnp.asarray(positions), kernel="pallas", calls=50, **told(held_by))
+        scored = {}
+        for twin, held, keys in (
+            ("", held_by, index_pool),
+            ("_permuted", perm[held_by], index_pool_moved),
+        ):
+            args = (q_i, w_i, keys, jnp.asarray(held), jnp.asarray(positions))
+            out[name + twin + "_ms"] = timed(
+                index_scores, *args, kernel="pallas", calls=50, **told(held))
+            scored[twin] = np.asarray(
+                index_scores(*args, kernel="pallas", **told(held)))
+            block = pa.index_block_pages(pages_per_seq)
+            out[name + twin] = copies(
+                count, held, positions, page, block, block)
+        same_bits &= np.array_equal(scored[""], scored["_permuted"])
+    out["index_permuted_pages_score_the_same_bits"] = bool(same_bits)
     groups = told(tables)
     tables, positions = jnp.asarray(tables), jnp.asarray(positions)
     scores_of = functools.partial(
@@ -198,6 +342,8 @@ def main() -> None:
     init_platform()
     out = {"pieces": pieces()}
     print(json.dumps(out), flush=True)
+    out["latent"] = latent_pieces()
+    print(json.dumps(out["latent"]), flush=True)
     if args.reference:
         out["reference"] = reference_pass()
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)

@@ -87,8 +87,10 @@ Block sizing (``pages_per_block``) comes from the ``ops/flash_autotune``
 harness' ``paged_decode`` family: measured winners on real hardware, a
 seeded table entry for CPU/interpret so CI never autotunes. A block is also
 the unit of waste: a row walks whole blocks, so it reads up to one block of
-keys it cannot see (:func:`kv_tokens_walked`; the engine's tracer counts
-both sides as ``decode_kv_tokens_fetched`` / ``_visible``).
+keys it cannot see (:func:`kv_tokens_walked`; ``serving/decode_reads.py``
+counts both sides as ``decode_kv_tokens_fetched`` / ``_visible``, at the block
+each call looks up: :func:`kv_block_pages`, :func:`latent_block_pages`,
+:func:`index_block_pages`).
 """
 
 from __future__ import annotations
@@ -378,6 +380,32 @@ def block_pages(
     return max(1, min(int(pages_per_block), int(pages_per_seq)))
 
 
+def kv_block_pages(
+    pages_per_seq: int, pool, dtype, pages_per_block=None
+) -> int:
+    """The block of the K/V kernel's call (:func:`paged_attention`) over a
+    table ``pages_per_seq`` wide, a pool ``[num_pages, page, Hkv, D]`` and
+    queries of ``dtype`` (int8 pages are looked up as their queries are).
+    The call and whoever counts what it reads ask here, with what the call is
+    handed (``serving/decode_reads.py``)."""
+    return block_pages(
+        pages_per_seq, pool.shape[1], pool.shape[-1], dtype, pages_per_block
+    )
+
+
+def latent_block_pages(pages_per_seq: int, pool, pages_per_block=None) -> int:
+    """The block of the latent kernel's call (:func:`paged_latent_attention`)
+    over a table ``pages_per_seq`` wide (a windowed call's:
+    :func:`window_pages`) and a pool ``[num_pages, page, W]``: looked up under
+    the pool's own width and dtype. As :func:`kv_block_pages`, for the call
+    and for the count alike; the index kernel's is
+    :func:`index_block_pages`."""
+    return block_pages(
+        pages_per_seq, pool.shape[1], pool.shape[-1], pool.dtype,
+        pages_per_block,
+    )
+
+
 def kv_tokens_walked(positions, block_tokens: int):
     """Key positions the kernel fetches and computes on for decode rows at
     ``positions`` (a row at ``pos`` sees ``pos + 1`` keys): whole blocks of
@@ -521,9 +549,8 @@ def paged_attention(
 
     run = functools.partial(
         _paged_flash,
-        pages_per_block=block_pages(
-            block_tables.shape[1], k_pool.shape[1], d, q.dtype,
-            pages_per_block,
+        pages_per_block=kv_block_pages(
+            block_tables.shape[1], k_pool, q.dtype, pages_per_block
         ),
         interpret=(mode == "interpret"),
         # A static argument: None keeps the default block's one trace.
@@ -1226,11 +1253,11 @@ def paged_latent_attention(
     Returns ``[S, T_step, H, v_width]``. As :func:`paged_attention`: a
     single-token step dispatches per ``kernel``, everything else takes
     :func:`paged_attention_reference`'s latent case; ``sm_scale`` ``None`` is
-    ``W ** -0.5``; :func:`block_pages` looks the block up under the pool's
-    width. ``row_groups`` is :func:`shared_prefix_groups`' ``(leader,
+    ``W ** -0.5``; :func:`latent_block_pages` looks the block up under the
+    pool's width. ``row_groups`` is :func:`shared_prefix_groups`' ``(leader,
     shared)`` for these tables and lengths where the caller has worked it out
-    (a decode program does, once for all its layers: the engine's
-    ``_decode_state_kw``);
+    (a decode program does, once for all its layers:
+    ``serving/decode_reads.py``'s ``operands``);
     ``None`` works it out here; a third member is :func:`latent_runs` of the
     same dispatch, for a caller that has worked that out once too. Only the
     kernel reads either.
@@ -1259,9 +1286,7 @@ def paged_latent_attention(
         )
         rows = jnp.arange(s, dtype=jnp.int32)
         row_groups, first_key = (rows, jnp.zeros_like(rows)), (lo,)
-    npb = block_pages(
-        block_tables.shape[1], pool.shape[1], w, pool.dtype, pages_per_block
-    )
+    npb = latent_block_pages(block_tables.shape[1], pool, pages_per_block)
     if row_groups is None:
         row_groups = shared_prefix_groups(
             block_tables, seq_lens, pool.shape[1], npb

@@ -96,13 +96,7 @@ it builds, whatever the states' shapes; there is no argument for it, and
 nothing below changed when the second kind of state arrived, nor the third:
 the mask, the reset rule, the prefill operand, the byte count and the
 refusals are keyed on ``STATE_KEYS`` and on the leaves' leading ``max_slots``
-alone. (What the third added is counters: a model with gated-delta layers'
-``step`` slice and ``stats()`` carry ``state_slots_updated``, live rows x
-such layers of the step's decode programs, and ``state_bytes_moved``, each of
-those states once in and once out, which is what the decode kernel
-``linear_attention._gated_delta_step`` moves; its ``prefill.chunk`` slice
-carries ``state_blocks``, the blocks of 64 tokens a layer evaluated for the
-piece.) The one design:
+alone. The one design:
 
 * **decode** runs all ``max_slots`` rows, row ``r`` on slot ``r``'s state in
   place. A row outside the dispatched group has a zeroed block table; the
@@ -176,79 +170,32 @@ layer keeps ``cached_index [num_pages, page_size, index width]`` (its
 indexer's key a token) beside its latent pool, a ``"latent_window"`` layer a
 latent pool of its own rank. All of them live under the ONE allocator, block
 table a sequence, trie and copy-on-write, because none of those asks what a
-page holds or how many arrays it spans. The engine reads
-``latent_layers`` from the model and the pools' geometry from the cache tree
-it builds (a page pool is a leaf whose first two axes are ``(num_pages,
-page_size)``; ``stats()["page_bytes_per_token_layer"]`` is what one layer's
-pools hold a token, whatever their kind); the allocator, the block tables, the
-trie, copy-on-write and the page-copy program never ask what a page holds, so
-``prefix_cache=True`` serves such a model as it serves any. The decode
-kernel's block is looked up under the pool's width. What is built on a K and
-a V pool of one head size is refused in the constructor, each with its
-reason: ``mesh`` (``KV_POOL_SPEC`` splits a KV-head axis), ``host_pages``
+page holds or how many arrays it spans (the pages behind a window stay
+allocated and are never read: one table a sequence serves every layer). The
+engine reads ``latent_layers`` from the model and the pools' geometry from the
+cache tree it builds (a page pool is a leaf whose first two axes are
+``(num_pages, page_size)``; ``stats()["page_bytes_per_token_layer"]`` is what
+one layer's pools hold a token, whatever their kind), so ``prefix_cache=True``
+serves such a model as it serves any. What is built on a K and a V pool of one
+head size is refused in the constructor, each with its reason: ``mesh``
+(``KV_POOL_SPEC`` splits a KV-head axis), ``host_pages``
 (``serving/hostkv.py`` reckons a page's bytes from ``Hkv x D``), ``kv_quant``
 (one scale a (token, head)), ``draft_model`` (no verify path over latent
-pages). On every ``step`` slice that dispatched a decode the tracer carries,
-beside ``decode_kv_tokens_fetched`` / ``_visible``,
-``decode_kv_tokens_distinct``: the key positions the rows could see with each
-PHYSICAL page counted once, so rows that share a document count it once.
+pages). A model with sparse layers' decode program also returns the positions
+each sparse layer selected, ``selected_positions`` (the last step's, ``[sparse
+layers, slots, index_top_k]``, -1 past a row's own); nobody reads them back
+but a caller that asks.
 
-Learned sparse attention and windows (``"latent_sparse"``, ``"latent_window"``
-layers): a sparse layer's decode scores EVERY visible token's index key and
-attends over the ``index_top_k`` best tokens' latents; a window layer's reads
-the pages that meet its window. The pages behind a window stay allocated and
-are never read (one table a sequence serves every layer). What a dispatch
-reads is counted from its rows' positions alone, with or without a tracer
-(``stats()``, and on the ``step`` slice the step's own): a layer's
-``decode_index_tokens_scored`` (``pos + 1`` a row),
-``decode_index_tokens_fetched`` (the index keys the index kernel copies:
-whole blocks of pages, a group's shared blocks once, below; every slot's whole
-table on the gather path) and, under a tracer,
-``decode_index_tokens_scored_distinct`` (each physical page once),
-``decode_kv_tokens_selected`` (``min(pos + 1, index_top_k)`` a row),
-``decode_window_tokens_visible`` (``min(pos + 1, window)``) and
-``decode_window_tokens_read`` (the whole pages that hold them); a model with
-such layers counts as ``decode_kv_tokens_fetched`` the latent rows a layer
-reads on average (selected, and a window's pages). A ``dsa.select`` instant a
-decode program carries the rows, the visible and selected tokens and their
-share. The decode program also returns the positions each sparse layer
-selected, ``selected_positions`` (the last step's, ``[sparse layers, slots,
-index_top_k]``, -1 past a row's own); nobody reads them back but a caller
-that asks.
-
-The latent decode kernel, and a sparse layer's index kernel, serve rows whose
-tables begin with the same physical pages (askers of one cached document) as
-a GROUP: the shared pages are copied out of the pool once for all of them
-(the index kernel scores them against the members' index queries stacked, in
-whole blocks of its own). Who shares what is read off the staged tables and
-positions by ONE rule, ``ops/paged_attention.py``'s ``shared_prefix_groups``:
-the decode program applies it once and tells its latent layers
-(:meth:`InferenceEngine._decode_state_kw`; no staged operand, no readback),
-and the host applies it to its own copies for the counters:
-``stats()["decode_rows_grouped"]`` (rows served in a group of two or more: by
-the index kernel in a model with sparse layers, by the latent kernel
-otherwise) and, on the ``step`` slice, ``decode_rows_grouped`` beside a
-``decode_kv_tokens_fetched`` (the latent kernel's) or a
-``decode_index_tokens_fetched`` (the index kernel's) that counts a group's
-shared pages once, so fetched / visible falls below 1 where rows share.
-Nothing switches it on or off: a dispatch whose tables share nothing groups
-nobody.
-
-Both kernels copy a RUN of neighbouring pages of the pool as one DMA (a turn
-of their copy loops whose table entries are ``first, first + 1, ...``:
-``ops/paged_attention.py`` ``is_run``; the leading such turns of a block, or
-blocks of a table); a document prefilled in one go holds such pages. The
-decode program works out which (``latent_runs``, beside the grouping, once
-for its layers). Beside ``decode_rows_grouped``, ``stats()`` and the ``step`` slice
-of a model with latent layers carry ``decode_page_copies``, the copy
-descriptors that ONE call of each such kernel starts for the dispatch (the
-latent kernel's; in a model with sparse and window layers the index kernel's
-and the windowed latent call's), and ``decode_pages_in_runs``, the pages among
-them that went as part of a run: by the host from its staged tables with the
-kernels' own rule (``latent_copies_started`` / ``index_copies_started``), as
-the tokens fetched are. A descriptor moves a run's pages or one page, so the
-pages copied are ``decode_page_copies`` less the runs plus
-``decode_pages_in_runs``; 0 on the gather path.
+What a decode dispatch READS (which kernels the model's layers call, at which
+block, under which copy rule), what the ``step`` slice and ``stats()`` count of
+it (``decode_kv_tokens_*``, ``decode_rows_grouped``, ``decode_page_copies``,
+``decode_pages_in_runs``, ``decode_index_tokens_*``, ``decode_window_tokens_*``,
+``state_slots_updated``, ``state_bytes_moved``) and what the decode program
+tells its kernels of its rows (which share a document's pages, which of their
+pages stand side by side) is ``serving/decode_reads.py``'s: the engine builds
+one :class:`~.decode_reads.DecodeReads` from its decode model and cache tree,
+hands it each dispatch's staged tables and positions ONCE, and knows no kind
+of layer for the sake of a counter.
 """
 
 from __future__ import annotations
@@ -300,6 +247,7 @@ from distributed_pytorch_tpu.serving.admission import (
     AdmissionController,
     ServingMetrics,
 )
+from distributed_pytorch_tpu.serving.decode_reads import DecodeReads
 from distributed_pytorch_tpu.serving.hostkv import HostPageTier
 from distributed_pytorch_tpu.serving.kv_cache import (
     NULL_PAGE,
@@ -523,48 +471,6 @@ class InferenceEngine:
         # with no head axis. Read from the model; what knows a K and a V pool
         # of one head size is refused with its reason.
         self.latent_layers = int(getattr(model, "latent_layers", 0))
-        kinds = tuple(getattr(model, "layer_types", None) or ())
-        # The plain latent layers' decode kernel, and a sparse layer's index
-        # kernel, group rows that share a document; a sparse layer attends
-        # over what it selected and a window layer walks a row's own last
-        # pages.
-        self.sparse_layers = kinds.count("latent_sparse")
-        self.window_layers = kinds.count("latent_window")
-        self._plain_latent_layers = kinds.count("latent")
-        self._grouping_layers = self._plain_latent_layers + self.sparse_layers
-        self._index_top_k = (
-            model.latent_sizes("latent_sparse")["index_top_k"]
-            if self.sparse_layers else 0
-        )
-        self._window = (
-            model.latent_sizes("latent_window")["window"]
-            if self.window_layers else 0
-        )
-        # Gated-delta layers (``models/gated_delta.py``): the step slice says
-        # how many (row, layer) states its decode program updated and the
-        # bytes that moved by the kernel's own rule (each state once in, once
-        # out: ``ops/linear_attention.state_bytes_moved``); a prefill piece
-        # says how many blocks a layer evaluated.
-        self.delta_layers = kinds.count("gated_delta")
-        self._delta_state_bytes = 0
-        if self.delta_layers:
-            from distributed_pytorch_tpu.models.mamba import STATE_DTYPE
-            from distributed_pytorch_tpu.ops import linear_attention as la
-
-            self._delta_state_bytes = la.state_bytes_moved(
-                1, model.linear_n_heads, model.linear_d_k, model.linear_d_v,
-                jnp.dtype(STATE_DTYPE).itemsize,
-            )
-            self._delta_block = la.BLOCK
-        self.state_slots_updated = 0
-        self.state_bytes_moved = 0
-        # Totals of what the decode dispatches read (module docstring).
-        self.decode_index_tokens_scored = 0
-        self.decode_index_tokens_fetched = 0
-        self.decode_index_tokens_scored_distinct = 0  # a tracer's runs only
-        self.decode_kv_tokens_selected = 0
-        self.decode_window_tokens_visible = 0
-        self.decode_window_tokens_read = 0
         self.selected_positions: List[jax.Array] = []  # the last step's
         if self.latent_layers:
             for given, what, why in (
@@ -649,23 +555,6 @@ class InferenceEngine:
         self.decode_model = model.clone(
             decode=True, page_size=page_size, num_pages=num_pages, **clone_kw
         )
-        # What a decode dispatch reads of the pools, for the tracer's
-        # ``decode_kv_tokens_*`` gauges: the kernel walks whole blocks of
-        # this many tokens; 0 is the gather path, which reads every slot's
-        # whole table.
-        self._kv_block_tokens = 0
-        # A traced step's decode dispatches: the rows' positions, their
-        # tables and, where the latent kernel groups rows, their grouping.
-        self._decode_dispatches: List[tuple] = []
-        # Rows a decode kernel served in a group of two or more (their
-        # tables begin with the same pages, read once for the group).
-        self.decode_rows_grouped = 0
-        # Copy descriptors the latent and index kernels start, a layer's call
-        # of each, and the pages among them that went as part of a run of
-        # neighbouring pages (module docstring); the windowed call's block.
-        self.decode_page_copies = 0
-        self.decode_pages_in_runs = 0
-        self._window_block_pages = 0
         # Size the paged pool from abstract shapes only (eval_shape traces
         # init without running it); token length 1 — pool shapes depend only
         # on (num_pages, page_size), never on the init input.
@@ -687,7 +576,7 @@ class InferenceEngine:
         pools = {"target": _zero_cache(self.decode_model)}
         # What the target's pages hold, read from the pools it declared
         # (leaves ``[num_pages, page_size, ...]``), whatever kind they are:
-        # bytes a token in one layer, and the last size of a latent pool.
+        # bytes a token in one layer.
         page_leaves = [
             (path, leaf)
             for path, leaf in jax.tree_util.tree_flatten_with_path(
@@ -701,33 +590,13 @@ class InferenceEngine:
             sum(leaf.nbytes for _, leaf in page_leaves)
             // max(1, paged_layers * num_pages * page_size)
         )
-        if self.paged_kernel:
-            from distributed_pytorch_tpu.ops import paged_attention as pa
-
-            if pa.resolve_kernel(self.paged_kernel) != "xla":
-                latent = [
-                    leaf for path, leaf in page_leaves
-                    if getattr(path[-1], "key", None) == "cached_latent"
-                ]
-                self._kv_block_tokens = page_size * pa.block_pages(
-                    self.pages_per_seq, page_size,
-                    latent[0].shape[-1] if latent
-                    else model.d_model // model.n_heads,
-                    model.dtype,
-                )
-                if self.window_layers:
-                    # As the windowed call looks its block up: under the
-                    # width of the window layers' own pool (whole lanes).
-                    from distributed_pytorch_tpu.models.mla import whole_lanes
-
-                    sizes = model.latent_sizes("latent_window")
-                    self._window_block_pages = pa.block_pages(
-                        pa.window_pages(self._window, page_size), page_size,
-                        whole_lanes(
-                            sizes["kv_lora_rank"] + sizes["qk_rope_head_dim"]
-                        ),
-                        model.dtype,
-                    )
+        # What a decode dispatch reads of these pools and what its program
+        # is told of its rows: which kernels the layers call, at which
+        # block, under which copy rule (``serving/decode_reads.py``).
+        self.reads = DecodeReads(
+            self.decode_model, pools["target"], max_slots=max_slots,
+            pages_per_seq=self.pages_per_seq,
+        )
         # Bytes of recurrent state one slot owns, over every layer.
         self.state_bytes_per_slot = sum(
             leaf.nbytes // max_slots
@@ -1318,12 +1187,12 @@ class InferenceEngine:
         cache)``, and for a model with routed layers a third result, the
         layers' routing counts ``[routed layers, n_experts]`` in layer
         order."""
-        if not (self.routed_layers or self.sparse_layers):
+        wanted = ("routing",) * bool(self.routed_layers) + (
+            "selection",) * self.reads.selection
+        if not wanted:
             return decode_token_step(
                 self.decode_model, params, cache, tokens, **kw
             )
-        wanted = ("routing",) * bool(self.routed_layers) + (
-            "selection",) * bool(self.sparse_layers)
         last_logits, cache, sown = decode_token_step(
             self.decode_model, params, cache, tokens,
             mutable=("cache",) + wanted, **kw,
@@ -1383,105 +1252,15 @@ class InferenceEngine:
         routed layers (nothing to any other): row ``r`` carries slot ``r``'s
         state, and is routed to experts and counted, iff the row is in the
         dispatched group, which is iff its staged block table is not the
-        zeroed one. And a model with latent or sparse layers whose decode
-        kernels are on: which rows' tables begin with the same physical pages
-        (:meth:`_row_groups`), worked out here once for all its layers."""
+        zeroed one. And what it tells its decode kernels of its rows
+        (:meth:`DecodeReads.operands`), worked out here once for all its
+        layers."""
         kw = {}
         if self.state_layers or self.routed_layers:
             rows = jnp.arange(self.max_slots, dtype=jnp.int32)
             kw["state_slots"] = jnp.where(tables[:, 0] != NULL_PAGE, rows, -1)
-        if self._grouping_layers and self._kv_block_tokens:
-            groups = self._row_groups(tables, lens)
-            if self._plain_latent_layers:
-                # Plain latent layers: which turns of their kernel's copy
-                # loop are runs of neighbouring pages, once for all of them.
-                from distributed_pytorch_tpu.ops.paged_attention import (
-                    latent_runs,
-                )
-
-                groups += (latent_runs(
-                    tables, lens, *groups, self.page_size,
-                    self._kv_block_tokens // self.page_size,
-                ),)
-            kw["row_groups"] = groups
+        kw.update(self.reads.operands(tables, lens))
         return kw
-
-    def _row_groups(self, tables, lens):
-        """``shared_prefix_groups`` of a decode dispatch's tables and
-        positions, traced in the decode program (the kernel's operand) or on
-        the host's staged copies (the counters): one rule for both."""
-        from distributed_pytorch_tpu.ops.paged_attention import (
-            shared_prefix_groups,
-        )
-
-        return shared_prefix_groups(
-            tables, lens, self.page_size,
-            self._kv_block_tokens // self.page_size,
-        )
-
-    def _rows_grouped(self, shared) -> int:
-        """Rows of a dispatch grouped as :meth:`_row_groups` says (its
-        ``shared``) that a decode kernel serves in a group of two or more:
-        the latent kernel's, whose group shares anything; in a model with
-        sparse layers the index kernel's, whose group shares a whole block
-        of its own."""
-        from distributed_pytorch_tpu.ops.paged_attention import (
-            index_block_pages,
-            index_rows_grouped,
-        )
-
-        if self.sparse_layers:
-            return index_rows_grouped(
-                shared, index_block_pages(self.pages_per_seq)
-            )
-        return int((shared > 0).sum())
-
-    def _page_copies(self, tables, positions, groups) -> Tuple[int, int]:
-        """``(copies, pages in runs)`` of a decode dispatch's live rows: the
-        copy descriptors that ONE call of each kernel that copies pages by
-        runs starts (the latent kernel's, or in a model with sparse and
-        window layers the index kernel's and the windowed latent call's), and
-        the pages among them that went as part of a run of neighbouring
-        pages, by the kernels' own rule (``ops/paged_attention.py``
-        ``is_run``) on the host's staged tables."""
-        from distributed_pytorch_tpu.ops import paged_attention as pa
-
-        page = self.page_size
-        counts = []
-        if self.sparse_layers:
-            counts.append(pa.index_copies_started(
-                tables, positions, *groups, page,
-                pa.index_block_pages(self.pages_per_seq),
-            ))
-        if self._plain_latent_layers:
-            counts.append(pa.latent_copies_started(
-                tables, positions, *groups, page,
-                self._kv_block_tokens // page,
-            ))
-        if self.window_layers:
-            windows = pa.window_tables(tables, positions, page, self._window)
-            rows = np.arange(len(tables), dtype=np.int32)
-            counts.append(pa.latent_copies_started(
-                *windows[:2], rows, np.zeros_like(rows), page,
-                self._window_block_pages,
-            ))
-        return tuple(map(sum, zip(*counts))) if counts else (0, 0)
-
-    def _index_tokens_fetched(self, positions, groups) -> int:
-        """Index keys a sparse layer's scoring copies for decode rows at
-        ``positions``: the index kernel's walks of rows grouped as ``groups``
-        says, or (``None``: the gather path) every slot's whole table."""
-        from distributed_pytorch_tpu.ops.paged_attention import (
-            index_block_pages,
-            index_tokens_fetched,
-        )
-
-        if groups is None:
-            return self.max_slots * self.pages_per_seq * self.page_size
-        return index_tokens_fetched(
-            positions, *groups, self.page_size,
-            index_block_pages(self.pages_per_seq), self.pages_per_seq,
-        )
 
     def _note_state_reset(self, slot: int, req: Request) -> None:
         """A row at position 0 is about to run: its state starts from
@@ -1590,12 +1369,9 @@ class InferenceEngine:
         if self._acct is not None and req.rework_until > start:
             self._note_rework(req, start, tokens)
         target, draft = self._prefill_programs[width]
-        blocks = (
-            {"state_blocks": -(-width // min(self._delta_block, width))}
-            if self.delta_layers else {}
-        )
         with self._phase(
-            "prefill.chunk", tokens=tokens, start=start, width=width, **blocks
+            "prefill.chunk", tokens=tokens, start=start, width=width,
+            **self.reads.prefill_args(width),
         ):
             tok = np.zeros((1, width), np.int32)
             tok[0, :tokens] = req.tokens[start : start + tokens]
@@ -2190,33 +1966,9 @@ class InferenceEngine:
                     bias.fill(0.0)
                 bias[slot] = row
         self._stage_row_keys(slots)
-        if self.delta_layers:
-            updated = len(slots) * self.delta_layers
-            self.state_slots_updated += updated
-            self.state_bytes_moved += updated * self._delta_state_bytes
-        groups = None
-        rows = sorted(slots)
-        if self._grouping_layers and self._kv_block_tokens:
-            # The rule the program applies to the same tables (absent rows
-            # are in no group, so the live rows, in slot order, group alike).
-            groups = self._row_groups(
-                self._stage_tables[rows], self._stage_lens[rows]
-            )
-            self.decode_rows_grouped += self._rows_grouped(groups[1])
-        copies = (0, 0)
-        if self.latent_layers and self._kv_block_tokens:
-            copies = self._page_copies(
-                self._stage_tables[rows], self._stage_lens[rows], groups
-            )
-            self.decode_page_copies += copies[0]
-            self.decode_pages_in_runs += copies[1]
-        if self.sparse_layers or self.window_layers:
-            self._count_narrowed_reads(self._stage_lens[rows], groups)
-        if self.tracer.enabled:
-            self._decode_dispatches.append(
-                (self._stage_lens[rows], self._stage_tables[rows], groups,
-                 copies)
-            )
+        self.reads.note(
+            self._stage_tables, self._stage_lens, sorted(slots), self.tracer
+        )
         staged = (
             self._stage_tokens.nbytes
             + self._stage_use_prev.nbytes
@@ -2245,51 +1997,10 @@ class InferenceEngine:
                 params, self.cache, tokens, prev, use_prev, tables, lens,
                 temps, keys, bias_arr,
             )
-        if self.sparse_layers and self.paged_kernel:
-            # The kernel path selects a list of positions (the gather path
-            # masks, and keeps none).
+        if self.reads.selection:
             self.selected_positions.append(extras.pop())
         self.routing_counts.extend(extras)
         return nxt
-
-    def _narrowed_reads(self, positions) -> Tuple[int, int, int]:
-        """What a sparse and a window layer read for decode rows at
-        ``positions`` (module docstring): the tokens selected, the tokens
-        inside the windows, and the tokens of the pages that hold those."""
-        from distributed_pytorch_tpu.ops.paged_attention import (
-            window_tokens_read,
-        )
-
-        selected = in_window = read = 0
-        if self.sparse_layers:
-            selected = int(np.minimum(positions + 1, self._index_top_k).sum())
-        if self.window_layers:
-            in_window = int(np.minimum(positions + 1, self._window).sum())
-            read = int(
-                window_tokens_read(positions, self._window, self.page_size).sum()
-            )
-        return selected, in_window, read
-
-    def _count_narrowed_reads(self, positions, groups) -> None:
-        """Add a decode dispatch's rows (grouped as ``groups`` says) to the
-        totals of what its sparse and window layers read, and write its
-        ``dsa.select`` instant."""
-        selected, in_window, read = self._narrowed_reads(positions)
-        visible = int(positions.sum()) + len(positions)
-        if self.sparse_layers:
-            self.decode_index_tokens_scored += visible
-            self.decode_index_tokens_fetched += self._index_tokens_fetched(
-                positions, groups
-            )
-            self.decode_kv_tokens_selected += selected
-            if self.tracer.enabled:
-                self.tracer.instant(
-                    "dsa.select", rows=len(positions), visible=visible,
-                    selected=selected, layers=self.sparse_layers,
-                    selected_share=selected / max(1, visible),
-                )
-        self.decode_window_tokens_visible += in_window
-        self.decode_window_tokens_read += read
 
     def _end_step_trace(self, plan) -> None:
         """Close the tracer's step slice with the per-step gauges: batch
@@ -2317,80 +2028,12 @@ class InferenceEngine:
         if self.state_layers:
             extra["state_slots_in_use"] = len(self.scheduler.running)
             extra["state_bytes"] = self._state_bytes()
-        if self.delta_layers:
-            extra["state_slots_updated"] = updated = (
-                len(plan.decode_slots) * self.delta_layers
-            )
-            extra["state_bytes_moved"] = updated * self._delta_state_bytes
         if self.routed_layers:
             # The step before this one has been read back: its counts cost
             # no wait. This step's wait for the next trace (or a flush).
             self._flush_routing()
             self._routing_due = (self.tracer.step_index, self.routing_counts)
-        if self._decode_dispatches:
-            # Over every decode dispatch of the step: the key positions its
-            # rows could see, and the ones read for them.
-            from distributed_pytorch_tpu.ops.paged_attention import (
-                kv_tokens_walked,
-                latent_tokens_fetched,
-            )
-
-            block = self._kv_block_tokens
-            whole = self.max_slots * self.pages_per_seq * self.page_size
-            fetched = visible = distinct = grouped = 0
-            selected = in_window = window_read = index_fetched = 0
-            narrowed = self.sparse_layers + self.window_layers
-            page_copies = pages_in_runs = 0
-            for pos, tables, groups, copies in self._decode_dispatches:
-                page_copies += copies[0]
-                pages_in_runs += copies[1]
-                visible += int(pos.sum()) + len(pos)
-                distinct += self._distinct_kv_tokens(pos, tables)
-                if narrowed:
-                    # A sparse layer reads the latents it selected, a window
-                    # layer the pages that meet its window: a layer's mean.
-                    chosen, inside, read = self._narrowed_reads(pos)
-                    selected += chosen
-                    in_window += inside
-                    window_read += read
-                    fetched += (
-                        self.sparse_layers * chosen + self.window_layers * read
-                    ) // narrowed
-                    if self.sparse_layers:
-                        # The index kernel: a group's shared blocks once.
-                        index_fetched += self._index_tokens_fetched(
-                            pos, groups
-                        )
-                        if groups is not None:
-                            grouped += self._rows_grouped(groups[1])
-                elif groups is not None:
-                    # The latent kernel: a group's shared pages once.
-                    fetched += latent_tokens_fetched(
-                        pos, *groups, self.page_size,
-                        block // self.page_size, self.pages_per_seq,
-                    )
-                    grouped += self._rows_grouped(groups[1])
-                elif block:
-                    fetched += int(kv_tokens_walked(pos, block).sum())
-                else:
-                    fetched += whole
-            extra["decode_kv_tokens_fetched"] = fetched
-            extra["decode_kv_tokens_visible"] = visible
-            extra["decode_kv_tokens_distinct"] = distinct
-            if self.latent_layers:
-                extra["decode_rows_grouped"] = grouped
-                extra["decode_page_copies"] = page_copies
-                extra["decode_pages_in_runs"] = pages_in_runs
-            if self.sparse_layers:
-                extra["decode_index_tokens_scored"] = visible
-                extra["decode_index_tokens_fetched"] = index_fetched
-                extra["decode_index_tokens_scored_distinct"] = distinct
-                extra["decode_kv_tokens_selected"] = selected
-                self.decode_index_tokens_scored_distinct += distinct
-            if self.window_layers:
-                extra["decode_window_tokens_visible"] = in_window
-                extra["decode_window_tokens_read"] = window_read
-            self._decode_dispatches.clear()
+        extra.update(self.reads.end_step())
         self.tracer.end_step(
             decode_rows=len(plan.decode_slots),
             prefill_programs=len(plan.prefill),
@@ -2406,21 +2049,6 @@ class InferenceEngine:
             pages_cached_idle=pages["pages_cached_idle"],
             **extra,
         )
-
-    def _distinct_kv_tokens(self, positions, tables) -> int:
-        """Key positions a decode dispatch's rows could see, each PHYSICAL
-        page counted once: rows that share a prefix share its pages, and a
-        kernel could serve them all by one read of it. A page counts the most
-        tokens any of its rows sees in it (a row at ``pos`` sees ``pos %
-        page + 1`` of its last page, every earlier one whole)."""
-        page = self.page_size
-        last = positions // page  # a row's last live logical page
-        seen = np.zeros((self.allocator.num_pages,), np.int64)
-        whole = np.arange(self.pages_per_seq)[None, :] < last[:, None]
-        seen[tables[whole]] = page
-        rows = np.arange(len(positions))
-        np.maximum.at(seen, tables[rows, last], positions % page + 1)
-        return int(seen.sum())
 
     def step(self) -> List[int]:
         """Run one engine iteration; returns ids of requests that FINISHED
@@ -2566,7 +2194,7 @@ class InferenceEngine:
         tr.begin_step()
         if self.routed_layers:
             self.routing_counts = []  # the last step's stay with who took them
-        if self.sparse_layers:
+        if self.reads.selection:
             self.selected_positions = []
         with self._phase("schedule"):
             plan = self.scheduler.schedule()
@@ -3141,27 +2769,7 @@ class InferenceEngine:
         out["prefill_programs"] = self.prefill_programs
         out["prefill_tokens"] = self.prefill_tokens
         out["prefill_width"] = self.prefill_width
-        out["decode_rows_grouped"] = self.decode_rows_grouped
-        if self.latent_layers:
-            out["decode_page_copies"] = self.decode_page_copies
-            out["decode_pages_in_runs"] = self.decode_pages_in_runs
-        if self.delta_layers:
-            out["state_slots_updated"] = self.state_slots_updated
-            out["state_bytes_moved"] = self.state_bytes_moved
-        if self.sparse_layers:
-            out["decode_index_tokens_scored"] = self.decode_index_tokens_scored
-            out["decode_index_tokens_fetched"] = (
-                self.decode_index_tokens_fetched
-            )
-            out["decode_index_tokens_scored_distinct"] = (
-                self.decode_index_tokens_scored_distinct
-            )
-            out["decode_kv_tokens_selected"] = self.decode_kv_tokens_selected
-        if self.window_layers:
-            out["decode_window_tokens_visible"] = (
-                self.decode_window_tokens_visible
-            )
-            out["decode_window_tokens_read"] = self.decode_window_tokens_read
+        out.update(self.reads.totals)
         if self.routed_layers:
             out["moe_product"] = self.moe_product
             out["moe_pairs_held"] = self.moe_pairs_held

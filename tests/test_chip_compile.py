@@ -197,15 +197,14 @@ def latent_program(chip, t_step, layers=2):
 
     def run(params, cache, tokens, tables, lens, valid):
         kw = {"valid_lens": valid}
-        if t_step == 1:  # as the engine's decode program tells the model
-            from distributed_pytorch_tpu.ops.paged_attention import (
-                block_pages, shared_prefix_groups,
+        if t_step == 1:  # what the engine's decode program tells the model
+            from distributed_pytorch_tpu.serving.decode_reads import (
+                DecodeReads,
             )
 
-            page = engine["page_size"]
-            kw = {"row_groups": shared_prefix_groups(
-                tables, lens, page,
-                block_pages(pages_per_seq, page, 640, jnp.bfloat16))}
+            kw = DecodeReads(
+                decode_model, cache, max_slots=rows,
+                pages_per_seq=pages_per_seq).operands(tables, lens)
         logits, updated = decode_model.apply(
             {"params": params, "cache": cache}, tokens, block_tables=tables,
             seq_lens=lens, state_slots=jnp.arange(rows, dtype=jnp.int32),
@@ -322,15 +321,14 @@ def sparse_program(chip, t_step):
 
     def run(params, cache, tokens, tables, lens, valid):
         kw = {"valid_lens": valid}
-        if t_step == 1:  # as the engine's decode program tells the model
-            from distributed_pytorch_tpu.ops.paged_attention import (
-                block_pages, shared_prefix_groups,
+        if t_step == 1:  # what the engine's decode program tells the model
+            from distributed_pytorch_tpu.serving.decode_reads import (
+                DecodeReads,
             )
 
-            page = engine["page_size"]
-            kw = {"row_groups": shared_prefix_groups(
-                tables, lens, page,
-                block_pages(pages_per_seq, page, 640, jnp.bfloat16))}
+            kw = DecodeReads(
+                decode_model, cache, max_slots=rows,
+                pages_per_seq=pages_per_seq).operands(tables, lens)
         logits, updated = decode_model.apply(
             {"params": params, "cache": cache}, tokens, block_tables=tables,
             seq_lens=lens, state_slots=jnp.arange(rows, dtype=jnp.int32),

@@ -272,7 +272,8 @@ def test_distinct_tokens_of_a_handmade_dispatch(program):
     tables[2, :1] = [7]  # shares row 0's LAST page and sees 4 of it
     positions = np.asarray([10, 8, 3])
     # pages 5, 6 whole (8), page 7 at the most any row sees (4), page 9 (1)
-    assert engine._distinct_kv_tokens(positions, tables) == 8 + 4 + 1
+    counts = engine.reads.count(tables, positions, traced=True)
+    assert counts["decode_kv_tokens_distinct"] == 8 + 4 + 1
     assert int(positions.sum()) + 3 == 11 + 9 + 4
 
 
@@ -297,14 +298,15 @@ def test_a_documents_neighbouring_pages_are_copied_in_runs(
     engine = engine_for(
         program, tracer=tracer, paged_kernel="interpret",
         max_prefill_chunk=32, token_budget=35)
-    assert engine._kv_block_tokens == 16 * 4
-    dispatches, count = [], engine._page_copies
+    assert engine.reads.blocks == {"latent": 16}
+    dispatches, count = [], engine.reads.count
 
-    def counted(tables, positions, groups):
-        dispatches.append((tables.copy(), positions.copy(), groups))
-        return count(tables, positions, groups)
+    def counted(tables, positions, traced):
+        dispatches.append((tables.copy(), positions.copy(),
+                           engine.reads.groups(tables, positions)))
+        return count(tables, positions, traced)
 
-    engine._page_copies = counted
+    engine.reads.count = counted
     serve(engine, [document + tokens(2, seed=69)], new_tokens=1)
     served = serve(engine, askers, new_tokens=6)
     stats = engine.stats()

@@ -84,8 +84,9 @@ def test_the_engine_finds_every_pool_of_every_width(held_program):
     names = sorted(path[-1].key for path, _ in leaves)
     assert names == ["cached_index"] * 2 + ["cached_latent"] * 4
     assert {leaf.shape for _, leaf in leaves} == {(9, 4, 128)}
-    assert engine.latent_layers == 4 and engine.sparse_layers == 2
-    assert engine.window_layers == 2 and engine.routed_layers == 3
+    assert engine.latent_layers == 4 and engine.routed_layers == 3
+    layers = engine.reads.layers
+    assert layers["latent_sparse"] == layers["latent_window"] == 2
     # Six pools over four layers: 1.5 pools of 128 float32 lanes a layer.
     assert engine.stats()["page_bytes_per_token_layer"] == 6 * 128 * 4 // 4
 
@@ -252,14 +253,15 @@ def test_a_documents_index_keys_are_copied_in_runs(held_program, monkeypatch):
     engine = engine_for(
         held_program, tracer=tracer, paged_kernel="interpret",
         max_prefill_chunk=32, token_budget=35)
-    assert engine._window_block_pages == 2
-    dispatches, count = [], engine._page_copies
+    assert engine.reads.blocks == {"index": 2, "window": 2}
+    dispatches, count = [], engine.reads.count
 
-    def counted(tables, positions, groups):
-        dispatches.append((tables.copy(), positions.copy(), groups))
-        return count(tables, positions, groups)
+    def counted(tables, positions, traced):
+        dispatches.append((tables.copy(), positions.copy(),
+                           engine.reads.groups(tables, positions)))
+        return count(tables, positions, traced)
 
-    engine._page_copies = counted
+    engine.reads.count = counted
     serve(engine, [document + tokens(2, seed=79)], new_tokens=1)
     served = serve(engine, askers, new_tokens=6)
     stats = engine.stats()

@@ -342,7 +342,7 @@ def test_the_engine_serves_it_beside_other_requests_and_after_preemption(
     together, stats, steps, chunks, engine = serve(
         range(6), max_slots=3, num_pages=9, token_budget=35)
     assert stats["preemptions"] > 0
-    assert engine.state_layers == engine.delta_layers == 3
+    assert engine.state_layers == engine.reads.layers["gated_delta"] == 3
     # [3 slots, 2 packed heads, 16, 128] float32 + a conv tail of 3 x 384
     assert engine.state_bytes_per_slot == 3 * (4 * 16 * 64 + 3 * 384) * 4
     for i, got in enumerate(together):

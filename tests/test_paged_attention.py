@@ -564,7 +564,7 @@ class TestEngineKernelParity:
             model, params, tracer=tracer, overlap=False,
             paged_kernel=kernel, **ENGINE_KW,
         )
-        assert eng._kv_block_tokens == block
+        assert eng.reads.blocks.get("kv", 0) * eng.page_size == block
         for prompt in (list(range(1, 16)), [1, 2, 3]):
             eng.submit(prompt, SamplingParams(max_new_tokens=4))
         eng.run()

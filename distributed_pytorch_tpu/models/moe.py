@@ -301,6 +301,9 @@ class RoutedExperts(nn.Module):
     dtype: Any = F32
     gating: str = GATINGS[0]  # one of GATINGS
     paged_kernel: str = ""  # the block's (see Attention): the products' mode
+    # What the gates are multiplied by after the rule has made them (a
+    # configuration's ``routed_scaling_factor``), in float32; 1.0 is none.
+    scale: float = 1.0
 
     @nn.compact
     def __call__(
@@ -337,6 +340,8 @@ class RoutedExperts(nn.Module):
                     F32,
                 ).astype(ROUTER_DTYPE)
             gates, experts = route(scores, k, self.gating, bias)  # [tokens, k]
+            if self.scale != 1.0:
+                gates = gates * jnp.asarray(self.scale, gates.dtype)
             if live is None:
                 alive = jnp.ones((tokens,), bool)
             elif live.ndim == 1:

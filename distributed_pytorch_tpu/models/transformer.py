@@ -175,9 +175,22 @@ class Attention(nn.Module):
     # An RMSNorm over the WHOLE query and the whole key projection (all heads
     # together, a scale an element) before the heads are split, the rotation
     # and the cache (the Olmo 2 family's QK-norm), statistics in float32 at
-    # ``norm_eps``.
-    qk_norm: bool = False
+    # ``norm_eps``. ``"head"`` is the other published form: an RMSNorm a HEAD
+    # (one learned ``[head_dim]`` vector for the queries, one for the keys),
+    # likewise before the rotation and the cache.
+    qk_norm: Any = False  # False | True (the whole projection) | "head"
     norm_eps: float = 1e-6
+    # A head's size where it is not ``d_model // n_heads`` (64 heads of 128 on
+    # a hidden size of 6144): the projections are ``[d_model, heads,
+    # head_dim]`` and ``out`` ``[heads, head_dim, d_model]``. 0 = the quotient.
+    head_dim: int = 0
+    # The paged tables this layer is handed are its window GROUP's short ones
+    # (``serving/kv_cache.py`` ``WindowTable``; ``ops/paged_attention.py``
+    # ``paged_window_attention``): entry 0 stands for the page that holds the
+    # window's first key, and the pages behind it have gone back to the
+    # group's allocator. Needs ``window``; a paged layer with a window and
+    # without this is refused below.
+    paged_window: bool = False
 
     def _scale(self, head_dim: int) -> float:
         if self.score_scale is None:
@@ -225,11 +238,19 @@ class Attention(nn.Module):
                 raise ValueError(
                     "paged decode does not compose with quantized_cache yet"
                 )
-            if self.window:
+            if self.window and not self.paged_window:
                 raise ValueError(
-                    "a window in a K/V (non-latent) layer is not served "
-                    "through pages yet; a latent layer's window is "
-                    "(models/mla.py)"
+                    "a window in a K/V (non-latent) layer is served through "
+                    "pages on its group's own tables alone (layer type "
+                    "'attention_window'); the model's attention_window is "
+                    "not. A latent layer's window is (models/mla.py)"
+                )
+            if self.paged_window and (
+                not self.window or self.kv_quant or self.mesh is not None
+            ):
+                raise ValueError(
+                    "a window group's layer needs a window and is served "
+                    "with neither int8 pages nor a mesh yet"
                 )
             if self.kv_quant not in ("", "int8"):
                 raise ValueError(
@@ -249,8 +270,13 @@ class Attention(nn.Module):
                 "paged_kernel / kv_quant require the paged cache "
                 "(page_size > 0)"
             )
-        head_dim = self.d_model // self.n_heads
+        head_dim = self.head_dim or self.d_model // self.n_heads
         kv_heads = self.n_kv_heads or self.n_heads
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(
+                f"unknown qk_norm {self.qk_norm!r} (expected False, True "
+                "for the whole projection, or 'head')"
+            )
         if self.n_heads % kv_heads:
             raise ValueError(
                 f"n_heads {self.n_heads} not divisible by n_kv_heads "
@@ -267,7 +293,13 @@ class Attention(nn.Module):
         q_raw = dense(self.n_heads, "query")(x)
         k_raw = dense(kv_heads, "key")(x)
         v = dense(kv_heads, "value")(x)
-        if self.qk_norm:
+        if self.qk_norm == "head":
+            a_head = lambda name: nn.RMSNorm(  # noqa: E731
+                epsilon=self.norm_eps, dtype=jnp.float32, name=name,
+            )
+            q_raw = a_head("q_norm")(q_raw).astype(self.dtype)
+            k_raw = a_head("k_norm")(k_raw).astype(self.dtype)
+        elif self.qk_norm:
             whole = lambda name: nn.RMSNorm(  # noqa: E731
                 epsilon=self.norm_eps, dtype=jnp.float32, name=name,
                 reduction_axes=(-2, -1), feature_axes=(-2, -1),
@@ -282,9 +314,11 @@ class Attention(nn.Module):
                         "paged decode requires block_tables and seq_lens "
                         "every step (the serving engine passes them)"
                     )
-                out = self._paged_decode_step(
-                    q_raw, k_raw, v, block_tables, seq_lens, valid_lens
+                step = (
+                    self._window_paged_step if self.paged_window
+                    else self._paged_decode_step
                 )
+                out = step(q_raw, k_raw, v, block_tables, seq_lens, valid_lens)
             else:
                 out = self._decode_step(q_raw, k_raw, v)
             return out_proj(out)
@@ -625,6 +659,61 @@ class Attention(nn.Module):
         out = jnp.einsum("bhgqk,bkhd->bqhgd", weights, values)
         return out.reshape(s, t_step, h, d)[:, :, :heads_out]
 
+    def _window_paged_step(
+        self, q_raw, k_raw, v, block_tables, seq_lens, valid_lens=None
+    ):
+        """:meth:`_paged_decode_step` for a layer of a WINDOW group: the same
+        write-then-attend, through the group's short tables. ``block_tables``
+        ``[S, width]`` hold each row's pages from the one that holds the
+        first key of its first new token's window on (``window_first_page``
+        of ``seq_lens``: the host stages them by the same rule), ``width``
+        the pages a decode row (``window_pages``) or a prefill piece
+        (``window_group_pages``) can meet. A position's page is found
+        relative to that first one; what falls outside the table (a padded
+        piece's tail) or past ``valid_lens`` is written to the null page. A
+        query at ``t`` reads the keys ``(t - window, t]``: the decode kernel
+        from its first live key, a piece through the gather path."""
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            paged_window_attention,
+            window_first_page,
+        )
+
+        cached_key = self.variable("cache", "cached_key", lambda: None)
+        cached_value = self.variable("cache", "cached_value", lambda: None)
+        s, t_step, h, d = q_raw.shape
+        kv_heads = k_raw.shape[2]
+        if cached_key.value.shape[2] != kv_heads:
+            raise ValueError(
+                f"a window group's pool holds its layer's {kv_heads} KV "
+                f"heads as they are, not {cached_key.value.shape[2]}"
+            )
+        page, width = self.page_size, block_tables.shape[1]
+        seq_lens = seq_lens.astype(jnp.int32)
+        positions = seq_lens[:, None] + jnp.arange(t_step, dtype=jnp.int32)
+        q = self._rope(q_raw, positions)
+        k = self._rope(k_raw, positions)
+        first = window_first_page(seq_lens, self.window, page)  # [S]
+        logical = (positions // page - first[:, None]).reshape(-1)
+        rows = jnp.repeat(jnp.arange(s, dtype=jnp.int32), t_step)
+        kept = (logical >= 0) & (logical < width)
+        if valid_lens is not None:
+            kept &= token_mask(valid_lens, t_step).reshape(-1)
+        phys = jnp.where(
+            kept, block_tables[rows, jnp.clip(logical, 0, width - 1)], 0
+        )
+        offset = positions.reshape(-1) % page
+        cached_key.value = cached_key.value.at[phys, offset].set(
+            k.astype(cached_key.value.dtype).reshape(-1, kv_heads, d)
+        )
+        cached_value.value = cached_value.value.at[phys, offset].set(
+            v.astype(cached_value.value.dtype).reshape(-1, kv_heads, d)
+        )
+        return paged_window_attention(
+            q, cached_key.value, cached_value.value, block_tables, seq_lens,
+            window=self.window, kernel=self.paged_kernel or "xla",
+            sm_scale=self.score_scale,
+        )
+
     def _update_quantized_cache(self, cached_key, cached_value, k, v, index):
         """Write this step's k/v as int8 + per-(token, head) float32 scales,
         and return the DEQUANTIZED full caches for the attention einsums —
@@ -682,8 +771,13 @@ class MLPBlock(nn.Module):
 
 LAYER_TYPES = (
     "attention", "mamba", "mamba2", "latent", "latent_sparse", "latent_window",
-    "gated_delta",
+    "gated_delta", "attention_window",
 )
+#: The layer types that are :class:`Attention`: the plain one keeps the
+#: model's window (``attention_window``), ``rope`` and ``rope_theta``; an
+#: ``"attention_window"`` layer takes its own from ``attention_variants`` and,
+#: served through pages, stands on its window GROUP's block tables.
+ATTENTION_TYPES = ("attention", "attention_window")
 #: The layer types that keep a per-slot recurrent state in decode mode.
 RECURRENT_TYPES = ("mamba", "mamba2", "gated_delta")
 #: The layer types that are models/mla.py's LatentAttention: the plain one,
@@ -758,7 +852,11 @@ class TransformerBlock(nn.Module):
     # "output" the Olmo 2 family's ``x + norm(f(x))`` (no norm on the input;
     # the same two parameters, ``ln_attn`` and ``ln_mlp``).
     norm_placement: str = "input"
-    qk_norm: bool = False  # see Attention
+    qk_norm: Any = False  # see Attention
+    head_dim: int = 0  # see Attention; 0 = d_model // n_heads
+    # An "attention_window" layer's own ``window``, ``rope`` and
+    # ``rope_theta`` as (field, value) pairs (TransformerLM.attention_variants).
+    attention: tuple = ()
 
     @nn.compact
     def __call__(
@@ -834,19 +932,26 @@ class TransformerBlock(nn.Module):
                 num_pages=self.num_pages, paged_kernel=self.paged_kernel,
                 name="mla", **dict(self.latent),
             )(normed, row_groups=row_groups, **paged_kw, **piece_kw)
-        elif self.mixer == "attention":
+        elif self.mixer in ATTENTION_TYPES:
+            own = dict(self.attention)  # a window layer's; else nothing
             mixed = Attention(
                 self.n_heads, self.d_model, self.dtype, self.causal,
-                n_kv_heads=self.n_kv_heads, window=self.window,
-                rope_scale=self.rope_scale, rope_theta=self.rope_theta,
+                n_kv_heads=self.n_kv_heads,
+                window=own.get("window", self.window),
+                rope_scale=self.rope_scale,
+                rope_theta=own.get("rope_theta", self.rope_theta),
                 mesh=self.mesh, sequence_axis=self.sequence_axis,
                 sequence_mode=self.sequence_mode, decode=self.decode,
                 quantized_cache=self.quantized_cache,
                 page_size=self.page_size, num_pages=self.num_pages,
                 paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
-                rope=self.rope, use_bias=self.use_bias,
+                rope=own.get("rope", self.rope), use_bias=self.use_bias,
                 score_scale=self.score_scale, qk_norm=self.qk_norm,
-                norm_eps=self.norm_eps, name="attention",
+                norm_eps=self.norm_eps, head_dim=self.head_dim,
+                paged_window=(
+                    self.mixer == "attention_window" and self.page_size > 0
+                ),
+                name="attention",
             )(normed, **paged_kw, **piece_kw)
         else:
             raise ValueError(
@@ -1058,7 +1163,20 @@ class TransformerLM(nn.Module):
     # The Olmo 2 family's block (see TransformerBlock and Attention): the
     # norms on each sublayer's OUTPUT, and an RMSNorm over the whole q and k.
     norm_placement: str = "input"  # "input" | "output"
-    qk_norm: bool = False
+    qk_norm: Any = False  # False | True (the whole projection) | "head"
+    # A head's size where it is not ``d_model // n_heads`` (see Attention).
+    head_dim: int = 0
+    # An "attention_window" layer is :class:`Attention` with a window, a
+    # ``rope`` and a ``rope_theta`` of its OWN: ``(("attention_window",
+    # (("window", 128), ("rope", True), ...)),)``, the way ``latent_variants``
+    # gives the latent layers theirs; what a variant leaves out is the
+    # model's. The plain "attention" layers keep the model's
+    # (``attention_window``, ``rope``, ``rope_theta``). In paged decode mode
+    # such layers are a block-table GROUP of their own: their pools hold
+    # ``window_num_pages`` pages (0: ``num_pages``), and ``__call__`` must be
+    # handed the group's short tables as ``window_tables``.
+    attention_variants: Optional[tuple] = None
+    window_num_pages: int = 0
     # Scalars some families put on the residual stream (defaults: none).
     # The scores' scale (None = head_dim ** -0.5) reaches every attention path
     # and the paged kernel; the others multiply the embedding, each branch
@@ -1079,6 +1197,7 @@ class TransformerLM(nn.Module):
     experts_held: Optional[tuple] = None
     shared_d_ff: int = 0
     routed_gating: str = "softmax_of_top_k"  # one of models/moe.py's GATINGS
+    routed_scale: float = 1.0  # on the routed gates (``routed_scaling_factor``)
     # A "dense" layer's width where it is not ``d_ff`` (which stays the
     # routed experts'); 0 = ``d_ff``.
     dense_d_ff: int = 0
@@ -1131,6 +1250,26 @@ class TransformerLM(nn.Module):
             sizes.update(dict(variants[layer_type]))
         return sizes
 
+    def attention_sizes(self) -> dict:
+        """An "attention_window" layer's own ``window``, ``rope`` and
+        ``rope_theta``: the model's, under the variant's."""
+        variants = dict(self.attention_variants or ())
+        own = dict(variants.get("attention_window", ()))
+        if own.get("window", 0) < 1:
+            raise ValueError(
+                "a model with 'attention_window' layers needs that type's "
+                "window in attention_variants"
+            )
+        return {"rope": self.rope, "rope_theta": self.rope_theta, **own}
+
+    @property
+    def kv_window(self) -> int:
+        """The window of the "attention_window" layers, whose pages stand in
+        a block-table group of their own; 0: the model has no such group."""
+        if "attention_window" not in (self.layer_types or ()):
+            return 0
+        return int(self.attention_sizes()["window"])
+
     @property
     def routed_layers(self) -> int:
         """How many layers sow a routing count (``"routing"`` collection)."""
@@ -1147,6 +1286,7 @@ class TransformerLM(nn.Module):
         state_slots: Optional[jnp.ndarray] = None,
         valid_lens: Optional[jnp.ndarray] = None,
         row_groups=None,
+        window_tables: Optional[jnp.ndarray] = None,
     ) -> jnp.ndarray:
         types = self.layer_types
         if types is not None and len(types) != self.n_layers:
@@ -1229,6 +1369,8 @@ class TransformerLM(nn.Module):
             residual_multiplier=self.residual_multiplier,
             norm_placement=self.norm_placement, qk_norm=self.qk_norm,
         )
+        if self.head_dim:
+            block_kw["head_dim"] = self.head_dim
         mixer_kw = {
             "mamba": (
                 ("d_state", self.mamba_d_state), ("d_conv", self.mamba_d_conv),
@@ -1250,12 +1392,23 @@ class TransformerLM(nn.Module):
                 mixer_kw[latent_type] = tuple(
                     sorted(self.latent_sizes(latent_type).items())
                 )
+        if "attention_window" in (types or ()):
+            mixer_kw["attention_window"] = tuple(
+                sorted(self.attention_sizes().items())
+            )
+            if block_tables is not None and window_tables is None:
+                raise ValueError(
+                    "a model with attention_window layers needs its window "
+                    "group's window_tables beside block_tables"
+                )
         routed = (
             ("n_experts", self.routed_experts),
             ("top_k", self.routed_top_k), ("held", self.experts_held),
         )
         if self.routed_gating != "softmax_of_top_k":
             routed += (("gating", self.routed_gating),)
+        if self.routed_scale != 1.0:
+            routed += (("scale", self.routed_scale),)
         routed_kw = dict(
             ffn="routed", shared_d_ff=self.shared_d_ff, routed=routed
         )
@@ -1263,8 +1416,17 @@ class TransformerLM(nn.Module):
             # GShard-style interleaving: every `moe_every`-th block is MoE.
             moe = self.n_experts if (i + 1) % self.moe_every == 0 else 0
             layer_kw = block_kw
+            layer_paged, num_pages = paged_kw, self.num_pages
             if types is not None and types[i] != "attention":
                 sizes = "latent" if types[i] in LATENT_TYPES else "mamba"
+                if types[i] == "attention_window":
+                    # The window group's tables and pool size.
+                    sizes = "attention"
+                    num_pages = self.window_num_pages or self.num_pages
+                    if window_tables is not None:
+                        layer_paged = dict(
+                            paged_kw, block_tables=window_tables
+                        )
                 layer_kw = dict(
                     block_kw, mixer=types[i],
                     **{sizes: mixer_kw.get(types[i], ())},
@@ -1289,10 +1451,10 @@ class TransformerLM(nn.Module):
                 n_experts=moe, moe_top_k=self.moe_top_k,
                 decode=self.decode, remat_mlp=remat_mlp,
                 quantized_cache=self.quantized_cache,
-                page_size=self.page_size, num_pages=self.num_pages,
+                page_size=self.page_size, num_pages=num_pages,
                 paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
                 name=f"block_{i}", **layer_kw,
-            )(x, **paged_kw)
+            )(x, **layer_paged)
         x = make_norm(self.norm, self.norm_eps, "ln_final")(x)
         if self.fused_head_chunk and self.vocab_size % self.fused_head_chunk:
             # Fail loudly here: a silent dense fallback would surface later as

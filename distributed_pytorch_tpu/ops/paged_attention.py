@@ -217,17 +217,30 @@ def paged_attention_reference(
 
 
 def _decode_kernel(
-    bt_ref, lens_ref, q_ref, *refs, npb, group, sm_scale, quantized
+    bt_ref, lens_ref, *refs, npb, group, sm_scale, quantized, windowed=False
 ):
     """One slot (grid step) of the flash-decode kernel: walk the row's own
     KV blocks, and only those.
 
-    ``refs`` unpacks to the K and V pools left in HBM, (when quantized) the
+    ``refs`` unpacks to (when ``windowed``) a third scalar-prefetch operand,
+    each row's first live key, then the row's queries, the K and V pools left
+    in HBM, (when quantized) the
     row's K and V scales by block, the output block, then the scratch: two
     buffers of ``npb`` pages for each pool, one DMA semaphore a buffer, the
     buffer the row's first block was prefetched into (SMEM), and the three
     fp32 accumulators (running max ``m``, denominator ``l``, output
-    ``acc``)."""
+    ``acc``).
+
+    ``windowed`` is a window layer's call (:func:`paged_window_attention`):
+    the table is the row's SHORT one, from the page that holds its window's
+    first key on, positions count from that page's first token, and a key
+    before ``lo_ref[row]`` is masked like one past ``pos``. That first key
+    stands in the table's first page, so every walked block still has a
+    visible key."""
+    lo_ref = None
+    if windowed:
+        lo_ref, *refs = refs
+    q_ref, *refs = refs
     k_hbm, v_hbm = refs[:2]
     ks_ref, vs_ref = refs[2:4] if quantized else (None, None)
     (o_ref, k_buf, v_buf, sems, first_buf, m_scr, l_scr, acc_scr) = refs[
@@ -339,7 +352,10 @@ def _decode_kernel(
             )
             # Every walked block has key ``j * bkv`` visible, so the running
             # max stays finite and no exp(NEG_INF - NEG_INF) row can arise.
-            s_blk = jnp.where(kpos <= pos, s_blk, NEG_INF)
+            visible = kpos <= pos
+            if windowed:
+                visible = jnp.logical_and(visible, kpos >= lo_ref[b])
+            s_blk = jnp.where(visible, s_blk, NEG_INF)
             s2 = s_blk.reshape(h, bkv)
             m_prev = m_scr[:, :1]
             l_prev = l_scr[:, :1]
@@ -381,16 +397,20 @@ def block_pages(
 
 
 def kv_block_pages(
-    pages_per_seq: int, pool, dtype, pages_per_block=None
+    pages_per_seq: int, pool, dtype, pages_per_block=None, *, short=False
 ) -> int:
     """The block of the K/V kernel's call (:func:`paged_attention`) over a
     table ``pages_per_seq`` wide, a pool ``[num_pages, page, Hkv, D]`` and
     queries of ``dtype`` (int8 pages are looked up as their queries are).
     The call and whoever counts what it reads ask here, with what the call is
-    handed (``serving/decode_reads.py``)."""
-    return block_pages(
-        pages_per_seq, pool.shape[1], pool.shape[-1], dtype, pages_per_block
-    )
+    handed (``serving/decode_reads.py``). ``short`` is a window group's table
+    (:func:`paged_window_attention`): looked up as the power of two that holds
+    it and held to its own width, so that a row's 9 pages are ONE block where
+    the device's block is 16 and not a block of 8 and one of 1."""
+    width = 1 << (pages_per_seq - 1).bit_length() if short else pages_per_seq
+    return min(pages_per_seq, block_pages(
+        width, pool.shape[1], pool.shape[-1], dtype, pages_per_block
+    ))
 
 
 def latent_block_pages(pages_per_seq: int, pool, pages_per_block=None) -> int:
@@ -418,7 +438,7 @@ def kv_tokens_walked(positions, block_tokens: int):
 )
 def _paged_flash(
     q3, k_pool, v_pool, block_tables, seq_lens, k_scale, v_scale,
-    *, pages_per_block, interpret, sm_scale=None,
+    first_key=None, *, pages_per_block, interpret, sm_scale=None,
 ):
     """Build and invoke the pallas_call for ``q3`` [S, H, D] (T_step == 1).
 
@@ -428,7 +448,9 @@ def _paged_flash(
     set-up in every process, compile cache or not. The kernel is named, so
     a device trace shows it as ``attention._paged_decode_step`` (the name it
     has had in every trace, then taken from the calling module's scope)
-    whoever calls it.
+    whoever calls it; a window layer's call (``first_key [S]``: each row's
+    first live key, counted like ``seq_lens`` from its short table's first
+    token) is :data:`KV_WINDOW_KERNEL`, so a trace tells the two kinds apart.
 
     The grid is the slots, run in order (``"arbitrary"``: a row's last block
     starts the next row's first). The block table and the lengths are scalar
@@ -449,11 +471,15 @@ def _paged_flash(
     npb = int(pages_per_block)
     nblk = -(-pages_per_seq // npb)
     quantized = k_scale is not None
+    windowed = first_key is not None
     bt = block_tables.astype(jnp.int32)
+    prefetch = (bt, seq_lens.astype(jnp.int32))
+    if windowed:
+        prefetch += (first_key.astype(jnp.int32),)
 
     def row_spec(shape):
         return pl.BlockSpec(
-            shape, lambda b, bt, lens: (b,) + (0,) * (len(shape) - 1),
+            shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1),
             memory_space=pltpu.VMEM,
         )
 
@@ -474,7 +500,7 @@ def _paged_flash(
         in_specs += [row_spec((1, nblk, kv_heads, npb * page))] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(s,),
         in_specs=in_specs,
         out_specs=row_spec((1, h, d)),
@@ -492,7 +518,7 @@ def _paged_flash(
         functools.partial(
             _decode_kernel, npb=npb, group=group,
             sm_scale=d**-0.5 if sm_scale is None else sm_scale,
-            quantized=quantized,
+            quantized=quantized, **({"windowed": True} if windowed else {}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, d), q3.dtype),
@@ -500,8 +526,8 @@ def _paged_flash(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-        name="attention._paged_decode_step",
-    )(bt, seq_lens.astype(jnp.int32), q3, *operands)
+        name=KV_WINDOW_KERNEL if windowed else "attention._paged_decode_step",
+    )(*prefetch, q3, *operands)
 
 
 def paged_attention(
@@ -593,6 +619,110 @@ def paged_attention(
         out_specs=P(None, heads_axis, None),
         check_vma=False,
     )(*args)
+    return out3.reshape(s, 1, h, d)
+
+
+# ------------------------------------------------------------- windowed K/V
+#
+# A K/V layer with a window (``models/transformer.py``'s ``"attention_window"``
+# layers) is served on block tables of its GROUP's own (``serving/kv_cache.py``
+# ``WindowTable``): the pages behind a window go back to the group's allocator,
+# so what a call is handed is each row's SHORT table, from the page that holds
+# its window's first key on: ``window_pages(window, page)`` entries for a decode
+# row, :func:`window_group_pages` for a prefill piece of several queries.
+
+KV_WINDOW_KERNEL = "attention._window_paged_decode_step"
+
+
+def window_first_page(seq_lens, window: int, page: int):
+    """The logical page that a short table's first entry stands for: the one
+    that holds the first key ``max(pos - window + 1, 0)`` of the window of a
+    row's FIRST new token at ``seq_lens``. NumPy or traced; the host stages
+    its tables from it (``WindowTable.as_row``) and the layer counts its
+    positions from it: one rule."""
+    xp = np if isinstance(seq_lens, (np.ndarray, int, np.integer)) else jnp
+    return xp.maximum(seq_lens - (window - 1), 0) // page
+
+
+def window_group_pages(window: int, page: int, tokens: int = 1) -> int:
+    """Pages a sequence holds in a window group while ``tokens`` new tokens
+    in a row are written and read: from the first one's window's first key
+    to the last one's own (:func:`window_pages` at one token: 9 at a window
+    of 128 on pages of 16, 41 inside a piece of 512)."""
+    return window_pages(window + tokens - 1, page)
+
+
+def paged_window_attention_reference(
+    q, k_pool, v_pool, block_tables, seq_lens, *, window: int,
+    sm_scale: Optional[float] = None,
+):
+    """The XLA gather path over SHORT tables: :func:`paged_attention_reference`
+    with key ``j`` of a row's gathered view standing at position
+    ``window_first_page * page + j``, and a query at ``t`` kept to the keys
+    ``(t - window, t]``. Any ``T_step``: a prefill piece reads its window's
+    pages and its own through it."""
+    s, t_step, h, d = q.shape
+    page, kv_heads = k_pool.shape[1:3]
+    kv_len = block_tables.shape[1] * page
+    lens = seq_lens.astype(jnp.int32)
+    positions = lens[:, None] + jnp.arange(t_step, dtype=jnp.int32)
+    keys = k_pool[block_tables].reshape(s, kv_len, kv_heads, d)
+    values = v_pool[block_tables].reshape(s, kv_len, kv_heads, d)
+    scale = d**-0.5 if sm_scale is None else sm_scale
+    k_abs = (window_first_page(lens, window, page) * page)[:, None, None] + (
+        jnp.arange(kv_len)[None, None, :]
+    )
+    visible = (k_abs <= positions[:, :, None]) & (
+        k_abs > positions[:, :, None] - window
+    )
+    group = h // kv_heads
+    qg = q.reshape(s, t_step, kv_heads, group, d)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, keys) * scale
+    logits = jnp.where(visible[:, None, None], logits, NEG_INF)
+    weights = jax.nn.softmax(
+        logits.astype(jnp.float32), axis=-1
+    ).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", weights, values)
+    return out.reshape(s, t_step, h, d)
+
+
+def paged_window_attention(
+    q, k_pool, v_pool, block_tables, seq_lens, *, window: int,
+    kernel="auto", pages_per_block: Optional[int] = None,
+    sm_scale: Optional[float] = None,
+):
+    """Paged attention of ``q`` [S, T_step, H, D] over a window group's pools
+    through its SHORT tables ``[S, width]`` (entry 0: the page
+    :func:`window_first_page` names for ``seq_lens``). As
+    :func:`paged_attention`: a single-token step dispatches per ``kernel`` to
+    ``_decode_kernel``, named :data:`KV_WINDOW_KERNEL`, told each row's first
+    live key beside its position; everything else takes the gather path.
+    :func:`kv_block_pages` decides the block over the short table's width (a
+    decode row's 9 pages at the published sizes are ONE block)."""
+    s, t_step, h, d = q.shape
+    page, kv_heads = k_pool.shape[1:3]
+    if h % kv_heads:
+        raise ValueError(
+            f"query heads {h} not divisible by kv heads {kv_heads}"
+        )
+    mode = resolve_kernel(kernel)
+    if mode == "xla" or t_step != 1:
+        return paged_window_attention_reference(
+            q, k_pool, v_pool, block_tables, seq_lens, window=window,
+            sm_scale=sm_scale,
+        )
+    lens = seq_lens.astype(jnp.int32)
+    base = window_first_page(lens, window, page) * page
+    out3 = _paged_flash(
+        q.reshape(s, h, d), k_pool, v_pool, block_tables.astype(jnp.int32),
+        lens - base, None, None, jnp.maximum(lens - (window - 1), 0) - base,
+        pages_per_block=kv_block_pages(
+            block_tables.shape[1], k_pool, q.dtype, pages_per_block,
+            short=True,
+        ),
+        interpret=(mode == "interpret"),
+        **({} if sm_scale is None else {"sm_scale": float(sm_scale)}),
+    )
     return out3.reshape(s, 1, h, d)
 
 

@@ -74,6 +74,8 @@ from distributed_pytorch_tpu.serving.kv_cache import (
     PagePoolGroup,
     PagedBlockAllocator,
     PrefixCache,
+    WindowGroup,
+    WindowTable,
 )
 from distributed_pytorch_tpu.serving.mesh import (
     make_serving_mesh,
@@ -143,6 +145,8 @@ __all__ = [
     "TenantQuotaExceeded",
     "TokenDFA",
     "TokenStream",
+    "WindowGroup",
+    "WindowTable",
     "adopt_snapshot",
     "compile_grammar",
     "drain_engine",

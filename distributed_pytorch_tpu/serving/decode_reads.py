@@ -49,7 +49,14 @@ starts no page copy.
   ``decode_kv_tokens_selected`` (``min(pos + 1, index_top_k)``) and, traced,
   ``decode_index_tokens_scored_distinct``; a ``dsa.select`` instant a dispatch;
 * window layers: ``decode_window_tokens_visible`` (``min(pos + 1, window)``)
-  and ``decode_window_tokens_read`` (the whole pages that hold them);
+  and ``decode_window_tokens_read`` (the whole pages that hold them). A K/V
+  layer with a window (``"attention_window"``: a block-table group of its
+  own, ``serving/kv_cache.py`` ``WindowGroup``) writes the same two, its
+  ``read`` being what the K/V kernel copies over the group's short table:
+  whole blocks (``kv_block_pages(..., short=True)``: a row's 9 pages are one),
+  a page past the row's last copied again in its place; on the gather path
+  the short table whole. The full layers' ``decode_kv_tokens_*`` count the
+  FULL group's table, as in a model with no window;
 * gated-delta layers: ``state_slots_updated`` (rows x such layers) and
   ``state_bytes_moved`` (each state once in and once out,
   ``linear_attention.state_bytes_moved``), on every ``step`` slice; a
@@ -91,6 +98,9 @@ TOTALLED = {
     "latent_window": (
         "decode_window_tokens_visible", "decode_window_tokens_read",
     ),
+    "attention_window": (
+        "decode_window_tokens_visible", "decode_window_tokens_read",
+    ),
 }
 
 
@@ -118,7 +128,8 @@ class DecodeReads:
         ]
         self.layers = {
             kind: len(where(kind)) for kind in
-            ("latent", "latent_sparse", "latent_window", "gated_delta")
+            ("latent", "latent_sparse", "latent_window", "gated_delta",
+             "attention_window")
         }
         self.layers["latent pages"] = len(where(*LATENT_TYPES))
         self.page, self.width = model.page_size, pages_per_seq
@@ -129,6 +140,11 @@ class DecodeReads:
             self.top_k = model.latent_sizes("latent_sparse")["index_top_k"]
         if self.layers["latent_window"]:
             self.window = model.latent_sizes("latent_window")["window"]
+        #: The K/V window layers' window and short table (a decode row's).
+        self.kv_window = self.kv_window_pages = 0
+        if self.layers["attention_window"]:
+            self.kv_window = model.kv_window
+            self.kv_window_pages = pa.window_pages(self.kv_window, self.page)
         if self.layers["gated_delta"]:
             self.state_bytes = la.state_bytes_moved(
                 1, model.linear_n_heads, model.linear_d_k, model.linear_d_v,
@@ -159,10 +175,18 @@ class DecodeReads:
                     pa.window_pages(self.window, self.page),
                     _pool(cache, "cached_latent", where("latent_window")),
                 )
-            keys = _pool(cache, "cached_key")
+            # The full group's K/V layers (every layer of a model that names
+            # no layer types), and the window group's.
+            keys = _pool(
+                cache, "cached_key", where("attention") if kinds else None)
             if keys is not None:
                 self.blocks["kv"] = pa.kv_block_pages(
                     pages_per_seq, keys, model.dtype)
+            if self.kv_window:
+                self.blocks["kv_window"] = pa.kv_block_pages(
+                    self.kv_window_pages,
+                    _pool(cache, "cached_key", where("attention_window")),
+                    model.dtype, short=True)
         self.totals = {"decode_rows_grouped": 0}
         for kind, names in TOTALLED.items():
             if self.layers[kind]:
@@ -240,6 +264,17 @@ class DecodeReads:
                 copies.append(pa.latent_copies_started(
                     *windows[:2], alone, np.zeros_like(alone), page,
                     blocks["window"]))
+        if self.kv_window:
+            first = pa.window_first_page(positions, self.kv_window, page)
+            out["decode_window_tokens_visible"] = out.get(
+                "decode_window_tokens_visible", 0) + int(
+                np.minimum(positions + 1, self.kv_window).sum())
+            out["decode_window_tokens_read"] = out.get(
+                "decode_window_tokens_read", 0) + (
+                int(pa.kv_tokens_walked(
+                    positions - first * page,
+                    blocks["kv_window"] * page).sum())
+                if blocks else rows * self.kv_window_pages * page)
         if self.layers["latent pages"]:
             out["decode_rows_grouped"] = 0 if groups is None else (
                 pa.index_rows_grouped(groups[1], blocks["index"]) if sparse

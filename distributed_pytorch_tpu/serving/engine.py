@@ -186,6 +186,33 @@ each sparse layer selected, ``selected_positions`` (the last step's, ``[sparse
 layers, slots, index_top_k]``, -1 past a row's own); nobody reads them back
 but a caller that asks.
 
+Window layers (a model whose ``layer_types`` name ``"attention_window"``
+layers: K/V attention with a window of its own, ``model.kv_window``): a second
+block-table GROUP. The full layers keep the sequence's one table, the
+allocator, the pool size ``num_pages`` and every program operand they have in
+a model without such layers; the window layers' pools hold ``window_pages``
+pages under an allocator of their own, and a sequence holds a second table
+there (``serving/kv_cache.py`` ``WindowTable`` / ``WindowGroup``), whose pages
+wholly behind its window go back after every prefill piece and decode
+dispatch: ``window_pages(W, page)`` a decoding row and ``window_group_pages``
+inside a piece, where one table a sequence would hold the whole context in
+every layer. The programs take one more operand, the group's SHORT tables
+(``[max_slots, decode pages]`` for the decode program, ``[1, piece pages]`` for
+a prefill piece: entry 0 the page that holds the window's first key), staged
+from the sequence's table by the rule the layer counts its positions by
+(``window_first_page``). The scheduler ensures, releases and preempts in both
+groups; ``close()`` holds both allocators quiescent. ``stats()`` and the
+``step`` slice carry ``window_pages_freed`` / ``_held`` / ``_free`` (``stats()``
+also ``window_pages_held_peak``, the most ONE sequence held at once), a
+``window.free`` instant a step that frees (``pages``, ``rows``), the
+``prefill.chunk`` slice ``window_pages``, ``engine.init.pools`` each group's
+bytes, the scheduler's ``admit`` event the pages a group the request comes to
+hold. Refused for such a model, each with its reason: ``prefix_cache=True`` (a
+hit would need the window layers' last ``W - 1`` positions, whose pages are
+gone), ``draft_model``, ``host_pages``, ``mesh``, ``kv_quant``. A model without
+such layers builds ONE group, and its programs lower to the bytes they always
+did.
+
 What a decode dispatch READS (which kernels the model's layers call, at which
 block, under which copy rule), what the ``step`` slice and ``stats()`` count of
 it (``decode_kv_tokens_*``, ``decode_rows_grouped``, ``decode_page_copies``,
@@ -254,6 +281,8 @@ from distributed_pytorch_tpu.serving.kv_cache import (
     PagedBlockAllocator,
     PagePoolGroup,
     PrefixCache,
+    WindowGroup,
+    window_span_pages,
 )
 from distributed_pytorch_tpu.serving.mods import AdapterStore, Mods, ModState
 from distributed_pytorch_tpu.serving.mesh import (
@@ -407,6 +436,7 @@ class InferenceEngine:
         host_pages: Optional[int] = None,
         paged_kernel=False,
         kv_quant: Optional[str] = None,
+        window_pages: Optional[int] = None,
     ):
         # Set-up slices go to the process's tracer, whatever ``tracer`` is
         # (made first, so that its ``process.start`` ends before them):
@@ -491,6 +521,36 @@ class InferenceEngine:
                         f"a model with latent layers cannot be served with "
                         f"{what} yet: {why}"
                     )
+        # Layers that attend inside a window on a block-table GROUP of their
+        # own (``serving/kv_cache.py`` ``WindowGroup``): read from the model.
+        # The full layers' table, allocator and programs are what they are
+        # without; what cannot follow a second group is refused.
+        self.kv_window = int(getattr(model, "kv_window", 0))
+        if self.kv_window:
+            for given, what, why in (
+                (prefix_cache, "prefix_cache=True",
+                 "a prefix hit would need the window layers' last "
+                 f"{self.kv_window - 1} positions, whose pages have gone "
+                 "back to the group's allocator"),
+                (draft_model is not None, "draft_model",
+                 "a speculative round has no path over two table groups"),
+                (host_pages, "host_pages",
+                 "the host tier names pages by the prefix trie's chain"),
+                (mesh is not None, "mesh",
+                 "the serving mesh places ONE group's pools and tables"),
+                (kv_quant, "kv_quant",
+                 "the windowed call reads no scale pools"),
+            ):
+                if given:
+                    raise ValueError(
+                        f"a model with a window group cannot be served with "
+                        f"{what} yet: {why}"
+                    )
+        elif window_pages is not None:
+            raise ValueError(
+                "window_pages sizes a window group's pool, and this model "
+                "has no attention_window layers"
+            )
         # Layers that route tokens to experts (module docstring): their
         # programs return the routing counts, read only under a tracer.
         self.routed_layers = int(getattr(model, "routed_layers", 0))
@@ -552,6 +612,26 @@ class InferenceEngine:
                 clone_kw["mesh"] = mesh
         if self.kv_quant:
             clone_kw["kv_quant"] = self.kv_quant
+        self.window_group = None
+        if self.kv_window:
+            # The group's pool: by default what every slot inside a piece
+            # would hold, so that nothing preempts.
+            piece = window_span_pages(
+                self.kv_window, page_size, max_prefill_chunk
+            )
+            if window_pages is None:
+                window_pages = max_slots * piece + 1
+            if window_pages < piece + 1:
+                raise ValueError(
+                    f"window_pages {window_pages} cannot hold one piece's "
+                    f"{piece} pages and the null page"
+                )
+            group = WindowGroup(
+                PagedBlockAllocator(window_pages), window=self.kv_window,
+                page_size=page_size, chunk=max_prefill_chunk,
+            )
+            self.window_group = group
+            clone_kw["window_num_pages"] = window_pages
         self.decode_model = model.clone(
             decode=True, page_size=page_size, num_pages=num_pages, **clone_kw
         )
@@ -652,6 +732,7 @@ class InferenceEngine:
                 for leaf in jax.tree_util.tree_leaves(self.pools[name])
             ),
             state_bytes=self.state_bytes_per_slot * max_slots,
+            **self.pool_bytes_by_group(),
         )
 
         # Zero-cost-when-disabled observability handle: one shared null
@@ -667,6 +748,8 @@ class InferenceEngine:
         self.allocator.tracer = self.tracer
         self.allocator.flight = self.flight
         self.allocator.pool_names = self.pools.names
+        if self.window_group is not None:
+            self.window_group.allocator.pool_names = ("target/window",)
         self.prefix_cache = (
             PrefixCache(self.allocator, page_size) if prefix_cache else None
         )
@@ -705,6 +788,10 @@ class InferenceEngine:
             debug=debug,
             tracer=self.tracer,
             flight=self.flight,
+            **(
+                {"window_group": self.window_group}
+                if self.window_group is not None else {}
+            ),
         )
         self.admission = AdmissionController(
             max_queue=max_queue,
@@ -827,6 +914,12 @@ class InferenceEngine:
             (max_slots, self.pages_per_seq), np.int32
         )
         self._stage_lens = np.zeros((max_slots,), np.int32)
+        if self.window_group is not None:
+            # The window group's short tables, a decode row's pages wide.
+            self._stage_window_tables = np.zeros(
+                (max_slots, self.window_group.decode_pages), np.int32
+            )
+            self._window_seen = (0, 0)  # pages freed, trims: last step's end
         self._stage_temps = np.zeros((max_slots,), np.float32)
         # A row's base key and, beside it, the index of the token it is
         # about to draw: the programs fold the one into the other.
@@ -860,6 +953,26 @@ class InferenceEngine:
             "engine.init", t_init, time.perf_counter() - t_init,
             slots=max_slots, pages=num_pages,
         )
+
+    def pool_bytes_by_group(self) -> dict:
+        """What ``engine.init.pools`` says of a model with a window group:
+        each group's pools' bytes (nothing where there is one group)."""
+        if self.window_group is None:
+            return {}
+        kinds = self.decode_model.layer_types
+        by_group = {"window_bytes": 0, "full_bytes": 0}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+            self.pools["target"]
+        )[0]:
+            block, _, layer = str(getattr(path[0], "key", "")).partition("_")
+            windowed = (
+                block == "block" and layer.isdigit()
+                and kinds[int(layer)] == "attention_window"
+            )
+            by_group["window_bytes" if windowed else "full_bytes"] += (
+                leaf.nbytes
+            )
+        return by_group
 
     def _default_goodput(self, model) -> GoodputTracker:
         """A :class:`GoodputTracker` configured from the engine's own
@@ -1145,12 +1258,14 @@ class InferenceEngine:
         row_sample = make_row_sampler(self._top_k, self._top_p)
 
         def run(params, cache, tokens, prev, use_prev, tables, lens, temps,
-                keys, bias):
+                keys, bias, *window):
             tok = jnp.where(use_prev > 0, prev, tokens)
             last_logits, cache, *routing = self._forward(
                 params, cache, tok[:, None],
                 block_tables=tables, seq_lens=lens,
                 **self._decode_state_kw(tables, lens),
+                # A window group's short tables (none: the model has none).
+                **({"window_tables": window[0]} if window else {}),
             )
             nxt = row_sample(last_logits, temps, fold_row_keys(keys), bias)
             return (nxt, cache, *routing)
@@ -1281,8 +1396,14 @@ class InferenceEngine:
         the slot whose state the piece carries on. A builder, not a store:
         :attr:`_prefill_programs` keeps what it returns."""
 
-        def run(params, cache, tokens, table, length, valid, *slot):
-            state_kw = {"state_slots": slot[0]} if slot else {}
+        def run(params, cache, tokens, table, length, valid, *more):
+            # With a window group its short table, a piece's pages wide,
+            # comes first; with recurrent layers the slot comes last.
+            state_kw = {}
+            if self.window_group is not None:
+                state_kw["window_tables"], *more = more
+            if more:
+                state_kw["state_slots"] = more[0]
             _, cache, *extras = self._forward(
                 params, cache, tokens, block_tables=table, seq_lens=length,
                 valid_lens=valid, **state_kw,
@@ -1335,6 +1456,9 @@ class InferenceEngine:
             zero, zero,
         )
         no_slot = (jnp.asarray([-1], jnp.int32),) if self.state_layers else ()
+        if self.window_group is not None:  # its null short table goes first
+            no_slot = (jnp.asarray(np.zeros(
+                (1, self.window_group.piece_pages), np.int32)),) + no_slot
         programs = {}
         with process_tracer().setup_phase(
             "engine.build_prefill_programs"
@@ -1369,9 +1493,14 @@ class InferenceEngine:
         if self._acct is not None and req.rework_until > start:
             self._note_rework(req, start, tokens)
         target, draft = self._prefill_programs[width]
+        group = self.window_group
         with self._phase(
             "prefill.chunk", tokens=tokens, start=start, width=width,
             **self.reads.prefill_args(width),
+            # What the piece holds at its widest: its window's pages and
+            # its own, before it gives the former back.
+            **({} if group is None
+               else {"window_pages": len(req.window_table.pages)}),
         ):
             tok = np.zeros((1, width), np.int32)
             tok[0, :tokens] = req.tokens[start : start + tokens]
@@ -1396,8 +1525,11 @@ class InferenceEngine:
                 else self.params
             )
             state_slot = ()
+            if group is not None:
+                state_slot = (jnp.asarray(req.window_table.as_row(
+                    group.piece_pages, group.first_page(start))[None]),)
             if self.state_layers:
-                state_slot = (jnp.asarray([slot], jnp.int32),)
+                state_slot += (jnp.asarray([slot], jnp.int32),)
                 if start == 0:
                     self._note_state_reset(slot, req)
             out = target(params, self.cache, *operands, *state_slot)
@@ -1942,6 +2074,9 @@ class InferenceEngine:
         self._stage_tables.fill(0)
         self._stage_lens.fill(0)
         self._stage_use_prev.fill(0)
+        group = self.window_group
+        if group is not None:
+            self._stage_window_tables.fill(0)
         bias = None
         for slot in slots:
             req = self.scheduler.slots[slot]
@@ -1956,6 +2091,9 @@ class InferenceEngine:
                 self._stage_tokens[slot] = tok
             self._stage_tables[slot] = req.table.as_row(self.pages_per_seq)
             self._stage_lens[slot] = pos
+            if group is not None:
+                self._stage_window_tables[slot] = req.window_table.as_row(
+                    group.decode_pages, group.first_page(pos))
             if pos == 0 and self.state_layers:  # a one-token prompt
                 self._note_state_reset(slot, req)
             self._stage_temps[slot] = req.params.temperature
@@ -1992,10 +2130,14 @@ class InferenceEngine:
             lens = _staged(self._stage_lens)
             temps = _staged(self._stage_temps)
             keys = _staged(self._stage_keys)
+            window = (
+                () if group is None
+                else (_staged(self._stage_window_tables),)
+            )
         with self._phase("dispatch.launch"):
             nxt, self.cache, *extras = decode_step(
                 params, self.cache, tokens, prev, use_prev, tables, lens,
-                temps, keys, bias_arr,
+                temps, keys, bias_arr, *window,
             )
         if self.reads.selection:
             self.selected_positions.append(extras.pop())
@@ -2034,6 +2176,7 @@ class InferenceEngine:
             self._flush_routing()
             self._routing_due = (self.tracer.step_index, self.routing_counts)
         extra.update(self.reads.end_step())
+        extra.update(self._window_step())
         self.tracer.end_step(
             decode_rows=len(plan.decode_slots),
             prefill_programs=len(plan.prefill),
@@ -2049,6 +2192,26 @@ class InferenceEngine:
             pages_cached_idle=pages["pages_cached_idle"],
             **extra,
         )
+
+    def _window_step(self) -> dict:
+        """What a ``step`` slice says of the window group (nothing where the
+        model has none): the pages its tables gave back in the step and hold
+        at its end, the allocator's free count; and the step's
+        ``window.free`` instant, if it freed."""
+        group = self.window_group
+        if group is None:
+            return {}
+        freed, trims = self._window_seen
+        self._window_seen = group.pages_freed, group.trims
+        freed, trims = group.pages_freed - freed, group.trims - trims
+        if freed:
+            self.tracer.instant("window.free", pages=freed, rows=trims)
+        counts = group.allocator.counters()
+        return {
+            "window_pages_freed": freed,
+            "window_pages_held": counts["pages_referenced"],
+            "window_pages_free": counts["pages_free"],
+        }
 
     def step(self) -> List[int]:
         """Run one engine iteration; returns ids of requests that FINISHED
@@ -2711,6 +2874,8 @@ class InferenceEngine:
                 if spilled and self.xla is not None:
                     self.xla.count_d2h(spilled, tag="hostkv_spill")
             self.allocator.assert_quiescent()
+            if self.window_group is not None:
+                self.window_group.allocator.assert_quiescent()
             if self.hostkv is not None:
                 self.hostkv.assert_quiescent()
             if self.flight.enabled:
@@ -2779,6 +2944,12 @@ class InferenceEngine:
         out["pages_allocated"] = self.allocator.num_allocated
         out["pages_idle"] = self.allocator.num_idle
         out["page_evictions"] = self.allocator.evictions
+        if self.window_group is not None:
+            group = self.window_group
+            out["window_pages_freed"] = group.pages_freed
+            out["window_pages_held"] = group.allocator.num_allocated
+            out["window_pages_held_peak"] = group.pages_held_peak
+            out["window_pages_free"] = group.allocator.num_free
         if self.prefix_cache is not None:
             out.update(self.prefix_cache.stats())
         if self.hostkv is not None:

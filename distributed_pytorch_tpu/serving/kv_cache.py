@@ -36,6 +36,15 @@ prefilled once. Invariants that keep sharing copy-free and leak-proof:
   id — and rejected-token rollback is O(1) in both pools for the same
   reason retire is copy-free: reads past ``seq_len`` are masked, so stale
   speculative K/V is dead by construction.
+* **A model's window layers are a GROUP of their own** (:class:`WindowGroup`):
+  pools of their own size, a second allocator over their own id space and a
+  :class:`WindowTable` a sequence, beside the table, allocator and trie above,
+  which the full layers keep unchanged. A window table gives back the pages
+  its window has left behind as the sequence advances, so it is short (a
+  decode row's ``window_pages``) where the full table holds the whole
+  context; the trie never sees its pages (the engine refuses a prefix cache
+  beside such a group), and each allocator's invariants and quiescence are
+  held for its own group.
 """
 
 from __future__ import annotations
@@ -361,6 +370,115 @@ class BlockTable:
         row = np.full((width,), NULL_PAGE, np.int32)
         row[: len(self.pages)] = self.pages
         return row
+
+
+class WindowTable(BlockTable):
+    """One sequence's table in a WINDOW group (:class:`WindowGroup`):
+    ``pages[i]`` is logical page ``first + i``. The pages before ``first``
+    held nothing a window can still meet and have gone back to the group's
+    allocator (:meth:`trim`); the table grows at its end as any table does."""
+
+    def __init__(self):
+        super().__init__()
+        self.first = 0
+
+    def ensure(
+        self, n_tokens: int, page_size: int, allocator: PagedBlockAllocator
+    ) -> int:
+        need = PagedBlockAllocator.pages_needed(n_tokens, page_size)
+        grow = need - self.first - len(self.pages)
+        if grow <= 0:
+            return 0
+        self.pages.extend(allocator.allocate(grow))
+        return grow
+
+    def trim(
+        self, live_from: int, page_size: int, allocator: PagedBlockAllocator
+    ) -> int:
+        """Give back every page wholly before position ``live_from``;
+        returns how many went."""
+        drop = min(live_from // page_size - self.first, len(self.pages))
+        if drop <= 0:
+            return 0
+        allocator.free(self.pages[:drop])
+        del self.pages[:drop]
+        self.first += drop
+        return drop
+
+    def release(self, allocator: PagedBlockAllocator) -> int:
+        self.first = 0
+        return super().release(allocator)
+
+    def as_row(self, width: int, first_page: int = 0) -> np.ndarray:
+        """``[width]`` int32 row of the SHORT table a window layer is
+        handed: entry ``j`` is logical page ``first_page + j`` where the
+        table holds it, the null page elsewhere."""
+        row = np.full((width,), NULL_PAGE, np.int32)
+        skip = first_page - self.first  # of this table's pages
+        held = self.pages[max(skip, 0) : max(skip, 0) + width + min(skip, 0)]
+        at = max(-skip, 0)
+        row[at : at + len(held)] = held
+        return row
+
+
+def window_span_pages(window: int, page_size: int, tokens: int = 1) -> int:
+    """Pages a sequence holds in a window group while ``tokens`` new tokens in
+    a row are written and read: from the first one's window's first key,
+    which may stand last in its page, to the last one's own
+    (``ops/paged_attention.py`` ``window_group_pages``: the same arithmetic,
+    kept free of JAX here)."""
+    return 1 + -(-(window + tokens - 2) // page_size)
+
+
+class WindowGroup:
+    """A block-table GROUP whose layers attend inside a window: a pool size,
+    an allocator and a :class:`WindowTable` a sequence of its own, beside the
+    full layers' (whose table, allocator and trie are what they always
+    were). A query at position ``t`` reads the keys ``(t - window, t]``, so
+    once a sequence has ``len_cached`` positions cached, every page wholly
+    before ``len_cached - (window - 1)`` is dead to it and goes back to the
+    group's allocator (:meth:`trim`, after every prefill piece and decode
+    dispatch: the device runs its programs in the order they were launched,
+    so a page handed on is written by a LATER program than the last that
+    read it). A decoding sequence so holds at most ``decode_pages`` pages
+    here and a sequence inside a piece of ``chunk`` tokens ``piece_pages``.
+
+    ``free_ahead`` moves the rule that many tokens forward; 0 is the rule.
+    The tests and the benchmark's control plant 1 (a page freed one step
+    early), and nothing else sets it."""
+
+    free_ahead = 0
+
+    def __init__(
+        self, allocator: PagedBlockAllocator, *, window: int, page_size: int,
+        chunk: int,
+    ):
+        if window < 1:
+            raise ValueError(f"a window group needs a window, got {window}")
+        self.allocator = allocator
+        self.window = window
+        self.page_size = page_size
+        # What the layers' short tables are wide.
+        self.decode_pages = window_span_pages(window, page_size)
+        self.piece_pages = window_span_pages(window, page_size, chunk)
+        self.pages_freed = 0  # lifetime, by trim alone
+        self.trims = 0  # the trims among them that freed a page
+        self.pages_held_peak = 0  # the most ONE sequence held at once
+
+    def first_page(self, position: int) -> int:
+        """The logical page a short table's first entry stands for when its
+        first new token is at ``position``."""
+        return max(position - (self.window - 1), 0) // self.page_size
+
+    def trim(self, table: WindowTable, len_cached: int) -> int:
+        live_from = max(0, len_cached - (self.window - 1) + self.free_ahead)
+        freed = table.trim(live_from, self.page_size, self.allocator)
+        self.pages_freed += freed
+        self.trims += freed > 0
+        return freed
+
+    def note_held(self, table: WindowTable) -> None:
+        self.pages_held_peak = max(self.pages_held_peak, len(table.pages))
 
 
 class PagePoolGroup:

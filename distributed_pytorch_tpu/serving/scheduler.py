@@ -68,6 +68,17 @@ Design decisions, in the order they bite:
   garbage can never be cached, and copy-on-write is decided on the one
   page containing ``len_cached`` exactly as in the single-token path —
   every later page a round touches was freshly allocated for this row.
+* **A model may name a second block-table group** (``window_group``, a
+  :class:`~.kv_cache.WindowGroup`: the layers that attend inside a window).
+  A sequence then holds a table in each group, ``table`` on ``allocator`` as
+  ever and ``window_table`` on the group's own allocator; pages are ensured
+  in both before a piece or a decode row is planned (a shortage in EITHER
+  preempts by the one priority rule), released from both on retire, cancel
+  and preemption (a preempted sequence is prefilled again from position 0),
+  and after every piece and decode dispatch the window table gives back the
+  pages its window has left behind. Such a request is planned ONE piece a
+  step: a piece's pages are reckoned after the one before has given its
+  own back.
 """
 
 from __future__ import annotations
@@ -85,6 +96,9 @@ from distributed_pytorch_tpu.serving.kv_cache import (
     OutOfPages,
     PagedBlockAllocator,
     PrefixCache,
+    WindowGroup,
+    WindowTable,
+    window_span_pages,
 )
 
 # Placeholder for a sampled token whose device readback has not landed yet
@@ -181,6 +195,9 @@ class Request:
     generated: List[int] = dataclasses.field(default_factory=list)
     len_cached: int = 0
     table: BlockTable = dataclasses.field(default_factory=BlockTable)
+    # The sequence's table in the model's window group (empty and unused
+    # where the scheduler has none).
+    window_table: WindowTable = dataclasses.field(default_factory=WindowTable)
     state: RequestState = RequestState.WAITING
     slot: Optional[int] = None
     submit_time: float = 0.0
@@ -312,7 +329,14 @@ class Scheduler:
         debug: bool = False,
         tracer=NULL_TRACER,
         flight=NULL_FLIGHT_RECORDER,
+        window_group: Optional[WindowGroup] = None,
     ):
+        if window_group is not None and (prefix_cache is not None or gamma):
+            raise ValueError(
+                "a window group is planned with neither a prefix cache (a "
+                "hit would need the window layers' pages, which are gone) "
+                "nor speculative rounds"
+            )
         if token_budget < 1:
             raise ValueError(f"token_budget must be >= 1, got {token_budget}")
         if gamma < 0:
@@ -332,6 +356,7 @@ class Scheduler:
         self.max_prefill_chunk = max_prefill_chunk
         self.prefill_granule = min(PREFILL_GRANULE, max_prefill_chunk)
         self.prefix_cache = prefix_cache
+        self.window_group = window_group
         self.gamma = gamma
         self.debug = debug
         self.tracer = tracer
@@ -440,6 +465,7 @@ class Scheduler:
                 prompt_tokens=len(req.prompt),
                 hit=req.len_cached > 0,
                 readmission=req.preempt_count > 0,
+                **self._group_pages(req),
             )
         if self.flight.enabled:
             self.flight.record(
@@ -451,6 +477,30 @@ class Scheduler:
                 readmission=req.preempt_count > 0,
                 **_flight_trace(req),
             )
+
+    def _group_pages(self, req: Request) -> dict:
+        """What an ``admit`` event says of a model with a window group: the
+        pages the request comes to hold in each group if it runs to its
+        last token (nothing where there is one group)."""
+        group = self.window_group
+        if group is None:
+            return {}
+        full = PagedBlockAllocator.pages_needed(
+            len(req.prompt) + req.params.max_new_tokens, self.page_size
+        )
+        piece = min(self.max_prefill_chunk, max(1, len(req.prompt) - 1))
+        return {
+            "pages_full": full,
+            "pages_window": min(full, window_span_pages(
+                group.window, self.page_size, piece
+            )),
+        }
+
+    def _release(self, req: Request) -> None:
+        """Drop ``req``'s pages in every group."""
+        if self.window_group is not None:
+            req.window_table.release(self.window_group.allocator)
+        req.table.release(self.allocator)
 
     def _admit_host_pages(self, req: Request, plan: StepPlan) -> int:
         """Extend ``req``'s device prefix match into the HOST tier: for
@@ -525,7 +575,7 @@ class Scheduler:
                 pages_released=len(req.table.pages),
                 **_flight_trace(req),
             )
-        req.table.release(self.allocator)
+        self._release(req)
         self.slots[req.slot] = None
         req.slot = None
         req.len_cached = 0
@@ -554,7 +604,7 @@ class Scheduler:
                     tuple(req.tokens[start:valid]),
                     req.table.pages[req.trie_pages],
                 )
-        req.table.release(self.allocator)
+        self._release(req)
         if req.slot is not None:
             self.slots[req.slot] = None
         elif req.state is RequestState.WAITING:
@@ -598,12 +648,12 @@ class Scheduler:
         if req.done:
             return False
         if req.slot is not None:
-            req.table.release(self.allocator)
+            self._release(req)
             self.slots[req.slot] = None
             req.slot = None
         elif req.state is RequestState.WAITING:
             self.waiting.remove(req)
-            req.table.release(self.allocator)  # empty by invariant
+            self._release(req)  # empty by invariant
         req.state = state
         req.finish_time = time.perf_counter() if now is None else now
         if state is RequestState.EXPIRED:
@@ -661,9 +711,15 @@ class Scheduler:
         """Cover ``n_tokens`` positions of ``req``'s table, preempting
         strictly lower-priority victims as needed. Returns False — after
         preempting ``req`` itself — when even that cannot free enough."""
+        group = self.window_group
         while True:
             try:
                 req.table.ensure(n_tokens, self.page_size, self.allocator)
+                if group is not None:
+                    req.window_table.ensure(
+                        n_tokens, self.page_size, group.allocator
+                    )
+                    group.note_held(req.window_table)
                 return True
             except OutOfPages:
                 if not self._reclaim_for(req):
@@ -782,6 +838,8 @@ class Scheduler:
                 plan.prefill.append((slot, piece))
                 planned += piece
                 budget -= piece
+                if self.window_group is not None:
+                    break  # one piece a step (module docstring)
             if req.state is not RequestState.PREFILL:
                 # Preempted while growing: drop any pieces already planned
                 # for its (now free) slot.
@@ -821,6 +879,8 @@ class Scheduler:
             plan.fetches = kept
         if self.debug:
             self.allocator.check_invariants()
+            if self.window_group is not None:
+                self.window_group.allocator.check_invariants()
         return plan
 
     # ----------------------------------------------------------- execution
@@ -861,6 +921,8 @@ class Scheduler:
         assert req.len_cached <= len(req.tokens) - 1, (
             f"request {req.req_id} prefilled past its last token"
         )
+        if self.window_group is not None:
+            self.window_group.trim(req.window_table, req.len_cached)
         self._register_filled(req)
         if req.remaining_prefill == 0:
             req.state = RequestState.DECODE
@@ -878,6 +940,8 @@ class Scheduler:
         assert req.len_cached == len(req.tokens), (
             f"request {req.req_id} decode out of sync"
         )
+        if self.window_group is not None:
+            self.window_group.trim(req.window_table, req.len_cached)
         req.pending_idx.append(len(req.tokens))
         req.tokens.append(PENDING_TOKEN)
         return req

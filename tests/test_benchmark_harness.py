@@ -4,7 +4,7 @@
 trace reduction it tests decides every per-layer metric: a package change
 that renames a slice would break a metric's reader unseen. This file puts
 ``benchmarks/`` on ``sys.path`` as ``benchmarks/tests/conftest.py`` does and
-hands pytest the cases of the eleven fast files there, each as a case of a
+hands pytest the cases of the twelve fast files there, each as a case of a
 class named after its file, so two files may each have a fixture ``spec``.
 The rehearsal files stay outside, for their 40-90 s each.
 
@@ -28,7 +28,7 @@ if BENCH not in sys.path:
 FILES = ("test_phases", "test_ssm_readers", "test_trace_reduction", "test_control",
          "test_kernel_readers", "test_moe_readers", "test_prefill_readers",
          "test_latent_readers", "test_setup_readers", "test_dsa_readers",
-         "test_gdn_readers")
+         "test_gdn_readers", "test_window_readers")
 
 
 def cases_of(stem):

@@ -613,6 +613,155 @@ def test_a_latent_pool_of_576_is_refused_by_mosaic(chip):
         latent_case(chip, width=576).compile()
 
 
+# ---------------------------------------------------------- a window group
+#
+# ``k-exaone-236b-a23b`` (PR 46): K/V layers with a window of 128 on block
+# tables of their own. The windowed call at the cell's shapes (32 rows, 64
+# query heads on 8 KV heads of 128, short tables of 9 pages over the window
+# group's ``[513, 16, 8, 128]`` pools), and the cell's programs over one
+# period of its layers (a dense layer and three sparse ones; three window
+# layers and a full one).
+
+EXAONE = dict(slots=32, heads=64, kv_heads=8, window=128, window_pages=513)
+
+
+def window_paged_case(chip):
+    from distributed_pytorch_tpu.ops.paged_attention import (
+        paged_window_attention,
+        window_pages,
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    s = EXAONE
+    pool = (s["window_pages"], PAGE, s["kv_heads"], HEAD_DIM)
+    fn = functools.partial(
+        paged_window_attention, window=s["window"], kernel="pallas",
+        pages_per_block=PAGED_DEFAULT_TABLE[KIND])
+    return jax.jit(fn).lower(
+        arg((s["slots"], 1, s["heads"], HEAD_DIM), jnp.bfloat16),
+        arg(pool, jnp.bfloat16), arg(pool, jnp.bfloat16),
+        arg((s["slots"], window_pages(s["window"], PAGE)), jnp.int32),
+        arg((s["slots"],), jnp.int32))
+
+
+def exaone_program(chip, t_step, layers=4):
+    """A serving program of the ``k-exaone-236b-a23b`` configuration at its
+    own shapes (published widths, the cell's engine and BOTH its table
+    groups), lowered for the described chip on abstract operands: the decode
+    step over all 32 slots (``t_step`` 1, through both K/V kernels and the
+    experts') or a prefill piece of ``t_step`` tokens. ``layers`` of the 8:
+    one whole period (the whole depth compiled by hand: PERF.md 4)."""
+    import json
+
+    from hybrid_toy import ROOT, load_by_path
+
+    from distributed_pytorch_tpu.ops.paged_attention import window_group_pages
+
+    reference = load_by_path("benchmarks/reference/exaone_moe.py")
+    driver = load_by_path("benchmarks/drivers/serve_window_moe.py")
+    with open(os.path.join(
+            ROOT, "benchmarks", "configs", "k-exaone-236b-a23b.json")) as f:
+        cfg = json.load(f)
+    cfg = dict(cfg, num_hidden_layers=layers, **{
+        key: cfg[key][:layers]
+        for key in ("layer_types", "mlp_layer_types", "sliding_windows")})
+    engine = cfg["assumed"]["engine"]
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+            tree)
+
+    weights = jax.eval_shape(lambda: reference.make_weights(cfg, 0))
+    model, params = driver.build_program(cfg, weights)
+    decode_model = model.clone(
+        decode=True, page_size=engine["page_size"],
+        num_pages=engine["num_pages"],
+        window_num_pages=engine["window_pages"], paged_kernel="pallas")
+    slots = engine["max_slots"]
+    rows = slots if t_step == 1 else 1
+    cache = jax.eval_shape(
+        decode_model.init, jax.random.PRNGKey(0),
+        jnp.zeros((slots, 1), jnp.int32))["cache"]
+    pages_per_seq = engine["max_seq_len"] // engine["page_size"]
+    short = window_group_pages(
+        cfg["sliding_window"], engine["page_size"],
+        1 if t_step == 1 else engine["max_prefill_chunk"])
+
+    def run(params, cache, tokens, tables, lens, valid, window_tables, slots):
+        kw = {} if t_step == 1 else {"valid_lens": valid}
+        logits, updated = decode_model.apply(
+            {"params": params, "cache": cache}, tokens, block_tables=tables,
+            seq_lens=lens, window_tables=window_tables, state_slots=slots,
+            mutable=["cache", "routing"], **kw)
+        return logits[:, -1], updated["cache"]
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    return jax.jit(run, donate_argnums=(1,)).lower(
+        abstract(params), abstract(cache), arg((rows, t_step)),
+        arg((rows, pages_per_seq)), arg((rows,)), arg((rows,)),
+        arg((rows, short)), arg((rows,)))
+
+
+CASES.update({
+    "window-paged-k-exaone": window_paged_case,
+    "k-exaone-cell-decode": functools.partial(exaone_program, t_step=1),
+})
+
+
+def test_the_windowed_call_is_named_and_copies_no_pool(chip):
+    """``benchmarks/harness/window.py`` tells the window layers' calls from
+    the full layers' by the kernel's name; a row's 9 pages are ONE block (the
+    scratch holds two buffers of 9 pages a pool); neither pool is copied."""
+    from distributed_pytorch_tpu.ops.paged_attention import KV_WINDOW_KERNEL
+
+    compiled = window_paged_case(chip).compile()
+    text = compiled.as_text()
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line)
+    assert KV_WINDOW_KERNEL in call.split(" = ", 1)[0]
+    assert not whole_pool_copies(compiled, EXAONE["window_pages"])
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
+
+
+def test_the_exaone_cells_decode_program_calls_both_kernels(chip):
+    """Three calls of the windowed kernel and one of the full layers' in a
+    period, the experts' products beside them, and no pool of either group
+    copied whole."""
+    compiled = exaone_program(chip, 1).compile()
+    names = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert sum(n.startswith("attention._window_paged_decode_step")
+               for n in names) == 3
+    assert sum(n.startswith("attention._paged_decode_step")
+               for n in names) == 1
+    assert sum(n.startswith("ragged-dot-stationary") for n in names) == 6
+    assert not whole_pool_copies(compiled, 513)
+    assert not whole_pool_copies(compiled, 9217)
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("t_step", [64, 512])
+def test_the_exaone_cells_prefill_programs_compile_for_v5e(chip, t_step):
+    """A piece attends through the gather path in both groups (the window
+    layers over their short table of 41 pages, the full layers over the
+    whole table): no attention kernel, the experts' products, and under a
+    gigabyte beside the weights and the pools."""
+    compiled = exaone_program(chip, t_step).compile()
+    names = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert names and all(n.startswith("ragged-dot-stationary") for n in names)
+    assert not whole_pool_copies(compiled, 513)
+    assert not whole_pool_copies(compiled, 9217)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(chip, name):
     assert "tpu_custom_call" in compiled_text(chip, name)

@@ -438,3 +438,52 @@ def test_the_products_mode_follows_the_blocks_kernel(monkeypatch):
     assert moe.product_mode("auto", 2048, 1408) == "pallas"
     assert moe.product_mode("auto", D, F) == "xla"
     assert moe.product_mode("pallas", 256, 64) == "xla"
+
+
+# --------------------------- the exaone_moe rule: sigmoid, a bias, gates x 2.5
+
+
+def test_the_eight_shares_of_scaled_sigmoid_gates_are_the_whole_layer(mode):
+    """``k-exaone-236b-a23b``'s routed layer on the deployment's eight chips:
+    sigmoid scores, a correction bias for the CHOICE alone, gates renormalised
+    and multiplied by 2.5 (``RoutedExperts.scale``). Each chip's part is the
+    reference's given the same share; the eight parts and ONE shared expert
+    add up to the uncut reference's feed-forward; and the scale is in it."""
+    import exaone_toy
+
+    ref = exaone_toy.reference
+    cfg = dict(exaone_toy.TOY, hidden_size=D, moe_intermediate_size=F,
+               num_experts=E, num_experts_per_tok=K)
+    rng = np.random.default_rng(31)
+    f = lambda *s: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)  # noqa: E731
+    w = {"router": f(D, E), "router_bias": f(E) / 3, "we_in": f(E, D, 2 * F),
+         "we_out": f(E, F, D), "ws_gate": f(D, F), "ws_up": f(D, F),
+         "ws_down": f(F, D)}
+    x = tokens_in(24, seed=32)
+
+    def part(held, scale=2.5):
+        lo, hi = held
+        layer = RoutedExperts(
+            E, K, F, D, held=held, paged_kernel=mode, gating="sigmoid_biased",
+            scale=scale)
+        return np.asarray(layer.apply({"params": {
+            "router_kernel": w["router"], "router_bias": w["router_bias"],
+            "in_kernel": w["we_in"][lo:hi],
+            "out_kernel": w["we_out"][lo:hi]}}, x[None])[0])
+
+    def wanted(held):
+        lo, hi = held
+        cut = dict(w, we_in=w["we_in"][lo:hi], we_out=w["we_out"][lo:hi])
+        return np.asarray(ref.routed_experts(x, cut, cfg=dict(
+            cfg, num_experts=hi - lo, experts_held=[lo, hi],
+            num_experts_published=E), einsum=jnp.einsum)[0])
+
+    parts = [part((e, e + 1)) for e in range(E)]
+    for e, got in enumerate(parts):
+        assert np.abs(got - wanted((e, e + 1))).max() < TOL
+    shared = np.asarray(ref.gated_mlp(
+        x, w["ws_gate"], w["ws_up"], w["ws_down"], jnp.einsum))
+    whole = wanted((0, E)) + shared
+    assert np.abs(sum(parts) + shared - whole).max() < 2 * TOL
+    assert np.abs(part((0, E)) - 2.5 * part((0, E), scale=1.0)).max() < 2 * TOL
+    assert np.abs(part((0, E), scale=1.0)).max() > 0.01

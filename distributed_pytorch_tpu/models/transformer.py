@@ -152,17 +152,17 @@ class Attention(nn.Module):
     # __call__ every step.
     page_size: int = 0
     num_pages: int = 0
-    # Serving decode read path for the paged cache: "" keeps the inline XLA
-    # gather math below (the bitwise reference), anything else names an
-    # ops/paged_attention kernel mode ("auto" | "pallas" | "interpret" |
-    # "xla"). Only single-token decode steps (t_step == 1) dispatch to the
-    # kernel; prefill chunks and speculative verify always use the inline
-    # math, which the kernel's fp path matches bitwise by construction.
+    # Serving decode read path for the paged cache: "" is ops/paged_attention's
+    # XLA gather reference (as "xla"), anything else names a kernel mode
+    # ("auto" | "pallas" | "interpret" | "xla"). Only single-token decode
+    # steps (t_step == 1) dispatch to the kernel; prefill chunks and
+    # speculative verify are the op's blockwise walk over the blocks their
+    # rows hold, whatever this says.
     paged_kernel: str = ""
     # "" = fp pages (pool dtype follows the activations); "int8" = symmetric
     # absmax per-(token, head) int8 pages with [num_pages, page_size, Hkv]
     # float32 scale pools, quantized at every page write, dequantized at
-    # read (inline gather or inside the kernel).
+    # read (by ops/paged_attention: gather, walk or kernel).
     kv_quant: str = ""
     # False: no positional term at all (a hybrid model whose recurrent
     # layers carry position). Every path below then attends on the raw
@@ -522,15 +522,16 @@ class Attention(nn.Module):
         a row's ``T_step`` tokens are its own; ``None`` means all of them.
 
         Same math as :meth:`_decode_step` — RoPE at absolute positions,
-        write-then-attend, grouped GQA einsums — except positions are
+        write-then-attend, grouped GQA products — except positions are
         per-row, the write is a scatter into (physical page, offset), and the
-        read gathers each row's pages into a [S, pages*page_size, Hkv, D]
-        view. Rows whose table is all zeros (inactive slots) write into the
-        null page; what they read is discarded and finite either way: on the
-        gather path garbage that the visibility mask averages (the null page
-        only ever holds finite values written by other inactive rows), in
-        the Pallas kernel nothing at all (``ops/paged_attention.py`` gives a
-        row whose table starts at the null page zeros).
+        read is ``ops/paged_attention.py``'s ``paged_attention`` at every
+        ``T_step``: it alone knows how pages are read. Rows whose table is
+        all zeros (inactive slots) write into the null page; what they read
+        is discarded and finite either way: on the gather path and in a
+        chunk's walk garbage that the visibility mask averages (the null
+        page only ever holds finite values written by other inactive rows),
+        in the Pallas kernel nothing at all (a row whose table starts at the
+        null page gets zeros).
         """
         cached_key = self.variable("cache", "cached_key", lambda: None)
         cached_value = self.variable("cache", "cached_value", lambda: None)
@@ -547,7 +548,6 @@ class Attention(nn.Module):
         positions = seq_lens[:, None] + jnp.arange(t_step, dtype=jnp.int32)
         q = self._rope(q_raw, positions)
         k = self._rope(k_raw, positions)
-        heads_out = h
         held = cached_key.value.shape[2]
         if held != kv_heads:
             # The pool holds padding heads (``pool_kv_heads``): zeros for
@@ -561,7 +561,7 @@ class Attention(nn.Module):
                 return x.reshape(s, t_step, held * group, d)
 
             q, k, v = widen(q, h // kv_heads), widen(k, 1), widen(v, 1)
-            h, kv_heads = held * (h // kv_heads), held
+            kv_heads = held
 
         # Scatter this step's K/V into (physical page, in-page offset). A
         # position at or past the row's table capacity — a speculative
@@ -608,56 +608,22 @@ class Attention(nn.Module):
                 v.astype(cached_value.value.dtype).reshape(-1, kv_heads, d)
             )
 
-        if self.paged_kernel and t_step == 1:
-            # Fused read path: the batched single-token decode step goes
-            # through ops/paged_attention (Pallas on TPU, its XLA reference
-            # elsewhere — which reproduces the inline math below bitwise).
-            # Prefill chunks and speculative verify (t_step > 1) keep the
-            # inline math: they are a tiny fraction of decode-step count and
-            # the kernel's one-query-row grid doesn't fit them.
-            from distributed_pytorch_tpu.ops.paged_attention import (
-                paged_attention,
-            )
-
-            return paged_attention(
-                q, cached_key.value, cached_value.value, block_tables,
-                seq_lens,
-                k_scale=None if key_scale is None else key_scale.value,
-                v_scale=None if value_scale is None else value_scale.value,
-                kernel=self.paged_kernel, mesh=self.mesh,
-                sm_scale=self.score_scale,
-            )[:, :, :heads_out]
-
-        # Gather each row's pages into its contiguous logical view. K below
-        # is pages_per_seq * page_size — the row's maximum context, not the
-        # pool size.
-        keys = cached_key.value[block_tables].reshape(
-            s, pages_per_seq * page, kv_heads, d
+        # The one read of K/V pages, behind ``ops/``: a decode step through the
+        # Pallas kernel (with it off, the gather reference), a chunk (a
+        # prefill piece, a speculative round's verification) as a walk over
+        # the blocks its rows hold.
+        from distributed_pytorch_tpu.ops.paged_attention import (
+            paged_attention,
         )
-        values = cached_value.value[block_tables].reshape(
-            s, pages_per_seq * page, kv_heads, d
-        )
-        if self.kv_quant:
-            ks = key_scale.value[block_tables].reshape(
-                s, pages_per_seq * page, kv_heads
-            )
-            vs = value_scale.value[block_tables].reshape(
-                s, pages_per_seq * page, kv_heads
-            )
-            keys = keys.astype(q.dtype) * ks[..., None].astype(q.dtype)
-            values = values.astype(q.dtype) * vs[..., None].astype(q.dtype)
-        scale = self._scale(d)
-        k_abs = jnp.arange(pages_per_seq * page)[None, None, :]
-        visible = k_abs <= positions[:, :, None]  # [S, T_step, K]
-        group = h // kv_heads
-        qg = q.reshape(s, t_step, kv_heads, group, d)
-        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, keys) * scale
-        logits = jnp.where(visible[:, None, None], logits, NEG_INF)
-        weights = jax.nn.softmax(
-            logits.astype(jnp.float32), axis=-1
-        ).astype(q.dtype)
-        out = jnp.einsum("bhgqk,bkhd->bqhgd", weights, values)
-        return out.reshape(s, t_step, h, d)[:, :, :heads_out]
+
+        return paged_attention(
+            q, cached_key.value, cached_value.value, block_tables, seq_lens,
+            valid_lens=valid_lens,
+            k_scale=None if key_scale is None else key_scale.value,
+            v_scale=None if value_scale is None else value_scale.value,
+            kernel=self.paged_kernel or "xla", mesh=self.mesh,
+            sm_scale=self.score_scale,
+        )[:, :, :h]
 
     def _window_paged_step(
         self, q_raw, k_raw, v, block_tables, seq_lens, valid_lens=None

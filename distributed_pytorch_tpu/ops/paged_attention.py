@@ -3,7 +3,7 @@
 The serving decode hot path reads the paged KV cache — a global per-layer
 pool ``[num_pages, page_size, Hkv, D]`` addressed through per-sequence block
 tables — and until this module existed it did so via a plain-XLA gather
-(``models/transformer.py:_paged_decode_step``): materialize every row's
+(now :func:`paged_attention_reference`): materialize every row's
 ``[pages_per_seq * page_size, Hkv, D]`` logical view in HBM, then attend.
 ``obs/roofline.py`` classifies that program bandwidth-bound; the gather
 writes and re-reads the whole working set once per generated token.
@@ -49,12 +49,20 @@ walks only the KV a row has:
   key, are applied to the block's scores and weights instead of its tiles:
   the same product in float32, in another order.
 
-``paged_attention_reference`` is the pure-XLA fallback: op-for-op the read
-side of ``_paged_decode_step``, so an engine toggling the kernel off is
-bitwise-identical to the pre-kernel engine. Mode resolution ("auto") uses
-the kernel on TPU and the reference elsewhere; ``kernel="interpret"`` runs
-the Pallas kernel through the interpreter — the CPU test rig's way of
-exercising the real kernel code path.
+``paged_attention_reference`` is the pure-XLA fallback of a decode step: the
+dense read (each row's whole table gathered, a one-shot softmax), what the
+engine did before the kernel and what every other path is tested against.
+Mode resolution ("auto") uses the kernel on TPU and the reference elsewhere;
+``kernel="interpret"`` runs the Pallas kernel through the interpreter — the
+CPU test rig's way of exercising the real kernel code path.
+
+A CHUNK of queries (``T_step > 1``: a prefill piece, a speculative round's
+verification) is neither: :func:`_paged_walk`, plain XLA, a ``fori_loop``
+over blocks of :data:`WALK_BLOCK_TOKENS` keys with an online softmax whose
+trip count is the blocks the longest row HOLDS (:func:`chunk_keys_walked`),
+so a piece at position 300 of a 4,096-key table scores 1,024 keys a query
+and not 4,096. :func:`paged_attention` is the one read of K/V pages at every
+``T_step``; ``models/transformer.py`` holds no gather of its own.
 
 GSPMD cannot partition a ``pallas_call``, so under a sharded jit pass
 ``mesh`` (as :class:`models.transformer.Attention` does): the kernel then
@@ -151,10 +159,11 @@ def paged_attention_reference(
     v_width: Optional[int] = None,
     window: int = 0,
 ) -> jnp.ndarray:
-    """The XLA gather path: op-for-op the read side of
-    ``_paged_decode_step`` (gather each row's pages into its contiguous
-    logical view, positional visibility mask, grouped GQA einsums, f32
-    softmax) — the bitwise-parity anchor the kernel is tested against.
+    """The XLA gather path, the DENSE read (gather each row's pages into its
+    contiguous logical view, positional visibility mask, grouped GQA
+    einsums, f32 softmax over the table's width): a decode step with the
+    kernel off, and the anchor the kernel and the chunk walk are tested
+    against.
 
     ``q`` [S, T_step, H, D] is post-RoPE; ``seq_lens`` [S] is each row's
     token count BEFORE the step (= the absolute position of its first new
@@ -530,6 +539,117 @@ def _paged_flash(
     )(*prefetch, q3, *operands)
 
 
+#: Key positions a block of the chunk walk (:func:`_paged_walk`) holds: the
+#: best ONE size over the cells' four geometries and every width and start of
+#: a piece (``tools/bench_prefill_attention.py``, PERF.md section 6, PR 47).
+WALK_BLOCK_TOKENS = 512
+
+
+def walk_block_pages(pages_per_seq: int, page: int) -> int:
+    """Pages in a block of the chunk walk over a table ``pages_per_seq``
+    wide: :data:`WALK_BLOCK_TOKENS`, held to the table (a table of at most
+    one block is one trip)."""
+    return max(1, min(WALK_BLOCK_TOKENS // page, pages_per_seq))
+
+
+def _blocks_walked(n_keys, pages_per_seq: int, page: int, bp: int):
+    """Blocks of ``bp`` pages that hold the first ``n_keys`` key positions of
+    a table ``pages_per_seq`` wide: at least one, at most the table's.
+    NumPy, an int or traced."""
+    xp = jnp if isinstance(n_keys, jax.Array) else np
+    return xp.clip(-(-n_keys // (bp * page)), 1, -(-pages_per_seq // bp))
+
+
+def chunk_keys_walked(n_keys, pages_per_seq: int, page: int):
+    """Key positions the chunk walk gathers and scores, a row, where its
+    longest row holds ``n_keys`` keys once the chunk is written (``start +
+    tokens`` of a prefill piece): whole blocks. The walk's trip count and the
+    host's count of it (``serving/decode_reads.py``) are one rule,
+    :func:`_blocks_walked` at :func:`walk_block_pages`."""
+    bp = walk_block_pages(pages_per_seq, page)
+    return _blocks_walked(n_keys, pages_per_seq, page, bp) * (bp * page)
+
+
+@functools.partial(jax.jit, static_argnames=("bp", "sm_scale"))
+def _paged_walk(
+    q, k_pool, v_pool, block_tables, seq_lens, valid_lens=None, k_scale=None,
+    v_scale=None, *, bp, sm_scale=None,
+):
+    """Attention of a CHUNK of queries ``q`` [S, T_step, H, D] (a prefill
+    piece; a speculative round's verification) over the rows' K/V pages, a
+    block of pages at a time with an online softmax, over the blocks that
+    hold a key some query can see and no others: a piece that starts at 300
+    in a table of 4,096 walks two blocks of 512, not eight. The trip count
+    is traced (:func:`chunk_keys_walked` of the longest row), so one program
+    serves every length; ``models/mla.py`` ``_attend_blocks`` is the same walk
+    over latent pages. Jitted, as :func:`_paged_flash` is and for its reason:
+    a model's layers all call it at one shape and share ONE trace and one
+    lowering a program (30 layers x 8 prefill programs traced a layer at a
+    time cost ``sc2-3b-completion`` 5.7 s of set-up; PERF.md section 6, PR
+    47); ``bp``, the block in pages, is static and its caller's to look up.
+
+    The reference's operands and precision: products of the pool's type,
+    scores, softmax statistics and accumulator in float32; int8 pages are
+    dequantised a block at a time. Key 0 is visible to every query, so the
+    running max is finite from the first block on; a row shorter than the
+    longest sees nothing in the blocks past its own and its state passes
+    through them unchanged. The padding of a piece sees what the walked
+    blocks hold below its position, and is its caller's to throw away."""
+    s, t_step, h, d = q.shape
+    page, kv_heads = k_pool.shape[1:3]
+    pages_per_seq = block_tables.shape[1]
+    bkv = bp * page
+    tables = jnp.pad(
+        block_tables, ((0, 0), (0, -(-pages_per_seq // bp) * bp - pages_per_seq))
+    )
+    lens = seq_lens.astype(jnp.int32)
+    held = lens + (t_step if valid_lens is None else valid_lens)
+    n_blocks = _blocks_walked(jnp.max(held), pages_per_seq, page, bp)
+    positions = lens[:, None] + jnp.arange(t_step, dtype=jnp.int32)
+    scale = d**-0.5 if sm_scale is None else sm_scale
+    # Heads lead, as the products' results are laid out: transposed once,
+    # outside the loop.
+    qg = q.reshape(s, t_step, kv_heads, h // kv_heads, d).transpose(
+        0, 2, 3, 1, 4
+    )
+
+    def block(j, carry):
+        m_prev, l_prev, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
+        keys = k_pool[ids].reshape(s, bkv, kv_heads, d)
+        values = v_pool[ids].reshape(s, bkv, kv_heads, d)
+        if k_scale is not None:
+            ks = k_scale[ids].reshape(s, bkv, kv_heads)
+            vs = v_scale[ids].reshape(s, bkv, kv_heads)
+            keys = keys.astype(q.dtype) * ks[..., None].astype(q.dtype)
+            values = values.astype(q.dtype) * vs[..., None].astype(q.dtype)
+        scores = jnp.einsum(
+            "bhgqd,bkhd->bhgqk", qg, keys,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        k_abs = j * bkv + jnp.arange(bkv, dtype=jnp.int32)
+        visible = k_abs[None, None, :] <= positions[:, :, None]  # [S, T, K]
+        scores = jnp.where(visible[:, None, None], scores, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
+        p = jnp.exp(scores - m_new[..., None])
+        correction = jnp.exp(m_prev - m_new)
+        l_new = l_prev * correction + jnp.sum(p, axis=-1)
+        pv = jnp.einsum(
+            "bhgqk,bkhd->bhgqd", p.astype(values.dtype), values,
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l_new, acc * correction[..., None] + pv
+
+    stats = (s, kv_heads, h // kv_heads, t_step)
+    _, l_fin, acc = jax.lax.fori_loop(0, n_blocks, block, (
+        jnp.full(stats, NEG_INF, jnp.float32),
+        jnp.zeros(stats, jnp.float32),
+        jnp.zeros(stats + (d,), jnp.float32),
+    ))
+    out = (acc / l_fin[..., None]).astype(q.dtype)  # [S, Hkv, G, T, D]
+    return out.transpose(0, 3, 1, 2, 4).reshape(s, t_step, h, d)
+
+
 def paged_attention(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
@@ -537,6 +657,7 @@ def paged_attention(
     block_tables: jnp.ndarray,
     seq_lens: jnp.ndarray,
     *,
+    valid_lens: Optional[jnp.ndarray] = None,
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     kernel="auto",
@@ -545,19 +666,25 @@ def paged_attention(
     heads_axis: str = "model",
     sm_scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Paged attention over ``q`` [S, T_step, H, D] against the page pools.
+    """Paged attention over ``q`` [S, T_step, H, D] against the page pools:
+    the one read of K/V pages, at every ``T_step``.
 
-    Kernel-eligible steps (T_step == 1, the batched decode step) dispatch
-    per ``kernel`` (see :func:`resolve_kernel`); chunked reads — prefill
-    chunks, speculative verification — always take the XLA reference, which
-    handles any T_step. ``pages_per_block`` defaults to the autotune
-    harness' ``paged_decode`` family entry for this shape. ``sm_scale``
+    A single-token step (the batched decode step) dispatches per ``kernel``
+    (see :func:`resolve_kernel`): the Pallas kernel, or with it off the XLA
+    reference. A CHUNK (``T_step > 1``: a prefill piece, a speculative
+    round's verification) is the blockwise walk, :func:`_paged_walk`,
+    whatever ``kernel`` says; ``valid_lens`` [S] tells it how many of a padded
+    piece's tokens are the rows' own (``None``: all), so that it walks no
+    block for the padding's sake. ``pages_per_block`` defaults to the
+    autotune harness' ``paged_decode`` family entry for this shape (the
+    kernel's block; the walk's is :func:`walk_block_pages`). ``sm_scale``
     multiplies the scores on every path (``None``: ``D ** -0.5``).
 
     Under a sharded jit pass ``mesh``: the kernel runs per-shard via
     ``shard_map`` with Q heads and KV heads (and scale heads) split over
     ``heads_axis`` and everything else replicated — the exact placement the
-    engine's pool/param shardings already use, so no extra collective."""
+    engine's pool/param shardings already use, so no extra collective. The
+    walk is plain XLA, partitioned like any other op of the program."""
     s, t_step, h, d = q.shape
     kv_heads = k_pool.shape[2]
     if h % kv_heads:
@@ -567,7 +694,14 @@ def paged_attention(
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
     mode = resolve_kernel(kernel)
-    if mode == "xla" or t_step != 1:
+    if t_step != 1:
+        return _paged_walk(
+            q, k_pool, v_pool, block_tables, seq_lens, valid_lens, k_scale,
+            v_scale, bp=walk_block_pages(block_tables.shape[1], k_pool.shape[1]),
+            # A static argument: None keeps the default scale's one trace.
+            **({} if sm_scale is None else {"sm_scale": float(sm_scale)}),
+        )
+    if mode == "xla":
         return paged_attention_reference(
             q, k_pool, v_pool, block_tables, seq_lens,
             k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,

@@ -61,7 +61,13 @@ starts no page copy.
   ``state_bytes_moved`` (each state once in and once out,
   ``linear_attention.state_bytes_moved``), on every ``step`` slice; a
   ``prefill.chunk`` slice carries ``state_blocks``, the blocks of 64 tokens a
-  layer evaluated for the piece.
+  layer evaluated for the piece;
+* K/V layers of the full group, a prefill piece (:meth:`DecodeReads.prefill_args`):
+  a ``prefill.chunk`` slice carries ``keys_walked`` (the whole blocks the
+  chunk walk gathers and scores for a piece that ends at ``start + tokens``,
+  by the walk's own rule ``chunk_keys_walked``) and ``keys_table`` (the
+  table's width in tokens: what the dense read scored); the ``step`` slice and
+  the totals their sums, ``prefill_keys_walked`` / ``prefill_keys_table``.
 
 :attr:`DecodeReads.totals` is what ``stats()`` shows of them; the ``step``
 slice carries the step's own (:meth:`DecodeReads.end_step`).
@@ -104,6 +110,13 @@ TOTALLED = {
 }
 
 
+#: ``prefill.chunk`` slice arg -> what ``stats()`` and the ``step`` slice total
+#: it as, for the prefill pieces of a model whose full group has K/V layers.
+PREFILL_TOTALLED = {
+    "keys_walked": "prefill_keys_walked", "keys_table": "prefill_keys_table",
+}
+
+
 def _pool(cache, name: str, layers=None) -> Optional[jax.Array]:
     """The pool ``name`` of the first of ``layers`` (indices of the model's
     blocks; ``None``: any) that keeps one, off the engine's cache tree."""
@@ -132,6 +145,10 @@ class DecodeReads:
              "attention_window")
         }
         self.layers["latent pages"] = len(where(*LATENT_TYPES))
+        # The full group's K/V layers: every layer of a model that names no
+        # layer types.
+        self.layers["attention"] = (
+            len(where("attention")) if kinds else model.n_layers)
         self.page, self.width = model.page_size, pages_per_seq
         self.num_pages = model.num_pages
         self.whole = max_slots * pages_per_seq * self.page
@@ -175,8 +192,7 @@ class DecodeReads:
                     pa.window_pages(self.window, self.page),
                     _pool(cache, "cached_latent", where("latent_window")),
                 )
-            # The full group's K/V layers (every layer of a model that names
-            # no layer types), and the window group's.
+            # The full group's K/V layers, and the window group's.
             keys = _pool(
                 cache, "cached_key", where("attention") if kinds else None)
             if keys is not None:
@@ -191,6 +207,10 @@ class DecodeReads:
         for kind, names in TOTALLED.items():
             if self.layers[kind]:
                 self.totals.update(dict.fromkeys(names, 0))
+        #: Whether a dispatch with no tracer has anything to count.
+        self._counted = len(self.totals) > 1
+        if self.layers["attention"]:
+            self.totals.update(dict.fromkeys(PREFILL_TOTALLED.values(), 0))
         # A model with gated-delta layers says so on every step slice.
         self._idle = {
             name: 0 for name in TOTALLED["gated_delta"] if name in self.totals
@@ -217,12 +237,26 @@ class DecodeReads:
                 tables, lens, *groups, self.page, self.blocks["latent"]),)
         return {"row_groups": groups}
 
-    def prefill_args(self, width: int) -> dict:
-        """What a ``prefill.chunk`` slice of a piece padded to ``width`` says
-        beside its tokens."""
-        if not self.layers["gated_delta"]:
-            return {}
-        return {"state_blocks": -(-width // min(la.BLOCK, width))}
+    def prefill_args(
+        self, width: int, start: int, tokens: int, traced: bool = False
+    ) -> dict:
+        """What a ``prefill.chunk`` slice of a piece of ``tokens`` tokens from
+        position ``start`` on, padded to ``width``, says beside them; its key
+        counts go into the totals and, ``traced``, into the step's own."""
+        out = {}
+        if self.layers["gated_delta"]:
+            out["state_blocks"] = -(-width // min(la.BLOCK, width))
+        if self.layers["attention"]:
+            keys = {
+                "keys_walked": int(pa.chunk_keys_walked(
+                    start + tokens, self.width, self.page)),
+                "keys_table": self.width * self.page,
+            }
+            out.update(keys)
+            for sums in [self.totals] + [self._step] * traced:
+                for arg, total in PREFILL_TOTALLED.items():
+                    sums[total] = sums.get(total, 0) + keys[arg]
+        return out
 
     def count(self, tables, positions, traced: bool = False) -> Dict[str, int]:
         """The counts of one dispatch (module docstring): ``tables [rows,
@@ -322,7 +356,7 @@ class DecodeReads:
         """Count a dispatch ONCE, the live ``rows`` (in slot order) of the
         staged ``tables`` and ``positions``: into the totals and, under
         ``tracer``, into the step's own and its ``dsa.select`` instant."""
-        if len(self.totals) == 1 and not tracer.enabled:
+        if not self._counted and not tracer.enabled:
             return  # K/V and S6 / Mamba-2 layers alone: a traced step's only
         tables, positions = tables[rows], positions[rows]
         counts = self.count(tables, positions, tracer.enabled)

@@ -1496,7 +1496,8 @@ class InferenceEngine:
         group = self.window_group
         with self._phase(
             "prefill.chunk", tokens=tokens, start=start, width=width,
-            **self.reads.prefill_args(width),
+            **self.reads.prefill_args(
+                width, start, tokens, self.tracer.enabled),
             # What the piece holds at its widest: its window's pages and
             # its own, before it gives the former back.
             **({} if group is None

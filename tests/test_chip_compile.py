@@ -557,7 +557,7 @@ CASES.update({
 @pytest.mark.parametrize("t_step", [64, 512])
 def test_the_olmo_cells_prefill_programs_compile_for_v5e(chip, t_step):
     """The blocked form is plain XLA operations (a unit-triangular solve a
-    block among them) and a prefill piece attends through the gather path:
+    block among them) and a prefill piece's full layers walk their blocks:
     no kernel to look for, so what is held is that the compiler takes the
     program, and what it needs beside the weights and the pools."""
     compiled = olmo_program(chip, t_step).compile()
@@ -748,10 +748,12 @@ def test_the_exaone_cells_decode_program_calls_both_kernels(chip):
 
 @pytest.mark.parametrize("t_step", [64, 512])
 def test_the_exaone_cells_prefill_programs_compile_for_v5e(chip, t_step):
-    """A piece attends through the gather path in both groups (the window
-    layers over their short table of 41 pages, the full layers over the
-    whole table): no attention kernel, the experts' products, and under a
-    gigabyte beside the weights and the pools."""
+    """A piece attends in plain XLA in both groups (the window layers
+    through the gather path over their short table of 41 pages, the full
+    layers as a walk over blocks of 512 keys of the 12,800-key table): no
+    attention kernel, the experts' products, and the widest piece under
+    300 MB beside the weights and the pools (225 MB compiled; 887 MB while
+    the full layers scored the whole table at once, before PR 47)."""
     compiled = exaone_program(chip, t_step).compile()
     names = [line.split(" = ", 1)[0].strip().lstrip("%")
              for line in compiled.as_text().splitlines()
@@ -759,7 +761,7 @@ def test_the_exaone_cells_prefill_programs_compile_for_v5e(chip, t_step):
     assert names and all(n.startswith("ragged-dot-stationary") for n in names)
     assert not whole_pool_copies(compiled, 513)
     assert not whole_pool_copies(compiled, 9217)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
 
 
 @pytest.mark.parametrize("name", sorted(CASES))

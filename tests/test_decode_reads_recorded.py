@@ -7,7 +7,11 @@ record it again).
 
 Recorded for PR 45 from commit b5b6f72 (PR 44), where the engine counted a
 kind of layer at a time in six methods of its own, twice a traced dispatch;
-``serving/decode_reads.py`` counts them since."""
+``serving/decode_reads.py`` counts them since. Recorded again for PR 47 from
+its own tree: the ``prefill.chunk`` events of the three families with K/V
+attention layers carry two more args, ``keys_walked`` and ``keys_table`` (64
+and 64: a toy's table is one block of the chunk walk); with those two keys
+taken out the file is the one recorded for PR 45, value for value."""
 
 import json
 

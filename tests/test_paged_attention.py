@@ -1,11 +1,13 @@
 """Paged-attention kernel + int8 KV pages: the ISSUE-19 contract.
 
 Op level: the Pallas flash-decode kernel (run in interpret mode on the
-CPU rig) must match the pure-XLA reference within float tolerance, the
-reference must match the engine's inline gather math BITWISE (that is
-what makes `paged_kernel="xla"` a no-op toggle), NULL-page (page 0)
-garbage must never survive the visibility mask, and the int8 path must
-dequantize to the same numbers the int8 reference computes.
+CPU rig) must match the pure-XLA reference within float tolerance, a
+decode step with the kernel off IS the reference bitwise (that is what
+makes `paged_kernel="xla"` a no-op toggle), a chunk of several queries
+is the blockwise walk, equal to the reference within float tolerance
+and blind to every page past the blocks its rows hold (PR 47), NULL-page
+(page 0) garbage must never survive the visibility mask, and the int8
+path must dequantize to the same numbers the int8 reference computes.
 
 Engine level: greedy tokens across the full toggle matrix (kernel
 on/off x prefix_cache x overlap x speculative x mesh (1,1)/(1,8)) must
@@ -27,8 +29,11 @@ import pytest
 from distributed_pytorch_tpu.models.transformer import TransformerLM
 from distributed_pytorch_tpu.ops import flash_autotune as fa
 from distributed_pytorch_tpu.obs import Tracer
+from distributed_pytorch_tpu.ops import paged_attention as pa
 from distributed_pytorch_tpu.ops.paged_attention import (
     NULL_PAGE,
+    _paged_walk,
+    chunk_keys_walked,
     kv_tokens_walked,
     paged_attention,
     paged_attention_reference,
@@ -160,12 +165,22 @@ class TestPagedAttentionOp:
         assert delta[0, :, :4, :].max() > 0
         assert delta[0, :, 4:, :].max() == 0
 
-    def test_t_step_gt1_falls_back_to_reference(self):
+    @pytest.mark.parametrize("kernel", ["interpret", "xla"])
+    def test_chunks_are_the_walk_and_the_reference_to_rounding(self, kernel):
+        """A chunk (t_step 2) is the blockwise walk whatever ``kernel`` says:
+        one result for both modes, the reference's within float tolerance.
+        A single-token step with the kernel off stays the reference's bits."""
         q, kp, vp, bt, lens = make_problem()
         q2 = jnp.concatenate([q, q], axis=1)  # t_step = 2 (prefill chunk)
-        ref = paged_attention_reference(q2, kp, vp, bt, lens)
-        out = paged_attention(q2, kp, vp, bt, lens, kernel="interpret")
-        assert (np.asarray(out) == np.asarray(ref)).all()
+        ref = np.asarray(paged_attention_reference(q2, kp, vp, bt, lens))
+        out = np.asarray(paged_attention(q2, kp, vp, bt, lens, kernel=kernel))
+        np.testing.assert_allclose(out, ref, atol=2e-6, rtol=2e-6)
+        walk = _paged_walk(
+            q2, kp, vp, bt, lens, bp=pa.walk_block_pages(bt.shape[1], 4))
+        assert (out == np.asarray(walk)).all()
+        one = paged_attention(q, kp, vp, bt, lens, kernel="xla")
+        assert (np.asarray(one) == np.asarray(
+            paged_attention_reference(q, kp, vp, bt, lens))).all()
 
     def test_resolve_kernel_validates(self):
         assert resolve_kernel("xla") == "xla"
@@ -364,6 +379,175 @@ class TestKernelWalksOwnKV:
         assert kv_tokens_walked(pos, BLOCK).tolist() == [
             BLOCK, BLOCK, 2 * BLOCK, 4 * BLOCK,
         ]
+
+
+# ------------------------------ a chunk walks only the blocks its rows hold
+#
+# ``paged_attention`` at ``t_step > 1`` (a prefill piece, a speculative
+# round's verification): ``_paged_walk``, a block of pages at a time with an
+# online softmax over the blocks that hold a key some query sees, against the
+# dense read ``paged_attention_reference``.
+
+WALK_PAGE, WALK_BLOCK = 4, 16  # a toy block of 4 pages
+
+
+def chunk_problem(starts, t_step, *, h=4, kv_heads=2, d=8, width=18,
+                  page=WALK_PAGE, owned=None, dtype=jnp.float32, seed=0):
+    """A chunk of ``t_step`` queries a row, row ``r`` from position
+    ``starts[r]`` on (``None``: a row on the null table): each row owns the
+    pages its ``start + t_step`` keys need (``owned``: that many instead),
+    the rest of its table is the null page, and every page holds random
+    numbers. ``(q, kp, vp, bt, lens)``."""
+    rng = np.random.default_rng(seed)
+    need = [
+        0 if st is None else owned or -(-(st + t_step) // page)
+        for st in starts
+    ]
+    num_pages = 1 + sum(need) + 2
+    ids = iter(rng.permutation(np.arange(1, num_pages)))
+    bt = np.zeros((len(starts), width), np.int32)
+    for row, n in enumerate(need):
+        bt[row, :n] = [next(ids) for _ in range(n)]
+    lens = np.asarray([st or 0 for st in starts], np.int32)
+    pool = (num_pages, page, kv_heads, d)
+    q = jnp.asarray(rng.standard_normal((len(starts), t_step, h, d)), dtype)
+    kp = jnp.asarray(rng.standard_normal(pool), dtype)
+    vp = jnp.asarray(rng.standard_normal(pool), dtype)
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(lens)
+
+
+@pytest.fixture
+def toy_walk_block(monkeypatch):
+    monkeypatch.setattr(pa, "WALK_BLOCK_TOKENS", WALK_BLOCK)
+
+
+#: name -> (chunk_problem's arguments, paged_attention's, what is compared).
+#: Tables of 18 pages of 4 are four and a half blocks of 16 tokens.
+WALKS = {
+    # StarCoder2's grouping; the piece ends inside its third block
+    "gqa_12_to_1": (dict(starts=[30], t_step=8, h=24, kv_heads=2), {}),
+    "a_piece_from_an_empty_row": (dict(starts=[0], t_step=8), {}),
+    "a_piece_that_ends_on_a_blocks_edge": (dict(starts=[24], t_step=8), {}),
+    "a_piece_up_to_the_tables_end": (dict(starts=[64], t_step=8), {}),
+    # a speculative round's verification: rows of different lengths, the
+    # trip count the longest row's, one of them on the null table
+    "rows_of_different_lengths": (
+        dict(starts=[3, 41, 17], t_step=4), {}),
+    "a_row_on_the_null_table": (
+        dict(starts=[20, None, 50], t_step=4), {}),
+    "the_score_scale": (dict(starts=[21], t_step=8), dict(sm_scale=0.05)),
+    # a table that is a whole number of blocks, and one of a single block
+    "whole_blocks": (dict(starts=[30], t_step=8, width=16), {}),
+    "one_block_a_table": (dict(starts=[5], t_step=8, width=4), {}),
+    "bf16_pages": (
+        dict(starts=[30], t_step=8, dtype=jnp.bfloat16), {}),
+}
+
+
+@pytest.mark.usefixtures("toy_walk_block")
+class TestChunkWalk:
+    @pytest.mark.parametrize("name", sorted(WALKS))
+    def test_the_walk_matches_the_dense_read(self, name):
+        problem, kw = WALKS[name]
+        q, kp, vp, bt, lens = chunk_problem(**problem)
+        ref = paged_attention_reference(q, kp, vp, bt, lens, **kw)
+        out = paged_attention(q, kp, vp, bt, lens, kernel="interpret", **kw)
+        assert out.shape == ref.shape and out.dtype == ref.dtype
+        tol = 2e-2 if q.dtype == jnp.bfloat16 else 1e-5
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=tol, rtol=tol)
+
+    def test_int8_pages_are_dequantised_a_block_at_a_time(self):
+        q, kp, vp, bt, lens = chunk_problem([30, 9], 8)
+        (k8, ks), (v8, vs) = quantize_pool(kp), quantize_pool(vp)
+        ref = paged_attention_reference(
+            q, k8, v8, bt, lens, k_scale=ks, v_scale=vs)
+        out = paged_attention(
+            q, k8, v8, bt, lens, k_scale=ks, v_scale=vs, kernel="xla")
+        np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+
+    def test_one_to_one_heads_with_the_pools_padding_heads(self):
+        """Olmo's full layers: 6 KV heads held as 8, as the model widens
+        them (zero K, V and query heads last): the real heads are the
+        reference's, the padding heads' output is zero."""
+        q, kp, vp, bt, lens = chunk_problem([30], 8, h=8, kv_heads=8)
+        q, kp, vp = (x.at[..., 6:, :].set(0) for x in (q, kp, vp))
+        ref = paged_attention_reference(q, kp, vp, bt, lens)
+        out = np.asarray(paged_attention(q, kp, vp, bt, lens, kernel="xla"))
+        np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+        assert (out[:, :, 6:] == 0).all() and np.abs(out[:, :, :6]).min() > 0
+
+    @pytest.mark.parametrize("valid", [1, 5, 8])
+    def test_a_padded_piece(self, valid):
+        """A piece of ``valid`` tokens padded to 8: the row's own queries are
+        the reference's, and the padding costs no block: the row holds
+        ``14 + valid`` keys, a block of 16 until ``valid`` passes 2."""
+        q, kp, vp, bt, lens = chunk_problem([14], 8, owned=18)
+        ref = np.asarray(paged_attention_reference(q, kp, vp, bt, lens))
+        past = np.asarray(bt)[0, (2 if valid > 2 else 1) * 4:]
+        kp, vp = kp.at[past].set(np.nan), vp.at[past].set(np.nan)
+        out = np.asarray(paged_attention(
+            q, kp, vp, bt, lens, valid_lens=jnp.asarray([valid], jnp.int32),
+            kernel="xla"))
+        np.testing.assert_allclose(
+            out[:, :valid], ref[:, :valid], atol=1e-5, rtol=1e-5)
+        assert np.isfinite(out).all()  # the padding: finite, and nobody's
+
+    @pytest.mark.parametrize("start", [0, 20, 40])
+    def test_the_dead_blocks_are_never_read(self, start):
+        """The row owns its whole table (a long prompt's early piece), and
+        every page past the blocks its ``start + 8`` keys reach holds NaN:
+        the dense read would spread it (0 x NaN), the walk never gathers
+        those pages and gives the reference's result on clean pages."""
+        q, kp, vp, bt, lens = chunk_problem([start], 8, owned=18)
+        ref = np.asarray(paged_attention_reference(q, kp, vp, bt, lens))
+        live = chunk_keys_walked(start + 8, 18, WALK_PAGE) // WALK_PAGE
+        assert live == 4 * -(-(start + 8) // WALK_BLOCK) < 18
+        dead = np.asarray(bt)[0, live:]
+        kp, vp = kp.at[dead].set(np.nan), vp.at[dead].set(np.nan)
+        assert not np.isfinite(np.asarray(
+            paged_attention_reference(q, kp, vp, bt, lens))).any()
+        out = np.asarray(paged_attention(q, kp, vp, bt, lens, kernel="xla"))
+        np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+
+    def test_one_program_serves_every_length(self):
+        """The trip count is traced: one jitted program, a short row and a
+        long one."""
+        fn = jax.jit(lambda *a: paged_attention(*a, kernel="xla"))
+        for start in (2, 60):
+            problem = chunk_problem([start], 8, owned=18)
+            np.testing.assert_allclose(
+                fn(*problem), paged_attention_reference(*problem),
+                atol=1e-5, rtol=1e-5)
+        assert fn._cache_size() == 1
+
+    def test_keys_walked_is_whole_blocks_of_the_table(self):
+        """The rule the walk's trip count and the host's count share: at
+        least one block, whole blocks, at most the table's (its last block
+        counted whole: 18 pages are five blocks of 4)."""
+        n_keys = np.asarray([0, 1, 16, 17, 64, 65, 72, 500])
+        assert chunk_keys_walked(n_keys, 18, WALK_PAGE).tolist() == [
+            16, 16, 16, 32, 64, 80, 80, 80]
+        traced = jax.jit(lambda n: chunk_keys_walked(n, 18, WALK_PAGE))
+        assert [int(traced(n)) for n in n_keys] == [
+            16, 16, 16, 32, 64, 80, 80, 80]
+        assert chunk_keys_walked(9, 2, WALK_PAGE) == 8  # a table of one block
+
+
+def test_the_walk_at_its_shipped_block():
+    """No toy block: 512 tokens a block over a table of 72 pages of 16 (two
+    and a quarter blocks), 24 query heads on 2 as in StarCoder2's cells."""
+    assert pa.WALK_BLOCK_TOKENS == 512
+    q, kp, vp, bt, lens = chunk_problem(
+        [500], 64, h=24, kv_heads=2, d=16, page=16, width=72, owned=72)
+    ref = np.asarray(paged_attention_reference(q, kp, vp, bt, lens))
+    assert chunk_keys_walked(564, 72, 16) == 1024
+    dead = np.asarray(bt)[0, 64:]
+    out = np.asarray(paged_attention(
+        q, kp.at[dead].set(np.nan), vp.at[dead].set(np.nan), bt, lens,
+        kernel="xla"))
+    np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
 
 # ------------------------------------------------------- autotune family
@@ -591,6 +775,45 @@ class TestEngineKernelParity:
             (2, 17 + 5, 32 + 16 if block else whole),
             (2, 18 + 6, 32 + 16 if block else whole),
         ]
+
+    @pytest.mark.parametrize("kernel", ["interpret", False])
+    def test_prefill_slices_count_keys_walked_and_the_tables(
+            self, model_and_params, kernel, monkeypatch):
+        """Prompts of 21 and 3 tokens over tables of 32 tokens, a block of 8
+        (one page). A prompt's last token is its first decode step's: the 20
+        go as pieces of 16 and 4, which walk 2 and 3 blocks; the 2 walk one.
+        ``prefill.chunk`` slices carry both sides, the ``step`` slices and
+        ``stats()`` their sums."""
+        monkeypatch.setattr(pa, "WALK_BLOCK_TOKENS", 8)
+        model, params = model_and_params
+        tracer = Tracer()
+        eng = InferenceEngine(
+            model, params, tracer=tracer, overlap=False, prefix_cache=False,
+            paged_kernel=kernel, **ENGINE_KW,
+        )
+        for prompt in (list(range(1, 22)), [1, 2, 3]):
+            eng.submit(prompt, SamplingParams(max_new_tokens=2))
+        eng.run()
+        eng.close()
+        slices = lambda name: [  # noqa: E731
+            e["args"] for e in tracer.events
+            if e.get("ph") == "X" and e["name"] == name
+        ]
+        pieces = sorted(
+            (a["start"], a["tokens"], a["keys_walked"], a["keys_table"])
+            for a in slices("prefill.chunk")
+        )
+        assert pieces == [(0, 2, 8, 32), (0, 16, 16, 32), (16, 4, 24, 32)]
+        stats = eng.stats()
+        assert stats["prefill_keys_walked"] == 48
+        assert stats["prefill_keys_table"] == 96
+        steps = slices("step")
+        for name in ("prefill_keys_walked", "prefill_keys_table"):
+            assert sum(a.get(name, 0) for a in steps) == stats[name]
+        assert all(
+            "prefill_keys_walked" not in a
+            for a in steps if not a["prefill_programs"]
+        )
 
     def test_bad_kernel_mode_fails_at_init(self, model_and_params):
         model, params = model_and_params

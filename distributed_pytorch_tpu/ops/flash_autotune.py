@@ -87,7 +87,12 @@ PAGED_FAMILY = "paged_decode"
 # 128: 32 slots of 256 pages with 24 query heads on 2 KV heads, at a full
 # batch of ~400 tokens a row and at 4 live rows of ~1,500; 128 slots of 128
 # pages with 20 heads on 1, at ~290 tokens a row. 8 is within 4-8% of it
-# there; rows of thousands of tokens would take 32 or 64.
+# there; rows of thousands of tokens would take 32 or 64. Since PR 48 a block
+# is computed on its tiles as stored, bf16 into both products (the kernel's
+# docstring); swept again on the chip at 8, 16 and 32 pages and six
+# geometries (PERF.md section 6, PR 48): 16 is within 6% of the best at 24 on
+# 2 (32 rows; 32 pages win by 15% at 4 long rows), at 20 on 1 and at 64 on 8;
+# at 32 on 32 KV heads 8 pages win by 12% (ROADMAP S3 (d)). The number stays.
 PAGED_DEFAULT_TABLE = {
     "cpu": 2,
     "tpu v5 lite": 16,
@@ -340,6 +345,8 @@ def autotune_paged(
     interpret: Optional[bool] = None,
     latent: int = 0,
     sharers: int = 1,
+    blocks=None,
+    timings: Optional[dict] = None,
 ) -> int:
     """Measured sweep for the paged decode kernel: times every legal
     pages-per-block over a synthetic decode batch whose rows hold about
@@ -361,7 +368,12 @@ def autotune_paged(
     (:func:`shared_tables`): every ``sharers`` rows hold one document of
     about ``context`` tokens under the same physical pages, and each a tail
     of its own of 100-500 tokens. The latent kernel copies a shared document
-    once for its rows, so its block is swept on what it runs."""
+    once for its rows, so its block is swept on what it runs.
+
+    ``timings``, a dict, is filled ``{pages_per_block: seconds a call}``: the
+    whole table, for whoever wants more than the winner. ``blocks`` times
+    just these pages-per-block (any the table's width holds: a window
+    group's 9), a measurement that tunes nothing: its winner is not kept."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -421,7 +433,7 @@ def autotune_paged(
     lens = jnp.asarray(held - 1, jnp.int32)
 
     best, best_dt = None, float("inf")
-    for npb in paged_candidates(pages_per_seq, page_size):
+    for npb in blocks or paged_candidates(pages_per_seq, page_size):
         try:
             if latent:
                 # The value is narrower than the query: pad it back.
@@ -452,6 +464,8 @@ def autotune_paged(
             continue
         if verbose:
             print(f"  npb={npb:3d}: {dt * 1e6:9.1f} us")
+        if timings is not None:
+            timings[npb] = dt
         if dt < best_dt:
             best, best_dt = npb, dt
     if best is None:
@@ -466,6 +480,8 @@ def autotune_paged(
         _runtime_cache[key] = (_PAGED_FALLBACK, _PAGED_FALLBACK * page_size)
         _failed_sweeps.add(key)
         return _PAGED_FALLBACK
+    if blocks:
+        return best
     _runtime_cache[key] = (best, best * page_size)
     _failed_sweeps.discard(key)
     disk = _load_disk_cache()
@@ -684,6 +700,18 @@ def main(argv=None) -> None:
         "under the same physical pages, with a tail of 100-500 tokens each "
         "(1: no row shares a page)",
     )
+    parser.add_argument(
+        "--blocks", default="",
+        help="paged sweep: the pages-per-block to time (default: every legal "
+        "one)",
+    )
+    parser.add_argument("--steps", default=20, type=int,
+                        help="paged sweep: calls timed inside one program")
+    parser.add_argument(
+        "--out", default="",
+        help="paged sweep: append each shape's timings to this JSON-lines "
+        "file",
+    )
     args = parser.parse_args(argv)
     kind = _device_kind()
     if kind == "unknown":
@@ -699,13 +727,28 @@ def main(argv=None) -> None:
             for page in (int(x) for x in args.page_sizes.split(",")):
                 for d in (int(x) for x in args.head_dims.split(",")):
                     print(f"kv={kv_len} page={page} d={d}:", flush=True)
+                    timings = {}
                     npb = autotune_paged(
                         kv_len, page, d, slots=args.slots,
                         kv_heads=args.kv_heads, group=args.group,
                         dtype=args.dtype, context=args.context,
                         verbose=True, force=args.force, latent=args.latent,
-                        sharers=args.sharers,
+                        sharers=args.sharers, steps=args.steps,
+                        blocks=[int(x) for x in args.blocks.split(",") if x],
+                        timings=timings,
                     )
+                    if args.out:
+                        with open(args.out, "a") as f:
+                            f.write(json.dumps({
+                                "device": kind, "kv_len": kv_len, "page": page,
+                                "head_dim": d, "slots": args.slots,
+                                "kv_heads": args.kv_heads, "group": args.group,
+                                "context": args.context, "dtype": args.dtype,
+                                "steps": args.steps,
+                                "us_a_call": {
+                                    b: dt * 1e6 for b, dt in timings.items()
+                                },
+                            }) + "\n")
                     key = _paged_key(kind, kv_len, page, d, args.dtype)
                     if key in _failed_sweeps:
                         print("  -> MEASUREMENT FAILED (excluded)", flush=True)

@@ -33,21 +33,33 @@ walks only the KV a row has:
   sign: a live one-token prompt decodes at position 0 and sees its own key.
   The model discards those rows' outputs either way, and their K/V writes
   still land in the null page.
+* a block is computed on its page tiles AS STORED (PR 48): a buffer's
+  ``[block tokens * Hkv, D]`` rows, in the pool's (token, KV head) order and
+  dtype, are the operand of both products as they were copied: no float32
+  copy, no reshape into heads, no transpose. ONE product ``[H, D] x rows^T``
+  scores all the query heads against every row (M = ``H``), and ONE product
+  ``[H, columns] x rows`` is the weighted sum; bf16 operands (exact in
+  float32), float32 accumulation. It does ``Hkv`` times the products and
+  ``exp``s of a layout a KV head, which the chip has to spare beside the
+  block's copy at 1 to 32 KV heads (PERF.md section 6, PR 48).
 * online softmax (FlashAttention-style running max / denominator / output
-  accumulator in fp32 VMEM scratch, persisting across a row's blocks) with
-  grouped-query head mapping: query head ``h`` reads kv head ``h // group``,
-  the same contraction layout as the XLA reference's grouped einsums.
-* masking is positional, exactly as the reference: key position ``kpos`` is
-  visible iff ``kpos <= pos`` (the row's current absolute position), which
-  also kills the dead tail of a row's last block.
+  accumulator in fp32 VMEM scratch, persisting across a row's blocks) over
+  the block's columns, with grouped-query head mapping in the mask: query
+  head ``h`` keeps the columns of kv head ``h // group`` (``col % Hkv``), the
+  reference's grouping.
+* masking is positional, exactly as the reference: key position ``kpos``
+  (``col // Hkv``) is visible iff ``kpos <= pos`` (the row's current absolute
+  position), which also kills the dead tail of a row's last block. The
+  columns' keys and heads are the same for every block: built once a row.
 * int8 KV pages: with ``k_scale``/``v_scale`` (``[num_pages, page_size,
   Hkv]`` float32, quantized on page write by the model) the kernel copies
   int8 pages, a quarter of the fp32 page bytes. A ``[page, Hkv]`` scale
   page has no lane-aligned slice a manual copy could take, so the rows'
   scales are gathered through the table outside the kernel (one float a
   key and kv head, a 1/D-th of a gathered view) and, being one number a
-  key, are applied to the block's scores and weights instead of its tiles:
-  the same product in float32, in another order.
+  column, are applied to the block's scores and weights instead of its tiles
+  (whose int8 numbers are exact in the queries' dtype): the same product in
+  float32, in another order.
 
 ``paged_attention_reference`` is the pure-XLA fallback of a decode step: the
 dense read (each row's whole table gathered, a one-shot softmax), what the
@@ -225,6 +237,19 @@ def paged_attention_reference(
     return out.reshape(s, t_step, h, d)
 
 
+#: How ``_decode_kernel`` computes a block, as ``engine.stats()`` names it
+#: (``kv_decode_block_form``): on the page tiles AS STORED. The sweep of PR 48
+#: (PERF.md section 6) timed it against the tile relaid a KV head (operands
+#: float32, bf16 after a float32 relayout, bf16 throughout) at every cell's
+#: geometry, 1 to 32 KV heads: it won or tied at all of them, so it is the
+#: one form.
+KV_BLOCK_FORM = "stored"
+
+#: The key of a score column that is not its query head's own: past any
+#: position.
+_NO_KEY = 2**30
+
+
 def _decode_kernel(
     bt_ref, lens_ref, *refs, npb, group, sm_scale, quantized, windowed=False
 ):
@@ -245,7 +270,24 @@ def _decode_kernel(
     first key on, positions count from that page's first token, and a key
     before ``lo_ref[row]`` is masked like one past ``pos``. That first key
     stands in the table's first page, so every walked block still has a
-    visible key."""
+    visible key.
+
+    A block is computed on its tiles AS STORED: a buffer's ``[block tokens *
+    Hkv, D]`` rows, (token, KV head) order, are the operand of both products
+    as they were copied. ONE product scores ALL the query heads against every
+    row, ``[H, D] x rows^T`` (M = ``H``), and a query head keeps the columns
+    of its own KV head (``col % Hkv == head // group``) at visible positions
+    (``col // Hkv``); the online softmax runs over the columns; ONE product
+    ``[H, columns] x rows`` is the weighted sum (the other heads' columns
+    weigh exactly 0). No float32 copy, reshape or transpose of a tile, at
+    ``Hkv`` times the products and ``exp``s, which the MXU and the vector unit
+    have to spare beside the block's copy at every geometry measured (PERF.md
+    section 6, PR 48). The operands are the pool's dtype (bf16 x bf16 is
+    exact in float32; int8 pages: the queries' dtype, exact too, their scales
+    multiplying scores and weights outside the products; the softmax weights
+    are rounded to it before the weighted sum, as the reference's are), the
+    products accumulate in float32, and max, ``exp``, sums, correction and
+    the accumulators are float32."""
     lo_ref = None
     if windowed:
         lo_ref, *refs = refs
@@ -262,6 +304,9 @@ def _decode_kernel(
     kv_heads = h // group
     page = k_buf.shape[2] // kv_heads
     bkv = npb * page
+    cols = bkv * kv_heads  # a block's rows, and its scores' columns
+    # int8 is exact in the queries' dtype.
+    operand = q_ref.dtype if quantized else k_buf.dtype
 
     def is_live(row):
         # A row out of the dispatch group stages a zeroed block table: its
@@ -320,6 +365,16 @@ def _decode_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
+        # The mask's constant part, once a row: the key a score column
+        # stands for, counted from its block's first, where the column's KV
+        # head is the query head's own; elsewhere a key past any position.
+        col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
+        own = jax.lax.rem(col, kv_heads) == jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0), group
+        )
+        key = jnp.where(own, jax.lax.div(col, kv_heads), _NO_KEY)
+        q = q_ref[0].astype(operand)  # [H, D]
+
         def block(j, carry):
             buf = (buf0 + j) % 2
 
@@ -334,53 +389,39 @@ def _decode_kernel(
 
             wait(buf)
 
-            def load(pages):
-                # [npb, page * Hkv, D] as stored -> [bkv, Hkv, D] f32
-                return pages[buf].astype(jnp.float32).reshape(
-                    bkv, kv_heads, d
-                )
+            def rows(pages):
+                # [npb, page * Hkv, D] -> [bkv * Hkv, D]: the leading axes
+                # merged, every row where it is.
+                return pages[buf].reshape(cols, d).astype(operand)
 
-            k = load(k_buf)
-            v = load(v_buf)
-            q = q_ref[0].astype(jnp.float32)  # [H, D]
-            # Grouped-query mapping: query head h reads kv head h // group —
-            # kv leads group, matching the reference's qg reshape.
-            qg = q.reshape(kv_heads, group, d)
-            kt = k.transpose(1, 0, 2)  # [Hkv, bkv, D]
             s_blk = jax.lax.dot_general(
-                qg, kt, (((2,), (2,)), ((0,), (0,))),
+                q, rows(k_buf), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [Hkv, group, bkv]
+            )  # [H, bkv * Hkv]
             if quantized:
-                # A key's scale is one number a (position, kv head): it
-                # factors out of the contraction over D.
-                s_blk = s_blk * ks_ref[0, j][:, None, :]
+                # A key's scale is one number a (position, kv head), a
+                # column: it factors out of the contraction over D.
+                s_blk = s_blk * ks_ref[0, j]
             s_blk = s_blk * sm_scale
-            kpos = j * bkv + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, bkv), 2
-            )
             # Every walked block has key ``j * bkv`` visible, so the running
             # max stays finite and no exp(NEG_INF - NEG_INF) row can arise.
-            visible = kpos <= pos
+            visible = key <= pos - j * bkv
             if windowed:
-                visible = jnp.logical_and(visible, kpos >= lo_ref[b])
+                visible = jnp.logical_and(visible, key >= lo_ref[b] - j * bkv)
             s_blk = jnp.where(visible, s_blk, NEG_INF)
-            s2 = s_blk.reshape(h, bkv)
             m_prev = m_scr[:, :1]
             l_prev = l_scr[:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1, keepdims=True))
-            p = jnp.exp(s2 - m_new)
+            m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
+            p = jnp.exp(s_blk - m_new)
             correction = jnp.exp(m_prev - m_new)
             l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-            pg = p.reshape(kv_heads, group, bkv)
             if quantized:
-                pg = pg * vs_ref[0, j][:, None, :]
-            vt = v.transpose(1, 0, 2)  # [Hkv, bkv, D]
+                p = p * vs_ref[0, j]
             pv = jax.lax.dot_general(
-                pg, vt, (((2,), (1,)), ((0,), (0,))),
+                p.astype(operand), rows(v_buf), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [Hkv, group, D]
-            acc_scr[:] = acc_scr[:] * correction + pv.reshape(h, d)
+            )  # [H, D]
+            acc_scr[:] = acc_scr[:] * correction + pv
             m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
             l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
             return carry
@@ -471,7 +512,7 @@ def _paged_flash(
 
     int8 pages: a scale page ``[page, Hkv]`` has no lane-aligned slice to
     copy, so the rows' scales are gathered through the table here, by block
-    and with positions on the lanes (``[S, blocks, Hkv, block tokens]``
+    and as the scores' columns are (``[S, blocks, 1, block tokens * Hkv]``
     float32: a 1/D-th of a gathered view), and ride in as one block a row."""
     s, h, d = q3.shape
     num_pages, page, kv_heads = k_pool.shape[:3]
@@ -495,9 +536,7 @@ def _paged_flash(
     padded = jnp.pad(bt, ((0, 0), (0, nblk * npb - pages_per_seq)))
 
     def by_block(scale):
-        return scale[padded].reshape(s, nblk, npb * page, kv_heads).swapaxes(
-            2, 3
-        )
+        return scale[padded].reshape(s, nblk, 1, npb * page * kv_heads)
 
     operands = [
         pool.reshape(num_pages, page * kv_heads, d)
@@ -506,7 +545,7 @@ def _paged_flash(
     in_specs = [row_spec((1, h, d))] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
     if quantized:
         operands += [by_block(k_scale), by_block(v_scale)]
-        in_specs += [row_spec((1, nblk, kv_heads, npb * page))] * 2
+        in_specs += [row_spec((1, nblk, 1, npb * page * kv_heads))] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),

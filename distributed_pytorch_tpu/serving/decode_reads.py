@@ -19,7 +19,10 @@ index key scored, a group's shared blocks once) and a gather of the
 windowed latent call (the pages that meet the window, every row by itself), a
 ``"gated_delta"`` layer through its matrix state, in place. Each kernel's block
 is what its call looks up, by the call's own helper on the layer's own pool
-(``kv_block_pages`` / ``latent_block_pages`` / ``index_block_pages``); with the
+(``kv_block_pages`` / ``latent_block_pages`` / ``index_block_pages``); a K/V
+kernel's call also names how it computes a block (``pa.KV_BLOCK_FORM``:
+:attr:`DecodeReads.forms`, ``stats()``'s ``kv_decode_block_form`` /
+``kv_window_decode_block_form``); with the
 kernels off (``paged_kernel`` unset or resolved to ``"xla"``) there are no
 blocks: the gather path reads every slot's whole table, groups nobody and
 starts no page copy.
@@ -174,6 +177,9 @@ class DecodeReads:
         )
         #: kernel -> pages a block of its call; none on the gather path.
         self.blocks: Dict[str, int] = {}
+        #: K/V kernel -> how its call computes a block (what ``stats()``
+        #: shows as ``<kernel>_decode_block_form``).
+        self.forms: Dict[str, str] = {}
         #: Pages a group shares at least: a block of the latent kernel at the
         #: grouping layers' pool (``None``: no layer groups rows).
         self.group_pages = None
@@ -203,6 +209,10 @@ class DecodeReads:
                     self.kv_window_pages,
                     _pool(cache, "cached_key", where("attention_window")),
                     model.dtype, short=True)
+            self.forms = {
+                kernel: pa.KV_BLOCK_FORM
+                for kernel in ("kv", "kv_window") if kernel in self.blocks
+            }
         self.totals = {"decode_rows_grouped": 0}
         for kind, names in TOTALLED.items():
             if self.layers[kind]:

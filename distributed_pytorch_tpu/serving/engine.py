@@ -2941,6 +2941,8 @@ class InferenceEngine:
             out["moe_pairs_held"] = self.moe_pairs_held
             out["moe_rows_computed"] = self.moe_rows_computed
         out["page_bytes_per_token_layer"] = self.page_bytes_per_token_layer
+        for kernel, form in self.reads.forms.items():
+            out[f"{kernel}_decode_block_form"] = form
         out["pages_free"] = self.allocator.num_free
         out["pages_allocated"] = self.allocator.num_allocated
         out["pages_idle"] = self.allocator.num_idle

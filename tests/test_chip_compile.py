@@ -6,6 +6,7 @@ executed, so this says nothing about results or times; about a second a case.
 """
 
 import functools
+import math
 import os
 import sys
 
@@ -52,25 +53,34 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def paged_case(chip, heads, kv_heads, quantized, slots=SLOTS,
+def paged_call(chip, heads, kv_heads, quantized, slots=SLOTS,
                pages_per_seq=PAGES_PER_SEQ, num_pages=None):
+    """``(fn, operands)`` of the K/V kernel's call at the device's block."""
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     num_pages = num_pages or slots * pages_per_seq + 1
     pool = (num_pages, PAGE, kv_heads, HEAD_DIM)
     pool_dtype = jnp.int8 if quantized else jnp.bfloat16
-    scale = arg(pool[:-1], jnp.float32) if quantized else None
-    fn = functools.partial(
-        paged_attention, kernel="pallas",
-        pages_per_block=PAGED_DEFAULT_TABLE[KIND],
-    )
-    return jax.jit(fn).lower(
+    scales = (arg(pool[:-1], jnp.float32),) * 2 if quantized else ()
+
+    def fn(q, k_pool, v_pool, tables, lens, k_scale=None, v_scale=None):
+        return paged_attention(
+            q, k_pool, v_pool, tables, lens, k_scale=k_scale, v_scale=v_scale,
+            kernel="pallas", pages_per_block=PAGED_DEFAULT_TABLE[KIND],
+        )
+
+    return fn, (
         arg((slots, 1, heads, HEAD_DIM), jnp.bfloat16),
         arg(pool, pool_dtype), arg(pool, pool_dtype),
         arg((slots, pages_per_seq), jnp.int32), arg((slots,), jnp.int32),
-        k_scale=scale, v_scale=scale,
+        *scales,
     )
+
+
+def paged_case(chip, **call):
+    fn, operands = paged_call(chip, **call)
+    return jax.jit(fn).lower(*operands)
 
 
 def flash_case(chip, t, grad):
@@ -115,6 +125,17 @@ CASES = {
     "paged-jamba2-3b": functools.partial(
         paged_case, heads=20, kv_heads=1, quantized=False, slots=128,
         pages_per_seq=128, num_pages=16385,
+    ),
+    # granite-4.0-h-small's 64 slots of 128 pages out of 8,193, 32 query
+    # heads on 8, and K-EXAONE's full layers' 32 slots of 800 out of 9,217,
+    # 64 on 8 (its window layers' call: ``window-paged-k-exaone`` below).
+    "paged-granite-4.0-h-small": functools.partial(
+        paged_case, heads=32, kv_heads=8, quantized=False, slots=64,
+        pages_per_seq=128, num_pages=8193,
+    ),
+    "paged-k-exaone-236b-a23b": functools.partial(
+        paged_case, heads=64, kv_heads=8, quantized=False, slots=32,
+        pages_per_seq=800, num_pages=9217,
     ),
     **{
         f"flash-T{t}-{'grad' if grad else 'fwd'}": functools.partial(
@@ -762,6 +783,32 @@ def test_the_exaone_cells_prefill_programs_compile_for_v5e(chip, t_step):
     assert not whole_pool_copies(compiled, 513)
     assert not whole_pool_copies(compiled, 9217)
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+
+
+@pytest.mark.parametrize("name", [
+    "paged-sc2-3b", "paged-jamba2-3b", "paged-granite-4.0-h-small",
+    "paged-k-exaone-236b-a23b", "paged-olmo-hybrid-7b", "paged-H16-Hkv8-int8",
+])
+def test_a_block_is_computed_on_its_tiles_as_stored(chip, name):
+    """The K/V kernel's body (its jaxpr) at the cells' geometries: both
+    products take the pool's bf16 rows as copied, ``[block tokens * Hkv,
+    128]`` (int8 pages: cast to the queries' bf16), no tile is transposed,
+    and no float32 array is as large as a tile: the largest is the block's
+    score tile ``[H, block tokens * Hkv]``, H under 128."""
+    import re
+
+    fn, operands = paged_call(chip, **CASES[name].keywords)
+    heads, kv_heads = operands[0].shape[2], operands[1].shape[2]
+    rows = PAGED_DEFAULT_TABLE[KIND] * PAGE * kv_heads
+    text = str(jax.make_jaxpr(fn)(*operands))
+    kernel = text[text.index("pallas_call"):]
+    assert "transpose" not in kernel
+    assert kernel.count("= dot_general[") == 2
+    floats = {tuple(map(int, dims.split(",")))
+              for dims in re.findall(r"f32\[([\d,]+)\]", kernel)}
+    assert max(floats, key=math.prod) == (heads, rows)
+    assert f"bf16[{rows},{HEAD_DIM}]" in kernel
+    assert f"f32[{rows},{HEAD_DIM}]" not in kernel
 
 
 @pytest.mark.parametrize("name", sorted(CASES))

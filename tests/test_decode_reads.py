@@ -63,6 +63,12 @@ def test_a_kernel_is_counted_at_the_block_it_is_lowered_with(
     assert set(lowered_with) == KERNELS[family]
     assert engine.reads.blocks == {
         kernel: block for kernel, (block,) in lowered_with.items()}
+    # ... and a K/V kernel's call names how it computes a block, which
+    # ``stats()`` shows.
+    assert engine.reads.forms == {
+        kernel: pa.KV_BLOCK_FORM for kernel in KERNELS[family] & {"kv"}}
+    assert engine.stats().get("kv_decode_block_form") == (
+        pa.KV_BLOCK_FORM if "kv" in KERNELS[family] else None)
     if family == "sparse_window_first":
         pools = [leaf.shape[-1] for path, leaf in
                  jax.tree_util.tree_flatten_with_path(engine.cache)[0]

@@ -100,6 +100,13 @@ def test_prefill_in_pieces_then_decode_past_several_windows(program, kernel):
     assert stats["window_pages_freed"] == (PROMPT + NEW - 1 - 8) // 4 + 1 - 1
     assert stats["window_pages_held"] == 0  # the request has retired
     assert stats["decode_window_tokens_visible"] == 8 * NEW
+    # Both table groups' K/V kernel calls name how they compute a block;
+    # the gather path has none.
+    forms = {key: value for key, value in stats.items()
+             if key.endswith("_decode_block_form")}
+    assert forms == ({} if not kernel else {
+        "kv_decode_block_form": "stored",
+        "kv_window_decode_block_form": "stored"})
 
 
 def _whole_projection_norm(program):

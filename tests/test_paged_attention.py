@@ -244,7 +244,7 @@ EDGES = [
 
 
 def ragged_problem(positions, *, h=4, kv_heads=2, d=8, page=PAGE,
-                   width=WIDTH, spare=3, seed=0):
+                   width=WIDTH, spare=3, seed=0, dtype=jnp.float32):
     """A decode batch with rows at ``positions``: each live row owns just
     the pages its ``pos + 1`` keys need, the rest of its table is the null
     page, and ``spare`` pages belong to no row. Every page holds random
@@ -258,9 +258,9 @@ def ragged_problem(positions, *, h=4, kv_heads=2, d=8, page=PAGE,
         bt[row, :n] = [next(ids) for _ in range(n)]
     lens = np.asarray([p or 0 for p in positions], np.int32)
     pool = (num_pages, page, kv_heads, d)
-    q = jnp.asarray(rng.standard_normal((len(positions), 1, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((len(positions), 1, h, d)), dtype)
+    kp = jnp.asarray(rng.standard_normal(pool), dtype)
+    vp = jnp.asarray(rng.standard_normal(pool), dtype)
     return q, kp, vp, jnp.asarray(bt), jnp.asarray(lens)
 
 
@@ -289,6 +289,13 @@ def through(variant, npb=NPB):
 VARIANTS = ["fp", "int8", "mesh", "jit"]
 
 
+def in_float32(reference, q, kp, vp, bt, lens, **kw):
+    """The reference's float32 arithmetic of a problem's own numbers (bf16
+    pools: what their kernel result rounds)."""
+    q, kp, vp = (x.astype(jnp.float32) for x in (q, kp, vp))
+    return reference(q, kp, vp, bt, lens, **kw)
+
+
 class TestKernelWalksOwnKV:
     @pytest.mark.parametrize("npb", [1, NPB, WIDTH])
     @pytest.mark.parametrize("variant", VARIANTS)
@@ -298,6 +305,21 @@ class TestKernelWalksOwnKV:
         problem = ragged_problem(EDGES)
         kernel, reference = through(variant, npb)
         assert_rows_match(kernel(*problem), reference(*problem), problem[3])
+
+    @pytest.mark.parametrize("npb", [1, NPB, WIDTH])
+    def test_ragged_rows_of_bf16_pages(self, npb):
+        """bf16 pools: the products' operands are bf16 (a bf16 x bf16 product
+        is exact in float32, the softmax weights are rounded to bf16 before
+        the weighted sum, as the reference's are), the statistics float32:
+        the float32 arithmetic of the same bf16 numbers to the output's
+        rounding; absent rows exactly zero."""
+        problem = ragged_problem(EDGES, dtype=jnp.bfloat16)
+        kernel, reference = through("fp", npb)
+        out = kernel(*problem)
+        assert out.dtype == jnp.bfloat16
+        assert_rows_match(
+            out.astype(jnp.float32), in_float32(reference, *problem),
+            problem[3], tol=2e-2)
 
     @pytest.mark.parametrize("variant", VARIANTS)
     def test_absent_rows_are_zero_and_move_no_live_row(self, variant):
@@ -336,24 +358,33 @@ class TestKernelWalksOwnKV:
         assert (np.asarray(kernel(q, kp, vp, bt, lens))[live] == clean[live]).all()
         assert (np.asarray(reference(q, kp, vp, bt, lens))[live] == ref[live]).all()
 
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("kv_heads, group, width, sm_scale", [
         (2, 12, 256, None),  # StarCoder2-3B: 24 query heads, 4,096 tokens a row
         (1, 20, 128, None),  # Jamba2-3B's attention layers: 2,048 tokens a row
         # granite-4.0-h-small's: 32 query heads on 8, scores scaled by 1/128
         (8, 4, 128, 1 / 128),
         (8, 4, 128, None),
+        (8, 8, 64, None),  # K-EXAONE's: 64 query heads on 8
+        (32, 1, 64, None),  # Olmo-Hybrid's: 30 on 30 held as 32 on 32
+        (3, 2, 64, None),  # a KV head count that is no power of two
     ])
     def test_the_cells_head_groupings_and_table_widths(
-            self, kv_heads, group, width, sm_scale):
+            self, kv_heads, group, width, sm_scale, dtype):
+        """Every cell's grouping (a score column is a (key, KV head): a
+        query head keeps its own KV head's), float32 pools to summation order
+        and bf16 pools to the output's rounding of the float32 arithmetic of
+        the same numbers."""
         problem = ragged_problem(
             [130, None, 0, 127], h=kv_heads * group, kv_heads=kv_heads,
-            d=128, page=16, width=width, seed=3,
+            d=128, page=16, width=width, seed=3, dtype=jnp.dtype(dtype),
         )
         kernel, reference = through("fp", npb=8)
         kw = {} if sm_scale is None else {"sm_scale": sm_scale}
         assert_rows_match(
-            kernel(*problem, **kw), reference(*problem, **kw), problem[3],
-            tol=1e-5,
+            kernel(*problem, **kw).astype(jnp.float32),
+            in_float32(reference, *problem, **kw), problem[3],
+            tol=1e-5 if dtype == "float32" else 2e-2,
         )
 
     @pytest.mark.parametrize("variant", VARIANTS)

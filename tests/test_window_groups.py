@@ -233,16 +233,18 @@ def pools_and_tables(positions, window, *, heads=8, kv_heads=2, d=16,
         np.asarray(positions, np.int32))
 
 
+@pytest.mark.parametrize("heads, kv_heads", [(8, 2), (6, 3)])
 @pytest.mark.parametrize("window", [8, 6, 13])
-def test_the_interpreted_kernel_is_the_gather_path(window):
+def test_the_interpreted_kernel_is_the_gather_path(window, heads, kv_heads):
     """Positions under, at and past the window; a window whose first key
-    stands last in its page (``pos - window + 1 = 3 mod 4``) and first in it;
+    stands last in its page (``pos - window + 1 = 3 mod 4``) and first in it,
+    inside its block and at its start (``pos - window + 1`` a multiple of 4);
     the table's every width in use; two blocks a row (the CPU's block is 2
     pages) with the lower bound in the first."""
     positions = [0, 2, window - 2, window - 1, window, window + 2,
                  window + 3 - (window % 4), 4 * window + 1, 4 * window + 2,
                  61]
-    args = pools_and_tables(positions, window)
+    args = pools_and_tables(positions, window, heads=heads, kv_heads=kv_heads)
     want = pa.paged_window_attention(*args, window=window, kernel="xla")
     got = pa.paged_window_attention(*args, window=window, kernel="interpret")
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-6

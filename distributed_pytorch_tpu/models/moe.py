@@ -243,15 +243,23 @@ def route_top_k(scores: jnp.ndarray, top_k: int):
 #: largest ``s + bias`` (a correction bias an expert, used for the CHOICE
 #: alone), gates ``s_e / sum of the chosen s`` (``scoring_func: sigmoid``,
 #: ``topk_method: noaux_tc`` with one group, ``norm_topk_prob: true``).
-GATINGS = ("softmax_of_top_k", "top_k_of_softmax", "sigmoid_biased")
+#: ``"softmax_biased"``: ``p = softmax(scores)`` over ALL the scores, the
+#: ``top_k`` experts of largest ``p + bias`` (a balancing bias an expert, used
+#: for the CHOICE alone), gates ``p_e`` as they are, NOT renormalised (with
+#: ``top_k`` 1: the one best expert at its own probability).
+GATINGS = (
+    "softmax_of_top_k", "top_k_of_softmax", "sigmoid_biased", "softmax_biased",
+)
+#: The rules that choose by ``score + bias`` (``router_bias [E]``, float32).
+BIASED_GATINGS = ("sigmoid_biased", "softmax_biased")
 
 
 def route(
     scores: jnp.ndarray, top_k: int, gating: str = GATINGS[0], bias=None
 ):
     """``(gates, experts)`` as :func:`route_top_k` gives them, under any of
-    :data:`GATINGS`. ``bias [E]`` is ``"sigmoid_biased"``'s correction bias
-    (``None``: zeros); the other rules take none."""
+    :data:`GATINGS`. ``bias [E]`` is the :data:`BIASED_GATINGS`' correction
+    bias (``None``: zeros); the other rules take none."""
     if gating == "softmax_of_top_k":
         return route_top_k(scores, top_k)
     if gating == "top_k_of_softmax":
@@ -261,6 +269,10 @@ def route(
         _, experts = jax.lax.top_k(s if bias is None else s + bias, top_k)
         chosen = jnp.take_along_axis(s, experts, axis=-1)
         return chosen / jnp.sum(chosen, axis=-1, keepdims=True), experts
+    if gating == "softmax_biased":
+        p = jax.nn.softmax(scores, axis=-1)
+        _, experts = jax.lax.top_k(p if bias is None else p + bias, top_k)
+        return jnp.take_along_axis(p, experts, axis=-1), experts
     raise ValueError(
         f"unknown gating rule {gating!r} (expected one of {GATINGS})"
     )
@@ -280,12 +292,67 @@ def product_mode(paged_kernel, d_model: int, d_ff: int) -> str:
     return mode
 
 
+#: What :class:`RoutedExperts` can be given as its router. ``"linear"``: one
+#: matrix ``router_kernel [d_model, n_experts]``. ``"mlp_carry"``:
+#: :class:`CarryRouter`, a small network with a carry between layers.
+ROUTERS = ("linear", "mlp_carry")
+#: The routers that take the previous routed layer's carry and return theirs.
+CARRY_ROUTERS = ("mlp_carry",)
+
+
+def _router_dot(x, kernel):
+    return jnp.dot(
+        x.astype(ROUTER_DTYPE), kernel.astype(ROUTER_DTYPE),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=ROUTER_DTYPE,
+    )
+
+
+class CarryRouter(nn.Module):
+    """A router that is a small network and hands a state from layer to layer
+    (the ``zaya`` family's): over a token's normed input ``n [tokens, d]`` and
+    the previous routed layer's carry ``r' [tokens, hidden]`` (``None``: the
+    first, zeros)::
+
+        r = W_d n + gamma r'                 the carry it returns, MIXED
+        s = W_3 gelu(W_2 gelu(W_1 rmsnorm(r))) ``[tokens, n_experts]``
+
+    ``gamma`` one learned scalar (``carry_scale``), the GELU exact, all of it
+    in ``ROUTER_DTYPE``. Returns ``(s, r)``."""
+
+    n_experts: int
+    hidden: int
+    norm_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, n, carry=None):
+        kernel = lambda name, shape: self.param(  # noqa: E731
+            name, nn.initializers.lecun_normal(), shape, F32
+        )
+        d, hid = n.shape[-1], self.hidden
+        r = _router_dot(n, kernel("down", (d, hid)))
+        gamma = self.param("carry_scale", nn.initializers.ones_init(), (), F32)
+        if carry is not None:
+            r = r + gamma.astype(ROUTER_DTYPE) * carry.astype(ROUTER_DTYPE)
+        y = nn.RMSNorm(epsilon=self.norm_eps, dtype=F32, name="norm")(
+            r
+        ).astype(ROUTER_DTYPE)
+        for name in ("w1", "w2"):
+            y = jax.nn.gelu(
+                _router_dot(y, kernel(name, (hid, hid))), approximate=False
+            )
+        return _router_dot(y, kernel("w3", (hid, self.n_experts))), r
+
+
 class RoutedExperts(nn.Module):
     """Dropless top-k routed gated-SiLU experts over a held range (module
-    docstring). ``[B, T, d_model] -> [B, T, d_model]``.
+    docstring). ``[B, T, d_model] -> [B, T, d_model]``; with a router of
+    :data:`CARRY_ROUTERS`, ``(that, the router's carry [B, T, hidden])``, and
+    the call takes the previous routed layer's ``carry``.
 
-    Parameters: ``router_kernel [d_model, n_experts]`` (no bias; under the
-    ``"sigmoid_biased"`` rule also ``router_bias [n_experts]``, float32),
+    Parameters: ``router_kernel [d_model, n_experts]`` (no bias; with
+    ``router="mlp_carry"`` :class:`CarryRouter`'s under ``router`` instead;
+    under the :data:`BIASED_GATINGS` also ``router_bias [n_experts]``, float32),
     ``in_kernel [held, d_model, 2 d_ff]`` (``[gate, up]``) and ``out_kernel
     [held, d_ff, d_model]``. ``live`` (optional) marks what carries a
     request: ``[B]`` whole batch rows (the batched decode step's rows inside
@@ -304,11 +371,15 @@ class RoutedExperts(nn.Module):
     # What the gates are multiplied by after the rule has made them (a
     # configuration's ``routed_scaling_factor``), in float32; 1.0 is none.
     scale: float = 1.0
+    router: str = ROUTERS[0]  # one of ROUTERS
+    router_hidden: int = 0  # the width of a router that is a network
+    norm_eps: float = 1e-5  # of such a router's norm
 
     @nn.compact
     def __call__(
-        self, x: jnp.ndarray, *, live: Optional[jnp.ndarray] = None
-    ) -> jnp.ndarray:
+        self, x: jnp.ndarray, *, live: Optional[jnp.ndarray] = None,
+        carry: Optional[jnp.ndarray] = None,
+    ):
         batch, t, d = x.shape
         lo, hi = self.held or (0, self.n_experts)
         if not 0 <= lo < hi <= self.n_experts:
@@ -323,18 +394,25 @@ class RoutedExperts(nn.Module):
         tokens = batch * t
         flat = x.reshape(tokens, d)
 
+        if self.router not in ROUTERS:
+            raise ValueError(
+                f"unknown router {self.router!r} (expected one of {ROUTERS})"
+            )
         with jax.named_scope("moe.route"):
-            router = self.param(
-                "router_kernel", nn.initializers.normal(0.02),
-                (d, self.n_experts), F32,
-            )
-            scores = jnp.dot(
-                flat.astype(ROUTER_DTYPE), router.astype(ROUTER_DTYPE),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=ROUTER_DTYPE,
-            )
+            if self.router == "mlp_carry":
+                scores, carry = CarryRouter(
+                    self.n_experts, self.router_hidden, self.norm_eps,
+                    name="router",
+                )(flat, None if carry is None else carry.reshape(tokens, -1))
+                carry = carry.reshape(batch, t, -1)
+            else:
+                router = self.param(
+                    "router_kernel", nn.initializers.normal(0.02),
+                    (d, self.n_experts), F32,
+                )
+                scores = _router_dot(flat, router)
             bias = None
-            if self.gating == "sigmoid_biased":
+            if self.gating in BIASED_GATINGS:
                 bias = self.param(
                     "router_bias", nn.initializers.zeros, (self.n_experts,),
                     F32,
@@ -397,4 +475,5 @@ class RoutedExperts(nn.Module):
             out = jnp.where(in_group[:, None], out * weight[:, None], 0.0)
             back = jnp.argsort(order)  # pair (token, choice) -> its row
             y = out[back].reshape(tokens, k, d).sum(axis=1)
-        return y.reshape(batch, t, d).astype(x.dtype)
+        y = y.reshape(batch, t, d).astype(x.dtype)
+        return (y, carry) if self.router in CARRY_ROUTERS else y

@@ -42,8 +42,13 @@ def apply_rope(
     theta: float = 10000.0,
     positions: Optional[jnp.ndarray] = None,
     scale: float = 1.0,
+    rotary_dim: Optional[int] = None,
 ) -> jnp.ndarray:
     """Rotary position embedding over [B, T, H, D].
+
+    ``rotary_dim`` (``None``: all of ``D``) rotates the FIRST that many of a
+    head's dimensions, paired by halves inside them, and passes the rest (a
+    configuration's ``partial_rotary_factor``).
 
     ``positions`` ([T] int/float) defaults to global positions 0..T-1; the
     decode path passes the cache offset so a single-token step rotates by its
@@ -57,6 +62,11 @@ def apply_rope(
     raising ``theta`` is the NTK-aware alternative (slower frequency decay).
     Both are plain parameterizations here — which to use, and any
     finetuning, is the caller's policy."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = apply_rope(
+            x[..., :rotary_dim], theta=theta, positions=positions, scale=scale
+        )
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d_half = x.shape[-1] // 2
     freqs = theta ** (-jnp.arange(0, d_half, dtype=jnp.float32) / d_half)
     if positions is None:
@@ -90,6 +100,31 @@ def pool_kv_heads(kv_heads: int) -> int:
     if kv_heads <= 8 or kv_heads % 8 == 0:
         return kv_heads
     return -(-kv_heads // 8) * 8
+
+
+def page_slots(block_tables, positions, page: int, valid_lens=None):
+    """Where a paged K/V layer writes the tokens at ``positions [S, T]`` of
+    rows with ``block_tables [S, pages_per_seq]``: ``(physical page,
+    in-page offset)``, each ``[S * T]``. A position at or past the row's table
+    capacity (a speculative chunk's tail can overhang the final tokens of a
+    sequence near max_seq_len) is routed to the reserved null page (id 0)
+    instead of letting the clipped logical index alias into the row's LAST
+    page, where it would clobber valid K/V at the same in-page offset. The
+    null page absorbs the garbage exactly like inactive rows' writes; the
+    visibility mask keeps it dead on every read. The padding of a prefill
+    piece (a position at or past ``valid_lens``) goes the same way: a real
+    token never attends to it (causal), and its own K/V must land on no page
+    a read can see."""
+    s, t_step = positions.shape
+    pages_per_seq = block_tables.shape[1]
+    flat_pos = positions.reshape(-1)  # [S*T_step]
+    logical = jnp.clip(flat_pos // page, 0, pages_per_seq - 1)
+    rows = jnp.repeat(jnp.arange(s, dtype=jnp.int32), t_step)
+    phys = block_tables[rows, logical]  # [S*T_step]
+    kept = flat_pos < pages_per_seq * page
+    if valid_lens is not None:
+        kept &= token_mask(valid_lens, t_step).reshape(-1)
+    return jnp.where(kept, phys, 0), flat_pos % page
 
 
 class Attention(nn.Module):
@@ -542,7 +577,6 @@ class Attention(nn.Module):
         s, t_step, h, d = q_raw.shape
         kv_heads = k_raw.shape[2]
         page = self.page_size
-        pages_per_seq = block_tables.shape[1]
 
         seq_lens = seq_lens.astype(jnp.int32)
         positions = seq_lens[:, None] + jnp.arange(t_step, dtype=jnp.int32)
@@ -563,26 +597,8 @@ class Attention(nn.Module):
             q, k, v = widen(q, h // kv_heads), widen(k, 1), widen(v, 1)
             kv_heads = held
 
-        # Scatter this step's K/V into (physical page, in-page offset). A
-        # position at or past the row's table capacity — a speculative
-        # chunk's tail can overhang the final tokens of a sequence near
-        # max_seq_len — is routed to the reserved null page (id 0) instead
-        # of letting the clipped logical index alias into the row's LAST
-        # page, where it would clobber valid K/V at the same in-page
-        # offset. The null page absorbs the garbage exactly like inactive
-        # rows' writes; the visibility mask keeps it dead on every read.
-        # The padding of a prefill piece (a position at or past ``seq_lens
-        # + valid_lens``) goes the same way: a real token never attends to
-        # it (causal), and its own K/V must land on no page a read can see.
-        flat_pos = positions.reshape(-1)  # [S*T_step]
-        logical = jnp.clip(flat_pos // page, 0, pages_per_seq - 1)
-        rows = jnp.repeat(jnp.arange(s, dtype=jnp.int32), t_step)
-        phys = block_tables[rows, logical]  # [S*T_step]
-        kept = flat_pos < pages_per_seq * page
-        if valid_lens is not None:
-            kept &= token_mask(valid_lens, t_step).reshape(-1)
-        phys = jnp.where(kept, phys, 0)
-        offset = flat_pos % page
+        # Scatter this step's K/V into (physical page, in-page offset).
+        phys, offset = page_slots(block_tables, positions, page, valid_lens)
         if self.kv_quant:
             # Quantize at the write: symmetric absmax per-(token, head) over
             # D — the pool holds int8, the [num_pages, page_size, Hkv] scale
@@ -737,15 +753,21 @@ class MLPBlock(nn.Module):
 
 LAYER_TYPES = (
     "attention", "mamba", "mamba2", "latent", "latent_sparse", "latent_window",
-    "gated_delta", "attention_window",
+    "gated_delta", "attention_window", "cca",
 )
 #: The layer types that are :class:`Attention`: the plain one keeps the
 #: model's window (``attention_window``), ``rope`` and ``rope_theta``; an
 #: ``"attention_window"`` layer takes its own from ``attention_variants`` and,
 #: served through pages, stands on its window GROUP's block tables.
 ATTENTION_TYPES = ("attention", "attention_window")
-#: The layer types that keep a per-slot recurrent state in decode mode.
-RECURRENT_TYPES = ("mamba", "mamba2", "gated_delta")
+#: The layer types that keep a per-slot recurrent state in decode mode. A
+#: ``"cca"`` layer (models/cca.py) is in BOTH this set and the next: its K and
+#: V go to pages, and the last token's conv inputs and half value to its slot.
+RECURRENT_TYPES = ("mamba", "mamba2", "gated_delta", "cca")
+#: The layer types whose K and V pages stand on the sequence's ONE block table
+#: and are read by ``ops/paged_attention.py``'s K/V calls (a model that names
+#: no layer types has such a layer everywhere).
+FULL_KV_TYPES = ("attention", "cca")
 #: The layer types that are models/mla.py's LatentAttention: the plain one,
 #: one with an indexer (learned sparse attention; a second page pool), one
 #: with a window. The last two take their sizes from ``latent_variants``.
@@ -823,6 +845,20 @@ class TransformerBlock(nn.Module):
     # An "attention_window" layer's own ``window``, ``rope`` and
     # ``rope_theta`` as (field, value) pairs (TransformerLM.attention_variants).
     attention: tuple = ()
+    cca: tuple = ()  # a "cca" layer's own fields (TransformerLM.cca_options)
+    # ``x <- a * x + b * f(norm(x))`` with learned ``[d_model]`` vectors ``a``
+    # and ``b`` a sublayer (``attn_skip_scale``, ``attn_branch_scale``,
+    # ``mlp_skip_scale``, ``mlp_branch_scale``; float32, ones at init).
+    residual_scales: bool = False
+
+    @property
+    def carries_router(self) -> bool:
+        """Whether the block takes and returns its router's carry (a routed
+        layer whose router has one: models/moe.py ``CARRY_ROUTERS``)."""
+        from distributed_pytorch_tpu.models.moe import CARRY_ROUTERS
+
+        return self.ffn == "routed" and dict(self.routed).get(
+            "router", "linear") in CARRY_ROUTERS
 
     @nn.compact
     def __call__(
@@ -834,7 +870,10 @@ class TransformerBlock(nn.Module):
         state_slots: Optional[jnp.ndarray] = None,
         valid_lens: Optional[jnp.ndarray] = None,
         row_groups=None,
-    ) -> jnp.ndarray:
+        router_carry: Optional[jnp.ndarray] = None,
+    ):
+        """``x`` after the block; where :attr:`carries_router`, ``(x, the
+        router's carry for the next layer)``."""
         def drop(y):
             # Active only when a "dropout" rng is supplied (the train step
             # with TrainState.rng armed); eval/decode never pass one, so
@@ -886,6 +925,17 @@ class TransformerBlock(nn.Module):
                 decode=self.decode, kernel=self.paged_kernel,
                 name="gated_delta", **dict(self.mamba),
             )(normed, seq_lens=seq_lens, state_slots=state_slots, **piece_kw)
+        elif self.mixer == "cca":
+            from distributed_pytorch_tpu.models.cca import CCAttention
+
+            mixed = CCAttention(
+                self.d_model, self.n_heads, self.n_kv_heads or self.n_heads,
+                self.head_dim or self.d_model // self.n_heads,
+                rope_theta=self.rope_theta, dtype=self.dtype,
+                decode=self.decode, page_size=self.page_size,
+                num_pages=self.num_pages, paged_kernel=self.paged_kernel,
+                name="cca", **dict(self.cca),
+            )(normed, state_slots=state_slots, **paged_kw, **piece_kw)
         elif self.mixer in LATENT_TYPES:
             from distributed_pytorch_tpu.models.mla import LatentAttention
 
@@ -929,9 +979,25 @@ class TransformerBlock(nn.Module):
                 return branch
             return branch * jnp.asarray(self.residual_multiplier, branch.dtype)
 
+        def merged(x, branch, sublayer):
+            branch = drop(scaled(branch))
+            if not self.residual_scales:
+                return x + branch
+            skip, gain = (
+                self.param(
+                    f"{sublayer}_{name}_scale", nn.initializers.ones_init(),
+                    (self.d_model,), jnp.float32,
+                ).astype(jnp.float32)
+                for name in ("skip", "branch")
+            )
+            return (
+                x.astype(jnp.float32) * skip
+                + branch.astype(jnp.float32) * gain
+            ).astype(x.dtype)
+
         if on_output:
             mixed = ln_attn(mixed).astype(x.dtype)
-        x = x + drop(scaled(mixed))
+        x = merged(x, mixed, "attn")
         ln_mlp = make_norm(self.norm, self.norm_eps, "ln_mlp")
         normed = x if on_output else ln_mlp(x)
         if self.ffn == "routed":
@@ -941,7 +1007,12 @@ class TransformerBlock(nn.Module):
                 d_ff=self.d_ff, d_model=self.d_model, dtype=self.dtype,
                 paged_kernel=self.paged_kernel, name="experts",
                 **dict(self.routed),
-            )(normed, live=live_tokens(state_slots, valid_lens, x.shape[1]))
+            )(
+                normed, live=live_tokens(state_slots, valid_lens, x.shape[1]),
+                **({"carry": router_carry} if self.carries_router else {}),
+            )
+            if self.carries_router:
+                fed, router_carry = fed
             if self.shared_d_ff:
                 fed = fed + MLPBlock(
                     self.shared_d_ff, self.d_model, self.dtype,
@@ -966,7 +1037,8 @@ class TransformerBlock(nn.Module):
             )(normed)
         if on_output:
             fed = ln_mlp(fed).astype(x.dtype)
-        return x + drop(scaled(fed))
+        x = merged(x, fed, "mlp")
+        return (x, router_carry) if self.carries_router else x
 
 
 class LMHead(nn.Module):
@@ -1143,6 +1215,16 @@ class TransformerLM(nn.Module):
     # handed the group's short tables as ``window_tables``.
     attention_variants: Optional[tuple] = None
     window_num_pages: int = 0
+    # A "cca" layer is models/cca.py's CCAttention on the model's ``n_heads``,
+    # ``n_kv_heads``, ``head_dim`` and ``rope_theta``; these are its own
+    # fields as (field, value) pairs (``time0``, ``time1``, ``rotary_dim`` and
+    # the options that file lists). In decode mode it keeps K and V pages on
+    # the sequence's table AND a per-slot state (``recurrent_layers`` counts
+    # it), so ``__call__`` must be told ``state_slots``.
+    cca_options: tuple = ()
+    # ``x <- a * x + b * f(norm(x))`` with learned vectors a sublayer
+    # (TransformerBlock.residual_scales).
+    residual_scales: bool = False
     # Scalars some families put on the residual stream (defaults: none).
     # The scores' scale (None = head_dim ** -0.5) reaches every attention path
     # and the paged kernel; the others multiply the embedding, each branch
@@ -1164,6 +1246,12 @@ class TransformerLM(nn.Module):
     shared_d_ff: int = 0
     routed_gating: str = "softmax_of_top_k"  # one of models/moe.py's GATINGS
     routed_scale: float = 1.0  # on the routed gates (``routed_scaling_factor``)
+    # The routed layers' router, one of models/moe.py's ROUTERS, and the
+    # width of one that is a network. A router with a carry (``"mlp_carry"``)
+    # hands its mixed hidden state from each routed layer to the next: the
+    # layer loop below carries it beside ``x``.
+    routed_router: str = "linear"
+    routed_router_hidden: int = 0
     # A "dense" layer's width where it is not ``d_ff`` (which stays the
     # routed experts'); 0 = ``d_ff``.
     dense_d_ff: int = 0
@@ -1335,6 +1423,8 @@ class TransformerLM(nn.Module):
             residual_multiplier=self.residual_multiplier,
             norm_placement=self.norm_placement, qk_norm=self.qk_norm,
         )
+        if self.residual_scales:
+            block_kw["residual_scales"] = True
         if self.head_dim:
             block_kw["head_dim"] = self.head_dim
         mixer_kw = {
@@ -1352,6 +1442,7 @@ class TransformerLM(nn.Module):
                 ("d_v", self.linear_d_v), ("d_conv", self.linear_d_conv),
                 ("neg_eigval", self.linear_neg_eigval),
             ),
+            "cca": tuple(self.cca_options),
         }
         for latent_type in LATENT_TYPES:
             if latent_type in (types or ()):
@@ -1375,9 +1466,16 @@ class TransformerLM(nn.Module):
             routed += (("gating", self.routed_gating),)
         if self.routed_scale != 1.0:
             routed += (("scale", self.routed_scale),)
+        if self.routed_router != "linear":
+            routed += (
+                ("router", self.routed_router),
+                ("router_hidden", self.routed_router_hidden),
+                ("norm_eps", self.norm_eps),
+            )
         routed_kw = dict(
             ffn="routed", shared_d_ff=self.shared_d_ff, routed=routed
         )
+        router_carry = None  # of the last routed layer whose router has one
         for i in range(self.n_layers):
             # GShard-style interleaving: every `moe_every`-th block is MoE.
             moe = self.n_experts if (i + 1) % self.moe_every == 0 else 0
@@ -1385,6 +1483,8 @@ class TransformerLM(nn.Module):
             layer_paged, num_pages = paged_kw, self.num_pages
             if types is not None and types[i] != "attention":
                 sizes = "latent" if types[i] in LATENT_TYPES else "mamba"
+                if types[i] == "cca":
+                    sizes = "cca"
                 if types[i] == "attention_window":
                     # The window group's tables and pool size.
                     sizes = "attention"
@@ -1407,7 +1507,7 @@ class TransformerLM(nn.Module):
                 d_ff = self.d_ff
             else:
                 d_ff = self.dense_d_ff or self.d_ff
-            x = block(
+            layer = block(
                 self.n_heads, self.d_model, d_ff, self.dtype,
                 True, self.mesh, self.sequence_axis,
                 sequence_mode=self.sequence_mode,
@@ -1420,7 +1520,13 @@ class TransformerLM(nn.Module):
                 page_size=self.page_size, num_pages=num_pages,
                 paged_kernel=self.paged_kernel, kv_quant=self.kv_quant,
                 name=f"block_{i}", **layer_kw,
-            )(x, **layer_paged)
+            )
+            if layer.carries_router:
+                x, router_carry = layer(
+                    x, router_carry=router_carry, **layer_paged
+                )
+            else:
+                x = layer(x, **layer_paged)
         x = make_norm(self.norm, self.norm_eps, "ln_final")(x)
         if self.fused_head_chunk and self.vocab_size % self.fused_head_chunk:
             # Fail loudly here: a silent dense fallback would surface later as

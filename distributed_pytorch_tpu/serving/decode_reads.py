@@ -17,7 +17,8 @@ GROUP (``shared_prefix_groups``: the shared pages copied once), a
 index key scored, a group's shared blocks once) and a gather of the
 ``index_top_k`` best tokens' latents, a ``"latent_window"`` layer through the
 windowed latent call (the pages that meet the window, every row by itself), a
-``"gated_delta"`` layer through its matrix state, in place. Each kernel's block
+``"gated_delta"`` layer through its matrix state, in place, a ``"cca"`` layer
+through the K/V kernel on the sequence's table AND its slot state. Each kernel's block
 is what its call looks up, by the call's own helper on the layer's own pool
 (``kv_block_pages`` / ``latent_block_pages`` / ``index_block_pages``); a K/V
 kernel's call also names how it computes a block (``pa.KV_BLOCK_FORM``:
@@ -60,11 +61,12 @@ starts no page copy.
   a page past the row's last copied again in its place; on the gather path
   the short table whole. The full layers' ``decode_kv_tokens_*`` count the
   FULL group's table, as in a model with no window;
-* gated-delta layers: ``state_slots_updated`` (rows x such layers) and
-  ``state_bytes_moved`` (each state once in and once out,
-  ``linear_attention.state_bytes_moved``), on every ``step`` slice; a
-  ``prefill.chunk`` slice carries ``state_blocks``, the blocks of 64 tokens a
-  layer evaluated for the piece;
+* gated-delta and CCA layers: ``state_slots_updated`` (rows x such layers) and
+  ``state_bytes_moved`` (each state once in and once out: the matrix state by
+  ``linear_attention.state_bytes_moved``, a CCA layer's slot leaves as the
+  cache tree holds them), on every ``step`` slice; a ``prefill.chunk`` slice
+  carries ``state_blocks``, the blocks a layer evaluated for the piece (of 64
+  tokens under the delta rule; a CCA layer's convolutions take a piece whole);
 * K/V layers of the full group, a prefill piece (:meth:`DecodeReads.prefill_args`):
   a ``prefill.chunk`` slice carries ``keys_walked`` (the whole blocks the
   chunk walk gathers and scores for a piece that ends at ``start + tokens``,
@@ -90,8 +92,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_pytorch_tpu.models.mamba import STATE_DTYPE
-from distributed_pytorch_tpu.models.transformer import LATENT_TYPES
+from distributed_pytorch_tpu.models.mamba import STATE_DTYPE, STATE_KEYS
+from distributed_pytorch_tpu.models.transformer import (
+    FULL_KV_TYPES,
+    LATENT_TYPES,
+)
 from distributed_pytorch_tpu.ops import linear_attention as la
 from distributed_pytorch_tpu.ops import paged_attention as pa
 
@@ -100,6 +105,7 @@ from distributed_pytorch_tpu.ops import paged_attention as pa
 TOTALLED = {
     "latent pages": ("decode_page_copies", "decode_pages_in_runs"),
     "gated_delta": ("state_slots_updated", "state_bytes_moved"),
+    "cca": ("state_slots_updated", "state_bytes_moved"),
     "latent_sparse": (
         "decode_index_tokens_scored", "decode_index_tokens_fetched",
         "decode_index_tokens_scored_distinct", "decode_kv_tokens_selected",
@@ -145,13 +151,13 @@ class DecodeReads:
         self.layers = {
             kind: len(where(kind)) for kind in
             ("latent", "latent_sparse", "latent_window", "gated_delta",
-             "attention_window")
+             "attention_window", "cca")
         }
         self.layers["latent pages"] = len(where(*LATENT_TYPES))
         # The full group's K/V layers: every layer of a model that names no
         # layer types.
         self.layers["attention"] = (
-            len(where("attention")) if kinds else model.n_layers)
+            len(where(*FULL_KV_TYPES)) if kinds else model.n_layers)
         self.page, self.width = model.page_size, pages_per_seq
         self.num_pages = model.num_pages
         self.whole = max_slots * pages_per_seq * self.page
@@ -165,11 +171,17 @@ class DecodeReads:
         if self.layers["attention_window"]:
             self.kv_window = model.kv_window
             self.kv_window_pages = pa.window_pages(self.kv_window, self.page)
+        #: Bytes a decode dispatch moves of state, summed over the layers
+        #: that count theirs, for ONE row: each state once in and once out.
         if self.layers["gated_delta"]:
-            self.state_bytes = la.state_bytes_moved(
+            self.state_bytes = self.layers["gated_delta"] * la.state_bytes_moved(
                 1, model.linear_n_heads, model.linear_d_k, model.linear_d_v,
                 jnp.dtype(STATE_DTYPE).itemsize,
             )
+        if self.layers["cca"]:
+            self.state_bytes += 2 * self.layers["cca"] * sum(
+                _pool(cache, key, where("cca")).nbytes for key in STATE_KEYS
+            ) // max_slots
         #: Whether the decode program returns what its sparse layers selected
         #: (their kernel path sows a list; the gather path masks, keeps none).
         self.selection = bool(
@@ -200,7 +212,7 @@ class DecodeReads:
                 )
             # The full group's K/V layers, and the window group's.
             keys = _pool(
-                cache, "cached_key", where("attention") if kinds else None)
+                cache, "cached_key", where(*FULL_KV_TYPES) if kinds else None)
             if keys is not None:
                 self.blocks["kv"] = pa.kv_block_pages(
                     pages_per_seq, keys, model.dtype)
@@ -221,7 +233,7 @@ class DecodeReads:
         self._counted = len(self.totals) > 1
         if self.layers["attention"]:
             self.totals.update(dict.fromkeys(PREFILL_TOTALLED.values(), 0))
-        # A model with gated-delta layers says so on every step slice.
+        # A model whose layers' state is counted says so on every step slice.
         self._idle = {
             name: 0 for name in TOTALLED["gated_delta"] if name in self.totals
         }
@@ -256,6 +268,8 @@ class DecodeReads:
         out = {}
         if self.layers["gated_delta"]:
             out["state_blocks"] = -(-width // min(la.BLOCK, width))
+        elif self.layers["cca"]:
+            out["state_blocks"] = 1
         if self.layers["attention"]:
             keys = {
                 "keys_walked": int(pa.chunk_keys_walked(
@@ -275,14 +289,15 @@ class DecodeReads:
         program's do). ``traced`` adds what only a ``step`` slice carries (the
         ``*_distinct`` counts allocate ``num_pages`` numbers)."""
         rows, page, blocks = len(positions), self.page, self.blocks
-        plain, sparse, sliding, delta = (self.layers[kind] for kind in (
-            "latent", "latent_sparse", "latent_window", "gated_delta"))
+        plain, sparse, sliding = (self.layers[kind] for kind in (
+            "latent", "latent_sparse", "latent_window"))
+        stateful = self.layers["gated_delta"] + self.layers["cca"]
         groups = self.groups(tables, positions)
         visible = int(positions.sum()) + rows
         out, copies, chosen, read = {}, [], 0, 0
-        if delta:
-            out["state_slots_updated"] = rows * delta
-            out["state_bytes_moved"] = rows * delta * self.state_bytes
+        if stateful:
+            out["state_slots_updated"] = rows * stateful
+            out["state_bytes_moved"] = rows * self.state_bytes
         if sparse:
             out["decode_index_tokens_scored"] = visible
             out["decode_index_tokens_fetched"] = self.whole

@@ -2936,7 +2936,12 @@ class InferenceEngine:
         out["prefill_tokens"] = self.prefill_tokens
         out["prefill_width"] = self.prefill_width
         out.update(self.reads.totals)
+        # The kinds of layer the model has, and of router: read off the model.
+        model = self.decode_model
+        out["layer_kinds"] = ",".join(sorted(set(
+            getattr(model, "layer_types", None) or ("attention",))))
         if self.routed_layers:
+            out["moe_router"] = getattr(model, "routed_router", "linear")
             out["moe_product"] = self.moe_product
             out["moe_pairs_held"] = self.moe_pairs_held
             out["moe_rows_computed"] = self.moe_rows_computed

@@ -137,6 +137,12 @@ CASES = {
         paged_case, heads=64, kv_heads=8, quantized=False, slots=32,
         pages_per_seq=800, num_pages=9217,
     ),
+    # zaya1-8b's 96 slots of 192 pages out of 8,193, 8 query heads on 2
+    # (StarCoder2's pool geometry at a third of its grouping).
+    "paged-zaya1-8b": functools.partial(
+        paged_case, heads=8, kv_heads=2, quantized=False, slots=96,
+        pages_per_seq=192, num_pages=8193,
+    ),
     **{
         f"flash-T{t}-{'grad' if grad else 'fwd'}": functools.partial(
             flash_case, t=t, grad=grad
@@ -785,9 +791,103 @@ def test_the_exaone_cells_prefill_programs_compile_for_v5e(chip, t_step):
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
 
 
+# ``zaya1-8b`` (PR 49): every layer compressed convolutional attention (K and V
+# pages AND a two-token slot state) and ONE expert of 16 by a router network.
+
+
+def zaya_program(chip, t_step, layers=2):
+    """A serving program of the ``zaya1-8b`` configuration at its own shapes
+    (published widths, all 16 experts, the 262,272-row tied head, the cell's
+    engine), lowered for the described chip on abstract operands: the decode
+    step over all 96 slots (``t_step`` 1, greedy over the whole vocabulary)
+    or a prefill piece of ``t_step`` tokens. ``layers`` of the 20 (the whole
+    depth compiled by hand: PERF.md 4)."""
+    import json
+
+    from hybrid_toy import ROOT, load_by_path
+
+    reference = load_by_path("benchmarks/reference/zaya.py")
+    driver = load_by_path("benchmarks/drivers/serve_cca_moe.py")
+    with open(os.path.join(
+            ROOT, "benchmarks", "configs", "zaya1-8b.json")) as f:
+        cfg = json.load(f)
+    cfg = dict(cfg, num_hidden_layers=layers,
+               layer_types=cfg["layer_types"][:layers])
+    engine = cfg["assumed"]["engine"]
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+            tree)
+
+    weights = jax.eval_shape(lambda: reference.make_weights(cfg, 0))
+    model, params = driver.build_program(cfg, weights)
+    decode_model = model.clone(
+        decode=True, page_size=engine["page_size"],
+        num_pages=engine["num_pages"], paged_kernel="pallas")
+    slots = engine["max_slots"]
+    rows = slots if t_step == 1 else 1
+    cache = jax.eval_shape(
+        decode_model.init, jax.random.PRNGKey(0),
+        jnp.zeros((slots, 1), jnp.int32))["cache"]
+
+    def run(params, cache, tokens, tables, lens, valid, slots):
+        kw = {} if t_step == 1 else {"valid_lens": valid}
+        logits, updated = decode_model.apply(
+            {"params": params, "cache": cache}, tokens, block_tables=tables,
+            seq_lens=lens, state_slots=slots, mutable=["cache", "routing"],
+            **kw)
+        if t_step == 1:
+            return jnp.argmax(logits[:, -1], axis=-1), updated["cache"]
+        return updated["cache"]  # a piece samples nothing: no head
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    return jax.jit(run, donate_argnums=(1,)).lower(
+        abstract(params), abstract(cache), arg((rows, t_step)),
+        arg((rows, engine["max_seq_len"] // engine["page_size"])),
+        arg((rows,)), arg((rows,)), arg((rows,)))
+
+
+def kernel_names(compiled):
+    return [line.split(" = ", 1)[0].strip().lstrip("%")
+            for line in compiled.as_text().splitlines()
+            if "tpu_custom_call" in line and " = " in line]
+
+
+def test_the_zaya_cells_decode_program_calls_the_kernels_it_has(chip):
+    """A layer calls the K/V decode kernel once (under the name the
+    benchmark's readers tell it by) and the experts' weight-stationary
+    product twice, at 96 rows on ONE expert each; neither pool is copied
+    whole, the slot state is updated in place, and beside weights and pools
+    the program needs under 60 MB (the float32 logits ``[96, 262272]`` are
+    never whole under a greedy choice: 6 MB compiled at two layers, 40 MB at
+    all twenty, by hand)."""
+    compiled = zaya_program(chip, 1).compile()
+    names = kernel_names(compiled)
+    assert sum(n.startswith("attention._paged_decode_step") for n in names) == 2
+    assert sum(n.startswith("ragged-dot-stationary") for n in names) == 4
+    assert not whole_pool_copies(compiled, 8193)
+    assert compiled.memory_analysis().temp_size_in_bytes < 60e6
+
+
+@pytest.mark.parametrize("t_step", [64, 512])
+def test_the_zaya_cells_prefill_programs_compile_for_v5e(chip, t_step):
+    """A piece's convolutions, shift and walk over its blocks are plain XLA:
+    the only kernels are the experts' products; the widest piece needs under
+    200 MB beside weights and pools (145 MB at all twenty layers, by hand)."""
+    compiled = zaya_program(chip, t_step).compile()
+    names = kernel_names(compiled)
+    assert names and all(n.startswith("ragged-dot-stationary") for n in names)
+    assert not whole_pool_copies(compiled, 8193)
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
 @pytest.mark.parametrize("name", [
     "paged-sc2-3b", "paged-jamba2-3b", "paged-granite-4.0-h-small",
     "paged-k-exaone-236b-a23b", "paged-olmo-hybrid-7b", "paged-H16-Hkv8-int8",
+    "paged-zaya1-8b",
 ])
 def test_a_block_is_computed_on_its_tiles_as_stored(chip, name):
     """The K/V kernel's body (its jaxpr) at the cells' geometries: both

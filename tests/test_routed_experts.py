@@ -404,7 +404,7 @@ def test_masked_tokens_of_a_piece_reach_no_expert_under_any_gating(
     live = jnp.asarray(np.arange(24) < 17)[None]
     params = {"router_kernel": w["router"], "in_kernel": w["we_in"][2:7],
               "out_kernel": w["we_out"][2:7]}
-    if gating == "sigmoid_biased":  # the rule that has a correction bias
+    if gating in moe.BIASED_GATINGS:  # the rules that have a correction bias
         params["router_bias"] = jnp.asarray(
             0.2 * np.random.default_rng(32).standard_normal(E), jnp.float32)
 
@@ -487,3 +487,101 @@ def test_the_eight_shares_of_scaled_sigmoid_gates_are_the_whole_layer(mode):
     assert np.abs(sum(parts) + shared - whole).max() < 2 * TOL
     assert np.abs(part((0, E)) - 2.5 * part((0, E), scale=1.0)).max() < 2 * TOL
     assert np.abs(part((0, E), scale=1.0)).max() > 0.01
+
+
+# ------------- the zaya rule: the ONE best of softmax + bias, a router network
+
+
+def test_the_best_of_softmax_plus_bias_at_its_own_probability():
+    """``GATINGS``' fourth rule: a softmax over ALL the scores, the ``top_k``
+    experts of largest ``p + bias`` (the bias for the CHOICE alone), gates
+    their own probabilities, not renormalised."""
+    rng = np.random.default_rng(41)
+    scores = jnp.asarray(rng.standard_normal((9, E)), jnp.float32)
+    bias = jnp.asarray(0.3 * rng.standard_normal(E), jnp.float32)
+    p = np.asarray(jax.nn.softmax(scores, axis=-1))
+    for k in (1, 3):
+        gates, experts = moe.route(scores, k, "softmax_biased", bias)
+        want = np.argsort(-(p + np.asarray(bias)), axis=-1)[:, :k]
+        assert np.array_equal(np.asarray(experts), want)
+        assert np.allclose(np.asarray(gates),
+                           np.take_along_axis(p, want, axis=-1), atol=1e-7)
+        assert np.all(np.asarray(gates).sum(-1) < 1.0)
+    plain, _ = moe.route(scores, 1, "softmax_biased")
+    assert np.allclose(np.asarray(plain)[:, 0], p.max(axis=-1), atol=1e-7)
+    # The bias moves choices, never a gate of the same expert.
+    _, unbiased = moe.route(scores, 1, "softmax_biased")
+    _, biased = moe.route(scores, 1, "softmax_biased", bias)
+    assert (np.asarray(unbiased) != np.asarray(biased)).any()
+
+
+#: sha256 (first 16) of the layer's float32 output bytes on seeded inputs,
+#: recorded at commit 640088f (PR 48), before the router became an option.
+LINEAR_ROUTER_BITS = {
+    "softmax_of_top_k": "45af69df8239f168",
+    "top_k_of_softmax": "ef0c61535040f9a1",
+    "sigmoid_biased": "5dc6d65f707a6f53",
+}
+
+
+@pytest.mark.parametrize("gating", sorted(LINEAR_ROUTER_BITS))
+def test_a_model_on_the_linear_router_gives_the_bits_it_gave(gating):
+    """The default router keeps its parameter names and its arithmetic: the
+    nine configurations before the router network see the same layer."""
+    import hashlib
+
+    rng = np.random.default_rng(49)
+    f = lambda *s: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)  # noqa: E731
+    params = {"router_kernel": f(D, E), "in_kernel": f(5, D, 2 * F),
+              "out_kernel": f(5, F, D), "router_bias": f(E) / 3}
+    x = f(2, 12, D)
+    biased = gating == "sigmoid_biased"
+    if not biased:
+        params.pop("router_bias")
+    layer = RoutedExperts(E, K, F, D, held=(2, 7), gating=gating,
+                          scale=2.5 if biased else 1.0)
+    out = layer.apply({"params": params}, x)
+    assert hashlib.sha256(np.asarray(out).tobytes()).hexdigest()[:16] == (
+        LINEAR_ROUTER_BITS[gating])
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"]
+    assert sorted(shapes) == sorted(params)
+
+
+@pytest.mark.parametrize("carried", [False, True])
+def test_the_router_network_with_its_carry_matches_the_reference(mode, carried):
+    """``router="mlp_carry"`` under the fourth rule at ONE expert a token,
+    every expert held: the layer's output, its counts and the carry it hands
+    on are ``benchmarks/reference/zaya.py``'s, with and without a carry from
+    the layer before."""
+    import zaya_toy
+
+    ref, cfg = zaya_toy.reference, zaya_toy.TOY
+    d, f_, e, r = (cfg["hidden_size"], cfg["moe_intermediate_size"],
+                   cfg["num_experts"], cfg["router_hidden_size"])
+    w = {k: v.astype(jnp.float32) for k, v in ref.make_weights(
+        cfg, zaya_toy.SEED)["layers"][1].items()}
+    rng = np.random.default_rng(51)
+    x = jnp.asarray(rng.standard_normal((20, d)), jnp.float32)
+    carry = jnp.asarray(rng.standard_normal((20, r)), jnp.float32) * carried
+    want, routed, want_carry = ref.experts(
+        x, w, carry, cfg=cfg, einsum=jnp.einsum)
+    layer = RoutedExperts(
+        e, 1, f_, d, held=(0, e), gating="softmax_biased", router="mlp_carry",
+        router_hidden=r, paged_kernel=mode)
+    params = {
+        "router": {"down": w["rd"], "carry_scale": w["gamma"],
+                   "norm": {"scale": w["rn_g"]}, "w1": w["r1"],
+                   "w2": w["r2"], "w3": w["r3"]},
+        "router_bias": w["router_bias"], "in_kernel": w["we_in"],
+        "out_kernel": w["we_out"]}
+    (got, got_carry), sown = layer.apply(
+        {"params": params}, x[None], carry=carry[None] if carried else None,
+        mutable=["routing"])
+    assert np.abs(np.asarray(got[0]) - np.asarray(want)).max() < TOL
+    assert np.abs(np.asarray(got_carry[0]) - np.asarray(want_carry)).max() < TOL
+    assert np.array_equal(np.asarray(sown["routing"]["counts"][0]),
+                          np.asarray(routed).sum(axis=0))
+    assert np.asarray(routed).sum(axis=0).max() < 20  # more than one expert
+    with pytest.raises(ValueError, match="unknown router"):
+        RoutedExperts(e, 1, f_, d, router="tree").apply(
+            {"params": params}, x[None])
